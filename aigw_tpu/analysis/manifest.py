@@ -81,6 +81,13 @@ STATE_ONLY: dict[str, str] = {
     "devices": "per-device dict list; DEVICE_GAUGES renders the "
                "labeled /metrics twins",
     "param_bytes_total": "derived sum over param_bytes_by_device",
+    "compile_cache_dir": "where utils/boot.py placed the persistent "
+                         "compile cache, string",
+    "weights": "resolved weight source (random | orbax:<dir>), string",
+    "weights_init_ms": "boot observable: wall time creating or "
+                       "restoring the weights",
+    "weights_quantize_ms": "boot observable: wall time quantizing "
+                           "them (0 when unquantized)",
     "param_bytes_per_device": "per-device dict",
     "migration": "capability flag, boolean",
     "max_slots": "EngineConfig echo; the picker derives free slots",

@@ -144,9 +144,6 @@ JIT_WARM_SURFACE: dict[str, str] = {
         "factory only: Engine.__init__ registers the returned callable "
         "with the CompileTracker as 'adapter_load' and warmup() "
         "pre-compiles it via AdapterStore.warm()"),
-    "aigw_tpu/ops/pallas/paged_attention.py::paged_attention_decode": (
-        "dispatched inside the registered decode programs "
-        "(Engine._decode_fn_for); pre-compiled by warmup()'s ladder"),
     "aigw_tpu/ops/pallas/paged_attention.py::paged_attention_decode_v2": (
         "dispatched inside the registered decode programs "
         "(Engine._decode_fn_for); pre-compiled by warmup()'s ladder"),

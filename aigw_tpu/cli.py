@@ -379,9 +379,17 @@ def main(argv: list[str] | None = None) -> int:
                               "device->host and revived by later "
                               "prefix hits instead of recomputed; 0 "
                               "disables the tier")
+    p_serve.add_argument("--weights", default="", choices=["", "random"],
+                         help="'random' serves the model from seeded "
+                              "random weights (chip bring-up, where no "
+                              "checkpoint exists); default: the model "
+                              "registry's source")
     p_serve.add_argument("--platform", default="",
-                         help="force a JAX platform (e.g. cpu for the "
-                              "fake-chip mode; default: auto/TPU)")
+                         help="JAX platform to serve on (e.g. cpu for "
+                              "the fake-chip mode). Default: whatever "
+                              "JAX_PLATFORMS names; when neither names "
+                              "one a TPU is REQUIRED and boot fails "
+                              "naming what JAX found instead")
     p_serve.add_argument("--log-level", default="info")
 
     args = parser.parse_args(argv)
@@ -693,10 +701,13 @@ def main(argv: list[str] | None = None) -> int:
         return 0
 
     if args.cmd == "tpuserve":
-        if args.platform:
-            import jax
+        from aigw_tpu.utils.boot import BootError, boot_jax
 
-            jax.config.update("jax_platforms", args.platform)
+        try:
+            boot_jax(args.platform)
+        except BootError as e:
+            print(f"tpuserve: {e}", file=sys.stderr)
+            return 1
         return asyncio.run(_run_tpuserve(args))
     return 2
 
@@ -918,6 +929,7 @@ async def _run_tpuserve(args: argparse.Namespace) -> int:
         ep=args.ep,
         sp=args.sp,
         quantize=args.quantize,
+        weights=args.weights,
         lora_adapters=lora_adapters or None,
         lora_slots=args.lora_slots,
         tenant_slot_cap=args.tenant_slot_cap,

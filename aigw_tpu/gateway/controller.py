@@ -66,6 +66,7 @@ import aiohttp
 from aigw_tpu.gateway.fleetstate import DEGRADED, DOWN, UNKNOWN, UP
 from aigw_tpu.gateway.picker import EndpointPicker
 from aigw_tpu.obs.slomon import SLOMonitor
+from aigw_tpu.utils.chips import chip_env
 
 logger = logging.getLogger(__name__)
 
@@ -169,11 +170,19 @@ class LocalProcessLauncher(ReplicaLauncher):
     ``benchmarks/serve_child.py`` topology: one tpuserve process per
     launch, serving the spec's model on a fresh port. SIGTERM on
     terminate rides tpuserve's graceful drain handler, SIGKILL only
-    after ``term_grace_s``."""
+    after ``term_grace_s``.
+
+    One process per chip: with ``chips`` = N the launcher owns chips
+    0..N-1 of this host and confines each replica to the lowest free
+    one through its environment (utils/chips.py) — set here, before
+    the child imports jax. A launch with every chip taken fails instead
+    of piling a second replica onto a chip (which fails or hangs, or —
+    before utils/boot.py — silently served from the CPU). 0 leaves the
+    environment alone (CPU replicas; one replica owning the host)."""
 
     def __init__(self, spec: dict, child_path: str = "",
                  env: dict | None = None, boot_timeout_s: float = 1200.0,
-                 term_grace_s: float = 30.0):
+                 term_grace_s: float = 30.0, chips: int = 0):
         self.spec = dict(spec)
         if not child_path:
             here = os.path.dirname(os.path.abspath(__file__))
@@ -183,6 +192,10 @@ class LocalProcessLauncher(ReplicaLauncher):
         self.env = dict(env or {})
         self.boot_timeout_s = boot_timeout_s
         self.term_grace_s = term_grace_s
+        self.chips = chips
+        #: replica address (or a booting launch's token) → the chip
+        #: index it was confined to
+        self._chip_of: dict[object, int] = {}
         self._procs: dict[str, subprocess.Popen] = {}
         #: exit codes of replicas this launcher terminated (the drain
         #: rig asserts exit 0 — a clean drain, not a SIGKILL)
@@ -197,6 +210,7 @@ class LocalProcessLauncher(ReplicaLauncher):
             env={str(k): str(x) for k, x in (v.get("env") or {}).items()},
             boot_timeout_s=float(v.get("boot_timeout_s", 1200.0)),
             term_grace_s=float(v.get("term_grace_s", 30.0)),
+            chips=int(v.get("chips", 0)),
         )
 
     def _wait_port(self, proc: subprocess.Popen) -> int:
@@ -226,20 +240,44 @@ class LocalProcessLauncher(ReplicaLauncher):
         raise RuntimeError("replica child never reported a port")
 
     async def launch(self) -> str:
-        proc = subprocess.Popen(
-            [sys.executable, self.child_path, json.dumps(self.spec)],
-            stdout=subprocess.PIPE, text=True,
-            env=dict(os.environ, **self.env),
-        )
+        env = dict(os.environ, **self.env)
+        chip = None
+        if self.chips:
+            # held: booting reservations and replicas still alive (a
+            # crashed replica's chip is free again)
+            taken = {c for a, c in self._chip_of.items()
+                     if a not in self._procs
+                     or self._procs[a].poll() is None}
+            free = [c for c in range(self.chips) if c not in taken]
+            if not free:
+                raise RuntimeError(
+                    f"all {self.chips} chips of this host hold a replica")
+            chip = free[0]
+            env.update(chip_env(chip))
+        # reserve before the (slow) boot: concurrent launches must not
+        # pick the same chip
+        booting = object()
+        if chip is not None:
+            self._chip_of[booting] = chip
         try:
-            port = await asyncio.to_thread(self._wait_port, proc)
-        except BaseException:
-            if proc.poll() is None:
-                proc.kill()
-            raise
+            proc = subprocess.Popen(
+                [sys.executable, self.child_path, json.dumps(self.spec)],
+                stdout=subprocess.PIPE, text=True, env=env,
+            )
+            try:
+                port = await asyncio.to_thread(self._wait_port, proc)
+            except BaseException:
+                if proc.poll() is None:
+                    proc.kill()
+                raise
+        finally:
+            self._chip_of.pop(booting, None)
         addr = f"127.0.0.1:{port}"
         self._procs[addr] = proc
-        logger.info("launched replica %s (pid %d)", addr, proc.pid)
+        if chip is not None:
+            self._chip_of[addr] = chip
+        logger.info("launched replica %s (pid %d%s)", addr, proc.pid,
+                    f", chip {chip}" if chip is not None else "")
         return addr
 
     def owns(self, address: str) -> bool:
@@ -260,6 +298,8 @@ class LocalProcessLauncher(ReplicaLauncher):
             except subprocess.TimeoutExpired:
                 proc.kill()
                 await asyncio.to_thread(proc.wait, 10)
+        # the chip is free only once its process is gone
+        self._chip_of.pop(address, None)
         self._exit_codes[address] = proc.returncode
         logger.info("terminated replica %s rc=%s", address,
                     proc.returncode)

@@ -94,6 +94,7 @@ from aigw_tpu.schemas import openai as oai
 from aigw_tpu.schemas import typed as typed_schemas
 from aigw_tpu.schemas import typed_response
 from aigw_tpu.translate import Endpoint, TranslationError, get_translator
+from aigw_tpu.utils import native
 
 logger = logging.getLogger(__name__)
 
@@ -541,6 +542,12 @@ class GatewayServer:
             "status": "ok",
             "uuid": self._runtime.config.uuid,
             "circuit": self.circuit.snapshot(),
+            # which SSE / event-stream scanner this process runs: the
+            # C++ one when native/libaigw_native.so was built (`make -C
+            # native`), else the pure-Python twin — visible, because the
+            # library is a build product and two checkouts can differ
+            "native_scanner": ("loaded" if native.available()
+                               else "python"),
         }
         # reconciling control plane: surface quarantined objects so an
         # operator doesn't have to know to cat aigw-status.json (the

@@ -33,16 +33,25 @@ def save_checkpoint(params: dict[str, jax.Array], path: str) -> None:
 
 
 def restore_checkpoint(
-    path: str, like: dict[str, jax.Array] | None = None
+    path: str, like: dict[str, jax.Array] | None = None,
+    sharding_of=None,
 ) -> dict[str, jax.Array]:
+    """Restore a flat param dict. ``like`` gives shapes and dtypes;
+    ``sharding_of(name, shape)`` (parallel.sharding.param_sharding_fn)
+    makes orbax restore each tensor directly into its mesh sharding —
+    a tp model larger than one chip never lands whole on one."""
     import orbax.checkpoint as ocp
 
     path = os.path.abspath(path)
     ckptr = ocp.StandardCheckpointer()
     if like is not None:
-        shapes = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), like
-        )
+        shapes = {
+            k: jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=(sharding_of(k, x.shape)
+                          if sharding_of is not None else None))
+            for k, x in like.items()
+        }
         return ckptr.restore(path, shapes)
     return ckptr.restore(path)
 

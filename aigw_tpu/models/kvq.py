@@ -83,28 +83,26 @@ def compute_dtype(kv_cache_dtype: str):
     return jnp.float32 if kv_cache_dtype == "float32" else jnp.bfloat16
 
 
-def make_pool(kv_shape: tuple, kv_cache_dtype: str):
+def make_pool(kv_shape: tuple, kv_cache_dtype: str, mesh=None,
+              data_spec=None):
     """Zero-initialized pool: bare array (native) or {"q","scale"}
-    pytree (quantized). ``kv_shape`` = [L, 2, n_slots, Hkv, D]."""
+    pytree (quantized). ``kv_shape`` = [L, 2, n_slots, Hkv, D]. With a
+    ``mesh`` the pool is created ALREADY sharded — the data leaf takes
+    ``data_spec`` (heads on "tp"), the scale leaf drops that spec's
+    trailing head_dim axis — so it never exists whole on one device."""
+    data = scale = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        data = NamedSharding(mesh, data_spec)
+        scale = NamedSharding(mesh, PartitionSpec(*data_spec[:-1]))
     if not is_quantized_dtype(kv_cache_dtype):
-        return jnp.zeros(kv_shape, compute_dtype(kv_cache_dtype))
+        return jnp.zeros(kv_shape, compute_dtype(kv_cache_dtype),
+                         device=data)
     return {
-        "q": jnp.zeros(kv_shape, _QDTYPE[kv_cache_dtype]),
-        "scale": jnp.zeros(kv_shape[:-1], jnp.float32),
+        "q": jnp.zeros(kv_shape, _QDTYPE[kv_cache_dtype], device=data),
+        "scale": jnp.zeros(kv_shape[:-1], jnp.float32, device=scale),
     }
-
-
-def pool_sharding_tree(kv: Any, mesh, data_spec) -> Any:
-    """NamedSharding pytree matching ``kv``: the data leaf takes
-    ``data_spec`` ([L, 2, slots, Hkv, D] — heads on "tp"); the scale
-    leaf drops the trailing head_dim axis of that spec."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    data = NamedSharding(mesh, data_spec)
-    if not is_quantized(kv):
-        return data
-    scale = NamedSharding(mesh, PartitionSpec(*data_spec[:-1]))
-    return {"q": data, "scale": scale}
 
 
 def quantize_rows(x: jax.Array, kv_cache_dtype: str):

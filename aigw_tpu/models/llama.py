@@ -23,6 +23,7 @@ be assigned per-leaf by name (aigw_tpu/parallel/sharding.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 from dataclasses import dataclass
@@ -81,40 +82,90 @@ TINY = LlamaConfig(
 )
 
 
+@functools.lru_cache(maxsize=None)
+def _tensor_maker(kind: str, shape: tuple, scale: float, dtype: Any,
+                  sharding: Any):
+    """One jitted program per (kind, shape, placement): the f32 draw,
+    the scale and the cast fuse, and ``out_shardings`` makes each
+    device create only its own shard — a tensor never exists whole on
+    one device, nor at f32 width in HBM."""
+    if kind == "normal":
+        def make(key):
+            return (jax.random.normal(key, shape, jnp.float32)
+                    * scale).astype(dtype)
+    else:
+        def make(key):
+            return jnp.full(shape, scale, dtype)
+    return jax.jit(make, out_shardings=sharding)
+
+
+class ParamBuilder:
+    """Creates a family's random weights ONE TENSOR AT A TIME.
+
+    ``sharding_of(name, shape)`` places each tensor as it is created
+    (a tp mesh never funnels the model through one chip);
+    ``finish(name, w) -> {leaf: array}`` consumes it on the spot (the
+    server quantizes there, so a 15 GB bf16 model is never resident on
+    a 16 GB chip — at most one bf16 tensor is alive beside the int8
+    leaves). Shared by every family's ``init_params``."""
+
+    def __init__(self, key: jax.Array, n_keys: int, dtype: Any,
+                 sharding_of=None, finish=None):
+        self._keys = iter(jax.random.split(key, n_keys))
+        self._dtype = dtype
+        self._sharding_of = sharding_of
+        self._finish = finish
+        self.params: dict[str, jax.Array] = {}
+
+    def _put(self, kind: str, name: str, shape: tuple, scale: float,
+             key) -> None:
+        sharding = (self._sharding_of(name, shape)
+                    if self._sharding_of is not None else None)
+        w = _tensor_maker(kind, tuple(shape), float(scale), self._dtype,
+                          sharding)(key)
+        if self._finish is None:
+            self.params[name] = w
+        else:
+            self.params.update(self._finish(name, w))
+
+    def dense(self, name: str, shape: tuple, scale: float = 0.0) -> None:
+        """N(0, scale²) draw; default scale 1/sqrt(fan_in), fan_in =
+        the second-to-last axis ([in, out] and [E, in, out])."""
+        scale = scale or 1.0 / math.sqrt(shape[-2])
+        self._put("normal", name, shape, scale, next(self._keys))
+
+    def const(self, name: str, shape: tuple, value: float) -> None:
+        self._put("const", name, shape, value, None)
+
+
 def init_params(
-    key: jax.Array, cfg: LlamaConfig, dtype: Any = jnp.bfloat16
+    key: jax.Array, cfg: LlamaConfig, dtype: Any = jnp.bfloat16,
+    sharding_of=None, finish=None,
 ) -> dict[str, jax.Array]:
-    """Random-init weights (testing / tiny-random serving)."""
-    keys = iter(jax.random.split(key, 4 + cfg.n_layers * 9))
-
-    def dense(shape, scale=None):
-        scale = scale or 1.0 / math.sqrt(shape[0])
-        return (jax.random.normal(next(keys), shape, jnp.float32) * scale).astype(
-            dtype
-        )
-
-    p: dict[str, jax.Array] = {
-        "embed": dense((cfg.vocab_size, cfg.dim), scale=0.02),
-        "norm_f": jnp.ones((cfg.dim,), dtype),
-    }
+    """Random-init weights (testing / ``--weights random`` serving);
+    see :class:`ParamBuilder` for the two placement hooks."""
+    b = ParamBuilder(key, 4 + cfg.n_layers * 9, dtype, sharding_of,
+                     finish)
+    b.dense("embed", (cfg.vocab_size, cfg.dim), scale=0.02)
+    b.const("norm_f", (cfg.dim,), 1.0)
     if not cfg.tie_embeddings:
-        p["lm_head"] = dense((cfg.dim, cfg.vocab_size))
+        b.dense("lm_head", (cfg.dim, cfg.vocab_size))
     hd = cfg.head_dim
     for i in range(cfg.n_layers):
-        p[f"l{i}.attn_norm"] = jnp.ones((cfg.dim,), dtype)
-        p[f"l{i}.wq"] = dense((cfg.dim, cfg.n_heads * hd))
-        p[f"l{i}.wk"] = dense((cfg.dim, cfg.n_kv_heads * hd))
-        p[f"l{i}.wv"] = dense((cfg.dim, cfg.n_kv_heads * hd))
+        b.const(f"l{i}.attn_norm", (cfg.dim,), 1.0)
+        b.dense(f"l{i}.wq", (cfg.dim, cfg.n_heads * hd))
+        b.dense(f"l{i}.wk", (cfg.dim, cfg.n_kv_heads * hd))
+        b.dense(f"l{i}.wv", (cfg.dim, cfg.n_kv_heads * hd))
         if cfg.attn_bias:
-            p[f"l{i}.bq"] = jnp.zeros((cfg.n_heads * hd,), dtype)
-            p[f"l{i}.bk"] = jnp.zeros((cfg.n_kv_heads * hd,), dtype)
-            p[f"l{i}.bv"] = jnp.zeros((cfg.n_kv_heads * hd,), dtype)
-        p[f"l{i}.wo"] = dense((cfg.n_heads * hd, cfg.dim))
-        p[f"l{i}.mlp_norm"] = jnp.ones((cfg.dim,), dtype)
-        p[f"l{i}.w_gate"] = dense((cfg.dim, cfg.ffn_dim))
-        p[f"l{i}.w_up"] = dense((cfg.dim, cfg.ffn_dim))
-        p[f"l{i}.w_down"] = dense((cfg.ffn_dim, cfg.dim))
-    return p
+            b.const(f"l{i}.bq", (cfg.n_heads * hd,), 0.0)
+            b.const(f"l{i}.bk", (cfg.n_kv_heads * hd,), 0.0)
+            b.const(f"l{i}.bv", (cfg.n_kv_heads * hd,), 0.0)
+        b.dense(f"l{i}.wo", (cfg.n_heads * hd, cfg.dim))
+        b.const(f"l{i}.mlp_norm", (cfg.dim,), 1.0)
+        b.dense(f"l{i}.w_gate", (cfg.dim, cfg.ffn_dim))
+        b.dense(f"l{i}.w_up", (cfg.dim, cfg.ffn_dim))
+        b.dense(f"l{i}.w_down", (cfg.ffn_dim, cfg.dim))
+    return b.params
 
 
 def _w(p: dict[str, jax.Array], key: str) -> jax.Array:

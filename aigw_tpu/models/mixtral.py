@@ -73,34 +73,29 @@ TINY_MOE = MixtralConfig(
 )
 
 
-def init_params(key: jax.Array, cfg: MixtralConfig,
-                dtype=jnp.bfloat16) -> dict[str, jax.Array]:
-    keys = iter(jax.random.split(key, 4 + cfg.n_layers * 8))
-
-    def dense(shape, scale=None):
-        scale = scale or 1.0 / math.sqrt(shape[-2] if len(shape) > 1 else shape[0])
-        return (jax.random.normal(next(keys), shape, jnp.float32) * scale
-                ).astype(dtype)
-
-    p: dict[str, jax.Array] = {
-        "embed": dense((cfg.vocab_size, cfg.dim), scale=0.02),
-        "norm_f": jnp.ones((cfg.dim,), dtype),
-        "lm_head": dense((cfg.dim, cfg.vocab_size)),
-    }
+def init_params(key: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16,
+                sharding_of=None, finish=None) -> dict[str, jax.Array]:
+    """Random-init weights; the placement hooks are
+    :class:`llama.ParamBuilder`'s."""
+    b = llama.ParamBuilder(key, 4 + cfg.n_layers * 8, dtype, sharding_of,
+                           finish)
+    b.dense("embed", (cfg.vocab_size, cfg.dim), scale=0.02)
+    b.const("norm_f", (cfg.dim,), 1.0)
+    b.dense("lm_head", (cfg.dim, cfg.vocab_size))
     hd = cfg.head_dim
     E = cfg.n_experts
     for i in range(cfg.n_layers):
-        p[f"l{i}.attn_norm"] = jnp.ones((cfg.dim,), dtype)
-        p[f"l{i}.wq"] = dense((cfg.dim, cfg.n_heads * hd))
-        p[f"l{i}.wk"] = dense((cfg.dim, cfg.n_kv_heads * hd))
-        p[f"l{i}.wv"] = dense((cfg.dim, cfg.n_kv_heads * hd))
-        p[f"l{i}.wo"] = dense((cfg.n_heads * hd, cfg.dim))
-        p[f"l{i}.mlp_norm"] = jnp.ones((cfg.dim,), dtype)
-        p[f"l{i}.gate"] = dense((cfg.dim, E))
-        p[f"l{i}.w_gate"] = dense((E, cfg.dim, cfg.ffn_dim))
-        p[f"l{i}.w_up"] = dense((E, cfg.dim, cfg.ffn_dim))
-        p[f"l{i}.w_down"] = dense((E, cfg.ffn_dim, cfg.dim))
-    return p
+        b.const(f"l{i}.attn_norm", (cfg.dim,), 1.0)
+        b.dense(f"l{i}.wq", (cfg.dim, cfg.n_heads * hd))
+        b.dense(f"l{i}.wk", (cfg.dim, cfg.n_kv_heads * hd))
+        b.dense(f"l{i}.wv", (cfg.dim, cfg.n_kv_heads * hd))
+        b.dense(f"l{i}.wo", (cfg.n_heads * hd, cfg.dim))
+        b.const(f"l{i}.mlp_norm", (cfg.dim,), 1.0)
+        b.dense(f"l{i}.gate", (cfg.dim, E))
+        b.dense(f"l{i}.w_gate", (E, cfg.dim, cfg.ffn_dim))
+        b.dense(f"l{i}.w_up", (E, cfg.dim, cfg.ffn_dim))
+        b.dense(f"l{i}.w_down", (E, cfg.ffn_dim, cfg.dim))
+    return b.params
 
 
 def moe_mlp(p: dict[str, jax.Array], i: int, x: jax.Array,

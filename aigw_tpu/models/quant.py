@@ -82,6 +82,28 @@ def _quantize_matrix_int4(
             scale.squeeze(-2).astype(jnp.float32))
 
 
+def quantize_tensor(name: str, w: jax.Array,
+                    mode: str = "int8") -> dict[str, jax.Array]:
+    """One named weight → the leaves that replace it: ``{name.q,
+    name.scale}`` for the matmul matrices, lm_head and the embedding,
+    ``{name: w}`` for everything else (norms, biases, routers)."""
+    if mode not in ("int8", "int4"):
+        raise ValueError(f"unknown quantization mode {mode!r}")
+    kind = name.rsplit(".", 1)[-1]
+    if (kind in _MATRIX_KINDS and w.ndim >= 2) or name == "lm_head":
+        # output channels = last axis for [in, out] (and [E, in, out])
+        if mode == "int4" and w.shape[-2] % GROUP4 == 0:
+            q, scale = _quantize_matrix_int4(w, GROUP4)
+        else:  # int8, or input dim not groupable
+            q, scale = _quantize_matrix_int8_channels(w)
+    elif name == "embed":
+        # consumed by row gather: per-row scales either mode
+        q, scale = _quantize_matrix(w, axis=0)
+    else:
+        return {name: w}
+    return {name + ".q": q, name + ".scale": scale}
+
+
 def quantize_params(
     params: dict[str, jax.Array], consume: bool = False,
     mode: str = "int8",
@@ -91,37 +113,14 @@ def quantize_params(
 
     ``consume=True`` removes each bf16 tensor from ``params`` as soon as
     its quantized replacement is materialized, bounding peak HBM to
-    bf16-model + one tensor instead of two full copies — required to
-    quantize an 8B bf16 model in place on a 16GB chip.
+    bf16-model + one tensor instead of two full copies. A model whose
+    bf16 form does not fit at all is quantized as it is created
+    instead (``init_params(finish=quantize_tensor)``).
     """
-    if mode not in ("int8", "int4"):
-        raise ValueError(f"unknown quantization mode {mode!r}")
     out: dict[str, jax.Array] = {}
     for name in list(params):
         w = params.pop(name) if consume else params[name]
-        kind = name.rsplit(".", 1)[-1]
-        if kind in _MATRIX_KINDS and w.ndim >= 2:
-            # output channels = last axis for [in, out] (and [E, in, out])
-            if mode == "int4" and w.shape[-2] % GROUP4 == 0:
-                q, scale = _quantize_matrix_int4(w, GROUP4)
-            else:  # int8, or input dim not groupable
-                q, scale = _quantize_matrix_int8_channels(w)
-            out[name + ".q"] = q
-            out[name + ".scale"] = scale
-        elif name == "lm_head":
-            if mode == "int4" and w.shape[0] % GROUP4 == 0:
-                q, scale = _quantize_matrix_int4(w, GROUP4)
-            else:
-                q, scale = _quantize_matrix_int8_channels(w)
-            out["lm_head.q"] = q
-            out["lm_head.scale"] = scale
-        elif name == "embed":
-            # consumed by row gather: per-row scales either mode
-            q, scale = _quantize_matrix(w, axis=0)
-            out["embed.q"] = q
-            out["embed.scale"] = scale
-        else:
-            out[name] = w
+        out.update(quantize_tensor(name, w, mode))
     return out
 
 

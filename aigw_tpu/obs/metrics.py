@@ -172,6 +172,10 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     # a nonzero delta after warmup is a hot-path compile regression
     ("xla_compiles", "tpuserve_xla_compiles_total"),
     ("xla_compile_ms", "tpuserve_xla_compile_ms_total"),
+    # persistent compile cache (utils/boot.py): a compile event above
+    # is a LOAD when the cache served it — these tell the two apart
+    ("xla_cache_hits", "tpuserve_xla_cache_hits_total"),
+    ("xla_cache_misses", "tpuserve_xla_cache_misses_total"),
     # adapter serving subsystem (ISSUE 7, tpuserve/adapters.py): hot
     # loads into the stacked LoRA rows, LRU evictions under row
     # pressure, resident adapters, and live slots decoding through a

@@ -8,7 +8,13 @@ operators and tests ask:
    backend compile (lowering and jaxpr-trace durations ride sibling
    keys). One module-level listener counts them and sums their wall
    time — the "did anything compile, and how long did it cost" counter
-   exported on ``/metrics`` and ``/state``.
+   exported on ``/metrics`` and ``/state``. That event wraps JAX's
+   ``compile_or_get_cached``, so a program LOADED from the persistent
+   compile cache (utils/boot.py places it) still counts as one: the
+   tripwire "zero new programs on the hot path" wants exactly that.
+   Which of the two it was is told by the sibling counters
+   ``/jax/compilation_cache/cache_hits`` and ``cache_misses``, counted
+   here too and exported as ``xla_cache_hits`` / ``xla_cache_misses``.
 
 2. **Per-engine program accounting** via the jit caches of the engine's
    REGISTERED hot-path callables (prefill ladder, decode/verify scans,
@@ -31,12 +37,17 @@ from typing import Any, Callable
 
 #: jax.monitoring duration keys counted as "an XLA compile happened"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: persistent-cache outcome of one compile request (plain events)
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 _lock = threading.Lock()
 _installed = False
 _compile_count = 0
 _compile_ms = 0.0
 _last_compile_at = 0.0
+_cache_hits = 0
+_cache_misses = 0
 
 
 def _on_duration(event: str, duration_secs: float, **_kw: Any) -> None:
@@ -47,6 +58,16 @@ def _on_duration(event: str, duration_secs: float, **_kw: Any) -> None:
         _compile_count += 1
         _compile_ms += duration_secs * 1e3
         _last_compile_at = time.time()
+
+
+def _on_event(event: str, **_kw: Any) -> None:
+    global _cache_hits, _cache_misses
+    if event == _CACHE_HIT_EVENT:
+        with _lock:
+            _cache_hits += 1
+    elif event == _CACHE_MISS_EVENT:
+        with _lock:
+            _cache_misses += 1
 
 
 def install() -> bool:
@@ -61,6 +82,7 @@ def install() -> bool:
         import jax.monitoring as monitoring
 
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
     except Exception:  # noqa: BLE001 — telemetry must never break serving
         return False
     with _lock:
@@ -77,6 +99,15 @@ def compile_count() -> int:
 def compile_ms() -> float:
     with _lock:
         return _compile_ms
+
+
+def cache_counts() -> tuple[int, int]:
+    """Persistent compile cache (hits, misses) process-wide since
+    install(): of the compile events counted above, ``hits`` were loads,
+    not builds. Process-wide (not a tracker delta) because weight
+    initialisation compiles before any engine exists."""
+    with _lock:
+        return _cache_hits, _cache_misses
 
 
 class CompileTracker:

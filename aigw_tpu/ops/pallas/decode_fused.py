@@ -81,12 +81,12 @@ def _swap_matrix(D: int):
 
 
 def _rope_rows(x: jax.Array, cos: jax.Array, sin: jax.Array):
-    """Rotate rows [R, D] by the interleaved tables [D] (f32 in/out)."""
+    """Rotate rows [R, D] by the interleaved tables [1, D] (f32 in/out)."""
     S = _swap_matrix(x.shape[-1])
     rot = jax.lax.dot_general(
         x, S, dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    return x * cos[None, :] + rot * sin[None, :]
+    return x * cos + rot * sin
 
 
 def _fused_kernel(
@@ -98,11 +98,11 @@ def _fused_kernel(
     #           for inactive slots)
     arow_ref,  # [B] int32 — row within that page (position % page)
     # blocks
-    q_ref,  # [1, H * D] unroped query
-    kn_ref,  # [1, Hkv * D] unroped new key
-    vn_ref,  # [1, Hkv * D] new value
-    cos_ref,  # [1, D] f32
-    sin_ref,  # [1, D] f32
+    q_ref,  # [1, H, D] unroped query
+    kn_ref,  # [1, 1, Hkv * D] unroped new key
+    vn_ref,  # [1, 1, Hkv * D] new value
+    cos_ref,  # [1, 1, D] f32
+    sin_ref,  # [1, 1, D] f32
     k_ref,  # [page, Hkv * D] pool page (walk index map)
     v_ref,  # [page, Hkv * D]
     *rest,  # [ks_ref, vs_ref,] o_ref, ko_ref, vo_ref[, kso_ref, vso_ref]
@@ -113,6 +113,11 @@ def _fused_kernel(
     head_dim: int,
     qmax: float,
 ):
+    # Mosaic layout discipline (the kernel never lowered before PR 21):
+    # every block's last two dims equal the array's or tile (8, 128);
+    # heads are addressed as lane windows [.., h*D:(h+1)*D] of 2-D rows
+    # — no in-kernel reshape between (H*D,) and (H, D), no 1-D values,
+    # no scalar-predicate selects (masks are iota comparisons).
     quant = qmax > 0.0
     if quant:
         (ks_ref, vs_ref, o_ref, ko_ref, vo_ref, kso_ref, vso_ref,
@@ -122,8 +127,17 @@ def _fused_kernel(
     b = pl.program_id(0)
     p = pl.program_id(1)
     D = head_dim
-    H = q_ref.shape[1] // D
+    H = q_ref.shape[1]
     grp = H // n_kv_heads
+    page = k_ref.shape[0]
+
+    def head_col(ref, h):
+        """Column h of a [page, Hkv] scale block as [page, 1] — a
+        masked lane reduction (a width-1 lane window at offset h is an
+        unaligned load Mosaic may refuse)."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, ref.shape, 1)
+        return jnp.sum(jnp.where(lane == h, ref[:], 0.0), axis=1,
+                       keepdims=True)
 
     @pl.when(p == 0)
     def _init():
@@ -131,13 +145,11 @@ def _fused_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
         # rope q once per sequence; reused (pre-scaled) by every page
-        # step and the finalize fold
-        cos = cos_ref[0]
-        sin = sin_ref[0]
-        q = q_ref[0].astype(jnp.float32).reshape(H, D)
-        # round through the model compute dtype exactly like the XLA
-        # path (rope() returns x.dtype before attention reads it)
-        qr = _rope_rows(q, cos, sin).astype(q_ref.dtype).astype(
+        # step and the finalize fold. Round through the model compute
+        # dtype exactly like the XLA path (rope() returns x.dtype
+        # before attention reads it)
+        qr = _rope_rows(q_ref[0].astype(jnp.float32), cos_ref[0],
+                        sin_ref[0]).astype(q_ref.dtype).astype(
             jnp.float32)
         qr_ref[:] = qr / math.sqrt(D)
 
@@ -146,7 +158,6 @@ def _fused_kernel(
 
     @pl.when(valid > 0)
     def _attend():
-        page = k_ref.shape[0]
         mask = jax.lax.broadcasted_iota(
             jnp.int32, (grp, page), 1) < valid
         for h in range(n_kv_heads):
@@ -154,8 +165,8 @@ def _fused_kernel(
             k_h = k_ref[:, h * D:(h + 1) * D].astype(jnp.float32)
             v_h = v_ref[:, h * D:(h + 1) * D].astype(jnp.float32)
             if quant:
-                k_h = k_h * ks_ref[:, h:h + 1]
-                v_h = v_h * vs_ref[:, h:h + 1]
+                k_h = k_h * head_col(ks_ref, h)
+                v_h = v_h * head_col(vs_ref, h)
             logits = jax.lax.dot_general(
                 qr_ref[rows, :], k_h,
                 dimension_numbers=(((1,), (1,)), ((), ())),
@@ -179,87 +190,94 @@ def _fused_kernel(
 
     @pl.when(p == n_pages - 1)
     def _finalize():
-        is_act = act_ref[b] == 1
         arow = arow_ref[b]
         cos = cos_ref[0]
         sin = sin_ref[0]
-        kn = _rope_rows(
-            kn_ref[0].astype(jnp.float32).reshape(n_kv_heads, D),
-            cos, sin).astype(kn_ref.dtype).astype(jnp.float32)  # [Hkv, D]
-        vn = vn_ref[0].astype(jnp.float32).reshape(n_kv_heads, D)
-        if quant:
-            # the kvq.py recipe, bit-for-bit: symmetric absmax/head,
-            # round-half-even, qmax-clipped
-            k_amax = jnp.max(jnp.abs(kn), axis=1)
-            v_amax = jnp.max(jnp.abs(vn), axis=1)
-            k_s = jnp.where(k_amax > 0.0, k_amax / qmax, 1.0)
-            v_s = jnp.where(v_amax > 0.0, v_amax / qmax, 1.0)
-            kq = jnp.clip(jnp.round(kn / k_s[:, None]), -qmax, qmax)
-            vq = jnp.clip(jnp.round(vn / v_s[:, None]), -qmax, qmax)
-            # the value every later read dequantizes to — fold THAT
-            k_eff = kq * k_s[:, None]
-            v_eff = vq * v_s[:, None]
-        else:
-            # the bf16/f32 round-trip the chained scatter+gather pays
-            k_eff = kn.astype(ko_ref.dtype).astype(jnp.float32)
-            v_eff = vn.astype(vo_ref.dtype).astype(jnp.float32)
+        # per KV head, as [1, D] rows: the value later reads see
+        # (k_eff/v_eff, f32) and the row the pool stores (k_st/v_st)
+        k_eff, v_eff, k_st, v_st, k_sc, v_sc = [], [], [], [], [], []
+        for h in range(n_kv_heads):
+            cols = slice(h * D, (h + 1) * D)
+            kn = _rope_rows(kn_ref[0, :, cols].astype(jnp.float32),
+                            cos, sin).astype(kn_ref.dtype).astype(
+                jnp.float32)
+            vn = vn_ref[0, :, cols].astype(jnp.float32)
+            if quant:
+                # the kvq.py recipe, bit-for-bit: symmetric absmax per
+                # head, round-half-even, qmax-clipped
+                k_amax = jnp.max(jnp.abs(kn), axis=1, keepdims=True)
+                v_amax = jnp.max(jnp.abs(vn), axis=1, keepdims=True)
+                k_s = jnp.where(k_amax > 0.0, k_amax / qmax, 1.0)
+                v_s = jnp.where(v_amax > 0.0, v_amax / qmax, 1.0)
+                kq = jnp.clip(jnp.round(kn / k_s), -qmax, qmax)
+                vq = jnp.clip(jnp.round(vn / v_s), -qmax, qmax)
+                # the value every later read dequantizes to — fold THAT
+                k_eff.append(kq * k_s)
+                v_eff.append(vq * v_s)
+                k_st.append(kq)
+                v_st.append(vq)
+                k_sc.append(k_s)
+                v_sc.append(v_s)
+            else:
+                # the bf16/f32 round-trip the chained scatter+gather pays
+                k_eff.append(kn.astype(ko_ref.dtype).astype(jnp.float32))
+                v_eff.append(vn.astype(vo_ref.dtype).astype(jnp.float32))
+                k_st.append(kn)
+                v_st.append(vn)
 
-        @pl.when(is_act)
+        @pl.when(act_ref[b] == 1)
         def _fold_new_token():
             for h in range(n_kv_heads):
                 rows = slice(h * grp, (h + 1) * grp)
-                logit = jax.lax.dot_general(
-                    qr_ref[rows, :], k_eff[h:h + 1, :],
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )  # [grp, 1]
+                logit = jnp.sum(qr_ref[rows, :] * k_eff[h], axis=1,
+                                keepdims=True)  # [grp, 1]
                 m_prev = m_ref[rows, 0:1]
                 m_new = jnp.maximum(m_prev, logit)
                 alpha = jnp.exp(m_prev - m_new)
                 pnew = jnp.exp(logit - m_new)
-                l_ref[rows, 0:1] = (alpha * l_ref[rows, 0:1] + pnew)
+                l_ref[rows, 0:1] = alpha * l_ref[rows, 0:1] + pnew
                 acc_ref[rows, :] = (acc_ref[rows, :] * alpha
-                                    + pnew * v_eff[h:h + 1, :])
+                                    + pnew * v_eff[h])
                 m_ref[rows, 0:1] = m_new
 
         denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0] = (acc_ref[:] / denom).reshape(1, H * D)[0].astype(
-            o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
         # -- append: rewrite the target page with the new row ----------
-        page = k_ref.shape[0]
-        row_mask = jax.lax.broadcasted_iota(
-            jnp.int32, (page, n_kv_heads * D), 0) == arow
-        # page-aligned append starts a FRESH page (the walk never
-        # fetched it — rows past the append are unwritten future
-        # positions); mid-page appends extend the final walk block
-        fresh = arow == 0
-        base_k = jnp.where(fresh, jnp.zeros_like(k_ref), k_ref[:])
-        base_v = jnp.where(fresh, jnp.zeros_like(v_ref), v_ref[:])
+        # rows below the append row are the sequence's earlier tokens
+        # (the final walk block already in VMEM — a mid-page append IS
+        # that block); the append row takes the new values; rows above
+        # it are unwritten future positions and come out zero. A
+        # page-aligned append (arow == 0) keeps nothing, so the stale
+        # walk block it never fetched cannot leak in. Inactive slots
+        # land on the dump page, whose content nobody reads.
+        row = jax.lax.broadcasted_iota(jnp.int32, (page, D), 0)
+        for h in range(n_kv_heads):
+            cols = slice(h * D, (h + 1) * D)
+            ko_ref[:, cols] = jnp.where(
+                row == arow, k_st[h].astype(ko_ref.dtype),
+                jnp.where(row < arow, k_ref[:, cols],
+                          jnp.zeros_like(k_ref[:, cols])))
+            vo_ref[:, cols] = jnp.where(
+                row == arow, v_st[h].astype(vo_ref.dtype),
+                jnp.where(row < arow, v_ref[:, cols],
+                          jnp.zeros_like(v_ref[:, cols])))
         if quant:
-            new_k = kq.reshape(1, n_kv_heads * D).astype(ko_ref.dtype)
-            new_v = vq.reshape(1, n_kv_heads * D).astype(vo_ref.dtype)
-        else:
-            new_k = kn.reshape(1, n_kv_heads * D).astype(ko_ref.dtype)
-            new_v = vn.reshape(1, n_kv_heads * D).astype(vo_ref.dtype)
-        zero_row = jnp.zeros_like(new_k)
-        ko_ref[:] = jnp.where(
-            row_mask, jnp.where(is_act, new_k, zero_row), base_k)
-        vo_ref[:] = jnp.where(
-            row_mask, jnp.where(is_act, new_v, zero_row), base_v)
-        if quant:
-            srow_mask = jax.lax.broadcasted_iota(
-                jnp.int32, (page, n_kv_heads), 0) == arow
-            base_ks = jnp.where(fresh, jnp.zeros_like(ks_ref),
-                                ks_ref[:])
-            base_vs = jnp.where(fresh, jnp.zeros_like(vs_ref),
-                                vs_ref[:])
+            srow = jax.lax.broadcasted_iota(
+                jnp.int32, (page, n_kv_heads), 0)
+            lane = jax.lax.broadcasted_iota(
+                jnp.int32, (1, n_kv_heads), 1)
+            ks_new = jnp.zeros((1, n_kv_heads), jnp.float32)
+            vs_new = jnp.zeros((1, n_kv_heads), jnp.float32)
+            for h in range(n_kv_heads):
+                ks_new = jnp.where(lane == h, k_sc[h], ks_new)
+                vs_new = jnp.where(lane == h, v_sc[h], vs_new)
             kso_ref[:] = jnp.where(
-                srow_mask,
-                jnp.where(is_act, k_s[None, :], 0.0), base_ks)
+                srow == arow, ks_new,
+                jnp.where(srow < arow, ks_ref[:], 0.0))
             vso_ref[:] = jnp.where(
-                srow_mask,
-                jnp.where(is_act, v_s[None, :], 0.0), base_vs)
+                srow == arow, vs_new,
+                jnp.where(srow < arow, vs_ref[:], 0.0))
 
 
 @functools.partial(
@@ -284,8 +302,8 @@ def fused_paged_decode(
     """One fused decode dispatch. Returns ``(attn [B, H, D] in q's
     dtype, k_rows', v_rows'[, k_scale', v_scale'])`` — the pool leaves
     are updated IN the kernel (input_output_aliases) with the new row
-    appended at ``positions``; inactive rows write a zero row into the
-    pool's last page (the engine-reserved dump page)."""
+    appended at ``positions``; inactive rows write into the pool's last
+    page (the engine-reserved dump page)."""
     B, H, D = q.shape
     n_slots, Hkv, _ = k_rows.shape
     P = page_table.shape[1]
@@ -305,15 +323,14 @@ def fused_paged_decode(
         jnp.int32)
     cos_t, sin_t = _rope_tables(positions, D, rope_theta)
 
-    q2d = q.reshape(B, H * D)
-    kn2d = k_new.reshape(B, Hkv * D)
-    vn2d = v_new.reshape(B, Hkv * D)
+    kn3d = k_new.reshape(B, 1, Hkv * D)
+    vn3d = v_new.reshape(B, 1, Hkv * D)
     k2d = k_rows.reshape(n_slots, Hkv * D)
     v2d = v_rows.reshape(n_slots, Hkv * D)
     flat_pt = page_table.reshape(-1)
 
     def row_index(b, p, pt, ln, ac, apg, ar):
-        return b, 0
+        return b, 0, 0
 
     def kv_index(b, p, pt, ln, ac, apg, ar):
         # ragged DMA skip: pages past the last valid page clamp to it
@@ -324,22 +341,23 @@ def fused_paged_decode(
         return apg[b], 0
 
     in_specs = [
-        pl.BlockSpec((1, H * D), row_index),
-        pl.BlockSpec((1, Hkv * D), row_index),
-        pl.BlockSpec((1, Hkv * D), row_index),
-        pl.BlockSpec((1, D), row_index),
-        pl.BlockSpec((1, D), row_index),
+        pl.BlockSpec((1, H, D), row_index),
+        pl.BlockSpec((1, 1, Hkv * D), row_index),
+        pl.BlockSpec((1, 1, Hkv * D), row_index),
+        pl.BlockSpec((1, 1, D), row_index),
+        pl.BlockSpec((1, 1, D), row_index),
         pl.BlockSpec((page_size, Hkv * D), kv_index),
         pl.BlockSpec((page_size, Hkv * D), kv_index),
     ]
-    inputs = [q2d, kn2d, vn2d, cos_t, sin_t, k2d, v2d]
+    inputs = [q, kn3d, vn3d, cos_t[:, None, :], sin_t[:, None, :],
+              k2d, v2d]
     out_specs = [
-        pl.BlockSpec((1, H * D), row_index),
+        pl.BlockSpec((1, H, D), row_index),
         pl.BlockSpec((page_size, Hkv * D), append_index),
         pl.BlockSpec((page_size, Hkv * D), append_index),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((B, H * D), q.dtype),
+        jax.ShapeDtypeStruct((B, H, D), q.dtype),
         jax.ShapeDtypeStruct(k2d.shape, k2d.dtype),
         jax.ShapeDtypeStruct(v2d.shape, v2d.dtype),
     ]
@@ -386,7 +404,7 @@ def fused_paged_decode(
         input_output_aliases=aliases,
         interpret=interpret,
     )(flat_pt, lengths, act, app_page, app_row, *inputs)
-    attn = outs[0].reshape(B, H, D)
+    attn = outs[0]
     k_out = outs[1].reshape(n_slots, Hkv, D)
     v_out = outs[2].reshape(n_slots, Hkv, D)
     if quant:
@@ -459,7 +477,6 @@ def paged_decode_walk_spmd(
     no cross-device collective inside attention (the layer all-reduce
     after wo is unchanged). Requires H and Hkv divisible by the axis
     size (the resolution matrix guards this)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Ps
 
     heads = Ps(None, axis, None)
@@ -482,5 +499,5 @@ def paged_decode_walk_spmd(
 
         in_specs = (heads, heads, heads, Ps(None, None), Ps(None))
         args = (q, k_rows, v_rows, page_table, lengths)
-    return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=heads, check_rep=False)(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=heads, check_vma=False)(*args)

@@ -1,20 +1,22 @@
-"""Pallas TPU kernel: paged-attention decode.
+"""Pallas TPU kernels: paged attention for decode, speculative verify
+and ragged prefill.
 
-One query token per sequence attends over its paged KV cache (the decode
-hot loop). Design (ragged-paged-attention style, PAPERS.md
-arxiv 2604.15464 — implementation is original):
+Queries attend over a paged KV cache. Design (ragged-paged-attention
+style, PAPERS.md arxiv 2604.15464 — implementation is original):
 
-- Grid ``(B, Hkv, P)`` — sequence, KV head, then pages innermost. The page
-  table is a **scalar-prefetch** argument, so each page's K/V block is
-  DMA'd from the HBM pool straight to VMEM by the Pallas pipeline (auto
+- Grid ``(B, P)`` — sequence, then pages innermost; one grid step
+  streams a full pool page (all KV heads). The page table is a
+  **scalar-prefetch** argument, so each page's K/V block is DMA'd from
+  the HBM pool straight to VMEM by the Pallas pipeline (auto
   double-buffered) using a *data-dependent* index map: page ``p`` of
   sequence ``b`` comes from pool row ``page_table[b, p]``.
 - Online softmax across pages: running max / denominator / weighted
   accumulator live in VMEM scratch, carried across the page loop for a
-  fixed (sequence, head); the output tile is written on the last page.
-- GQA: each grid step processes the ``group = H // Hkv`` query heads that
-  share one KV head, as plain 2D matmuls (Mosaic-friendly; K/V stay
-  un-repeated in HBM since bandwidth is the decode bottleneck).
+  fixed sequence; the output tile is written on the last page.
+- GQA: each KV head's ``group = H // Hkv`` query heads run as plain 2D
+  matmuls against that head's lane window of the page (Mosaic-friendly;
+  K/V stay un-repeated in HBM since bandwidth is the decode
+  bottleneck).
 - **Ragged DMA skip** — the reason this beats the XLA gather path: the
   gather materializes the FULL padded window per layer regardless of how
   long each sequence actually is. Here the index map *clamps* page
@@ -40,8 +42,8 @@ from jax.experimental.pallas import tpu as pltpu
 def _flash_update(rows, q, k_h, v_h, mask, m_ref, l_ref, acc_ref):
     """One online-softmax step for a row block: fold this page's
     masked logits into the running (max, denom, accumulator) scratch.
-    Shared by all three kernels (decode v1/v2 and the speculative
-    verifier) — they differ only in row layout and mask construction."""
+    Shared by the decode, verify and ragged-prefill kernels — they
+    differ only in row layout and mask construction."""
     D = q.shape[1]
     logits = jax.lax.dot_general(
         q, k_h,
@@ -63,113 +65,6 @@ def _flash_update(rows, q, k_h, v_h, mask, m_ref, l_ref, acc_ref):
     )
     acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
     m_ref[rows, 0:1] = m_new
-
-
-def _decode_kernel(
-    # scalar prefetch
-    page_table_ref,  # [B * P] int32 — pool page id per (b, p)
-    lengths_ref,  # [B] int32 — attend length per sequence
-    # blocks
-    q_ref,  # [1, 1, group, D]
-    k_ref,  # [page, D] (pool page row + head column selected by index map)
-    v_ref,  # [page, D]
-    o_ref,  # [1, 1, group, D]
-    # scratch
-    m_ref,  # [group, 128] f32 running max (col 0 used)
-    l_ref,  # [group, 128] f32 running denom (col 0 used)
-    acc_ref,  # [group, D] f32 weighted accumulator
-    *,
-    page_size: int,
-    n_pages: int,
-):
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    length = lengths_ref[b]
-    valid = jnp.clip(length - p * page_size, 0, page_size)
-
-    @pl.when(valid > 0)
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32)  # [group, D]
-        k = k_ref[:].astype(jnp.float32)  # [page, D]
-        v = v_ref[:].astype(jnp.float32)  # [page, D]
-        group = q.shape[0]
-        page = k.shape[0]
-        mask = jax.lax.broadcasted_iota(
-            jnp.int32, (group, page), 1) < valid
-        _flash_update(slice(None), q, k, v, mask, m_ref, l_ref, acc_ref)
-
-    @pl.when(p == n_pages - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def paged_attention_decode(
-    q: jax.Array,  # [B, H, D]
-    k_pool: jax.Array,  # [n_slots, Hkv, D] flattened page pool
-    v_pool: jax.Array,  # [n_slots, Hkv, D]
-    page_table: jax.Array,  # [B, P] int32
-    lengths: jax.Array,  # [B] int32
-    *,
-    page_size: int,
-    interpret: bool = False,
-) -> jax.Array:
-    """Returns attention output [B, H, D] (same dtype as q)."""
-    B, H, D = q.shape
-    n_slots, Hkv, _ = k_pool.shape
-    P = page_table.shape[1]
-    group = H // Hkv
-    # views for block indexing: the pool flattens to 2D so a (page, D)
-    # block can select [pool row = page id, column window = kv head] —
-    # contiguous reshapes only, no data movement.
-    q4 = q.reshape(B, Hkv, group, D)
-    k2d = k_pool.reshape(n_slots, Hkv * D)
-    v2d = v_pool.reshape(n_slots, Hkv * D)
-    flat_pt = page_table.reshape(-1)
-
-    def kv_index(b, h, p, pt, ln):
-        # ragged DMA skip: pages past the sequence's last valid page map
-        # to the last valid page — unchanged block index ⇒ no re-fetch
-        last = jnp.maximum(ln[b] - 1, 0) // page_size
-        return pt[b * P + jnp.minimum(p, last)], h
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, P),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, group, D), lambda b, h, p, pt, ln: (b, h, 0, 0),
-            ),
-            pl.BlockSpec((page_size, D), kv_index),
-            pl.BlockSpec((page_size, D), kv_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, group, D), lambda b, h, p, pt, ln: (b, h, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel, page_size=page_size, n_pages=P
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
-        interpret=interpret,
-    )(flat_pt, lengths, q4, k2d, v2d)
-    return out.reshape(B, H, D)
 
 
 def _decode_kernel_v2(
@@ -230,8 +125,8 @@ def paged_attention_decode_v2(
     page_size: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """Grid (B, P): one instance streams a full page (all KV heads) —
-    fewer grid steps, bigger DMAs than v1."""
+    """Returns attention output [B, H, D] (same dtype as q). Grid
+    (B, P): one instance streams a full page (all KV heads)."""
     B, H, D = q.shape
     n_slots, Hkv, _ = k_pool.shape
     P = page_table.shape[1]

@@ -28,11 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams → CompilerParams across jax releases;
-# accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 # int8 weight-tile byte budget per grid step; double-buffered by the
 # pipeline, so ~2x this lives in VMEM (16MB/core) alongside x and out.
 _TILE_BYTES = 2 * 1024 * 1024
@@ -71,7 +66,7 @@ def _w8a16_matmul(x, q, scale, interpret=False):
         out_specs=pl.BlockSpec((m, tile_n), lambda j: (0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

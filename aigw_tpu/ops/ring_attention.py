@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from aigw_tpu.utils.shard_compat import shard_map_untyped_carry
-
 
 def _ring_attention_local(
     q: jax.Array,  # [B, S_loc, H, D] — this device's query shard
@@ -76,9 +74,8 @@ def _ring_attention_local(
         vb = jax.lax.ppermute(vb, axis, perm)
         return (acc, m_new, l_new, kb, vb), None
 
-    # plain accumulators: the varying-manual-axes check that once
-    # required pvary-tagging these is disabled at the shard_map call
-    # (utils/shard_compat.py — the deprecated lax.pvary migration)
+    # plain accumulators: ppermute makes them vary over the manual
+    # axis mid-scan, so the shard_map call passes check_vma=False
     acc0 = jnp.zeros((B, S, Hkv, group, D), jnp.float32)
     m0 = jnp.full((B, Hkv, group, S), -1e30, jnp.float32)
     l0 = jnp.zeros((B, Hkv, group, S), jnp.float32)
@@ -248,7 +245,7 @@ def ring_attention(
         _ring_attention_local if strategy == "ring"
         else _ulysses_attention_local
     )
-    fn = shard_map_untyped_carry(
+    fn = jax.shard_map(
         functools.partial(local, axis=axis, causal=causal),
         mesh=mesh,
         in_specs=(
@@ -257,6 +254,7 @@ def ring_attention(
             P(None, axis, None, None),
         ),
         out_specs=P(None, axis, None),
+        check_vma=False,
     )
     return fn(q, k, v)
 
@@ -281,7 +279,7 @@ def ring_attention_prefix(
     Ulysses would all-to-all the full window per layer, defeating the
     point of chunking. Returns [B, S, H*D] sharded like q.
     """
-    fn = shard_map_untyped_carry(
+    fn = jax.shard_map(
         functools.partial(_ring_prefix_attention_local, axis=axis),
         mesh=mesh,
         in_specs=(
@@ -293,5 +291,6 @@ def ring_attention_prefix(
             P(None),
         ),
         out_specs=P(None, axis, None),
+        check_vma=False,
     )
     return fn(q, k, v, kc, vc, prefix_lens)

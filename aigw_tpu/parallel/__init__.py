@@ -14,6 +14,7 @@ from aigw_tpu.parallel.sharding import (
     kv_cache_spec,
     llama_param_specs,
     mixtral_param_specs,
+    param_sharding_fn,
     shard_params,
 )
 
@@ -24,5 +25,6 @@ __all__ = [
     "llama_param_specs",
     "mixtral_param_specs",
     "make_mesh",
+    "param_sharding_fn",
     "shard_params",
 ]

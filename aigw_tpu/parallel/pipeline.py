@@ -25,8 +25,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from aigw_tpu.models import llama
 from aigw_tpu.models.llama import LlamaConfig
 
-from aigw_tpu.utils.shard_compat import shard_map_untyped_carry
-
 _STAGE_KEYS = (
     "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm",
     "w_gate", "w_up", "w_down",
@@ -130,9 +128,8 @@ def pipeline_logits(
             )
             return (received, outputs), None
 
-        # plain carries: the varying-manual-axes check that once needed
-        # pvary tagging is disabled at the shard_map call
-        # (utils/shard_compat.py — the deprecated lax.pvary migration)
+        # plain carries: ppermute makes them vary over the manual axis
+        # mid-scan, so the shard_map call passes check_vma=False
         received0 = jnp.zeros((microbatch, S, D), embed.dtype)
         outputs0 = jnp.zeros((M, microbatch, S, V), jnp.float32)
         (_, outputs), _ = lax.scan(
@@ -140,7 +137,7 @@ def pipeline_logits(
         )
         return outputs[None]  # [1, M, mb, S, V] — this stage's view
 
-    fn = shard_map_untyped_carry(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -148,6 +145,7 @@ def pipeline_logits(
             P(), P(), P(), P(),
         ),
         out_specs=P("pp"),
+        check_vma=False,
     )
     out = fn(stages, embed, norm_f, head, mb_tokens)  # [pp, M, mb, S, V]
     # only the last stage's row holds real logits

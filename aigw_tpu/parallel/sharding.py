@@ -44,6 +44,34 @@ def llama_param_specs(cfg: LlamaConfig) -> dict[str, P]:
     return specs
 
 
+def param_sharding_fn(cfg, mesh: Mesh):
+    """``(name, shape) -> NamedSharding`` for every parameter leaf of a
+    family on ``mesh`` — the ONE placement rule both weight creation
+    (models' ``init_params(sharding_of=…)``, checkpoint restore) and
+    the engine use, so weights are born where they will live.
+
+    Quantized leaves: ``name.q`` shards like the base matrix;
+    ``name.scale`` keeps the base spec only on axes it has extent in
+    and that the mesh axis divides (int8 keepdims axes of size 1 stay
+    unsharded; an int4 group count smaller than the axis replicates
+    instead of failing placement)."""
+    specs = (mixtral_param_specs(cfg) if hasattr(cfg, "n_experts")
+             else llama_param_specs(cfg))
+
+    def sharding_of(name: str, shape: tuple) -> NamedSharding:
+        if name.endswith(".q"):
+            return NamedSharding(mesh, specs[name[:-2]])
+        if name.endswith(".scale"):
+            base = specs[name[: -len(".scale")]]
+            return NamedSharding(mesh, P(*(
+                ax if (ax is not None and shape[i] > 1
+                       and shape[i] % mesh.shape[ax] == 0) else None
+                for i, ax in enumerate(base))))
+        return NamedSharding(mesh, specs[name])
+
+    return sharding_of
+
+
 def kv_cache_spec() -> P:
     """[L, 2, slots, n_kv_heads, head_dim] — shard KV heads over tp."""
     return P(None, None, None, "tp", None)

@@ -40,6 +40,7 @@ import numpy as np
 from aigw_tpu.analysis.registry import engine_thread_only
 from aigw_tpu.models import kvq, llama
 from aigw_tpu.obs.metrics import EnginePhases
+from aigw_tpu.obs import xla_events
 from aigw_tpu.obs.xla_events import CompileTracker
 from aigw_tpu.tpuserve import constrain, speculation
 from aigw_tpu.tpuserve.kvcache import (
@@ -76,13 +77,14 @@ def device_memory_stats() -> tuple[int, int]:
             int(ms.get("bytes_limit", 0) or 0))
 
 
-def device_memory_stats_all() -> list[tuple[int, str, int, int]]:
-    """Live (device_id, platform, bytes_in_use, bytes_limit) for EVERY
-    local device — the mesh-serving fix for PR 9's device-0-only poll
-    (a sharded engine's hottest device is rarely device 0). Zeros on
-    backends without memory stats (CPU); the list itself is still real
-    so per-device KV/param accounting has a device to hang off."""
-    out: list[tuple[int, str, int, int]] = []
+def device_memory_stats_all() -> list[dict]:
+    """Identity (id, platform, kind, coords) and live memory_stats()
+    bytes (in use, limit, peak) for EVERY local device — the
+    mesh-serving fix for PR 9's device-0-only poll (a sharded engine's
+    hottest device is rarely device 0). Zero bytes on backends without
+    memory stats (CPU); the list itself is still real so per-device
+    KV/param accounting has a device to hang off."""
+    out: list[dict] = []
     try:
         devices = jax.local_devices()
     except Exception:  # noqa: BLE001 — telemetry must never raise
@@ -92,9 +94,16 @@ def device_memory_stats_all() -> list[tuple[int, str, int, int]]:
             ms = d.memory_stats() or {}
         except Exception:  # noqa: BLE001
             ms = {}
-        out.append((int(d.id), str(getattr(d, "platform", "")),
-                    int(ms.get("bytes_in_use", 0) or 0),
-                    int(ms.get("bytes_limit", 0) or 0)))
+        out.append({
+            "id": int(d.id),
+            "platform": str(getattr(d, "platform", "")),
+            "kind": str(getattr(d, "device_kind", "")),
+            "coords": list(getattr(d, "coords", None) or ()),
+            "bytes_in_use": int(ms.get("bytes_in_use", 0) or 0),
+            "bytes_limit": int(ms.get("bytes_limit", 0) or 0),
+            "peak_bytes_in_use": int(
+                ms.get("peak_bytes_in_use", 0) or 0),
+        })
     return out
 
 
@@ -741,6 +750,11 @@ class EngineStats:
     # tick; a post-warmup delta is a hot-path compile regression
     xla_compiles: int = 0
     xla_compile_ms: float = 0.0
+    # persistent compile cache outcome, process-wide (weights compile
+    # before the engine exists): of all compile requests, how many were
+    # LOADED from the cache utils/boot.py placed and how many were built
+    xla_cache_hits: int = 0
+    xla_cache_misses: int = 0
     # prefill rate the gateway prices prompt length with (/state
     # prefill_ms_per_token): a token-decayed average rather than the
     # process-lifetime mean, so a traffic-mix change (chunked-sp long
@@ -961,54 +975,20 @@ class Engine:
             model_cfg.head_dim,
         )
         if mesh is not None:
-            from jax.sharding import NamedSharding
-
             from aigw_tpu.parallel.sharding import (
                 kv_cache_spec,
-                llama_param_specs,
-                mixtral_param_specs,
+                param_sharding_fn,
             )
 
-            specs = (
-                mixtral_param_specs(model_cfg)
-                if hasattr(model_cfg, "n_experts")
-                else llama_param_specs(model_cfg)
-            )
-
-            def spec_for(key: str, value) -> object:
-                # quantized weights: name.q shards like the base matrix;
-                # name.scale keeps the base spec only on axes it actually
-                # has extent in (keepdims axes of size 1 stay unsharded)
-                from jax.sharding import PartitionSpec as P
-
-                if key.endswith(".q"):
-                    return specs[key[:-2]]
-                if key.endswith(".scale"):
-                    # int8: keepdims size-1 axes stay unsharded. int4:
-                    # group axes ([.., in/G, out]) shard like the base
-                    # only when divisible by the mesh axis — a group
-                    # count smaller than the axis replicates instead of
-                    # failing device_put
-                    base = specs[key[: -len(".scale")]]
-
-                    def ok(i: int, ax) -> bool:
-                        if value.shape[i] <= 1 or ax is None:
-                            return False
-                        return value.shape[i] % mesh.shape[ax] == 0
-
-                    return P(*(
-                        ax if ok(i, ax) else None
-                        for i, ax in enumerate(base)
-                    ))
-                return specs[key]
-
+            # a no-op for weights the server created in place
+            # (init_params(sharding_of=…)); a reshard for the rest
+            sharding_of = param_sharding_fn(model_cfg, mesh)
             self.params = {
-                k: jax.device_put(v, NamedSharding(mesh, spec_for(k, v)))
+                k: jax.device_put(v, sharding_of(k, v.shape))
                 for k, v in params.items()
             }
-            pool = kvq.make_pool(kv_shape, cfg.kv_cache_dtype)
-            self.kv_cache = jax.device_put(
-                pool, kvq.pool_sharding_tree(pool, mesh, kv_cache_spec()))
+            self.kv_cache = kvq.make_pool(
+                kv_shape, cfg.kv_cache_dtype, mesh, kv_cache_spec())
         else:
             self.kv_cache = kvq.make_pool(kv_shape, cfg.kv_cache_dtype)
         # Per-slot decode state lives ON DEVICE between ticks (uploaded
@@ -2117,6 +2097,24 @@ class Engine:
         return chosen
 
     # -- public API -------------------------------------------------------
+    def queue_depth(self) -> tuple[int, float]:
+        """(interactive queue depth, age in ms of its oldest request),
+        read LIVE — callable from any thread. /state serves these
+        instead of the per-tick snapshot: while the engine thread sits
+        in an XLA compile (~20 s per program on the chip) nothing
+        refreshes ``stats``, and a picker routing on a stale ``queued``
+        of 0 piles a whole cold fleet's traffic onto one replica (seen
+        on four chips, PR 21: 56 of 56 requests on replica 0). Peeking
+        the underlying deque is safe: entries are only appended by
+        other threads, and a request popped between the qsize check and
+        the peek just yields a fresher head."""
+        depth = self._queue.qsize()
+        try:
+            head = self._queue.queue[0]
+        except IndexError:
+            return depth, 0.0
+        return depth, 1e3 * (time.monotonic() - head.enqueued_at)
+
     def start(self) -> None:
         self._thread = threading.Thread(
             target=self._run, name="tpuserve-engine", daemon=True
@@ -4494,7 +4492,7 @@ class Engine:
         # ``queued`` is INTERACTIVE depth only — the picker's
         # predicted_ttft_ms and the controller's idle predicate price
         # it; offline backlog rides the batch_* pair below
-        self.stats.queued = self._queue.qsize()
+        self.stats.queued, self.stats.queue_wait_ms = self.queue_depth()
         self.stats.batch_queued = (self._batch_q.qsize()
                                    + len(self._parked_batch))
         self.stats.batch_active = self._batch_active()
@@ -4505,6 +4503,8 @@ class Engine:
         self.stats.xla_compiles = self.compile_tracker.compiles()
         self.stats.xla_compile_ms = round(
             self.compile_tracker.compiles_total_ms(), 3)
+        self.stats.xla_cache_hits, self.stats.xla_cache_misses = (
+            xla_events.cache_counts())
         self.stats.kv_pages_free = self.allocator.free_pages
         self.stats.kv_occupancy = self.allocator.occupancy
         # adapter residency + tenant fairness gauges (ISSUE 7)
@@ -4549,17 +4549,15 @@ class Engine:
             mine = set(self.param_bytes_by_device) | set(kv_by_dev)
             devs: list[dict] = []
             worst = 0.0
-            for did, platform, used_d, limit_d in \
-                    device_memory_stats_all():
+            for dev in device_memory_stats_all():
+                did = dev["id"]
                 if mine and did not in mine:
                     continue
-                frac = round(used_d / limit_d, 4) if limit_d else 0.0
+                frac = (round(dev["bytes_in_use"] / dev["bytes_limit"], 4)
+                        if dev["bytes_limit"] else 0.0)
                 worst = max(worst, frac)
                 devs.append({
-                    "id": did,
-                    "platform": platform,
-                    "bytes_in_use": used_d,
-                    "bytes_limit": limit_d,
+                    **dev,
                     "memory_frac": frac,
                     "kv_pool_bytes": kv_by_dev.get(did, 0),
                     "kv_bytes_in_use": round(
@@ -4626,16 +4624,6 @@ class Engine:
         if self.prefix_cache is not None and now_d >= self._kv_digest_next:
             self._kv_digest_next = now_d + 0.5
             self._refresh_kv_digest()
-        # age of the oldest waiting request — the picker's queue-latency
-        # term. Peeking the underlying deque is safe here: entries are
-        # only appended by other threads, and a request popped between
-        # the qsize check and the peek just yields a fresher head.
-        try:
-            head = self._queue.queue[0]
-            self.stats.queue_wait_ms = 1e3 * (
-                time.monotonic() - head.enqueued_at)
-        except IndexError:
-            self.stats.queue_wait_ms = 0.0
 
 
 def continuation_request(blob: dict,

@@ -45,6 +45,7 @@ from aigw_tpu.obs.metrics import (
     render_engine_gauges,
     render_moe_gauges,
 )
+from aigw_tpu.obs import xla_events
 from aigw_tpu.obs.tracing import SpanContext, Tracer, genai_attributes
 from aigw_tpu.schemas import openai as oai
 from aigw_tpu.translate.sse import SSEEvent
@@ -53,6 +54,7 @@ from aigw_tpu.translate.structured import (
     parse_response_format,
 )
 from aigw_tpu.tpuserve import constrain
+from aigw_tpu.utils.boot import compile_cache_dir
 from aigw_tpu.utils.net import set_tcp_nodelay
 from aigw_tpu.tpuserve.engine import (
     Engine,
@@ -159,30 +161,32 @@ def _push_all(decoder: StreamingDecoder, toks: list[int]) -> list[str]:
 
 
 @functools.lru_cache(maxsize=1)
-def _device_topology_cached() -> tuple[str, tuple[int, ...]]:
-    try:
-        d = jax.devices()[0]
-    except Exception:  # backend init failure must not break /state
-        return "", ()
+def device_topology() -> dict[str, Any]:
+    """What JAX reports about this process's devices, for /state: the
+    platform, device kind and device count (so a reader can tell WHERE
+    the replica runs without importing jax), the slice the chips belong
+    to and the first chip's torus coords. The gateway picker keys its
+    same-slice preference (KV/ICI locality on failover) on ``slice``."""
+    devices = jax.devices()
+    d = devices[0]
     # TPU devices expose slice_index on multislice deployments and
     # coords (the chip's position in the ICI torus); CPU/GPU have
     # neither — they report an empty slice, and the picker falls back
     # to the statically configured slice label.
     slice_idx = getattr(d, "slice_index", None)
     coords = getattr(d, "coords", None)
-    slice_name = (
-        f"{d.platform}-slice-{slice_idx}" if slice_idx is not None else ""
-    )
-    return slice_name, tuple(coords) if coords is not None else ()
-
-
-def device_topology() -> dict[str, Any]:
-    """ICI topology of this server's devices for /state: the slice the
-    chips belong to and the first chip's torus coords, straight from
-    jax.devices(). The gateway picker keys its same-slice preference
-    (KV/ICI locality on failover) on the ``slice`` field."""
-    slice_name, coords = _device_topology_cached()
-    return {"slice": slice_name, "device_coords": list(coords)}
+    return {
+        "platform": d.platform,
+        "device_kind": d.device_kind,
+        "process_device_count": len(devices),
+        # the chip(s) the launcher confined this process to
+        # (utils/chips.py); "" = every chip of the host. A confined
+        # process sees its chip as device 0, so THIS is its identity
+        "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS", ""),
+        "slice": (f"{d.platform}-slice-{slice_idx}"
+                  if slice_idx is not None else ""),
+        "device_coords": list(coords) if coords is not None else [],
+    }
 
 
 def _find_stop(text: str, stop_strs: list[str]) -> int | None:
@@ -207,6 +211,11 @@ class TPUServeServer:
         ep: int = 1,  # expert parallel (MoE families)
         sp: int = 1,  # sequence parallel (ring-attention long prefill)
         quantize: str = "",  # "" | "int8" | "int4" (llama-family only)
+        # "random" overrides the registry's weight source ("" keeps
+        # it). Full-width models are registered with a checkpoint
+        # path: ``random`` serves them from seeded random weights where
+        # no checkpoint exists (chip bring-up)
+        weights: str = "",
         # name → adapter param dict (un-stacked [r,in]/[out,r] per target);
         # served when a request's model == "<base>:<adapter>" or the bare
         # adapter name. The dict is the ZOO — only lora_slots adapters
@@ -227,8 +236,12 @@ class TPUServeServer:
         # profiler endpoint on the data port is a DoS/inspection surface
         enable_profile_endpoint: bool = False,
     ):
+        # before any weight program compiles, so the process-wide
+        # compile / cache-hit counters see those too
+        xla_events.install()
         self.model_name = model
         spec = get_model_spec(model)
+        self.weights = weights or spec.weights
         self.fns = family_fns(spec.family)
         self.model_cfg = spec.config
         self.tokenizer = load_tokenizer(spec.tokenizer)
@@ -290,14 +303,7 @@ class TPUServeServer:
                 "weight quantization supports the llama and mixtral "
                 "families"
             )
-        params = self._load_params(spec)
-        if quantize:
-            from aigw_tpu.models.quant import quantize_params
-
-            params = quantize_params(params, consume=True,
-                                     mode=quantize)
-            logger.info("weights quantized to %s (W%sA16)", quantize,
-                        quantize[-1])
+        params = self._load_params(spec, mesh, quantize)
         adapter_store = None
         if lora_adapters:
             if spec.family != "llama":
@@ -391,21 +397,62 @@ class TPUServeServer:
         self.app.on_startup.append(self._on_start)
         self.app.on_cleanup.append(self._on_stop)
 
-    def _load_params(self, spec) -> dict[str, jax.Array]:
-        if spec.weights == "random":
+    def _load_params(self, spec, mesh, quantize: str
+                     ) -> dict[str, jax.Array]:
+        """Create or restore the weights WHERE THEY WILL LIVE: each
+        tensor is born with its mesh sharding (a tp model never passes
+        whole through one chip), and random weights are quantized
+        tensor by tensor as they are created (a bf16 model that does
+        not fit the chip never has to). Records the two boot
+        observables /state exports."""
+        from aigw_tpu.models.quant import quantize_params, quantize_tensor
+
+        sharding_of = None
+        if mesh is not None:
+            from aigw_tpu.parallel.sharding import param_sharding_fn
+
+            sharding_of = param_sharding_fn(self.model_cfg, mesh)
+        key = jax.random.PRNGKey(0)
+        quant_s = 0.0
+        t0 = time.monotonic()
+        if self.weights == "random":
             logger.info("initializing random weights for %s", spec.name)
-            return self.fns.init_params(jax.random.PRNGKey(0), self.model_cfg)
-        if spec.weights.startswith("orbax:"):
+
+            def finish(name: str, w: jax.Array) -> dict:
+                nonlocal quant_s
+                jax.block_until_ready(w)
+                tq = time.monotonic()
+                out = jax.block_until_ready(
+                    quantize_tensor(name, w, quantize))
+                quant_s += time.monotonic() - tq
+                return out
+
+            params = self.fns.init_params(
+                key, self.model_cfg, sharding_of=sharding_of,
+                finish=finish if quantize else None)
+        elif self.weights.startswith("orbax:"):
             from aigw_tpu.models.checkpoint import restore_checkpoint
 
-            path = spec.weights[len("orbax:") :]
+            path = self.weights[len("orbax:"):]
             logger.info("restoring orbax checkpoint %s", path)
             like = jax.eval_shape(
-                lambda: self.fns.init_params(jax.random.PRNGKey(0),
-                                             self.model_cfg)
-            )
-            return restore_checkpoint(path, like)
-        raise ValueError(f"unsupported weight source {spec.weights}")
+                lambda: self.fns.init_params(key, self.model_cfg))
+            params = restore_checkpoint(path, like, sharding_of)
+            if quantize:
+                tq = time.monotonic()
+                params = jax.block_until_ready(quantize_params(
+                    params, consume=True, mode=quantize))
+                quant_s = time.monotonic() - tq
+        else:
+            raise ValueError(f"unsupported weight source {self.weights}")
+        jax.block_until_ready(params)
+        total_s = time.monotonic() - t0
+        self.weights_init_ms = round(1e3 * (total_s - quant_s), 1)
+        self.weights_quantize_ms = round(1e3 * quant_s, 1)
+        if quantize:
+            logger.info("weights quantized to %s (W%sA16)", quantize,
+                        quantize[-1])
+        return params
 
     @property
     def adapter_names(self) -> tuple[str, ...]:
@@ -2096,6 +2143,9 @@ class TPUServeServer:
         s = self.engine.stats
         store = self.adapter_store
         tenant_slots = self.engine._tenant_slots()
+        # live, not the engine thread's per-tick snapshot: the backlog
+        # must stay visible to the picker while that thread compiles
+        queued, queue_wait_ms = self.engine.queue_depth()
         return web.json_response(
             {
                 "model": self.model_name,
@@ -2254,7 +2304,7 @@ class TPUServeServer:
                 "migration": self.engine.migratable,
                 "active_slots": s.active_slots,
                 "max_slots": self.engine.cfg.max_batch_size,
-                "queued": s.queued,
+                "queued": queued,
                 # priority-tiered serving (ISSUE 19): the offline class's
                 # footprint. ``queued``/``queue_wait_ms`` above stay
                 # interactive-only by construction (batch rides its own
@@ -2267,7 +2317,7 @@ class TPUServeServer:
                 "batch_resumed": s.batch_resumed,
                 "batch_tokens": s.batch_tokens,
                 "batch_slot_frac": self.engine.cfg.batch_slot_frac,
-                "queue_wait_ms": round(s.queue_wait_ms, 3),
+                "queue_wait_ms": round(queue_wait_ms, 3),
                 "kv_pages_free": s.kv_pages_free,
                 "kv_occupancy": s.kv_occupancy,
                 "tokens_generated": s.tokens_generated,
@@ -2327,6 +2377,17 @@ class TPUServeServer:
                 # growth after warmup = a hot-path compile regression
                 "xla_compiles": s.xla_compiles,
                 "xla_compile_ms": s.xla_compile_ms,
+                # a compile event is a LOAD when the persistent cache
+                # (utils/boot.py) served it — hits vs misses say which,
+                # process-wide so weight-init programs count too
+                "xla_cache_hits": s.xla_cache_hits,
+                "xla_cache_misses": s.xla_cache_misses,
+                "compile_cache_dir": compile_cache_dir(),
+                # boot observables: where the weights came from and
+                # what creating / quantizing them cost
+                "weights": self.weights,
+                "weights_init_ms": self.weights_init_ms,
+                "weights_quantize_ms": self.weights_quantize_ms,
                 # serving-phase latency distributions (p50/p95/p99 per
                 # ENGINE_HISTOGRAMS phase; -1 = no observations yet) —
                 # the bench reads TTFT/per-token spreads from here
@@ -2768,6 +2829,7 @@ async def run_tpuserve(
     ep: int = 1,
     sp: int = 1,
     quantize: str = "",
+    weights: str = "",
     lora_adapters: dict | None = None,
     lora_slots: int = 0,
     tenant_slot_cap: int = 0,
@@ -2829,6 +2891,7 @@ async def run_tpuserve(
         ep=ep,
         sp=sp,
         quantize=quantize,
+        weights=weights,
         lora_adapters=lora_adapters,
         lora_slots=lora_slots,
         flight_entries=flight_entries,

@@ -1,7 +1,11 @@
 """ctypes bindings for the C++ hot-loop helpers (native/sse_scan.cpp).
 
-Loaded lazily; every caller has a pure-Python fallback so the framework
-runs without the compiled library (build with ``make -C native``).
+Loaded lazily; every caller has a pure-Python twin so the framework
+runs without the compiled library (build with ``make -C native``). The
+library is a build product (git-ignored), so which scanner a process
+runs is reported, not assumed: the gateway's ``/health`` carries
+``native_scanner: loaded | python``, and ``chip_smoke.py`` builds the
+library from the committed sources and requires ``loaded``.
 """
 
 from __future__ import annotations

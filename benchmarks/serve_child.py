@@ -19,11 +19,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 
 def _install_trace(trace_path: str) -> None:
     """AIGW_TTFT_TRACE: append (event, t, id) lines for handler arrival,
@@ -83,6 +78,14 @@ def _install_trace(trace_path: str) -> None:
 
 
 def main() -> None:
+    # the platform is the one the launcher named in JAX_PLATFORMS; with
+    # none named a TPU is required (utils/boot.py) — this child never
+    # lands on a CPU nobody asked for
+    from aigw_tpu.utils.boot import boot_jax
+
+    boot_jax()
+
+    import jax
     from aiohttp import web
 
     from aigw_tpu.models import llama

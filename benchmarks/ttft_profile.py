@@ -23,11 +23,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 BATCH = 8
 PROMPT_LEN = 64
 GEN_TOKENS = 64

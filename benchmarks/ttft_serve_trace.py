@@ -21,11 +21,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 BATCH = 8
 CFG = {
     "vocab_size": 8192, "dim": 512, "n_layers": 4, "n_heads": 8,

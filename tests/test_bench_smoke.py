@@ -73,11 +73,16 @@ def test_bench_mfu_analytical():
                         + cfg.dim * cfg.vocab_size)
     # attention term grows with context
     assert bench.model_flops_per_token(cfg, 512) > f0
-    # mfu is linear in throughput and normalized by the chip peak
-    m1 = bench.model_mfu(cfg, 100.0, 128)
+    # mfu is linear in throughput and normalized by the peak listed
+    # for the device kind the rate was measured on
+    m1 = bench.model_mfu(cfg, 100.0, 128, "TPU v5 lite")
     assert m1 > 0
-    assert abs(bench.model_mfu(cfg, 200.0, 128) - 2 * m1) < 1e-12
-    assert bench.model_mfu(cfg, 100.0, 128, peak_flops=1e12) > m1
+    assert abs(bench.model_mfu(cfg, 200.0, 128, "TPU v5 lite")
+               - 2 * m1) < 1e-12
+    # a kind with no listed peak is an error, never a default: a CPU
+    # run cannot print a utilization
+    with pytest.raises(ValueError, match="cpu"):
+        bench.model_mfu(cfg, 100.0, 128, jax.devices()[0].device_kind)
 
 
 @pytest.mark.bench_smoke

@@ -27,6 +27,33 @@ def test_orbax_roundtrip(tmp_path):
                                       np.asarray(got[k]))
 
 
+def test_orbax_restore_lands_sharded(tmp_path):
+    """``sharding_of``: each tensor is restored straight into its mesh
+    sharding (the path a ``--tp`` model larger than one chip takes),
+    not restored whole and resharded afterwards."""
+    from aigw_tpu.parallel.mesh import MeshSpec, make_mesh
+    from aigw_tpu.parallel.sharding import param_sharding_fn
+
+    params = llama.init_params(jax.random.PRNGKey(0), CFG)
+    path = str(tmp_path / "ckpt")
+    save_checkpoint(params, path)
+    mesh = make_mesh(MeshSpec(tp=2))
+    sharding_of = param_sharding_fn(CFG, mesh)
+    like = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), CFG))
+    got = restore_checkpoint(path, like, sharding_of)
+    assert set(got) == set(params)
+    for k, x in got.items():
+        assert x.sharding.is_equivalent_to(
+            sharding_of(k, x.shape), x.ndim), k
+        np.testing.assert_array_equal(np.asarray(params[k]), np.asarray(x))
+    # a column-parallel matrix: half of it on each of the two devices
+    wq = got["l0.wq"]
+    assert len(wq.addressable_shards) == 2
+    assert {s.data.shape for s in wq.addressable_shards} == {
+        (wq.shape[0], wq.shape[1] // 2)}
+
+
 def test_hf_import_matches_native(tmp_path):
     """Write our params in HF layout (names + [out,in] transposes), import
     them back, and prove identical logits."""

@@ -1005,3 +1005,46 @@ class TestLosslessDrainLive:
         finally:
             asyncio.run(launcher.close())
             rep_b.term(timeout=60)
+
+
+def test_local_launcher_gives_each_replica_its_own_chip(tmp_path):
+    """One process per chip (ISSUE 21): with ``chips`` = N the launcher
+    confines each child to the lowest free chip through its environment
+    — set before the child starts — refuses a launch when every chip is
+    taken, and hands a chip out again once its replica is gone. The
+    child here is a stub that reports the environment it was given."""
+    child = tmp_path / "child.py"
+    child.write_text(
+        "import json, os, sys, time\n"
+        "spec = json.loads(sys.argv[1])\n"
+        "port = spec['base'] + int(os.environ['TPU_VISIBLE_CHIPS'])\n"
+        "open(os.path.join(spec['dir'], str(port)), 'w').write(\n"
+        "    json.dumps({k: v for k, v in os.environ.items()\n"
+        "                if k.startswith('TPU_')}))\n"
+        "print(f'SERVE_PORT={port}', flush=True)\n"
+        "time.sleep(60)\n")
+    launcher = LocalProcessLauncher(
+        {"dir": str(tmp_path), "base": 40000}, child_path=str(child),
+        term_grace_s=5.0, chips=2)
+
+    def env_of(addr: str) -> dict:
+        return json.loads((tmp_path / addr.rsplit(":", 1)[1]).read_text())
+
+    async def main():
+        try:
+            a, b = await asyncio.gather(launcher.launch(),
+                                        launcher.launch())
+            assert sorted(env_of(x)["TPU_VISIBLE_CHIPS"]
+                          for x in (a, b)) == ["0", "1"]
+            assert env_of(a)["TPU_PROCESS_BOUNDS"] == "1,1,1"
+            assert env_of(a)["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+            with pytest.raises(RuntimeError, match="hold a replica"):
+                await launcher.launch()
+            freed = env_of(a)["TPU_VISIBLE_CHIPS"]
+            await launcher.terminate(a)
+            c = await launcher.launch()
+            assert env_of(c)["TPU_VISIBLE_CHIPS"] == freed
+        finally:
+            await launcher.close()
+
+    asyncio.run(main())

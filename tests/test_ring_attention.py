@@ -68,8 +68,8 @@ def test_ring_production_shape_ab_smoke():
     """A/B smoke at the shape the sp path actually serves — llama-3-8B
     attention extents (H=32, Hkv=8, D=128) at the sp_prefill_min_tokens
     threshold (S=1024) — ring kernel on the virtual 8-device mesh vs
-    the single-device XLA reference. Exercises the pvary-migrated scan
-    carries (utils/shard_compat.py) at production extents, where a
+    the single-device XLA reference. Exercises the plain scan carries
+    (jax.shard_map(check_vma=False)) at production extents, where a
     varying-axes typing bug would corrupt the online-softmax
     accumulator rather than just failing to trace."""
     B, S, H, Hkv, D = 1, 1024, 32, 8, 128

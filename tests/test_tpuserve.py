@@ -829,6 +829,12 @@ def test_queue_overload_raises():
     with pytest.raises(EngineOverloadedError):
         eng.submit(GenRequest(prompt=[1], max_tokens=1,
                               sampling=SamplingParams()))
+    # the backlog is readable LIVE from any thread — /state serves this,
+    # not the engine thread's per-tick snapshot, which goes stale for as
+    # long as that thread sits in a compile (here: never started)
+    depth, wait_ms = eng.queue_depth()
+    assert depth == 2 and wait_ms > 0.0
+    assert eng.stats.queued == 0
 
 
 def test_top_p_temperature_order():
