@@ -188,6 +188,7 @@ def _w(p: dict[str, jax.Array], key: str) -> jax.Array:
     return q.astype(jnp.bfloat16) * scale.astype(jnp.bfloat16)
 
 
+@jax.named_scope("embed")
 def _embed_rows(p: dict[str, jax.Array], tokens: jax.Array) -> jax.Array:
     q = p.get("embed.q")
     if q is None:
@@ -219,6 +220,7 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+@jax.named_scope("layer/attn")
 def _attention(
     q: jax.Array,  # [B, S, H, D]
     k: jax.Array,  # [B, T, Hkv, D]
@@ -264,6 +266,15 @@ def _matmul(p: dict[str, jax.Array], key: str, x: jax.Array) -> jax.Array:
     return y.reshape(*lead, n)
 
 
+@jax.named_scope("layer/kv_gather")
+def _gather_kv(kv_cache, i, gslot):
+    """Layer i's K/V window read out of the page pool (dequantizing
+    when the pool is int8/int4), under a scope of its own: in a trace
+    it is the decode step's HBM-bound half."""
+    return kvq.gather_kv(kv_cache, i, gslot)
+
+
+@jax.named_scope("layer/attn")
 def _wo_project(p, i, attn, lora=None, adapter_idx=None):
     """Attention out-projection with optional per-slot LoRA delta."""
     out = _matmul(p, f"l{i}.wo", attn)
@@ -271,6 +282,7 @@ def _wo_project(p, i, attn, lora=None, adapter_idx=None):
     return out if d is None else out + d
 
 
+@jax.named_scope("layer/attn")
 def _project_qkv(p, i, x, positions, cfg, lora=None, adapter_idx=None,
                  apply_rope=True):
     hd = cfg.head_dim
@@ -298,6 +310,7 @@ def _project_qkv(p, i, x, positions, cfg, lora=None, adapter_idx=None,
     return q, k, v
 
 
+@jax.named_scope("layer/mlp")
 def _mlp(p, i, x, lora=None, adapter_idx=None):
     def with_delta(y, name, inp):
         d = lora_delta(lora, f"l{i}.{name}", inp, adapter_idx)
@@ -310,6 +323,7 @@ def _mlp(p, i, x, lora=None, adapter_idx=None):
     return with_delta(_matmul(p, f"l{i}.w_down", h), "w_down", h)
 
 
+@jax.named_scope("lm_head")
 def _logits(p: dict[str, jax.Array], cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     if cfg.tie_embeddings:
         return (x @ _w(p, "embed").T).astype(jnp.float32)
@@ -410,10 +424,11 @@ def prefill_sp(
         q, k, v = _project_qkv(p, i, h, positions, cfg, lora, adapter_idx)
         flat = jnp.where(valid, slot, n_slots)
         kv_cache = kvq.scatter_kv(kv_cache, i, flat, k, v)
-        attn = ring_attention(
-            q, k.astype(q.dtype), v.astype(q.dtype),
-            mesh=mesh, causal=True, strategy=strategy,
-        ).astype(x.dtype)
+        with jax.named_scope("layer/attn"):
+            attn = ring_attention(
+                q, k.astype(q.dtype), v.astype(q.dtype),
+                mesh=mesh, causal=True, strategy=strategy,
+            ).astype(x.dtype)
         x = x + _wo_project(p, i, attn, lora, adapter_idx)
         h = rms_norm(x, p[f"l{i}.mlp_norm"], cfg.norm_eps)
         x = x + (mlp(p, i, h) if mlp is not None
@@ -477,12 +492,13 @@ def prefill_sp_suffix(
         h = rms_norm(x, p[f"l{i}.attn_norm"], cfg.norm_eps)
         q, k, v = _project_qkv(p, i, h, positions, cfg, lora, adapter_idx)
         kv_cache = kvq.scatter_kv(kv_cache, i, flat, k, v)
-        k_all, v_all = kvq.gather_kv(kv_cache, i, gslot)
-        attn = ring_attention_prefix(
-            q, k.astype(q.dtype), v.astype(q.dtype),
-            k_all.astype(q.dtype), v_all.astype(q.dtype),
-            prefix_lens, mesh=mesh,
-        ).astype(x.dtype)
+        k_all, v_all = _gather_kv(kv_cache, i, gslot)
+        with jax.named_scope("layer/attn"):
+            attn = ring_attention_prefix(
+                q, k.astype(q.dtype), v.astype(q.dtype),
+                k_all.astype(q.dtype), v_all.astype(q.dtype),
+                prefix_lens, mesh=mesh,
+            ).astype(x.dtype)
         x = x + _wo_project(p, i, attn, lora, adapter_idx)
         h = rms_norm(x, p[f"l{i}.mlp_norm"], cfg.norm_eps)
         x = x + (mlp(p, i, h) if mlp is not None
@@ -592,35 +608,38 @@ def decode_step(
             # come back with the new row already written
             kr, ksc = kvq.layer_pool(kv_cache, i, 0)
             vr, vsc = kvq.layer_pool(kv_cache, i, 1)
-            outs = fused_paged_decode(
-                q[:, 0], k[:, 0], v[:, 0], kr, vr, page_table,
-                positions, active, k_scale=ksc, v_scale=vsc,
-                rope_theta=cfg.rope_theta, page_size=page_size,
-                interpret=interp)
+            with jax.named_scope("layer/attn"):
+                outs = fused_paged_decode(
+                    q[:, 0], k[:, 0], v[:, 0], kr, vr, page_table,
+                    positions, active, k_scale=ksc, v_scale=vsc,
+                    rope_theta=cfg.rope_theta, page_size=page_size,
+                    interpret=interp)
             attn = outs[0].reshape(B, 1, HD)
             kv_cache = kvq.set_layer_pool(kv_cache, i, *outs[1:])
         else:
             kv_cache = kvq.scatter_kv(kv_cache, i, slot, k, v)
             if use_pallas:
-                attn = paged_attention_decode_v2(
-                    q[:, 0], kv_cache[i, 0], kv_cache[i, 1], page_table,
-                    lengths, page_size=page_size, interpret=interp,
-                ).reshape(B, 1, HD)
+                with jax.named_scope("layer/attn"):
+                    attn = paged_attention_decode_v2(
+                        q[:, 0], kv_cache[i, 0], kv_cache[i, 1], page_table,
+                        lengths, page_size=page_size, interpret=interp,
+                    ).reshape(B, 1, HD)
             elif use_fused_walk:
                 kr, ksc = kvq.layer_pool(kv_cache, i, 0)
                 vr, vsc = kvq.layer_pool(kv_cache, i, 1)
-                if mesh is not None:
-                    attn = paged_decode_walk_spmd(
-                        q[:, 0], kr, vr, page_table, lengths,
-                        mesh=mesh, page_size=page_size,
-                        k_scale=ksc, v_scale=vsc)
-                else:
-                    attn = paged_decode_walk(
-                        q[:, 0], kr, vr, page_table, lengths,
-                        page_size=page_size, k_scale=ksc, v_scale=vsc)
+                with jax.named_scope("layer/attn"):
+                    if mesh is not None:
+                        attn = paged_decode_walk_spmd(
+                            q[:, 0], kr, vr, page_table, lengths,
+                            mesh=mesh, page_size=page_size,
+                            k_scale=ksc, v_scale=vsc)
+                    else:
+                        attn = paged_decode_walk(
+                            q[:, 0], kr, vr, page_table, lengths,
+                            page_size=page_size, k_scale=ksc, v_scale=vsc)
                 attn = attn.reshape(B, 1, HD)
             else:
-                k_all, v_all = kvq.gather_kv(kv_cache, i, gslot)
+                k_all, v_all = _gather_kv(kv_cache, i, gslot)
                 attn = _attention(q, k_all, v_all, attend[:, None, :])
         x = x + _wo_project(p, i, attn, lora, adapter_idx)
         h = rms_norm(x, p[f"l{i}.mlp_norm"], cfg.norm_eps)
@@ -697,12 +716,13 @@ def verify_step(
         q, k, v = _project_qkv(p, i, h, positions, cfg, lora, adapter_idx)
         kv_cache = kvq.scatter_kv(kv_cache, i, flat, k, v)
         if use_pallas:
-            attn = paged_attention_verify(
-                q, kv_cache[i, 0], kv_cache[i, 1], page_table, pal_pos,
-                page_size=page_size, interpret=interp,
-            ).reshape(B, S, cfg.n_heads * cfg.head_dim)
+            with jax.named_scope("layer/attn"):
+                attn = paged_attention_verify(
+                    q, kv_cache[i, 0], kv_cache[i, 1], page_table, pal_pos,
+                    page_size=page_size, interpret=interp,
+                ).reshape(B, S, cfg.n_heads * cfg.head_dim)
         else:
-            k_all, v_all = kvq.gather_kv(kv_cache, i, gslot)
+            k_all, v_all = _gather_kv(kv_cache, i, gslot)
             mask = (t_idx[:, None, :] <= positions[:, :, None]) \
                 & valid[..., None]
             attn = _attention(q, k_all, v_all, mask)
@@ -714,6 +734,7 @@ def verify_step(
     return _logits(p, cfg, x), kv_cache
 
 
+@jax.named_scope("layer/attn")
 def _ragged_window_attention(
     q: jax.Array,  # [T, H, D] packed queries (f32/bf16)
     k_pool: jax.Array,  # [n_slots, Hkv, D] (native or int8/int4)
@@ -845,10 +866,11 @@ def prefill_ragged(
         q, k, v = _project_qkv(p, i, h, pos2, cfg, lora, atok)
         kv_cache = kvq.scatter_kv(kv_cache, i, flat, k, v)
         if use_pallas:
-            attn = ragged_prefill_attention(
-                q[:, 0], kv_cache[i, 0], kv_cache[i, 1], page_table,
-                cu, start, page_size=page_size, interpret=interp,
-            ).reshape(T, 1, cfg.n_heads * cfg.head_dim)
+            with jax.named_scope("layer/attn"):
+                attn = ragged_prefill_attention(
+                    q[:, 0], kv_cache[i, 0], kv_cache[i, 1], page_table,
+                    cu, start, page_size=page_size, interpret=interp,
+                ).reshape(T, 1, cfg.n_heads * cfg.head_dim)
         else:
             kr, ksc = kvq.layer_pool(kv_cache, i, 0)
             vr, vsc = kvq.layer_pool(kv_cache, i, 1)
@@ -939,7 +961,7 @@ def prefill_suffix(
         h = rms_norm(x, p[f"l{i}.attn_norm"], cfg.norm_eps)
         q, k, v = _project_qkv(p, i, h, positions, cfg, lora, adapter_idx)
         kv_cache = kvq.scatter_kv(kv_cache, i, flat, k, v)
-        k_all, v_all = kvq.gather_kv(kv_cache, i, gslot)
+        k_all, v_all = _gather_kv(kv_cache, i, gslot)
         # causal over global positions; padded queries masked by `valid`
         mask = (t_idx[:, None, :] <= positions[:, :, None]) & valid[..., None]
         attn = _attention(q, k_all, v_all, mask)

@@ -116,38 +116,45 @@ def moe_mlp(p: dict[str, jax.Array], i: int, x: jax.Array,
     C = min(C, T)
     xt = x.reshape(T, D)
 
-    logits = (xt.astype(jnp.float32) @ p[f"l{i}.gate"].astype(jnp.float32))
-    topv, topi = jax.lax.top_k(logits, K)  # [T, K]
-    weights = jax.nn.softmax(topv, axis=-1)  # normalize over chosen experts
+    with jax.named_scope("layer/moe_route"):
+        logits = (xt.astype(jnp.float32)
+                  @ p[f"l{i}.gate"].astype(jnp.float32))
+        topv, topi = jax.lax.top_k(logits, K)  # [T, K]
+        # normalize over chosen experts
+        weights = jax.nn.softmax(topv, axis=-1)
 
-    # one-hot expert choice per (token, k): [T, K, E]
-    choice = jax.nn.one_hot(topi, E, dtype=jnp.float32)
-    # position of each (t, k) within its expert: cumulative count over the
-    # flattened (t, k) order
-    flat_choice = choice.reshape(T * K, E)
-    pos = (jnp.cumsum(flat_choice, axis=0) - flat_choice).reshape(T, K, E)
-    pos = jnp.sum(pos * choice, axis=-1).astype(jnp.int32)  # [T, K]
-    keep = pos < C  # capacity fence
-    pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32) * keep[..., None]
-    # dispatch [T, E, C]
-    dispatch = jnp.einsum("tke,tkc->tec", choice, pos_oh)
-    combine = jnp.einsum("tke,tkc,tk->tec", choice, pos_oh, weights)
-    if tape is not None:
-        placed = jnp.sum(dispatch, axis=(0, 2)).astype(jnp.int32)  # [E]
-        dropped = jnp.sum(~keep).astype(jnp.int32)
-        tape.append(jnp.concatenate([placed, dropped[None]]))
+        # one-hot expert choice per (token, k): [T, K, E]
+        choice = jax.nn.one_hot(topi, E, dtype=jnp.float32)
+        # position of each (t, k) within its expert: cumulative count
+        # over the flattened (t, k) order
+        flat_choice = choice.reshape(T * K, E)
+        pos = (jnp.cumsum(flat_choice, axis=0)
+               - flat_choice).reshape(T, K, E)
+        pos = jnp.sum(pos * choice, axis=-1).astype(jnp.int32)  # [T, K]
+        keep = pos < C  # capacity fence
+        pos_oh = (jax.nn.one_hot(pos, C, dtype=jnp.float32)
+                  * keep[..., None])
+        # dispatch [T, E, C]
+        dispatch = jnp.einsum("tke,tkc->tec", choice, pos_oh)
+        combine = jnp.einsum("tke,tkc,tk->tec", choice, pos_oh, weights)
+        if tape is not None:
+            # [E]
+            placed = jnp.sum(dispatch, axis=(0, 2)).astype(jnp.int32)
+            dropped = jnp.sum(~keep).astype(jnp.int32)
+            tape.append(jnp.concatenate([placed, dropped[None]]))
 
-    xe = jnp.einsum("tec,td->ecd", dispatch, xt.astype(jnp.float32))
-    xe = xe.astype(x.dtype)
-    # expert weights resolve through llama._w so W8A16/W4A16 params
-    # ([E, in, out] int8 per-channel / int4 group scales) dequantize at
-    # the einsum operand — XLA fuses it; HBM streams the packed bytes
-    gate = jax.nn.silu(jnp.einsum(
-        "ecd,edf->ecf", xe, llama._w(p, f"l{i}.w_gate")))
-    up = jnp.einsum("ecd,edf->ecf", xe, llama._w(p, f"l{i}.w_up"))
-    ye = jnp.einsum("ecf,efd->ecd", gate * up,
-                    llama._w(p, f"l{i}.w_down"))
-    out = jnp.einsum("tec,ecd->td", combine, ye.astype(jnp.float32))
+    with jax.named_scope("layer/moe_experts"):
+        xe = jnp.einsum("tec,td->ecd", dispatch, xt.astype(jnp.float32))
+        xe = xe.astype(x.dtype)
+        # expert weights resolve through llama._w so W8A16/W4A16 params
+        # ([E, in, out] int8 per-channel / int4 group scales) dequantize at
+        # the einsum operand — XLA fuses it; HBM streams the packed bytes
+        gate = jax.nn.silu(jnp.einsum(
+            "ecd,edf->ecf", xe, llama._w(p, f"l{i}.w_gate")))
+        up = jnp.einsum("ecd,edf->ecf", xe, llama._w(p, f"l{i}.w_up"))
+        ye = jnp.einsum("ecf,efd->ecd", gate * up,
+                        llama._w(p, f"l{i}.w_down"))
+        out = jnp.einsum("tec,ecd->td", combine, ye.astype(jnp.float32))
     return out.astype(x.dtype).reshape(B, S, D)
 
 

@@ -18,6 +18,12 @@ Threading: entries are written by the engine thread and the server's
 event loop and read by debug endpoints. Every mutation is a dict/list
 append or scalar store (GIL-atomic); the ring itself takes a small lock
 only on begin/finish, never per token or per event.
+
+The recorder's other half is the :class:`LoopLedger`: what the ENGINE
+LOOP did, as cumulative self time per phase (``/state`` ``loop_*``), and
+— only while ``/debug/profile`` captures — the same phases as
+``engine/<phase>`` annotations on the profiler's clock, with the
+engine's counters cut to the capture (``capture_*``).
 """
 
 from __future__ import annotations
@@ -27,6 +33,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any
+
+from aigw_tpu.obs.metrics import CAPTURE_COUNTERS, LOOP_PHASES
 
 #: per-entry cap on recorded events — a long generation must not grow an
 #: unbounded timeline; past the cap only counters advance
@@ -200,14 +208,22 @@ class RequestTrace:
     observed by the engine itself (they cover untraced requests too);
     this sink only records timelines and spans."""
 
-    __slots__ = ("entry", "tracer", "span", "_decode_span")
+    __slots__ = ("entry", "tracer", "span", "_decode_span", "loop")
 
     def __init__(self, entry: FlightEntry, tracer: Any = None,
-                 span: Any = None):
+                 span: Any = None, loop: "LoopLedger | None" = None):
         self.entry = entry
         self.tracer = tracer
         self.span = span
         self._decode_span = None
+        # the engine's loop ledger: while a profiler capture runs, the
+        # three events that explain a device gap (admitted, first token,
+        # finished) also land on the profiler's clock, under this id
+        self.loop = loop
+
+    def _instant(self, name: str) -> None:
+        if self.loop is not None:  # a no-op unless a capture runs
+            self.loop.instant(name, self.entry.rid)
 
     @property
     def trace_id(self) -> str:
@@ -246,6 +262,7 @@ class RequestTrace:
             self.entry.event("admission", **attrs)
             if self.span is not None:
                 self.span.add_event("admission", attrs)
+            self._instant("request/admitted")
         except Exception:  # noqa: BLE001
             pass
 
@@ -274,6 +291,7 @@ class RequestTrace:
             self.entry.event("first_token")
             if self.span is not None:
                 self.span.add_event("first_token")
+            self._instant("request/first_token")
         except Exception:  # noqa: BLE001
             pass
 
@@ -329,6 +347,7 @@ class RequestTrace:
         trims and client disconnects the engine never sees)."""
         try:
             self.event("engine_finish", reason=reason)
+            self._instant("request/finished")
             if self._decode_span is not None:
                 self._decode_span.set(
                     "tpuserve.decode_windows", self.entry.decode_windows)
@@ -338,3 +357,151 @@ class RequestTrace:
                 self._decode_span = None
         except Exception:  # noqa: BLE001
             pass
+
+
+# -- the engine loop's ledger ------------------------------------------------
+#: phase ids (indices into LOOP_PHASES), for ``LoopLedger.enter``
+(REAP, ADMIT, ADMIT_WAIT, PREFILL_DISPATCH, PREFILL_BLOCK, STATE_BUILD,
+ ROW_UPDATE, DECODE_DISPATCH, WINDOW_FETCH, EMIT, IDLE,
+ OTHER) = range(len(LOOP_PHASES))
+
+#: ``LoopLedger.capture``: no capture / /debug/profile raised the flag /
+#: it lowered the flag and waits for the engine thread to close the span
+CAPTURE_OFF, CAPTURE_ON, CAPTURE_CLOSING = 0, 1, 2
+
+
+def _trace_annotation(name: str, **facts: Any):
+    """A span on the profiler's clock (constructed only while a capture
+    runs; tests count the calls through this name)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **facts)
+
+
+class LoopLedger:
+    """Where the engine loop's time went: cumulative nanoseconds and
+    entry counts per phase of ``LOOP_PHASES``, owned by the engine
+    thread. ``enter(phase)`` closes the running phase at one
+    ``perf_counter_ns`` read and opens the next, so the phases partition
+    the loop and each holds SELF time: code that enters a phase inside
+    another takes the id ``enter`` returned and hands it to ``resume``
+    when done. No lock and no allocation: other threads read the two
+    lists (int loads are GIL-atomic; a reader may see one phase change
+    half applied, which is a few microseconds of one phase).
+
+    While ``capture`` is set (by ``/debug/profile``, between
+    ``start_trace`` and ``stop_trace``) every phase is also an
+    ``engine/<phase>`` ``TraceAnnotation`` carrying the ``facts`` its
+    call site had at hand, and the counters of ``CAPTURE_COUNTERS`` (read
+    off ``stats``) are snapshotted at the first phase boundary after the
+    flag went up and at the first after it went down: their differences
+    accumulate under ``capture_*``, counted over whole windows and whole
+    prefill calls that lie inside the trace. Off the capture the cost is
+    one attribute test per phase change."""
+
+    def __init__(self, stats: Any = None) -> None:
+        n = len(LOOP_PHASES)
+        self.ns = [0] * n
+        self.n = [0] * n
+        self.cur = OTHER
+        self.t = time.perf_counter_ns()
+        self.stats = stats
+        self.capture = CAPTURE_OFF
+        self._ann: Any = None  # the open engine/<phase> annotation
+        self._snap: tuple | None = None  # counters at the capture's start
+        self.captured = dict.fromkeys(
+            ["capture_ns", *(f"capture_{c}" for c in CAPTURE_COUNTERS),
+             *(f"capture_loop_{p}_ns" for p in LOOP_PHASES)], 0)
+        self.last_capture: dict[str, int] = {}
+
+    # -- engine thread ------------------------------------------------------
+    def enter(self, phase: int, facts: dict | None = None) -> int:
+        """Switch to ``phase``; returns the phase that was running."""
+        prev = self.resume(phase, facts)
+        self.n[phase] += 1
+        return prev
+
+    def resume(self, phase: int, facts: dict | None = None) -> int:
+        """``enter`` without counting an entry: back to a suspended
+        phase, or the running phase again with the facts now known."""
+        now = time.perf_counter_ns()
+        prev = self.cur
+        self.ns[prev] += now - self.t
+        self.t = now
+        self.cur = phase
+        if self.capture:
+            self._traced(phase, facts, now)
+        return prev
+
+    def start(self) -> None:
+        """The loop starts: what came before is nobody's time."""
+        self.t = time.perf_counter_ns()
+        self.cur = OTHER
+
+    def prefill_ns(self) -> int:
+        return self.ns[PREFILL_DISPATCH] + self.ns[PREFILL_BLOCK]
+
+    def instant(self, name: str, rid: str) -> None:
+        """A zero-length mark on the profiler's clock (engine thread,
+        inside the running phase's span)."""
+        if self._ann is not None:
+            a = _trace_annotation(name, rid=rid)
+            a.__enter__()
+            a.__exit__(None, None, None)
+
+    def _counters(self, now: int) -> tuple:
+        return (now, list(self.ns),
+                [int(getattr(self.stats, c, 0)) for c in CAPTURE_COUNTERS])
+
+    def _traced(self, phase: int, facts: dict | None, now: int) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._snap is None:  # first boundary after the flag went up
+            self._snap = self._counters(now)
+        if self.capture == CAPTURE_CLOSING:
+            t0, ns0, c0 = self._snap
+            _, ns1, c1 = self._counters(now)
+            last = {"capture_ns": now - t0}
+            for c, a, b in zip(CAPTURE_COUNTERS, c0, c1):
+                last[f"capture_{c}"] = b - a
+            for p, a, b in zip(LOOP_PHASES, ns0, ns1):
+                last[f"capture_loop_{p}_ns"] = b - a
+            for k, v in last.items():
+                self.captured[k] += v
+            self.last_capture = last
+            self._snap = None
+            self.capture = CAPTURE_OFF
+            return
+        self._ann = _trace_annotation(
+            "engine/" + LOOP_PHASES[phase], **(facts or {}))
+        self._ann.__enter__()
+
+    # -- any thread ---------------------------------------------------------
+    def capture_begin(self) -> None:
+        """Call after ``start_trace`` has returned."""
+        self.capture = CAPTURE_ON
+
+    def capture_end(self, timeout_s: float = 2.0) -> dict[str, int]:
+        """Call before ``stop_trace``: lowers the flag, waits for the
+        engine thread's next phase boundary (an idle loop has one every
+        50 ms) and returns that capture's counts — empty if the engine
+        thread never came."""
+        self.last_capture = {}
+        self.capture = CAPTURE_CLOSING
+        deadline = time.monotonic() + timeout_s
+        while self.capture != CAPTURE_OFF and time.monotonic() < deadline:
+            time.sleep(0.002)
+        return dict(self.last_capture)
+
+    def flat(self) -> dict[str, int]:
+        """The ledger as flat, cumulative /state keys (LOOP_GAUGES)."""
+        ns, n = list(self.ns), list(self.n)
+        total = sum(ns)
+        out = {"loop_ns": total, "loop_busy_ns": total - ns[IDLE]}
+        for i, p in enumerate(LOOP_PHASES):
+            out[f"loop_{p}_ns"] = ns[i]
+            out[f"loop_{p}_n"] = n[i]
+        out["prefill_calls"] = int(getattr(self.stats, "prefill_calls", 0))
+        out.update(self.captured)
+        return out

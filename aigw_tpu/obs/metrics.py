@@ -459,14 +459,69 @@ def render_controller_gauges(values: dict, backend: str = "") -> bytes:
     return ("\n".join(lines) + "\n").encode()
 
 
+#: the engine loop's phases (obs/flight.py LoopLedger; the index is the
+#: phase id ``enter()`` takes). Every nanosecond of ``Engine._run``
+#: belongs to exactly one: a phase entered inside another suspends the
+#: outer one, so each is SELF time. By where the work happens —
+#: reap: cancelled slots and the migration queue; admit: queue pops,
+#: classification, prefix lookups, slot set-up (admit_wait: the
+#: coalescing sleep alone); prefill_dispatch / prefill_block: host
+#: building and dispatching a prefill call / host blocked on its
+#: sampled token; state_build: the full device-state upload;
+#: row_update: the per-row scatters (dirty rows, draft lengths, grammar
+#: masks); decode_dispatch: the tick's bookkeeping and the window's
+#: dispatch; window_fetch: host blocked fetching the in-flight window's
+#: tokens (waiting for the device); emit: handing tokens to consumers;
+#: idle: nothing to do (``_wake.wait``); other: the remainder.
+LOOP_PHASES: tuple[str, ...] = (
+    "reap", "admit", "admit_wait", "prefill_dispatch", "prefill_block",
+    "state_build", "row_update", "decode_dispatch", "window_fetch",
+    "emit", "idle", "other",
+)
+
+#: engine counters cut to a profiler capture: their deltas between the
+#: first phase boundary after /debug/profile raised the capture flag
+#: and the first after it lowered it accumulate under ``capture_<name>``
+CAPTURE_COUNTERS: tuple[str, ...] = (
+    "decode_steps", "tokens_generated", "prefill_tokens_real",
+    "prefill_tokens_padded", "prefill_calls",
+)
+
+#: the loop ledger's flat surface: key of ``LoopLedger.flat()`` (spread
+#: into /state as it is) → /metrics family. Generated from the two
+#: tables above, rendered by ``render_engine_gauges`` after
+#: ENGINE_GAUGES; all cumulative, none ever decreases.
+LOOP_GAUGES: tuple[tuple[str, str], ...] = (
+    ("loop_ns", "tpuserve_loop_ns_total"),
+    ("loop_busy_ns", "tpuserve_loop_busy_ns_total"),
+    *((f"loop_{p}_ns", f"tpuserve_loop_{p}_ns_total")
+      for p in LOOP_PHASES),
+    *((f"loop_{p}_n", f"tpuserve_loop_{p}_entries_total")
+      for p in LOOP_PHASES),
+    ("prefill_calls", "tpuserve_prefill_calls_total"),
+    ("capture_ns", "tpuserve_capture_ns_total"),
+    *((f"capture_{c}", f"tpuserve_capture_{c}_total")
+      for c in CAPTURE_COUNTERS),
+    *((f"capture_loop_{p}_ns", f"tpuserve_capture_loop_{p}_ns_total")
+      for p in LOOP_PHASES),
+)
+
+
 def render_engine_gauges(stats: object) -> bytes:
     """EngineStats → Prometheus text exposition (appended to the
-    prometheus_client registry output on tpuserve's /metrics)."""
+    prometheus_client registry output on tpuserve's /metrics): the
+    ENGINE_GAUGES attrs, then the loop ledger's LOOP_GAUGES keys."""
     lines = []
     for attr, name in ENGINE_GAUGES:
         value = getattr(stats, attr, 0)
         lines.append(f"# TYPE {name} gauge")
         lines.append(f"{name} {value}")
+    loop = getattr(stats, "loop", None)
+    if loop is not None:
+        flat = loop.flat()
+        for key, name in LOOP_GAUGES:
+            lines.append(f"# TYPE {name} gauge")
+            lines.append(f"{name} {flat[key]}")
     return ("\n".join(lines) + "\n").encode()
 
 

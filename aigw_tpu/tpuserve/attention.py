@@ -47,6 +47,8 @@ from typing import Any, TYPE_CHECKING
 import jax.numpy as jnp
 import numpy as np
 
+from aigw_tpu.obs.flight import ADMIT, PREFILL_BLOCK, PREFILL_DISPATCH
+
 if TYPE_CHECKING:  # pragma: no cover
     from aigw_tpu.tpuserve.engine import Engine, GenRequest
 
@@ -114,14 +116,17 @@ class AttentionBackend:
         prompts). Returns (next_tok_device_output, info dict) or an
         abort status string ("stop" | "stop_consumed" | "skipped") —
         the engine frees pages and requeues on abort. ``info`` carries
-        consumed/tick_ms/bucket/chunks/padded_frac for stats+traces."""
+        consumed/bucket/chunks/padded_frac for stats+traces. Runs under
+        the caller's ``prefill_dispatch`` phase of the loop ledger; the
+        decode ticks between chunks suspend it."""
         raise NotImplementedError
 
     # -- shared helpers ---------------------------------------------------
-    def _account(self, real: int, padded: int) -> None:
+    def _account(self, real: int, padded: int, calls: int = 1) -> None:
         st = self.eng.stats
         st.prefill_tokens_real += real
         st.prefill_tokens_padded += padded
+        st.prefill_calls += calls
 
     def _observe_admission(self, items: list, chain_by_req: dict,
                            bucket_of) -> None:
@@ -214,7 +219,13 @@ class XlaBucketedBackend(AttentionBackend):
         top_k = np.zeros((G2,), np.int32)
         bias = np.zeros((G2, V), np.float32)
         adapter = np.full((G2,), eng._base_row, np.int32)
-        t0 = time.monotonic()
+        loop = eng.stats.loop
+        ns0 = loop.prefill_ns()
+        outer = loop.enter(
+            PREFILL_DISPATCH,
+            {"bucket": S, "group": G,
+             "tokens": sum(it[2] for it in items)}
+            if loop.capture else None)
         self._observe_admission(items, chain_by_req, lambda it: S)
         for g, (req, seq_id, n, _total) in enumerate(items):
             tokens[g, :n] = req.prompt
@@ -240,6 +251,7 @@ class XlaBucketedBackend(AttentionBackend):
             # the prefill's remaining on-device compute (async-transfer
             # machinery; values are identical to the blocking fetch)
             eng._start_host_copy(next_tok)
+        loop.enter(PREFILL_BLOCK)  # host blocked on the sampled tokens
         lp_data = None
         if cfg.logprobs_topk and isinstance(next_tok, tuple):
             next_tok, chosen, tk_ids, tk_vals = next_tok
@@ -250,8 +262,8 @@ class XlaBucketedBackend(AttentionBackend):
         # free host-side np add on the settled routing-stats leaf
         eng._fold_moe(moe)
         self._account(int(seq_lens.sum()), G2 * S)
-        prefill_ms = 1e3 * (time.monotonic() - t0)
-        eng.stats.prefill_ms += prefill_ms
+        loop.resume(outer)
+        prefill_ms = (loop.prefill_ns() - ns0) / 1e6
         eng.stats.note_prefill_call(prefill_ms, int(seq_lens.sum()))
         results = []
         for g, (req, seq_id, n, total) in enumerate(items):
@@ -281,7 +293,6 @@ class XlaBucketedBackend(AttentionBackend):
         eng = self.eng
         cfg = eng.cfg
         ns = len(suffix)
-        tick_ms = 0.0
         # chunked prefill: long prompts run as fixed-size suffix
         # steps so no giant bucket is ever compiled and a decode
         # tick runs between chunks — active streams keep emitting
@@ -329,9 +340,7 @@ class XlaBucketedBackend(AttentionBackend):
                                     consumed=prefix_len + consumed)
                 # interleave: active streams keep decoding between
                 # chunks (their windows overlap this chunk's compute)
-                t_tick = time.monotonic()
                 eng._decode_tick()
-                tick_ms += 1e3 * (time.monotonic() - t_tick)
 
         eff_prefix = prefix_len + consumed
         tail = suffix[consumed:]
@@ -362,11 +371,14 @@ class XlaBucketedBackend(AttentionBackend):
                 *sampling_args,
             )
         moes.append(moe)
+        # everything is dispatched: from here the host waits (an MoE
+        # family's routing-stats fold already blocks on the programs)
+        eng.stats.loop.enter(PREFILL_BLOCK)
         for m in moes:
             eng._fold_moe(m)
         self._account(ns_tail, S)
         return next_tok, {
-            "consumed": consumed, "tick_ms": tick_ms, "bucket": S,
+            "consumed": consumed, "bucket": S,
             "chunks": consumed // chunk if chunk else 0,
             "padded_frac": round(1.0 - ns_tail / S, 3) if S else 0.0,
         }
@@ -393,7 +405,6 @@ def sp_chunked_prefill(eng, req, seq_id: int, suffix: list[int],
     cfg = eng.cfg
     sp = eng._sp
     ns = len(suffix)
-    tick_ms = 0.0
     chunk = max(cfg.sp_chunk_tokens, sp)
     chunk = -(-chunk // sp) * sp  # ring shards the chunk over sp
     consumed = 0
@@ -430,6 +441,7 @@ def sp_chunked_prefill(eng, req, seq_id: int, suffix: list[int],
             consumed += chunk
             eng.stats.prefill_tokens_real += chunk
             eng.stats.prefill_tokens_padded += chunk
+            eng.stats.prefill_calls += 1
             eng.stats.chunked_prefill_steps += 1
             if req.trace is not None:
                 req.trace.event("prefill_chunk", tokens=chunk,
@@ -438,10 +450,10 @@ def sp_chunked_prefill(eng, req, seq_id: int, suffix: list[int],
             # (their own fast prefill emits their first token NOW, not
             # after this long prefill drains), then live streams — the
             # just-admitted one included — take a decode tick
-            t_tick = time.monotonic()
+            outer = eng.stats.loop.enter(ADMIT)
             eng._admit_interactive()
+            eng.stats.loop.resume(outer)
             eng._decode_tick()
-            tick_ms += 1e3 * (time.monotonic() - t_tick)
     tail = suffix[consumed:]
     ns_tail = len(tail)
     S = eng._prefill_bucket(ns_tail, multiple_of=sp)
@@ -458,12 +470,14 @@ def sp_chunked_prefill(eng, req, seq_id: int, suffix: list[int],
         *sampling_args,
     )
     moes.append(moe)
+    eng.stats.loop.enter(PREFILL_BLOCK)  # dispatched: the host waits
     for m in moes:
         eng._fold_moe(m)
     eng.stats.prefill_tokens_real += ns_tail
     eng.stats.prefill_tokens_padded += S
+    eng.stats.prefill_calls += 1
     return next_tok, {
-        "consumed": consumed, "tick_ms": tick_ms, "bucket": S,
+        "consumed": consumed, "bucket": S,
         "chunks": consumed // chunk,
         "padded_frac": round(1.0 - ns_tail / S, 3) if S else 0.0,
     }
@@ -580,7 +594,6 @@ class RaggedPrefillBackend(AttentionBackend):
         # loop so no mid-loop host sync stalls the packed stream
         moes: list = []
         calls = 0
-        tick_ms = 0.0
         real = padded = 0
         last_rung = 0
         while True:
@@ -610,9 +623,7 @@ class RaggedPrefillBackend(AttentionBackend):
                             return "stop"
                         return "stop_consumed"
                     return "skipped"
-                t_tick = time.monotonic()
                 eng._decode_tick()
-                tick_ms += 1e3 * (time.monotonic() - t_tick)
             T = self._rung_for(t_used)
             last_rung = T
             tokens = np.zeros((T,), np.int32)
@@ -652,11 +663,12 @@ class RaggedPrefillBackend(AttentionBackend):
         # intermediate budget-boundary device steps ride the same gauge
         # as the bucketed chunk loop
         eng.stats.chunked_prefill_steps += max(0, calls - 1)
+        eng.stats.loop.enter(PREFILL_BLOCK)  # dispatched: the host waits
         for m in moes:
             eng._fold_moe(m)
-        self._account(real, padded)
+        self._account(real, padded, calls)
         return final_out, {
-            "tick_ms": tick_ms, "bucket": last_rung, "chunks": calls - 1,
+            "bucket": last_rung, "chunks": calls - 1,
             "padded_frac": (round(1.0 - real / padded, 3) if padded
                             else 0.0),
             "calls": calls, "real": real, "padded": padded,
@@ -702,7 +714,12 @@ class RaggedPrefillBackend(AttentionBackend):
     # -- interface ---------------------------------------------------------
     def group_prefill(self, items: list, chain_by_req: dict) -> list:
         eng = self.eng
-        t0 = time.monotonic()
+        loop = eng.stats.loop
+        ns0 = loop.prefill_ns()
+        outer = loop.enter(
+            PREFILL_DISPATCH,
+            {"group": len(items), "tokens": sum(it[2] for it in items)}
+            if loop.capture else None)
         self._observe_admission(items, chain_by_req, lambda it: None)
         segs = []
         by_row = {}
@@ -715,12 +732,13 @@ class RaggedPrefillBackend(AttentionBackend):
             by_row[g] = (req, seq_id)
         sampling_args = self._sampling_rows(by_row)
         final_out, info = self._run_packed(segs, sampling_args)
-        prefill_ms = max(
-            0.0, 1e3 * (time.monotonic() - t0) - info["tick_ms"])
-        eng.stats.prefill_ms += prefill_ms
+        unpacked = [self._unpack_row(final_out[s.g], s.g) for s in segs]
+        loop.resume(outer)
+        prefill_ms = (loop.prefill_ns() - ns0) / 1e6
         eng.stats.note_prefill_call(prefill_ms, info["real"])
         results = []
-        for s, (req, seq_id, n, total) in zip(segs, items):
+        for s, (req, seq_id, n, total), (tok, first_lp) in zip(
+                segs, items, unpacked):
             eng.phases.observe(
                 "prefill", prefill_ms,
                 req.trace.trace_id if req.trace is not None else "")
@@ -729,7 +747,6 @@ class RaggedPrefillBackend(AttentionBackend):
                     prefill_ms, bucket=info["bucket"], group=len(items),
                     padded_frac=info["padded_frac"],
                     chunks=info["chunks"])
-            tok, first_lp = self._unpack_row(final_out[s.g], s.g)
             results.append(GroupResult(
                 req=req, seq_id=seq_id, n=n, total=total, tok=tok,
                 first_lp=first_lp, page_row=s.page_row,

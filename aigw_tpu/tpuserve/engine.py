@@ -39,6 +39,21 @@ import numpy as np
 
 from aigw_tpu.analysis.registry import engine_thread_only
 from aigw_tpu.models import kvq, llama
+from aigw_tpu.obs.flight import (
+    ADMIT,
+    ADMIT_WAIT,
+    DECODE_DISPATCH,
+    EMIT,
+    IDLE,
+    OTHER,
+    PREFILL_BLOCK,
+    PREFILL_DISPATCH,
+    REAP,
+    ROW_UPDATE,
+    STATE_BUILD,
+    WINDOW_FETCH,
+    LoopLedger,
+)
 from aigw_tpu.obs.metrics import EnginePhases
 from aigw_tpu.obs import xla_events
 from aigw_tpu.obs.xla_events import CompileTracker
@@ -719,16 +734,12 @@ class EngineStats:
     moe_tokens_dropped: int = 0
     moe_dropped_frac: float = 0.0
     moe_expert_imbalance: float = 0.0
-    # serving-path phase breakdown (cumulative milliseconds):
-    # prefill_ms = host time blocked on prefill device calls,
-    # transfer_ms = host time blocked fetching window tokens,
-    # emit_ms = host time distributing tokens to consumers,
-    # first_emit_ms = host time from a prefill's sampled token being
-    # host-available to its first-token emit callback returning (the
-    # fast path's residual: slot setup + prefix-cache insert + emit)
-    prefill_ms: float = 0.0
-    transfer_ms: float = 0.0
-    emit_ms: float = 0.0
+    # serving-path phase breakdown (cumulative milliseconds).
+    # prefill_ms / transfer_ms / emit_ms are VIEWS of the loop ledger
+    # (properties below); first_emit_ms = host time from a prefill's
+    # sampled token being host-available to its first-token emit
+    # callback returning (the fast path's residual: slot setup +
+    # prefix-cache insert + emit)
     first_emit_ms: float = 0.0
     # prefill padding tax (ISSUE 6): real prompt tokens vs tokens the
     # padded program geometry actually processed (bucket/batch padding
@@ -738,6 +749,9 @@ class EngineStats:
     prefill_tokens_real: int = 0
     prefill_tokens_padded: int = 0
     prefill_padded_frac: float = 0.0
+    # prefill programs dispatched (a batched group, a chunk and a tail
+    # are one call each): the unit the capture's counters are cut to
+    prefill_calls: int = 0
     # warmup cost: wall time of the last warmup() and the compiled
     # hot-path program count it left behind (compile tracker) — the
     # "collapsed compile surface = faster cold start" observables
@@ -783,7 +797,31 @@ class EngineStats:
     meter_hbm_page_byte_s: float = 0.0
     meter_host_page_byte_s: float = 0.0
 
+    # where the engine loop's time went (obs/flight.py LoopLedger:
+    # self time per phase of obs/metrics.LOOP_PHASES, /state loop_*);
+    # the engine thread is its one writer
+    loop: LoopLedger = field(init=False, repr=False, compare=False)
+
     PREFILL_RATE_HALF_LIFE_TOKENS = 16384
+
+    def __post_init__(self) -> None:
+        self.loop = LoopLedger(self)
+
+    @property
+    def prefill_ms(self) -> float:
+        """Host time building, dispatching and blocked on prefill
+        device calls (the ledger's prefill_dispatch + prefill_block)."""
+        return self.loop.prefill_ns() / 1e6
+
+    @property
+    def transfer_ms(self) -> float:
+        """Host time blocked fetching window tokens (window_fetch)."""
+        return self.loop.ns[WINDOW_FETCH] / 1e6
+
+    @property
+    def emit_ms(self) -> float:
+        """Host time distributing window tokens to consumers (emit)."""
+        return self.loop.ns[EMIT] / 1e6
 
     def note_prefill_call(self, ms: float, tokens: int) -> None:
         """Fold one prefill device call (``ms`` host-blocked time over
@@ -2764,10 +2802,18 @@ class Engine:
         logger.info("engine loop started (batch=%d, pages=%d×%d)",
                     self.cfg.max_batch_size, self.cfg.num_pages,
                     self.cfg.page_size)
+        # every nanosecond from here to the loop's end belongs to one
+        # phase of the ledger (obs/flight.py): the calls below open
+        # theirs, what they enter inside suspends them, and the
+        # remainder is ``other``
+        loop = self.stats.loop
+        loop.start()
         while not self._stop.is_set():
             try:
+                loop.enter(REAP)
                 self._reap_cancelled()
                 self._process_migrations()
+                loop.enter(ADMIT)
                 admitted = self._admit()
                 # the offline tier soaks whatever interactive left idle
                 admitted |= self._admit_batch_tier()
@@ -2781,16 +2827,20 @@ class Engine:
                 self.healthy = False
                 self.last_error = f"{type(e).__name__}: {e}"
                 self._abort_all(str(e))
+                loop.enter(OTHER)
                 return
             if not admitted and not worked:
+                loop.enter(IDLE)
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
+            loop.enter(OTHER)
         # deliver any tokens still in flight before exiting
         try:
             self._drain_inflight()
             self._apply_frees()
         except Exception:
             pass
+        loop.enter(OTHER)  # settles the last phase (and closes its span)
         logger.info("engine loop stopped")
 
     @engine_thread_only
@@ -2915,6 +2965,8 @@ class Engine:
                 # while real bursts (which surface a second submit
                 # within the probe) still coalesce fully.
                 wait_ms = self.cfg.admission_coalesce_ms
+                loop = self.stats.loop
+                outer = loop.enter(ADMIT_WAIT)
                 if self.cfg.first_token_fast_path and len(pending) == 1:
                     probe = min(1.0, wait_ms)
                     time.sleep(probe / 1e3)
@@ -2932,6 +2984,7 @@ class Engine:
                             pending.append(self._queue.get_nowait())
                     except queue.Empty:
                         pass
+                loop.resume(outer)
             # fairness guard (ISSUE 7): per-tenant slot cap + deficit
             # ordering over the popped window. Deferred requests must
             # not occlude admissible tenants still queued behind them,
@@ -3554,6 +3607,13 @@ class Engine:
         while bucket < need:
             bucket *= 2
         bucket = min(bucket, self.cfg.max_pages_per_seq)
+        # host building + dispatching the prefill call(s); the decode
+        # ticks a chunked prompt interleaves suspend it (self time)
+        loop = self.stats.loop
+        ns0 = loop.prefill_ns()
+        outer = loop.enter(
+            PREFILL_DISPATCH,
+            {"tokens": ns, "pages": bucket} if loop.capture else None)
 
         if use_sp_chunked:
             # sequence-sharded chunked prefill: ring-attention chunk
@@ -3566,6 +3626,7 @@ class Engine:
                 self, req, seq_id, suffix, prefix_len, n, pt, bucket,
                 sampling_args)
             if isinstance(res, str):
+                loop.resume(outer)
                 self._release_adapter_row(adapter_row)
                 self.allocator.free(seq_id)
                 return res
@@ -3592,11 +3653,12 @@ class Engine:
                 jnp.asarray(pt),
                 *sampling_args,
             )
+            loop.enter(PREFILL_BLOCK)  # dispatched: the host waits
             self._fold_moe(moe)
             self.stats.prefill_tokens_real += ns
             self.stats.prefill_tokens_padded += S
-            info = {"consumed": 0, "tick_ms": 0.0, "bucket": S,
-                    "chunks": 0,
+            self.stats.prefill_calls += 1
+            info = {"consumed": 0, "bucket": S, "chunks": 0,
                     "padded_frac": round(1.0 - ns / S, 3) if S else 0.0}
         else:
             # the attention backend runs the prompt: bucketed chunk
@@ -3610,11 +3672,11 @@ class Engine:
                 # cancelled / engine stopping mid-prompt: hand it back
                 # like an OutOfPages retry ("stop") or consume it —
                 # the adapter pin never made it to a slot
+                loop.resume(outer)
                 self._release_adapter_row(adapter_row)
                 self.allocator.free(seq_id)
                 return res
             next_tok, info = res
-        tick_ms = info["tick_ms"]
         eff_prefix = prefix_len + info["consumed"]
 
         if prefix_len:
@@ -3626,6 +3688,8 @@ class Engine:
         if self.cfg.first_token_fast_path:
             # start token 0's host copy under the prefill's compute
             self._start_host_copy(next_tok)
+        # (every branch above left the ledger in prefill_block: host
+        # blocked on the sampled token)
         first_lp = None
         if self.cfg.logprobs_topk and isinstance(next_tok, tuple):
             next_tok, chosen, tk_ids, tk_vals = next_tok
@@ -3635,9 +3699,10 @@ class Engine:
                     np.asarray(tk_ids)[0], np.asarray(tk_vals)[0])],
             )
         tok = int(next_tok[0])
+        loop.resume(outer)
         self.stats.prefills += 1
-        prefill_ms = max(0.0, 1e3 * (time.monotonic() - t0) - tick_ms)
-        self.stats.prefill_ms += prefill_ms
+        # this request's share of the ledger's two prefill phases
+        prefill_ms = (loop.prefill_ns() - ns0) / 1e6
         self.stats.note_prefill_call(prefill_ms, ns)
         self.phases.observe(
             "prefill", prefill_ms,
@@ -3943,11 +4008,17 @@ class Engine:
         stalls the decode pipeline for a whole window."""
         self._row_update_fn_built()
         P = self._state_bucket
+        loop = self.stats.loop
+        outer = loop.enter(
+            ROW_UPDATE,
+            {"pages": P, "rows": len(self._dirty_rows)}
+            if loop.capture else None)
         for i in sorted(self._dirty_rows):
             self._device_state = self._row_update_fn(
                 self._device_state, np.int32(i),
                 self._row_host_values(i, P))
         self._dirty_rows.clear()
+        loop.resume(outer)
 
     def _spec_update_fn_built(self):
         if self._spec_update_fn is None:
@@ -3969,6 +4040,7 @@ class Engine:
         the draft length is position-independent and safe to patch at
         any time."""
         self._spec_update_fn_built()
+        outer = self.stats.loop.enter(ROW_UPDATE)
         for i in sorted(self._spec_dirty):
             s = self._slots[i]
             d = (s.ctrl.draft_len()
@@ -3978,6 +4050,7 @@ class Engine:
             if s is not None:
                 s.dev_draft_len = d
         self._spec_dirty.clear()
+        self.stats.loop.resume(outer)
 
     def _cn_bias_row(self, s: _Slot) -> np.ndarray:
         """Host-side bias row of a constrained slot: the request's
@@ -4008,6 +4081,7 @@ class Engine:
         upload (_apply_row_updates) already carries the mask, so rows
         in _dirty_rows are skipped here."""
         fn = self._cn_update_fn_built()
+        outer = self.stats.loop.enter(ROW_UPDATE)
         for i in sorted(self._cn_dirty):
             s = self._slots[i]
             if s is None or s.cn is None or i in self._dirty_rows:
@@ -4016,6 +4090,7 @@ class Engine:
                 self._device_state, np.int32(i), self._cn_bias_row(s))
             self.stats.constraint_mask_updates += 1
         self._cn_dirty.clear()
+        self.stats.loop.resume(outer)
 
     @engine_thread_only
     def _cn_verify(self, i: int, s: _Slot, tok: int,
@@ -4216,11 +4291,14 @@ class Engine:
         w, self._inflight = self._inflight, None
         if w is None:
             return
-        t0 = time.monotonic()
+        loop = self.stats.loop
+        ns0 = loop.ns[WINDOW_FETCH]
+        outer = loop.enter(
+            WINDOW_FETCH,
+            {"k": w.k, "slots": len(w.members)} if loop.capture else None)
         host = jax.tree_util.tree_map(np.asarray, w.sampled)
-        t1 = time.monotonic()
-        tr_ms = 1e3 * (t1 - t0)
-        self.stats.transfer_ms += tr_ms
+        loop.enter(EMIT)
+        tr_ms = (loop.ns[WINDOW_FETCH] - ns0) / 1e6
         ex = ""
         for _i, _req in w.members:
             if _req.trace is not None:
@@ -4238,7 +4316,7 @@ class Engine:
                                  w.members, ce)
         else:
             self._process_window(host, None, w.members, ce)
-        self.stats.emit_ms += 1e3 * (time.monotonic() - t1)
+        loop.resume(outer)
         # the window's routing-stats leaf settles with the window — a
         # dispatch-time read would sync against the running program
         self._fold_moe(w.moe)
@@ -4284,6 +4362,21 @@ class Engine:
 
     @engine_thread_only
     def _decode_tick(self) -> bool:
+        """One tick of ``_tick`` under the ledger's ``decode_dispatch``
+        phase: the tick's bookkeeping and the dispatch itself are that
+        phase's self time; state builds, row updates, the fetch of the
+        in-flight window and its emits open their own inside it. The
+        caller's phase (``admit`` from the loop, ``prefill_dispatch``
+        between a long prompt's chunks) resumes when the tick is done."""
+        loop = self.stats.loop
+        outer = loop.enter(DECODE_DISPATCH)
+        try:
+            return self._tick()
+        finally:
+            loop.resume(outer)
+
+    @engine_thread_only
+    def _tick(self) -> bool:
         """Pipelined: dispatch window N+1, then process window N while
         the device runs. Membership changes are scattered into the live
         device state as row updates (chained asynchronously after the
@@ -4330,7 +4423,15 @@ class Engine:
                 self._refresh_stats()
                 return True
             P = self._decode_bucket_pages()
+            # (the builder itself stays pure: warmup() calls it from
+            # the server thread, which must not write the ledger)
+            loop = self.stats.loop
+            outer = loop.enter(
+                STATE_BUILD,
+                {"pages": P, "slots": len(active_idx)}
+                if loop.capture else None)
             self._device_state = self._build_device_state(bucket=P)
+            loop.resume(outer)
             self._state_bucket = P
             self._need_rebuild = False
             self._dirty_rows.clear()
@@ -4396,6 +4497,12 @@ class Engine:
         frees, self._pending_frees = self._pending_frees, []
         lean = draft == 0 and self._lean_decode_ok()
         decode_fn = self._decode_fn_for(k, lean, draft)
+        if self.stats.loop.capture:
+            # the same phase again, now that the window's facts are known
+            self.stats.loop.resume(
+                DECODE_DISPATCH,
+                {"k": k, "slots": len(members), "draft": draft,
+                 "pages": self._state_bucket})
         sampled, self._device_state, self.kv_cache, moe = decode_fn(
             self.params, self.lora_params, self.kv_cache, self._device_state
         )
@@ -4463,10 +4570,12 @@ class Engine:
             # dequeues the finish item observes the record (engine
             # thread posts both; call_soon_threadsafe keeps FIFO order)
             self._meter_finish(s, finish)
-        _send(send_tok, finish)
+        # counters BEFORE the emit too: a consumer woken by the terminal
+        # item may read /state before this thread runs again
         self.stats.tokens_generated += 1
         if req.priority == "batch":
             self.stats.batch_tokens += 1
+        _send(send_tok, finish)
         if finish is not None:
             if s.generated > 1 and s.first_emit_at:
                 self.phases.observe(

@@ -97,6 +97,7 @@ def spec_accept(
     return n_emit, d_idx < n_emit[:, None]
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jax.Array,  # [B, V] float32
     keys: jax.Array,  # [B, 2] uint32 (jax PRNG keys, one per slot)

@@ -766,7 +766,8 @@ class TPUServeServer:
                       else parent.trace_id if parent is not None else ""),
             span_id=(span.context.span_id if span is not None else ""),
         )
-        return RequestTrace(entry=entry, tracer=self.tracer, span=span)
+        return RequestTrace(entry=entry, tracer=self.tracer, span=span,
+                            loop=self.engine.stats.loop)
 
     def _end_trace(self, trace: RequestTrace, finish: str, n_out: int,
                    n_prompt: int = 0, error: str = "") -> None:
@@ -2396,6 +2397,12 @@ class TPUServeServer:
                 # ICI topology: the picker's same-slice preference term
                 # (gateway/picker.py) keys on this
                 **device_topology(),
+                # what the engine loop did (obs/flight.py LoopLedger),
+                # flat and cumulative: loop_<phase>_ns / _n, loop_ns,
+                # loop_busy_ns, and the counters cut to the profiler
+                # captures taken so far (capture_*); the keys are
+                # obs/metrics.LOOP_GAUGES
+                **s.loop.flat(),
             }
         )
 
@@ -2648,7 +2655,7 @@ class TPUServeServer:
             rid, model=self.model_name, prompt_tokens=len(tokens),
             max_tokens=creq.max_tokens, stream=True)
         creq.trace = RequestTrace(entry=entry, tracer=self.tracer,
-                                  span=None)
+                                  span=None, loop=self.engine.stats.loop)
         rm = RequestMetrics(
             metrics=self.metrics,
             operation="chat" if chat else "text_completion",
@@ -2796,13 +2803,32 @@ class TPUServeServer:
                 content_type="application/json")
         async with self._profile_lock:
             out_dir = tempfile.mkdtemp(prefix="tpuserve-profile-")
+            loop = self.engine.stats.loop
+            captured: dict = {}
+            # the device planes and the host's TraceMe events (the
+            # ledger's engine/<phase> spans) are all a reader of this
+            # trace uses: no interpreter frames, no HLO protos beside
+            # them. (Neither is what makes stop_trace slow on a TPU —
+            # PERF.md section 5 — but both are dead weight.)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            options.enable_hlo_proto = False
 
             def capture() -> None:
-                jax.profiler.start_trace(out_dir)
+                jax.profiler.start_trace(out_dir, profiler_options=options)
                 try:
+                    # the flag goes up once the trace runs and down
+                    # before it stops, so every span and every counted
+                    # window or prefill call lies inside it
+                    loop.capture_begin()
                     time.sleep(seconds)
+                    captured.update(loop.capture_end())
                 finally:
+                    t_stop = time.monotonic()
                     jax.profiler.stop_trace()
+                    captured["write_out_s"] = round(
+                        time.monotonic() - t_stop, 3)
 
             try:
                 await asyncio.to_thread(capture)
@@ -2813,8 +2839,10 @@ class TPUServeServer:
                     body=oai.error_body(f"profiler capture failed: {e}",
                                         type_="server_error"),
                     content_type="application/json")
+        logger.info("profile capture: %.1fs traced, %.1fs to write out",
+                    seconds, captured.get("write_out_s", -1.0))
         return web.json_response(
-            {"profile_dir": out_dir, "seconds": seconds})
+            {"profile_dir": out_dir, "seconds": seconds, **captured})
 
 
 async def run_tpuserve(

@@ -1,0 +1,94 @@
+"""Names inside the device programs: every decode and prefill program
+of both model families carries each ``jax.named_scope`` of its blocks,
+and the scopes are operation metadata only — the hash JAX's persistent
+compile cache takes of a program is the same with and without them, so
+no serving program recompiles for having been named.
+
+The programs are lowered by ``tests/scope_keys_child.py`` in two child
+processes (the scopes are decorators, applied at import; the second
+child turns ``jax.named_scope`` into a no-op before it imports the
+program). On the CPU, no skip condition."""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+DENSE = {"embed", "layer/attn", "layer/mlp", "lm_head", "sample"}
+MOE = {"embed", "layer/attn", "layer/moe_route", "layer/moe_experts",
+       "lm_head", "sample"}
+#: program -> the scopes its lowered text must carry
+WANT = {
+    "llama.decode": DENSE | {"layer/kv_gather"},
+    "llama.prefill": DENSE,
+    "llama.prefill_suffix": DENSE | {"layer/kv_gather"},
+    "mixtral.decode": MOE | {"layer/kv_gather"},
+    "mixtral.prefill": MOE,
+    "mixtral.prefill_suffix": MOE | {"layer/kv_gather"},
+}
+
+
+def _child(*flags: str) -> subprocess.Popen:
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)  # one CPU device is enough
+    return subprocess.Popen(
+        [sys.executable, os.path.join(HERE, "scope_keys_child.py"), *flags],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+        text=True)
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    procs = {"scoped": _child(), "bare": _child("--no-scopes")}
+    out = {}
+    for name, proc in procs.items():
+        stdout, _ = proc.communicate(timeout=600)
+        assert proc.returncode == 0, name
+        out[name] = json.loads(stdout.strip().splitlines()[-1])
+    return out
+
+
+@pytest.mark.parametrize("program,scope", [
+    (p, s) for p in sorted(WANT) for s in sorted(WANT[p])])
+def test_program_carries_scope(lowered, program, scope):
+    assert scope in lowered["scoped"][program]["scopes"]
+
+
+@pytest.mark.parametrize("program", sorted(WANT))
+def test_program_carries_no_other_scope(lowered, program):
+    assert set(lowered["scoped"][program]["scopes"]) == WANT[program]
+    assert lowered["bare"][program]["scopes"] == []  # the child's switch
+
+
+@pytest.mark.parametrize("program", sorted(WANT))
+def test_compile_cache_key_ignores_the_scopes(lowered, program):
+    assert lowered["scoped"][program]["key"] == \
+        lowered["bare"][program]["key"]
+
+
+def test_scopes_live_only_in_the_programs_and_the_ledger():
+    """``grep -rn "TraceAnnotation\\|named_scope" aigw_tpu`` finds them
+    only where they were put: the model blocks, the sampler, and the
+    one factory of the loop ledger."""
+    found = {}
+    root = os.path.join(os.path.dirname(HERE), "aigw_tpu")
+    for path, _, files in os.walk(root):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(path, name)) as f:
+                    text = f.read()
+                rel = os.path.relpath(os.path.join(path, name), root)
+                for word in ("named_scope(", "TraceAnnotation("):
+                    if word in text:
+                        found.setdefault(word, set()).add(rel)
+    assert found == {
+        "named_scope(": {"models/llama.py", "models/mixtral.py",
+                         "tpuserve/sampling.py"},
+        "TraceAnnotation(": {"obs/flight.py"},
+    }

@@ -133,10 +133,12 @@ def test_prefill_rate_decays_to_recent_mix():
     long steady history at one rate converges to a NEW rate within a
     few half-lives of tokens — and falls back to the lifetime mean
     before any call is observed."""
+    from aigw_tpu.obs.flight import PREFILL_BLOCK
     from aigw_tpu.tpuserve.engine import EngineStats
 
     st = EngineStats()
-    st.prefill_ms, st.prefill_tokens_real = 500.0, 100_000
+    # 500 ms of prefill on the loop ledger (prefill_ms is its view)
+    st.loop.ns[PREFILL_BLOCK], st.prefill_tokens_real = 500_000_000, 100_000
     assert st.prefill_ms_per_token() == pytest.approx(0.005)
     # 1M tokens at 0.005 ms/tok, then 3 half-lives at 0.05 ms/tok
     for _ in range(100):
