@@ -126,6 +126,7 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     ("sp_interactive_admits", "tpuserve_sp_interactive_admits_total"),
     ("chunked_prefill_steps", "tpuserve_chunked_prefill_steps_total"),
     ("decode_steps", "tpuserve_decode_steps_total"),
+    ("sample_sort_steps", "tpuserve_sample_sort_steps_total"),
     ("decode_window", "tpuserve_decode_window_steps"),
     ("window_shrinks", "tpuserve_decode_window_shrinks_total"),
     ("window_grows", "tpuserve_decode_window_grows_total"),

@@ -701,6 +701,10 @@ class EngineStats:
     sp_interactive_admits: int = 0
     chunked_prefill_steps: int = 0  # intermediate chunk device steps
     decode_steps: int = 0
+    # decode steps dispatched while an occupied slot's request truncates
+    # (top_k > 0 or top_p < 1): the steps in which `sample` pays its
+    # vocabulary-wide sort. Over decode_steps: the share that pay it
+    sample_sort_steps: int = 0
     prefix_cache_hits: int = 0
     prefix_tokens_reused: int = 0
     # prefix-cache surface (ISSUE 3): misses counted over page-eligible
@@ -862,6 +866,9 @@ class _Window:
     # the drain-side controller update needs what was actually offered
     draft: int = 0
     draft_lens: tuple[tuple[int, int], ...] = ()
+    # an occupied slot truncated (top_k / top_p) at dispatch: the
+    # window's steps count into sample_sort_steps when it settles
+    sorts: bool = False
     # constrained slots at DISPATCH time: (slot, rollback epoch, the
     # mask row live on device for the window). A drain whose captured
     # epoch trails the slot's current one discards that slot's tokens
@@ -1308,7 +1315,7 @@ class Engine:
                         st["pres_pen"], st["bias"],
                     )
                 sampled = sample(logits, st["keys"], st["temp"],
-                                 st["top_p"], st["top_k"])
+                                 st["top_p"], st["top_k"], act)
                 step = act.astype(jnp.uint32)
                 B = sampled.shape[0]
                 counts = (st["counts"] if lean
@@ -1423,7 +1430,7 @@ class Engine:
                 )
                 sampled = jax.vmap(
                     lambda l, k: sample(l, k, st["temp"], st["top_p"],
-                                        st["top_k"])
+                                        st["top_k"], act)
                 )(lT, keys_d).T  # [B, D1]
                 n_emit, emit_mask = spec_accept(
                     drafts, sampled, act,
@@ -1707,6 +1714,17 @@ class Engine:
             s is None
             or (s.req.sampling.frequency_penalty == 0.0
                 and s.req.sampling.presence_penalty == 0.0)
+            for s in self._slots
+        )
+
+    def _sample_sorts(self) -> bool:
+        """True when an occupied slot's request truncates — host-side
+        twin of the predicate `sample` guards its vocabulary-wide sort
+        with (an upper bound: the device also leaves out dead rows)."""
+        return any(
+            s is not None
+            and (s.req.sampling.top_k > 0
+                 or not s.req.sampling.top_p >= 1.0)
             for s in self._slots
         )
 
@@ -4307,6 +4325,8 @@ class Engine:
         self.phases.observe("transfer", tr_ms, ex)
         ce = ({i: (ep, m) for i, ep, m in w.cn_epochs}
               if w.cn_epochs else None)
+        if w.sorts:
+            self.stats.sample_sort_steps += w.k
         if w.draft:
             self._process_spec_window(host[0], host[1], host[2],
                                       w.members, w.draft_lens, ce)
@@ -4496,6 +4516,7 @@ class Engine:
         )
         frees, self._pending_frees = self._pending_frees, []
         lean = draft == 0 and self._lean_decode_ok()
+        sorts = self._sample_sorts()
         decode_fn = self._decode_fn_for(k, lean, draft)
         if self.stats.loop.capture:
             # the same phase again, now that the window's facts are known
@@ -4515,7 +4536,8 @@ class Engine:
         self._inflight = _Window(sampled=sampled, members=members, k=k,
                                  frees=frees, draft=draft,
                                  draft_lens=draft_lens,
-                                 cn_epochs=cn_epochs, moe=moe)
+                                 cn_epochs=cn_epochs, moe=moe,
+                                 sorts=sorts)
         for _i, _req in members:
             if _req.trace is not None:
                 _req.trace.decode_window(k, lean, draft)

@@ -104,30 +104,54 @@ def sample(
     temperature: jax.Array,  # [B] float32; 0 = greedy
     top_p: jax.Array,  # [B] float32
     top_k: jax.Array,  # [B] int32; 0 = off
+    live: jax.Array | None = None,  # [B] bool; None = every row
 ) -> jax.Array:
-    """Returns sampled token ids [B] int32."""
-    V = logits.shape[-1]
-    # top-k mask: keep the k highest logits (k==0 → keep all)
-    sorted_logits = jnp.sort(logits, axis=-1)[:, ::-1]  # descending
-    k_idx = jnp.clip(top_k - 1, 0, V - 1)
-    kth = jnp.take_along_axis(sorted_logits, k_idx[:, None], axis=-1)
-    keep_k = (top_k[:, None] <= 0) | (logits >= kth)
+    """Returns sampled token ids [B] int32.
 
-    # top-p (nucleus) mask over the sorted distribution. OpenAI/vLLM
-    # semantics: temperature scaling precedes the nucleus cutoff, so
-    # membership is computed on the *scaled* distribution (sort order is
-    # invariant under the positive scale, so one sort serves both masks).
-    inv_t = 1.0 / jnp.maximum(temperature[:, None], 1e-6)
-    probs_sorted = jax.nn.softmax(sorted_logits * inv_t, axis=-1)
-    cum = jnp.cumsum(probs_sorted, axis=-1)
-    # keep tokens whose cumulative mass *before* them is < top_p
-    cutoff_mass = cum - probs_sorted
-    keep_sorted = cutoff_mass < top_p[:, None]
-    # threshold logit: smallest kept logit in sorted order
-    last_kept = jnp.sum(keep_sorted.astype(jnp.int32), axis=-1) - 1
-    thresh = jnp.take_along_axis(
-        sorted_logits, jnp.clip(last_kept, 0, V - 1)[:, None], axis=-1
-    )
+    The vocabulary-wide sort behind the top-k / top-p thresholds runs
+    only when a ``live`` row truncates: the predicate is the row-wise
+    complement of the two tests the masks below are ORed with, so a
+    batch that skips the sort has both masks all true and samples the
+    same tokens bit for bit. It reads only ``top_k``, ``top_p`` and
+    ``live`` — never ``logits`` or ``keys`` — so a ``vmap`` over those
+    two (the speculative verify body) leaves it one conditional. A row
+    that is not ``live`` (an empty slot, a row at its limit) never
+    forces the sort; its sample is junk the caller discards."""
+    B, V = logits.shape
+    truncates = (top_k > 0) | ~(top_p >= 1.0)
+    if live is not None:
+        truncates = truncates & live
+
+    def thresholds(logits):
+        # top-k: the k-th highest logit (k==0 → masked off below)
+        sorted_logits = jnp.sort(logits, axis=-1)[:, ::-1]  # descending
+        k_idx = jnp.clip(top_k - 1, 0, V - 1)
+        kth = jnp.take_along_axis(sorted_logits, k_idx[:, None], axis=-1)
+        # top-p (nucleus) over the sorted distribution. OpenAI/vLLM
+        # semantics: temperature scaling precedes the nucleus cutoff, so
+        # membership is computed on the *scaled* distribution (sort order
+        # is invariant under the positive scale, so one sort serves both
+        # thresholds).
+        inv_t = 1.0 / jnp.maximum(temperature[:, None], 1e-6)
+        probs_sorted = jax.nn.softmax(sorted_logits * inv_t, axis=-1)
+        cum = jnp.cumsum(probs_sorted, axis=-1)
+        # keep tokens whose cumulative mass *before* them is < top_p
+        cutoff_mass = cum - probs_sorted
+        keep_sorted = cutoff_mass < top_p[:, None]
+        # threshold logit: smallest kept logit in sorted order
+        last_kept = jnp.sum(keep_sorted.astype(jnp.int32), axis=-1) - 1
+        thresh = jnp.take_along_axis(
+            sorted_logits, jnp.clip(last_kept, 0, V - 1)[:, None], axis=-1
+        )
+        return kth, thresh
+
+    def no_thresholds(logits):
+        none = jnp.full((B, 1), -jnp.inf, logits.dtype)
+        return none, none
+
+    kth, thresh = jax.lax.cond(
+        jnp.any(truncates), thresholds, no_thresholds, logits)
+    keep_k = (top_k[:, None] <= 0) | (logits >= kth)
     keep_p = (top_p[:, None] >= 1.0) | (logits >= thresh)
 
     masked = jnp.where(keep_k & keep_p, logits, -jnp.inf)

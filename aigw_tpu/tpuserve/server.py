@@ -2323,6 +2323,7 @@ class TPUServeServer:
                 "kv_occupancy": s.kv_occupancy,
                 "tokens_generated": s.tokens_generated,
                 "decode_steps": s.decode_steps,
+                "sample_sort_steps": s.sample_sort_steps,
                 "decode_window": s.decode_window,
                 "prefill_ms": round(s.prefill_ms, 3),
                 "transfer_ms": round(s.transfer_ms, 3),

@@ -1,8 +1,9 @@
 """Child of tests/test_device_scopes.py: lowers the decode window and a
 prefill step of each model family (tiny widths, W8A16 so the Pallas
 matmul is in the program) and prints, per program, the hash JAX's
-persistent compile cache takes of the computation and the named scopes
-its text carries. With ``--no-scopes`` ``jax.named_scope`` is a no-op
+persistent compile cache takes of the computation, the named scopes
+its text carries and the name stacks of the operations inside a
+conditional's branches. With ``--no-scopes`` ``jax.named_scope`` is a no-op
 from before the first import of the program, which is the tree without
 this PR's scopes. With ``--tpu`` the programs are lowered for a
 described (not attached) TPU v5e, Mosaic kernels included.
@@ -85,7 +86,7 @@ def main(argv: list[str]) -> int:
                 logits, cache = mod.decode_step(
                     p, cfg, toks, pos, cache, pt, PAGE, active)
                 nxt = sample(logits, keys, jnp.zeros((B,)),
-                             jnp.ones((B,)), jnp.zeros((B,), i32))
+                             jnp.ones((B,)), jnp.zeros((B,), i32), active)
                 return (nxt, pos + 1, cache), nxt
             return jax.lax.scan(
                 body, (tokens, positions, kv_cache), None, length=2)
@@ -125,9 +126,19 @@ def main(argv: list[str]) -> int:
             stacks = re.findall(r'loc\("([^"]*)"', text)
             scopes = sorted({m for st in stacks for m in re.findall(
                 r"(?:^|/)(embed|layer/[a-z_]+|lm_head|sample)(?=/)", st)})
+            # operations inside a conditional's branches (the sampler's
+            # guarded sort): "…/cond/branch_1_fun/jit(sort)"; a bare
+            # "cond/branch_1_fun/jit" is a fragment of such a name, not
+            # an operation's stack
+            in_cond = sorted({
+                st for st in stacks
+                if re.search(r"(?:^|/)cond/branch_\d+_fun/", st)
+                and not st.endswith("_fun/jit")})
             out[f"{fam}.{name}"] = {
                 "key": h.hexdigest(), "scopes": scopes,
-                "mosaic": "tpu_custom_call" in text}
+                "mosaic": "tpu_custom_call" in text,
+                "in_cond": in_cond,
+                "sorts": text.count("stablehlo.sort")}
     print(json.dumps(out))
     return 0
 

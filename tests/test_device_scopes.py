@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -64,6 +65,19 @@ def test_program_carries_scope(lowered, program, scope):
 def test_program_carries_no_other_scope(lowered, program):
     assert set(lowered["scoped"][program]["scopes"]) == WANT[program]
     assert lowered["bare"][program]["scopes"] == []  # the child's switch
+
+
+@pytest.mark.parametrize("program", sorted(WANT))
+def test_guarded_sort_keeps_the_sample_scope(lowered, program):
+    """The sampler's sort sits inside a conditional (ISSUE 26); what is
+    in its branches is still named ``sample``, so ``tools/trace_gaps.py``
+    keeps attributing it."""
+    got = lowered["scoped"][program]
+    assert got["sorts"] == 1
+    assert any("jit(sort)" in st for st in got["in_cond"])
+    assert [st for st in got["in_cond"]
+            if not re.search(r"(?:^|/)sample/cond/branch_", st)] == []
+    assert lowered["bare"][program]["sorts"] == 1
 
 
 @pytest.mark.parametrize("program", sorted(WANT))
