@@ -109,6 +109,8 @@ STATE_ONLY: dict[str, str] = {
     # priority-tiered serving surface (ISSUE 19)
     "batch_slot_frac": "EngineConfig echo; the batch class's slot "
                        "ceiling fraction",
+    "features_off": "feature -> reason dict: what a per-slot-state "
+                    "family switches off (models/cache.py)",
     # MoE serving surface (ISSUE 18)
     "moe_expert_load": "per-expert token list [E]; /metrics renders "
                        "the labeled tpuserve_moe_expert_load twins",

@@ -1,0 +1,105 @@
+"""What a model family keeps on the device between steps, as one small
+description the engine asks for shapes and bytes.
+
+Two kinds of memory can live side by side:
+
+- the **page pool** ``[kv_layers, 2, rows, n_kv_heads, head_dim]`` of
+  models/kvq.py — only the layers that attend over keys and values have
+  pages (every layer of the llama and mixtral families; every fourth of
+  a hybrid linear-attention family);
+- **per-slot state**: leaves ``[layers, slots, ...]`` indexed by decode
+  slot and not paged — a recurrent layer's state does not grow with the
+  context, so a sequence owns exactly one row of each for its lifetime.
+
+A family with no per-slot state keeps the bare pool of models/kvq.py:
+its programs, their pytrees and their compile-cache keys are what they
+were before this description existed. A family with state gets a
+:class:`StateCache` — the pool and the state leaves in one pytree that
+rides the same donation chain and the decode scan's carry.
+
+What moves pages only (prefix-cache hits, the host KV tier, parking and
+migration of a live sequence, fleet fetch, speculative verify) cannot
+serve a family with per-slot state: a page without the state that goes
+with it is half a sequence. The engine switches those off by asking
+``spec.stateful`` — by what the family is, not by a flag.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any, NamedTuple
+
+import jax.numpy as jnp
+
+from aigw_tpu.models import kvq
+
+
+class StateCache(NamedTuple):
+    """The device cache of a family with per-slot state (a pytree)."""
+
+    kv: Any  # the page pool of models/kvq.py (array, or {"q","scale"})
+    slots: dict  # name -> [layers, n_slots, ...] per-slot state leaf
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    kv_layers: int  # layers that own pages
+    n_kv_heads: int
+    head_dim: int
+    #: per-slot leaves: (name, layers, shape of one slot's row, dtype
+    #: name, or ``activation``)
+    slot_state: tuple[tuple[str, int, tuple[int, ...], str], ...] = ()
+
+    @property
+    def stateful(self) -> bool:
+        return bool(self.slot_state)
+
+    def kv_shape(self, n_rows: int) -> tuple[int, ...]:
+        return (self.kv_layers, 2, n_rows, self.n_kv_heads, self.head_dim)
+
+    def kv_page_bytes(self, page_size: int, kv_cache_dtype: str) -> int:
+        """HBM bytes of one page across the layers that have pages
+        (quantized pools: packed elements plus the f32 scale rows)."""
+        per_elt = kvq.bytes_per_kv_element(kv_cache_dtype)
+        scale = 4 if kvq.is_quantized_dtype(kv_cache_dtype) else 0
+        return int(self.kv_layers * 2 * page_size * self.n_kv_heads
+                   * (self.head_dim * per_elt + scale))
+
+    def state_bytes_per_slot(self, kv_cache_dtype: str) -> int:
+        """Bytes one decode slot's recurrent state holds, whatever the
+        length of its context."""
+        return sum(layers * math.prod(shape)
+                   * _leaf_dtype(dt, kv_cache_dtype).itemsize
+                   for _, layers, shape, dt in self.slot_state)
+
+    def make(self, n_rows: int, n_slots: int, kv_cache_dtype: str,
+             mesh=None, data_spec=None):
+        """Zero-initialised device cache: the bare pool, or a
+        :class:`StateCache` around it."""
+        pool = kvq.make_pool(self.kv_shape(n_rows), kv_cache_dtype, mesh,
+                             data_spec)
+        if not self.stateful:
+            return pool
+        return StateCache(pool, {
+            name: jnp.zeros((layers, n_slots, *shape),
+                            _leaf_dtype(dt, kv_cache_dtype))
+            for name, layers, shape, dt in self.slot_state})
+
+
+def _leaf_dtype(name: str, kv_cache_dtype: str):
+    """A state leaf's dtype; ``activation`` follows the pool's compute
+    width (float32 with a float32 pool, else bfloat16)."""
+    if name == "activation":
+        name = "float32" if kv_cache_dtype == "float32" else "bfloat16"
+    return jnp.dtype(name)
+
+
+def spec_of(model_cfg: Any) -> CacheSpec:
+    """The family's own description (``model_cfg.cache_spec()``), or
+    the uniform pool of a family whose every layer has pages."""
+    own = getattr(model_cfg, "cache_spec", None)
+    if own is not None:
+        return own()
+    return CacheSpec(model_cfg.n_layers, model_cfg.n_kv_heads,
+                     model_cfg.head_dim)

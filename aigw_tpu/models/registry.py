@@ -17,7 +17,7 @@ from aigw_tpu.models import llama
 @dataclass(frozen=True)
 class ModelSpec:
     name: str
-    family: str  # "llama" | "mixtral"
+    family: str  # "llama" | "mixtral" | "qwen3_next"
     config: Any
     weights: str = "random"  # "random" | "orbax:<dir>" | "hf:<dir>"
     tokenizer: str = "byte"  # "byte" | path to tokenizer.json
@@ -47,10 +47,16 @@ class ModelFns:
     # engine's prompt-lookup speculation for the family
     verify_step: Any = None
     # packed variable-length prefill (one program per token-budget
-    # chunk). Every registered family provides it; None remains only as
-    # the hand-built-ModelFns escape hatch (it falls the attention
-    # backend back to xla-bucketed)
+    # chunk). None falls the attention backend back to xla-bucketed:
+    # qwen3_next (its recurrent state would have to reset at every
+    # packed segment's start) and hand-built ModelFns
     prefill_ragged: Any = None
+    # whether ``decode_step`` takes the kernel rungs of the decode
+    # fallback matrix as ``attn_impl`` ("pallas", "fused",
+    # "fused-pallas"). False: the family's attention has no kernel rung
+    # and every request resolves to the window gather
+    # (tpuserve/attention.resolve_decode_backend)
+    decode_kernels: bool = True
     # static kwarg contract: entry points accept ``moe_stats=True`` and
     # return a trailing [L, E+1] int32 routing-stats leaf (per-expert
     # placed counts + capacity drops per layer). The engine turns it on
@@ -78,6 +84,18 @@ def family_fns(family: str) -> ModelFns:
                         verify_step=mixtral.verify_step,
                         prefill_ragged=mixtral.prefill_ragged,
                         moe_stats=True)
+    if family == "qwen3_next":
+        from aigw_tpu.models import qwen3_next
+
+        # no verify_step (a rejected draft would need the DeltaNet
+        # state rolled back), no sequence-parallel prefill, no ragged
+        # prefill: the engine reads each as "off for this family". No
+        # decode kernel rung either: the Pallas kernels fuse full-width
+        # rotary and know no q/k norm or output gate
+        return ModelFns(qwen3_next.init_params, qwen3_next.prefill,
+                        qwen3_next.decode_step, qwen3_next.hidden_states,
+                        prefill_suffix=qwen3_next.prefill_suffix,
+                        decode_kernels=False, moe_stats=True)
     raise KeyError(f"unknown model family {family!r}")
 
 
@@ -109,6 +127,16 @@ def _register_mixtral() -> None:
 
 
 _register_mixtral()
+
+
+def _register_qwen3_next() -> None:
+    from aigw_tpu.models import qwen3_next
+
+    register_model(ModelSpec("tiny-qwen3-next", "qwen3_next",
+                             qwen3_next.TINY, chat_template="chatml"))
+
+
+_register_qwen3_next()
 register_model(ModelSpec("llama-3-8b", "llama", llama.LLAMA3_8B,
                          weights="orbax:checkpoints/llama-3-8b"))
 register_model(ModelSpec("qwen2-7b", "llama", llama.QWEN2_7B,

@@ -263,6 +263,17 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     ("moe_tokens_dropped", "tpuserve_moe_tokens_dropped_total"),
     ("moe_dropped_frac", "tpuserve_moe_dropped_frac"),
     ("moe_expert_imbalance", "tpuserve_moe_expert_imbalance"),
+    # an expert layer that holds one chip's share of its experts:
+    # assignments routed anywhere / landed on a held expert, and held
+    # experts hit summed over layers and decode steps (0 elsewhere)
+    ("moe_local_assignments", "tpuserve_moe_local_assignments_total"),
+    ("moe_total_assignments", "tpuserve_moe_total_assignments_total"),
+    ("moe_held_hits_decode", "tpuserve_moe_held_hits_decode_total"),
+    # the device cache's description (models/cache.py): layers with
+    # pages, and the per-slot recurrent state of a hybrid family
+    ("kv_layers", "tpuserve_kv_layers"),
+    ("state_bytes_per_slot", "tpuserve_state_bytes_per_slot"),
+    ("state_bytes_total", "tpuserve_state_bytes_total"),
     # priority-tiered serving (ISSUE 19): the offline /v1/batches
     # class. Queued = never-shed backlog + host-parked preempted
     # sessions; active = decode slots it holds (≤ the batch_slot_frac
@@ -486,6 +497,8 @@ LOOP_PHASES: tuple[str, ...] = (
 CAPTURE_COUNTERS: tuple[str, ...] = (
     "decode_steps", "tokens_generated", "prefill_tokens_real",
     "prefill_tokens_padded", "prefill_calls",
+    "moe_local_assignments", "moe_total_assignments",
+    "moe_held_hits_decode",
 )
 
 #: the loop ledger's flat surface: key of ``LoopLedger.flat()`` (spread

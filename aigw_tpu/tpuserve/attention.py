@@ -245,7 +245,8 @@ class XlaBucketedBackend(AttentionBackend):
             eng.params, eng.lora_params, jnp.asarray(tokens),
             jnp.asarray(seq_lens), eng.kv_cache, jnp.asarray(pt),
             jnp.asarray(keys), jnp.asarray(temp), jnp.asarray(top_p),
-            jnp.asarray(top_k), jnp.asarray(bias), jnp.asarray(adapter))
+            jnp.asarray(top_k), jnp.asarray(bias), jnp.asarray(adapter),
+            **eng.slot_kw([it[1] for it in items], G2))
         if cfg.first_token_fast_path:
             # token 0's device→host copy starts at dispatch and overlaps
             # the prefill's remaining on-device compute (async-transfer
@@ -305,13 +306,13 @@ class XlaBucketedBackend(AttentionBackend):
         # fold them only at the end so the host never syncs mid-loop
         # (the decode interleave between chunks stays pipelined)
         moes: list = []
+        slot_kw = eng.slot_kw([seq_id])
         if (chunk > 0 and eng.fns.prefill_suffix is not None
                 and ns > chunk):
             # loop-invariant device uploads hoisted; each boundary
             # is also a cancellation/shutdown yield point — exactly
             # what chunking exists to provide
             pt_dev = jnp.asarray(pt[:, :bucket])
-            ctokens = np.zeros((1, chunk), np.int32)
             while ns - consumed > chunk:
                 if req.cancelled.is_set() or eng._stop.is_set():
                     if eng._stop.is_set():
@@ -319,7 +320,11 @@ class XlaBucketedBackend(AttentionBackend):
                             return "stop"
                         return "stop_consumed"
                     return "skipped"
-                ctokens[0, :] = suffix[consumed:consumed + chunk]
+                # a buffer of its own for every chunk: on the CPU the
+                # device array may alias the host's, and the program
+                # that reads it runs after this loop has moved on
+                ctokens = np.asarray(
+                    suffix[consumed:consumed + chunk], np.int32)[None]
                 _, eng.kv_cache, cmoe = eng._prefill_suffix_fn(
                     eng.params,
                     eng.lora_params,
@@ -330,6 +335,7 @@ class XlaBucketedBackend(AttentionBackend):
                     eng.kv_cache,
                     pt_dev,
                     *sampling_args,
+                    **slot_kw,
                 )
                 moes.append(cmoe)
                 consumed += chunk
@@ -359,6 +365,7 @@ class XlaBucketedBackend(AttentionBackend):
                 eng.kv_cache,
                 jnp.asarray(pt[:, :bucket]),
                 *sampling_args,
+                **slot_kw,
             )
         else:
             next_tok, eng.kv_cache, moe = eng._prefill_fn(
@@ -369,6 +376,7 @@ class XlaBucketedBackend(AttentionBackend):
                 eng.kv_cache,
                 jnp.asarray(pt),
                 *sampling_args,
+                **slot_kw,
             )
         moes.append(moe)
         # everything is dispatched: from here the host waits (an MoE
@@ -416,7 +424,6 @@ def sp_chunked_prefill(eng, req, seq_id: int, suffix: list[int],
     # interactive admits + decode ticks between chunks stay pipelined)
     moes: list = []
     if ns > chunk:
-        ctokens = np.zeros((1, chunk), np.int32)
         while ns - consumed > chunk:
             # chunk boundaries are cancellation/shutdown yield points —
             # exactly what chunking exists to provide
@@ -426,7 +433,9 @@ def sp_chunked_prefill(eng, req, seq_id: int, suffix: list[int],
                         return "stop"
                     return "stop_consumed"
                 return "skipped"
-            ctokens[0, :] = suffix[consumed:consumed + chunk]
+            # (its own buffer: see XlaBucketedBackend.single_prefill)
+            ctokens = np.asarray(
+                suffix[consumed:consumed + chunk], np.int32)[None]
             _, eng.kv_cache, cmoe = eng._prefill_sp_suffix_fn(
                 eng.params,
                 eng.lora_params,
@@ -805,12 +814,14 @@ def resolve_attention_backend(engine: "Engine") -> tuple[str, str]:
     | pallas-ragged | no   | no  | any       | pallas-ragged | XLA windowed        |
     | pallas-ragged | yes  | any | any       | pallas-ragged | XLA windowed (SPMD) |
 
-    The old ``family w/o prefill_ragged → xla-bucketed`` row is GONE
-    (ISSUE 18): every registered model family — dense and MoE alike —
-    provides a ragged prefill entry point, so no family is routed off
-    the packed stream anymore. What remains below is an escape hatch
-    for hand-built ``ModelFns`` (tests construct them with
-    ``prefill_ragged=None``), not a family property.
+    | pallas-ragged | any  | any | any, qwen3_next | xla-bucketed | XLA dense (bucketed) |
+
+    The llama and mixtral families provide a ragged prefill entry
+    point. ``qwen3_next`` does not (its DeltaNet state would have to
+    reset at every packed segment's start and its convolution would
+    have to stop at it; ROADMAP.md M4), so the last row routes it to
+    the bucketed backend — as it does hand-built ``ModelFns`` with
+    ``prefill_ragged=None``.
 
     The Pallas kernel itself stays single-chip TPU (its scalar-prefetch
     page walk addresses one local pool); a mesh keeps the RAGGED
@@ -821,16 +832,15 @@ def resolve_attention_backend(engine: "Engine") -> tuple[str, str]:
     if name != "pallas-ragged":
         return "xla-bucketed", "requested"
     if engine._prefill_ragged_fn is None:
-        # not a family row: every registered family ships
-        # prefill_ragged; only hand-built ModelFns land here
         return ("xla-bucketed",
-                "pallas-ragged requested but these hand-built ModelFns "
+                "pallas-ragged requested but this family's ModelFns "
                 "have no ragged prefill entry point")
     # engine._ragged_reason explains the kernel-vs-windowed choice
     return "pallas-ragged", engine._ragged_reason
 
 
-def resolve_decode_backend(cfg, model_cfg, mesh) -> tuple[str, str]:
+def resolve_decode_backend(cfg, model_cfg, mesh,
+                           fns=None) -> tuple[str, str]:
     """The DECODE half of the fallback matrix (ISSUE 13): (resolved
     decode-attention impl, WHY), exported verbatim on /state as
     ``decode_attn_impl`` / ``decode_attn_reason`` — never a silent
@@ -848,6 +858,7 @@ def resolve_decode_backend(cfg, model_cfg, mesh) -> tuple[str, str]:
     | fused              | no   | no  | any       | fused-xla       |
     | fused              | yes  | any | any       | fused-xla-spmd  |
     | fused, heads % tp != 0          | any       | xla-gather (narrowed) |
+    | any, ``fns.decode_kernels`` false (qwen3_next)  | xla-gather    |
 
     The fused rung has no model-family exception (ISSUE 18): MoE
     families run the same fused decode programs as dense ones — the
@@ -870,6 +881,14 @@ def resolve_decode_backend(cfg, model_cfg, mesh) -> tuple[str, str]:
     exactly like AIGW_RAGGED_PREFILL_IMPL on the prefill side."""
     from aigw_tpu.ops.pallas._compat import is_tpu_backend
 
+    if fns is not None and not fns.decode_kernels:
+        # the family row, as the family's ModelFns declares it (today:
+        # qwen3_next's gated attention with q/k RMSNorm and rotary on a
+        # part of each head, which no kernel rung knows)
+        return ("xla-gather",
+                "this family's decode_step takes no kernel rung "
+                "(ModelFns.decode_kernels is false): the window gather "
+                "serves every request")
     quant = cfg.kv_cache_dtype in ("int8", "int4")
     req = "chained" if cfg.decode_backend == "auto" else cfg.decode_backend
     wants_fused = req == "fused" or (

@@ -39,6 +39,7 @@ import numpy as np
 
 from aigw_tpu.analysis.registry import engine_thread_only
 from aigw_tpu.models import kvq, llama
+from aigw_tpu.models.cache import spec_of
 from aigw_tpu.obs.flight import (
     ADMIT,
     ADMIT_WAIT,
@@ -738,6 +739,24 @@ class EngineStats:
     moe_tokens_dropped: int = 0
     moe_dropped_frac: float = 0.0
     moe_expert_imbalance: float = 0.0
+    # a family that holds one chip's SHARE of its experts (routes over
+    # the router's whole width, computes what its own experts give):
+    # assignments the router made anywhere / those that landed on a
+    # held expert (real tokens only; their ratio is the local share,
+    # 1/ep under a balanced router), and, summed over layers and decode
+    # steps, how many held experts got at least one assignment (over
+    # decode_steps x layers: the held experts a decode step touches).
+    # moe_tokens_dropped stays structurally 0: there is no fence.
+    moe_local_assignments: int = 0
+    moe_total_assignments: int = 0
+    moe_held_hits_decode: int = 0
+    # the device cache beside the weights (models/cache.py): layers
+    # that own pages, and the per-slot recurrent state of a hybrid
+    # family — bytes a slot holds whatever its context, and the pool's
+    # total (0 for families whose every layer has pages)
+    kv_layers: int = 0
+    state_bytes_per_slot: int = 0
+    state_bytes_total: int = 0
     # serving-path phase breakdown (cumulative milliseconds).
     # prefill_ms / transfer_ms / emit_ms are VIEWS of the loop ledger
     # (properties below); first_emit_ms = host time from a prefill's
@@ -931,7 +950,37 @@ class Engine:
         self.model_cfg = model_cfg
         self.cfg = cfg
         self.eos = eos_token_ids
-        if cfg.enable_prefix_cache and self.fns.prefill_suffix is not None:
+        # what the family keeps on the device (models/cache.py): pages
+        # for the layers that attend over keys and values and, for a
+        # family with recurrent layers, per-slot state beside them.
+        # What moves PAGES ONLY cannot serve such a family — a page
+        # without the state that goes with it is half a sequence — so
+        # it is off by what the family is: prefix-cache hits (and with
+        # them the host KV tier, parking, migration and fleet fetch,
+        # which all need the content-addressed allocator), speculation
+        # (a rejected draft would need the state rolled back) and LoRA.
+        # Chunked prefill stays: a chunk resumes from the slot's state.
+        self.cache_spec = spec_of(model_cfg)
+        self._stateful = self.cache_spec.stateful
+        self.features_off: dict[str, str] = {}
+        if self._stateful:
+            why = ("the family keeps per-slot recurrent state beside its "
+                   "pages (ROADMAP.md M4: state snapshots)")
+            self.features_off = dict.fromkeys(
+                ("prefix_cache", "kv_host_tier", "migration",
+                 "batch_parking", "kv_fleet_fetch", "speculation", "lora"),
+                why)
+            if lora_params or adapter_names or adapter_store is not None:
+                raise ValueError(f"LoRA serving is off: {why}")
+            if mesh is not None:
+                raise ValueError(
+                    "mesh serving is off for this family: its state pool "
+                    "and expert share have no partition specs yet")
+            if cfg.kv_host_bytes > 0 or cfg.spec_tokens > 0:
+                logger.warning(
+                    "kv_host_bytes / spec_tokens ignored: %s", why)
+        if (cfg.enable_prefix_cache and not self._stateful
+                and self.fns.prefill_suffix is not None):
             self.allocator = RefcountedAllocator(cfg.num_pages, cfg.page_size)
             self.prefix_cache = PrefixCache(self.allocator, cfg.page_size)
         else:
@@ -961,6 +1010,11 @@ class Engine:
         self.stats.kv_quant_bits = kvq.quant_bits(cfg.kv_cache_dtype)
         self.stats.kv_bytes_per_token = round(
             self.kv_page_bytes / cfg.page_size, 3)
+        self.stats.kv_layers = self.cache_spec.kv_layers
+        self.stats.state_bytes_per_slot = (
+            self.cache_spec.state_bytes_per_slot(cfg.kv_cache_dtype))
+        self.stats.state_bytes_total = (
+            self.stats.state_bytes_per_slot * cfg.max_batch_size)
         # serving-phase latency histograms (queue_wait/prefill/ttft/…)
         # with trace-id exemplars — /metrics renders them, /state
         # summarizes p50/p95/p99 (obs/metrics.py ENGINE_HISTOGRAMS)
@@ -985,6 +1039,10 @@ class Engine:
         # without the reservation a nested _admit_one would pick the
         # same first-None index and the outer install would orphan it.
         self._reserved_slots: set[int] = set()
+        # per-slot-state families: the decode slot each sequence being
+        # prefilled will take (its state is written there by the
+        # prefill itself), from the pick until the _Slot is installed
+        self._slot_of_seq: dict[int, int] = {}
         self._queue: "queue.Queue[GenRequest]" = queue.Queue()
         # priority-tiered serving (ISSUE 19): the offline batch class.
         # Its queue is SEPARATE (and unbounded — batch never sheds) so
@@ -1012,13 +1070,7 @@ class Engine:
         # (the XLA paths get the same guarantee from OOB-drop
         # scatters). Never allocated, never referenced by a page
         # table, excluded from capacity accounting.
-        kv_shape = (
-            model_cfg.n_layers,
-            2,
-            (cfg.num_pages + 1) * cfg.page_size,
-            model_cfg.n_kv_heads,
-            model_cfg.head_dim,
-        )
+        kv_rows = (cfg.num_pages + 1) * cfg.page_size
         if mesh is not None:
             from aigw_tpu.parallel.sharding import (
                 kv_cache_spec,
@@ -1032,10 +1084,12 @@ class Engine:
                 k: jax.device_put(v, sharding_of(k, v.shape))
                 for k, v in params.items()
             }
-            self.kv_cache = kvq.make_pool(
-                kv_shape, cfg.kv_cache_dtype, mesh, kv_cache_spec())
+            self.kv_cache = self.cache_spec.make(
+                kv_rows, cfg.max_batch_size, cfg.kv_cache_dtype, mesh,
+                kv_cache_spec())
         else:
-            self.kv_cache = kvq.make_pool(kv_shape, cfg.kv_cache_dtype)
+            self.kv_cache = self.cache_spec.make(
+                kv_rows, cfg.max_batch_size, cfg.kv_cache_dtype)
         # Per-slot decode state lives ON DEVICE between ticks (uploaded
         # only when membership/sampling changes) — the decode hot loop
         # transfers just the sampled [K, B] tokens per round-trip.
@@ -1121,7 +1175,7 @@ class Engine:
         from aigw_tpu.tpuserve.attention import resolve_decode_backend
 
         self.decode_attn_impl, self.decode_attn_reason = (
-            resolve_decode_backend(cfg, model_cfg, mesh))
+            resolve_decode_backend(cfg, model_cfg, mesh, self.fns))
         if (cfg.pallas_attn or cfg.decode_backend == "fused") \
                 and self.decode_attn_impl == "xla-gather":
             logger.warning("decode backend fell back to xla-gather: %s",
@@ -1160,10 +1214,24 @@ class Engine:
         moe_kw = {"moe_stats": True} if is_moe else {}
         self._moe_experts = (int(getattr(model_cfg, "n_experts", 0))
                              if is_moe else 0)
+        # columns of a layer's routing-stats row: E placed counts and
+        # the drops; a family that holds a share of its experts adds
+        # (every assignment routed, held experts hit) behind them
+        self._moe_tape_width = int(getattr(
+            model_cfg, "moe_tape_width", self._moe_experts + 1))
         self._moe_expert_tokens = np.zeros(
             max(self._moe_experts, 1), np.int64)
         self._moe_layer_drops = np.zeros(
             max(int(model_cfg.n_layers), 1), np.int64)
+
+        tape_width = self._moe_tape_width
+        # per-slot-state families: each prefill row names the decode
+        # slot whose state it continues (a leafless None elsewhere, so
+        # the other families' programs and cache keys are unchanged)
+        stateful = self._stateful
+
+        def _slot_kw(slot_ids):
+            return {"slot_ids": slot_ids} if stateful else {}
 
         def _moe_split(out):
             """Normalize a model-entry-point result to
@@ -1215,10 +1283,12 @@ class Engine:
             return sampled, chosen, tk_ids, tk_vals
 
         def _prefill_step(params, lora, tokens, seq_lens, kv, page_table,
-                          keys, temp, top_p, top_k, bias, adapter_idx):
+                          keys, temp, top_p, top_k, bias, adapter_idx,
+                          slot_ids=None):
             logits, kv, moe = _moe_split(model_prefill(
                 params, mc, tokens, seq_lens, kv, page_table, ps,
-                lora=lora, adapter_idx=adapter_idx, **moe_kw))
+                lora=lora, adapter_idx=adapter_idx, **moe_kw,
+                **_slot_kw(slot_ids)))
             return _sample_maybe_lp(logits + bias, keys, temp, top_p,
                                     top_k), kv, moe
 
@@ -1226,10 +1296,12 @@ class Engine:
 
         def _prefill_suffix_step(params, lora, tokens, prefix_lens,
                                  seq_lens, kv, page_table, keys, temp,
-                                 top_p, top_k, bias, adapter_idx):
+                                 top_p, top_k, bias, adapter_idx,
+                                 slot_ids=None):
             logits, kv, moe = _moe_split(model_prefill_suffix(
                 params, mc, tokens, prefix_lens, seq_lens, kv, page_table,
-                ps, lora=lora, adapter_idx=adapter_idx, **moe_kw))
+                ps, lora=lora, adapter_idx=adapter_idx, **moe_kw,
+                **_slot_kw(slot_ids)))
             return _sample_maybe_lp(logits + bias, keys, temp, top_p,
                                     top_k), kv, moe
 
@@ -1342,7 +1414,7 @@ class Engine:
                 return (kv, new, macc), sampled
 
             def scan_k(params, lora, kv, state):
-                macc0 = (jnp.zeros((mc.n_layers, mc.n_experts + 1),
+                macc0 = (jnp.zeros((mc.n_layers, tape_width),
                                    jnp.int32) if is_moe else None)
                 (kv, state, macc), sampled = jax.lax.scan(
                     lambda c, _: body(params, lora, c),
@@ -1466,7 +1538,7 @@ class Engine:
                 return (kv, new, macc), (sampled, n_emit, n_prop)
 
             def scan_k(params, lora, kv, state):
-                macc0 = (jnp.zeros((mc.n_layers, mc.n_experts + 1),
+                macc0 = (jnp.zeros((mc.n_layers, tape_width),
                                    jnp.int32) if is_moe else None)
                 (kv, state, macc), out = jax.lax.scan(
                     lambda c, _: body(params, lora, c),
@@ -2073,12 +2145,8 @@ class Engine:
         """HBM bytes of one KV page (the /state bytes-pinned signal).
         Quantized pools count the packed element bytes PLUS the page's
         f32 scale block (one scale per token row × KV head per k/v)."""
-        mc = self.model_cfg
-        per_elt = kvq.bytes_per_kv_element(self.cfg.kv_cache_dtype)
-        scale = (4 if kvq.is_quantized_dtype(self.cfg.kv_cache_dtype)
-                 else 0)
-        return int(mc.n_layers * 2 * self.cfg.page_size * mc.n_kv_heads
-                   * (mc.head_dim * per_elt + scale))
+        return self.cache_spec.kv_page_bytes(self.cfg.page_size,
+                                             self.cfg.kv_cache_dtype)
 
     def mesh_axes(self) -> dict[str, int]:
         """Mesh axis name → size ({} off-mesh) — the /state topology
@@ -2276,9 +2344,11 @@ class Engine:
         # mid-traffic — round-trip page 0 through the host exactly as a
         # real migration does (idempotent rewrites of page 0's own
         # content; nothing is serving yet)
-        rows = kvq.page_to_host(self._export_page_dev(0))
-        for r in self._import_rungs():
-            self._import_pages_dev([0] * r, [rows] * r)
+        # (nothing moves pages alone for a family with per-slot state)
+        if not self._stateful:
+            rows = kvq.page_to_host(self._export_page_dev(0))
+            for r in self._import_rungs():
+                self._import_pages_dev([0] * r, [rows] * r)
         # NOTE: warm passes discard program results wholesale, so the
         # MoE routing accumulators stay at zero here — the exported
         # stats count real traffic only (folds happen at the traffic
@@ -2326,6 +2396,7 @@ class Engine:
                 jnp.zeros((G2,), jnp.int32),
                 jnp.zeros((G2, V), jnp.float32),
                 jnp.full((G2,), self._base_row, jnp.int32),
+                **self.slot_kw([], G2),
             )
             G2 *= 2
 
@@ -2931,6 +3002,19 @@ class Engine:
                 return i
         return None
 
+    def slot_kw(self, seq_ids: list[int], rows: int = 0) -> dict:
+        """The extra argument of a prefill program of a per-slot-state
+        family: the decode slot of each row's sequence, padding rows
+        (up to ``rows``) out of range so that they write nowhere.
+        Nothing for the other families."""
+        if not self._stateful:
+            return {}
+        ids = np.full((max(rows, len(seq_ids)),), self.cfg.max_batch_size,
+                      np.int32)
+        for r, seq_id in enumerate(seq_ids):
+            ids[r] = self._slot_of_seq[seq_id]
+        return {"slot_ids": jnp.asarray(ids)}
+
     def _free_slot_count(self) -> int:
         return sum(1 for i, s in enumerate(self._slots)
                    if s is None and i not in self._reserved_slots)
@@ -3368,6 +3452,13 @@ class Engine:
             req.id = seq_id
             prepared.append((req, seq_id, n, total))
         count = 0
+        if prepared and self._stateful:
+            # results install in item order, each into the first free
+            # slot: the k-th item's state goes to the k-th free slot
+            free = [i for i, x in enumerate(self._slots)
+                    if x is None and i not in self._reserved_slots]
+            for item, i in zip(prepared, free):
+                self._slot_of_seq[item[1]] = i
         if prepared:
             # the attention backend owns grouping + device calls
             # (bucket groups on xla-bucketed, one token-budget pack on
@@ -3377,6 +3468,8 @@ class Engine:
             for r in results:
                 slot_idx = self._free_slot_index()
                 assert slot_idx is not None  # len(items) <= free slots
+                planned = self._slot_of_seq.pop(r.seq_id, slot_idx)
+                assert planned == slot_idx, (planned, slot_idx)
                 chain = chain_by_req.get(id(r.req), [])
                 if self.prefix_cache is not None and chain:
                     # batched path = classified with no reusable prefix
@@ -3399,7 +3492,8 @@ class Engine:
                     m_prefill_real=r.n, m_prefill_padded=r.n,
                     m_res_t0=time.monotonic(),
                     m_res_bytes=(len(self.allocator.pages(r.seq_id))
-                                 * self.kv_page_bytes),
+                                 * self.kv_page_bytes
+                                 + self.stats.state_bytes_per_slot),
                 )
                 self.stats.prefills += 1
                 self._mark_admitted(slot_idx)
@@ -3451,6 +3545,9 @@ class Engine:
             return self._admit_one_reserved(req, slot_idx, chain)
         finally:
             self._reserved_slots.discard(slot_idx)
+            for sid in [k for k, v in self._slot_of_seq.items()
+                        if v == slot_idx]:
+                del self._slot_of_seq[sid]
 
     @engine_thread_only
     def _admit_one_reserved(self, req: GenRequest, slot_idx: int,
@@ -3459,6 +3556,8 @@ class Engine:
         total = min(n + req.max_tokens, self.cfg.max_seq_len)
         seq_id = next(self._seq_ids)
         ps = self.cfg.page_size
+        if self._stateful:
+            self._slot_of_seq[seq_id] = slot_idx
 
         # prefix cache: adopt the longest cached page-prefix. A FULL
         # prefix hit (every prompt page cached, prompt page-aligned)
@@ -3780,7 +3879,8 @@ class Engine:
             m_prefill_real=ns, m_prefill_padded=m_padded,
             m_prefix_reused=prefix_len,
             m_res_t0=time.monotonic(),
-            m_res_bytes=len(pages) * self.kv_page_bytes,
+            m_res_bytes=(len(pages) * self.kv_page_bytes
+                         + self.stats.state_bytes_per_slot),
             m_carry=ims.get("meter_carry"),
         )
         self._mark_admitted(slot_idx)
@@ -4339,21 +4439,30 @@ class Engine:
         loop.resume(outer)
         # the window's routing-stats leaf settles with the window — a
         # dispatch-time read would sync against the running program
-        self._fold_moe(w.moe)
+        self._fold_moe(w.moe, decode=True)
         for seq_id in w.frees:
             self.allocator.free(seq_id)
 
     @engine_thread_only
-    def _fold_moe(self, moe) -> None:
-        """Fold one program's [L, E+1] routing-stats leaf (per-expert
-        placed counts + capacity drops per layer) into the numpy
-        accumulators behind the /state MoE surface. No-op (None) on
-        dense families — call sites stay uniform."""
+    def _fold_moe(self, moe, decode: bool = False) -> None:
+        """Fold one program's [L, width] routing-stats leaf (per-expert
+        placed counts + capacity drops per layer; a family that holds a
+        share of its experts adds every assignment routed and the held
+        experts hit) into the numpy accumulators behind the /state MoE
+        surface. ``decode``: the leaf is a decode window's. No-op
+        (None) on dense families — call sites stay uniform."""
         if moe is None:
             return
         arr = np.asarray(moe, np.int64)
-        self._moe_expert_tokens += arr[:, :-1].sum(axis=0)
-        self._moe_layer_drops += arr[:, -1]
+        E = self._moe_experts
+        self._moe_expert_tokens += arr[:, :E].sum(axis=0)
+        self._moe_layer_drops += arr[:, E]
+        if arr.shape[1] > E + 1:
+            st = self.stats
+            st.moe_local_assignments += int(arr[:, :E].sum())
+            st.moe_total_assignments += int(arr[:, E + 1].sum())
+            if decode:
+                st.moe_held_hits_decode += int(arr[:, E + 2].sum())
 
     def moe_expert_load(self) -> list[int]:
         """Per-expert placed-token totals [E] for /state and the
@@ -4618,6 +4727,16 @@ class Engine:
             s.token_counts[tok] = s.token_counts.get(tok, 0) + 1
             s.gen_tokens.append(tok)
 
+    def _pool_bytes(self) -> tuple[int, int]:
+        """(bytes of the device cache, bytes sequences hold of it): the
+        page pool and what is allocated of it, plus — for a family with
+        per-slot state — the state pool and the taken slots' rows."""
+        pool = self.cfg.num_pages * self.kv_page_bytes
+        used = round(pool * self.allocator.occupancy)
+        taken = sum(x is not None for x in self._slots)
+        return (pool + self.stats.state_bytes_total,
+                used + taken * self.stats.state_bytes_per_slot)
+
     @engine_thread_only
     def _refresh_stats(self) -> None:
         # ``queued`` is INTERACTIVE depth only — the picker's
@@ -4638,6 +4757,13 @@ class Engine:
             xla_events.cache_counts())
         self.stats.kv_pages_free = self.allocator.free_pages
         self.stats.kv_occupancy = self.allocator.occupancy
+        if self._stateful:
+            # both pools: live pages, and the state of every slot that
+            # is taken (a slot's state costs the same at any context).
+            # The occupancy the picker reads is the share of the two
+            # pools' bytes that sequences hold.
+            pool, used = self._pool_bytes()
+            self.stats.kv_occupancy = round(used / max(pool, 1), 4)
         # adapter residency + tenant fairness gauges (ISSUE 7)
         if self._adapter_store is not None:
             self.stats.adapter_loads = self._adapter_store.loads
@@ -4663,10 +4789,8 @@ class Engine:
             self.stats.device_bytes_limit = limit
             self.stats.device_memory_frac = (
                 round(used / limit, 4) if limit else 0.0)
-            self.stats.kv_pool_bytes = (
-                self.cfg.num_pages * self.kv_page_bytes)
-            self.stats.kv_bytes_in_use = round(
-                self.stats.kv_pool_bytes * self.allocator.occupancy)
+            self.stats.kv_pool_bytes, self.stats.kv_bytes_in_use = (
+                self._pool_bytes())
             # mesh serving (ISSUE 10): EVERY local device, not just
             # device 0 — per-device memory_stats, the device's real
             # share of the (head-sharded) KV pool, and its share of the

@@ -2254,6 +2254,17 @@ class TPUServeServer:
                 "moe_tokens_dropped": s.moe_tokens_dropped,
                 "moe_dropped_frac": s.moe_dropped_frac,
                 "moe_expert_imbalance": s.moe_expert_imbalance,
+                "moe_local_assignments": s.moe_local_assignments,
+                "moe_total_assignments": s.moe_total_assignments,
+                "moe_held_hits_decode": s.moe_held_hits_decode,
+                # the device cache beside the weights: layers that own
+                # pages, the per-slot recurrent state of a hybrid
+                # family, and what is off because pages alone are half
+                # of such a family's sequence (feature -> why)
+                "kv_layers": s.kv_layers,
+                "state_bytes_per_slot": s.state_bytes_per_slot,
+                "state_bytes_total": s.state_bytes_total,
+                "features_off": self.engine.features_off,
                 "moe_expert_load": self.engine.moe_expert_load(),
                 "moe_layer_drops": self.engine.moe_layer_drops(),
                 # mesh serving (ISSUE 10): real per-device signals —

@@ -37,7 +37,7 @@ def main(argv: list[str]) -> int:
     import jax.numpy as jnp
     from jax._src import cache_key
 
-    from aigw_tpu.models import llama, mixtral, quant
+    from aigw_tpu.models import llama, mixtral, quant, qwen3_next
     from aigw_tpu.tpuserve.sampling import sample
 
     sharding = None
@@ -61,16 +61,26 @@ def main(argv: list[str]) -> int:
         "mixtral": (mixtral, mixtral.MixtralConfig(
             vocab_size=512, dim=256, n_layers=2, n_heads=4, n_kv_heads=2,
             ffn_dim=512, n_experts=4, experts_per_token=2)),
+        # bfloat16 as served: the family has no quantized weights
+        "qwen3_next": (qwen3_next, qwen3_next.TINY),
     }
     out: dict[str, dict] = {}
     for fam, (mod, cfg) in fams.items():
-        params = jax.eval_shape(
-            lambda: quant.quantize_params(
-                mod.init_params(jax.random.PRNGKey(0), cfg), mode="int8"))
-        hd = cfg.dim // cfg.n_heads
-        kv = jax.ShapeDtypeStruct(
-            (cfg.n_layers, 2, (B * P + 1) * PAGE, cfg.n_kv_heads, hd),
-            jnp.bfloat16)
+        if fam == "qwen3_next":
+            params = jax.eval_shape(
+                lambda: mod.init_params(jax.random.PRNGKey(0), cfg))
+            # pages for the full-attention layers, per-slot state beside
+            kv = jax.eval_shape(lambda: cfg.cache_spec().make(
+                (B * P + 1) * PAGE, B, "bfloat16"))
+        else:
+            params = jax.eval_shape(
+                lambda: quant.quantize_params(
+                    mod.init_params(jax.random.PRNGKey(0), cfg),
+                    mode="int8"))
+            hd = cfg.dim // cfg.n_heads
+            kv = jax.ShapeDtypeStruct(
+                (cfg.n_layers, 2, (B * P + 1) * PAGE, cfg.n_kv_heads, hd),
+                jnp.bfloat16)
         i32 = jnp.int32
 
         def sds(shape, dtype):

@@ -1,5 +1,5 @@
 """Names inside the device programs: every decode and prefill program
-of both model families carries each ``jax.named_scope`` of its blocks,
+of the three model families carries each ``jax.named_scope`` of its blocks,
 and the scopes are operation metadata only — the hash JAX's persistent
 compile cache takes of a program is the same with and without them, so
 no serving program recompiles for having been named.
@@ -24,6 +24,14 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 DENSE = {"embed", "layer/attn", "layer/mlp", "lm_head", "sample"}
 MOE = {"embed", "layer/attn", "layer/moe_route", "layer/moe_experts",
        "lm_head", "sample"}
+#: the hybrid family: its DeltaNet layers' projections, convolution and
+#: rule (chunked in a prefill, the recurrence in a decode step), its
+#: gated attention (``layer/attn`` is the softmax core it shares with
+#: the other families, nested inside; a trace attributes it to the outer
+#: scope), and the expert layer's three parts
+HYBRID = {"embed", "layer/gdn_proj", "layer/gdn_conv", "layer/attn_gated",
+          "layer/attn", "layer/moe_route", "layer/moe_experts",
+          "layer/moe_shared", "lm_head", "sample"}
 #: program -> the scopes its lowered text must carry
 WANT = {
     "llama.decode": DENSE | {"layer/kv_gather"},
@@ -32,6 +40,11 @@ WANT = {
     "mixtral.decode": MOE | {"layer/kv_gather"},
     "mixtral.prefill": MOE,
     "mixtral.prefill_suffix": MOE | {"layer/kv_gather"},
+    "qwen3_next.decode": HYBRID | {"layer/gdn_recurrent",
+                                   "layer/kv_gather"},
+    "qwen3_next.prefill": HYBRID | {"layer/gdn_chunk"},
+    "qwen3_next.prefill_suffix": HYBRID | {"layer/gdn_chunk",
+                                           "layer/kv_gather"},
 }
 
 
@@ -103,6 +116,6 @@ def test_scopes_live_only_in_the_programs_and_the_ledger():
                         found.setdefault(word, set()).add(rel)
     assert found == {
         "named_scope(": {"models/llama.py", "models/mixtral.py",
-                         "tpuserve/sampling.py"},
+                         "models/qwen3_next.py", "tpuserve/sampling.py"},
         "TraceAnnotation(": {"obs/flight.py"},
     }

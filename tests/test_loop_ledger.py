@@ -518,14 +518,24 @@ def test_state_and_metrics_carry_the_ledger_and_only_grow(served):
 def test_profile_reply_carries_the_captures_counts(served):
     async def main():
         async with aiohttp.ClientSession() as http:
+            # every program the capture's traffic runs is compiled
+            # BEFORE it, by two requests of the same lengths (a prefix
+            # miss, then a partial hit on the template's head): a
+            # compile inside the capture holds the engine thread in one
+            # phase for seconds under a loaded machine, longer than
+            # capture_end waits for its next phase boundary — the reply
+            # then carried no counts at all (the take-up run's failure)
+            for i in (100, 101):
+                await _chat(http, served, f"during the capture {i}", 12)
             before = await _state(http, served)
 
             captured = asyncio.Event()
 
             async def traffic():
                 # requests back to back for as long as the capture runs,
-                # however slowly a loaded machine starts the trace
-                i = 0
+                # however slowly a loaded machine starts the trace; all
+                # of one length, no two alike (no full-prefix hit)
+                i = 102
                 while not captured.is_set():
                     await _chat(http, served, f"during the capture {i}", 12)
                     i += 1

@@ -1,0 +1,360 @@
+"""Qwen3-Next (models/qwen3_next.py) against its float32 reference
+(models/reference/qwen3_next_ref.py), at a tiny size on the CPU, in
+float32 — LOGITS, never tokens.
+
+The tolerance. Program and reference compute the same float32
+mathematics in another order (the chunked WY form against the token
+recurrence, a window gather against a full score matrix, dense experts
+times combine weights against one expert at a time): what separates
+them is float32 rounding through eight layers, observed at 1e-5 to 5e-5
+on logits of magnitude ~3. ``TOL`` leaves that ten times of room and is
+still fifty times under what the cheapest wrong program gives — the
+mutation tests at the bottom prove that a bfloat16 state, a dropped
+expert assignment, rotary on every dimension and a missing output gate
+each fail it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import types
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from aigw_tpu.models import qwen3_next as qn
+from aigw_tpu.models.cache import StateCache
+from aigw_tpu.models.reference import qwen3_next_ref as ref
+from qwen3_next_util import SHARE, make_cache, make_params, ref_logits
+
+TOL = 5e-4
+PS = 16  # page size
+CONFIGS = {"all_held": qn.TINY, "share_8_of_16": SHARE}
+
+
+@pytest.fixture(scope="module", params=list(CONFIGS))
+def model(request):
+    cfg = CONFIGS[request.param]
+    return cfg, make_params(cfg)
+
+
+def _tokens(cfg, n, seed=0):
+    return np.random.default_rng(seed).integers(0, cfg.vocab_size, n)
+
+
+def _page_table(rows: list[list[int]], width: int = 16):
+    pt = np.zeros((len(rows), width), np.int32)
+    for r, pages in enumerate(rows):
+        pt[r, :len(pages)] = pages
+    return jnp.asarray(pt)
+
+
+def _err(got, want) -> float:
+    return float(np.abs(np.asarray(got) - np.asarray(want)).max())
+
+
+def _prefill(cfg):
+    return jax.jit(partial(qn.prefill, cfg=cfg, page_size=PS))
+
+
+def _suffix(cfg):
+    return jax.jit(partial(qn.prefill_suffix, cfg=cfg, page_size=PS))
+
+
+def _decode(cfg):
+    return jax.jit(partial(qn.decode_step, cfg=cfg, page_size=PS))
+
+
+def test_layer_pattern_and_cache_spec():
+    cfg = qn.Qwen3NextConfig(num_hidden_layers=12)
+    assert cfg.layer_kinds == ("linear", "linear", "linear", "full") * 3
+    spec = cfg.cache_spec()
+    assert spec.stateful and spec.kv_layers == 3
+    # 3 layers x (K and V) x 2 heads x 256 x 2 bytes = 6 KiB a token
+    assert spec.kv_page_bytes(128, "bfloat16") == 128 * 6 * 1024
+    # 9 layers x (32x128x128 float32 + 3x8192 bfloat16)
+    assert spec.state_bytes_per_slot("bfloat16") == 9 * (
+        32 * 128 * 128 * 4 + 3 * 8192 * 2)
+    assert cfg.rotary_dim == 64 and cfg.conv_dim == 8192
+
+
+def test_one_shot_prefill_matches_reference(model):
+    """(a) a right-padded [2, S] prefill, rows of different lengths
+    into chosen slots, and a padded group row that must write nothing."""
+    cfg, p = model
+    lens = [100, 37]
+    toks = [_tokens(cfg, n, seed=i) for i, n in enumerate(lens)]
+    tokens = np.zeros((4, 128), np.int32)
+    for r, t in enumerate(toks):
+        tokens[r, :len(t)] = t
+    cache = make_cache(cfg, 32, PS, 4)
+    marker = jax.tree_util.tree_map(lambda a: a + 7, cache.slots)
+    cache = StateCache(cache.kv, marker)
+    logits, cache = _prefill(cfg)(
+        p, tokens=jnp.asarray(tokens),
+        seq_lens=jnp.asarray(lens + [0, 0], jnp.int32), cache=cache,
+        page_table=_page_table([list(range(1, 9)), list(range(9, 17)),
+                                [], []]),
+        slot_ids=jnp.asarray([2, 0, 4, 4], jnp.int32))
+    for r, t in enumerate(toks):
+        assert _err(logits[r], ref_logits(p, cfg, t)[-1]) < TOL
+    # slots 1 and 3 were nobody's: the padded rows wrote nothing
+    for name, leaf in cache.slots.items():
+        for s in (1, 3):
+            np.testing.assert_array_equal(
+                np.asarray(leaf[:, s]), np.asarray(marker[name][:, s]))
+
+
+def test_chunked_prefill_matches_reference(model):
+    """(b) chunks of 32 through ``prefill_suffix`` — the state carried
+    from chunk to chunk in the slot, the full layers attending over the
+    page window — and a 4-token tail padded to 16."""
+    cfg, p = model
+    n, chunk = 100, 32
+    toks = _tokens(cfg, n, seed=3)
+    want = ref_logits(p, cfg, toks)
+    cache = make_cache(cfg, 32, PS, 4)
+    pt = _page_table([list(range(5, 13))])
+    slot = jnp.asarray([3], jnp.int32)
+    step = _suffix(cfg)
+    done = 0
+    while n - done > chunk:
+        logits, cache = step(
+            p, tokens=jnp.asarray(toks[None, done:done + chunk]),
+            prefix_lens=jnp.asarray([done], jnp.int32),
+            seq_lens=jnp.asarray([done + chunk], jnp.int32), cache=cache,
+            page_table=pt, slot_ids=slot)
+        done += chunk
+        assert _err(logits[0], want[done - 1]) < TOL
+    tail = np.zeros((1, 16), np.int32)
+    tail[0, :n - done] = toks[done:]
+    logits, cache = step(
+        p, tokens=jnp.asarray(tail),
+        prefix_lens=jnp.asarray([done], jnp.int32),
+        seq_lens=jnp.asarray([n], jnp.int32), cache=cache, page_table=pt,
+        slot_ids=slot)
+    assert _err(logits[0], want[-1]) < TOL
+
+
+def _prefill_then_decode(cfg, p, steps=20, between=None):
+    """(c) two prompts of different lengths prefilled into slots 1 and
+    3 of four, then ``steps`` teacher-forced decode steps with slots 0
+    and 2 inactive. Returns (largest logit error, inactive rows
+    untouched)."""
+    lens = [90, 37]
+    seqs = [_tokens(cfg, n + steps, seed=10 + i)
+            for i, n in enumerate(lens)]
+    want = [ref_logits(p, cfg, s) for s in seqs]
+    cache = make_cache(cfg, 40, PS, 4)
+    rows = [[], list(range(1, 9)), [], list(range(9, 17))]
+    tokens = np.zeros((2, 96), np.int32)
+    for r, (s, n) in enumerate(zip(seqs, lens)):
+        tokens[r, :n] = s[:n]
+    logits, cache = _prefill(cfg)(
+        p, tokens=jnp.asarray(tokens), seq_lens=jnp.asarray(lens, jnp.int32),
+        cache=cache, page_table=_page_table([rows[1], rows[3]]),
+        slot_ids=jnp.asarray([1, 3], jnp.int32))
+    worst = max(_err(logits[r], want[r][lens[r] - 1]) for r in range(2))
+    # the idle slots hold a stranger's state: it must come through whole
+    slots = {k: v.at[:, 0].add(3.0).at[:, 2].add(5.0)
+             for k, v in cache.slots.items()}
+    cache = StateCache(cache.kv, slots)
+    before = {k: np.asarray(v) for k, v in slots.items()}
+    active = jnp.asarray([False, True, False, True])
+    pt = _page_table(rows)
+    step = _decode(cfg)
+    for t in range(steps):
+        toks = np.zeros((4,), np.int32)
+        pos = np.zeros((4,), np.int32)
+        for r, slot in enumerate((1, 3)):
+            toks[slot] = seqs[r][lens[r] + t]
+            pos[slot] = lens[r] + t
+        logits, cache = step(p, tokens=jnp.asarray(toks),
+                             positions=jnp.asarray(pos), cache=cache,
+                             page_table=pt, active=active)
+        if between is not None:
+            cache = between(cache)
+        for r, slot in enumerate((1, 3)):
+            worst = max(worst, _err(logits[slot], want[r][lens[r] + t]))
+    untouched = all(
+        np.array_equal(np.asarray(v[:, s]), before[k][:, s])
+        for k, v in cache.slots.items() for s in (0, 2))
+    return worst, untouched
+
+
+def test_prefill_then_decode_matches_reference(model):
+    cfg, p = model
+    worst, untouched = _prefill_then_decode(cfg, p)
+    assert worst < TOL
+    assert untouched, "a decode step wrote an inactive slot's state"
+
+
+def test_hidden_states_is_the_reference_mean(model):
+    cfg, p = model
+    toks = _tokens(cfg, 40, seed=5)
+    tokens = np.zeros((1, 64), np.int32)
+    tokens[0, :40] = toks
+    got = qn.hidden_states(p, cfg, jnp.asarray(tokens), jnp.asarray([40]))
+    assert got.shape == (1, cfg.hidden_size)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_chunked_form_agrees_with_the_recurrence():
+    """The WY form over 3 blocks (one padded) against the token rule,
+    from a non-zero state, to float32 rounding: 1e-5 on values of
+    order 1."""
+    rng = np.random.default_rng(0)
+    B, S, H, dk, dv = 2, 150, 3, 16, 8
+    q, k = (rng.normal(size=(B, S, H, dk)).astype(np.float32)
+            for _ in range(2))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.normal(size=(B, S, H, dv)).astype(np.float32)
+    g = -rng.uniform(0.0, 0.3, (B, S, H)).astype(np.float32)
+    beta = rng.uniform(0.0, 1.0, (B, S, H)).astype(np.float32)
+    # row 1 is real for 70 tokens only
+    live = (np.arange(S)[None, :] < np.array([S, 70])[:, None])[..., None]
+    g, beta = g * live, beta * live
+    s0 = rng.normal(size=(B, H, dk, dv)).astype(np.float32)
+    out, state = qn._gdn_chunk(*map(jnp.asarray, (q, k, v, g, beta, s0)))
+    st = jnp.asarray(s0)
+    for t in range(S):
+        o, st = qn._gdn_recurrent(q[:, t], k[:, t], v[:, t], g[:, t],
+                                  beta[:, t], st)
+        assert _err(out[0, t], o[0]) < 2e-5
+        if t < 70:
+            assert _err(out[1, t], o[1]) < 2e-5
+    assert _err(state, st) < 2e-5
+
+
+def test_the_four_shares_add_up_to_the_whole_layer():
+    """What each of four chips computes of one expert layer (its 4 of
+    16 experts), plus the shared expert counted once, is the uncut
+    reference's MoE output."""
+    whole = qn.TINY
+    p = make_params(whole, seed=4)
+    F = whole.moe_intermediate_size
+    i = 2
+    x = jax.random.normal(jax.random.PRNGKey(9), (1, 24, whole.hidden_size))
+    cfgd = dataclasses.asdict(whole)
+    want = ref.moe_layer(p, i, cfgd, x[0]) + ref.shared_expert(p, i, x[0])
+    # the shared expert and its gate, alone: a share that holds nothing
+    none = dataclasses.replace(whole, num_experts=4, router_experts=16,
+                               held_from=16)
+    shared = qn.moe(_share_params(p, i, 0, 4, F), i, x, none)[0]
+    np.testing.assert_allclose(
+        shared, ref.shared_expert(p, i, x[0]), atol=1e-5)
+    total = shared
+    placed = 0
+    for first in (0, 4, 8, 12):
+        share = dataclasses.replace(whole, num_experts=4,
+                                    router_experts=16, held_from=first)
+        tape: list = []
+        part = qn.moe(_share_params(p, i, first, 4, F), i, x, share,
+                      tape=tape)[0]
+        total = total + (part - shared)
+        placed += int(tape[0][:4].sum())
+        assert int(tape[0][4]) == 0  # dropped: structurally nothing
+        assert int(tape[0][5]) == 24 * whole.num_experts_per_tok
+        # and the reference given the same share agrees with the part
+        np.testing.assert_allclose(
+            part - shared,
+            ref.moe_layer(p, i, cfgd, x[0], first, 4), atol=1e-5)
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    assert placed == 24 * whole.num_experts_per_tok  # nothing dropped
+
+
+def _share_params(p, i, first, count, F):
+    q = dict(p)
+    cols = slice(first * F, (first + count) * F)
+    q[f"l{i}.experts_gate"] = p[f"l{i}.experts_gate"][:, cols]
+    q[f"l{i}.experts_up"] = p[f"l{i}.experts_up"][:, cols]
+    q[f"l{i}.experts_down"] = p[f"l{i}.experts_down"][cols]
+    return q
+
+
+def test_routing_stats_tape_counts_real_tokens_only():
+    cfg = SHARE
+    p = make_params(cfg)
+    toks = _tokens(cfg, 20)
+    tokens = np.zeros((1, 32), np.int32)
+    tokens[0, :20] = toks
+    cache = make_cache(cfg, 8, PS, 2)
+    _, _, stats = jax.jit(partial(
+        qn.prefill, cfg=cfg, page_size=PS, moe_stats=True))(
+        p, tokens=jnp.asarray(tokens), seq_lens=jnp.asarray([20]),
+        cache=cache, page_table=_page_table([[1, 2]]))
+    stats = np.asarray(stats)
+    E = cfg.num_experts
+    assert stats.shape == (cfg.num_hidden_layers, cfg.moe_tape_width)
+    assert (stats[:, E] == 0).all()
+    assert (stats[:, E + 1] == 20 * cfg.num_experts_per_tok).all()
+    assert (stats[:, :E].sum(1) <= stats[:, E + 1]).all()
+    assert ((stats[:, :E] > 0).sum(1) == stats[:, E + 2]).all()
+
+
+# -- what the tolerance has to catch ---------------------------------------
+def _bf16_state(cache):
+    slots = dict(cache.slots)
+    slots["gdn_state"] = slots["gdn_state"].astype(
+        jnp.bfloat16).astype(jnp.float32)
+    return StateCache(cache.kv, slots)
+
+
+def test_a_bfloat16_state_fails_the_tolerance():
+    cfg = qn.TINY
+    worst, _ = _prefill_then_decode(cfg, make_params(cfg),
+                                    between=_bf16_state)
+    assert worst > 3 * TOL
+
+
+def test_rotary_on_every_dimension_fails_the_tolerance():
+    cfg = qn.TINY
+    p = make_params(cfg)
+    toks = _tokens(cfg, 60)
+    wrong = dataclasses.replace(cfg, partial_rotary_factor=1.0)
+    got, _ = _prefill(wrong)(
+        p, tokens=jnp.asarray(toks[None]), seq_lens=jnp.asarray([60]),
+        cache=make_cache(cfg, 8, PS, 1), page_table=_page_table([[1, 2, 3, 4]]))
+    assert _err(got[0], ref_logits(p, cfg, toks)[-1]) > 3 * TOL
+
+
+def test_a_missing_output_gate_fails_the_tolerance(monkeypatch):
+    cfg = qn.TINY
+    p = make_params(cfg)
+    toks = _tokens(cfg, 60)
+
+    def ungated(p, i, q, k, v, mask, gate):
+        attn = qn.llama._attention(q, k.astype(q.dtype), v.astype(q.dtype),
+                                   mask)
+        return qn.llama._matmul(p, f"l{i}.o_proj", attn)
+
+    monkeypatch.setattr(qn, "_attn_out", ungated)
+    got, _ = qn.prefill(
+        p, cfg, jnp.asarray(toks[None]), jnp.asarray([60]),
+        make_cache(cfg, 8, PS, 1), _page_table([[1, 2, 3, 4]]), PS)
+    assert _err(got[0], ref_logits(p, cfg, toks)[-1]) > 3 * TOL
+
+
+def test_a_dropped_assignment_fails_the_tolerance(monkeypatch):
+    """One token's largest expert assignment lost in every layer, as a
+    capacity fence would lose it: after the renormalisation, so nothing
+    else moves."""
+    cfg = qn.TINY
+    p = make_params(cfg)
+    toks = _tokens(cfg, 60)
+
+    def top_k(x, k):
+        vals, idx = jax.lax.top_k(x, k)
+        return vals, idx.at[59, 0].set(10 ** 6)  # an id nobody holds
+
+    shim = types.SimpleNamespace(**{n: getattr(jax.lax, n)
+                                    for n in dir(jax.lax)})
+    shim.top_k = top_k
+    monkeypatch.setattr(qn, "lax", shim)
+    got, _ = qn.prefill(
+        p, cfg, jnp.asarray(toks[None]), jnp.asarray([60]),
+        make_cache(cfg, 8, PS, 1), _page_table([[1, 2, 3, 4]]), PS)
+    assert _err(got[0], ref_logits(p, cfg, toks)[-1]) > 3 * TOL
