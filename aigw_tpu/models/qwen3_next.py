@@ -22,8 +22,10 @@ width, computes the shared expert plus its own experts' weighted
 outputs, and leaves out what absent experts would add — one chip's
 share of an expert-parallel deployment, with no stand-in for the
 exchange. With everything held it is the whole layer. No token is ever
-dropped: the held experts run densely over every token and the combine
-weights select (a sort-and-grouped path is ROADMAP.md's to add).
+dropped: a sequence runs the held experts densely over every token and
+the combine weights select (a sort-and-grouped path is ROADMAP.md's to
+add); a decode step loops over the held experts its live rows hit and
+reads no other.
 
 Departures from the checkpoint's tensor layout (a loader permutes; the
 mathematics is the source's): ``in_proj_qkvz`` holds q | k | v | z as
@@ -245,18 +247,56 @@ def _rope_partial(x: jax.Array, positions: jax.Array, theta: float,
 
 
 # -- the expert layer -------------------------------------------------------
+def _hit_experts(p: dict, i: int, xt: jax.Array, weights: jax.Array,
+                 hit: jax.Array, n_hit: jax.Array,
+                 cfg: Qwen3NextConfig) -> jax.Array:
+    """The routed mixture of a decode step, one trip of a loop for each
+    held expert that ``hit`` [E] marks (``n_hit`` of them): a trip
+    reads that expert's ``[D, F]`` gate and up columns and ``[F, D]``
+    down rows out of the flat matrices and adds its weighted output for
+    all ``T`` rows, float32. An expert nobody picked is never read,
+    and none is copied: the slices are operands of their products
+    (tests/test_pallas_tpu_aot.py holds the compiled step's temporary
+    memory under one expert's bytes)."""
+    T, D = xt.shape
+    E, F = cfg.num_experts, cfg.moe_intermediate_size
+    gate, up, down = (p[f"l{i}.experts_{m}"] for m in ("gate", "up", "down"))
+    ids = jnp.nonzero(hit, size=E, fill_value=0)[0].astype(jnp.int32)
+
+    def trip(j, acc):
+        e = ids[j]
+        g = lax.dynamic_slice(gate, (0, e * F), (D, F))
+        u = lax.dynamic_slice(up, (0, e * F), (D, F))
+        d = lax.dynamic_slice(down, (e * F, 0), (F, D))
+        w = lax.dynamic_slice(weights, (0, e), (T, 1))
+        h = (jax.nn.silu(xt @ g) * (xt @ u)).astype(jnp.float32) * w
+        return acc + jnp.dot(h.astype(xt.dtype), d,
+                             preferred_element_type=jnp.float32)
+
+    return lax.fori_loop(0, n_hit, trip, jnp.zeros((T, D), jnp.float32))
+
+
 def moe(p: dict, i: int, x: jax.Array, cfg: Qwen3NextConfig,
         valid: jax.Array | None = None,
         tape: list | None = None) -> jax.Array:
     """Shared expert + the held experts' part of the routed mixture.
     x: [B, S, D]. ``valid`` [B, S] marks real tokens for the stats
-    ``tape`` (one ``[moe_tape_width]`` int32 row a layer); it does not
-    change what is computed."""
+    ``tape`` (one ``[moe_tape_width]`` int32 row a layer).
+
+    A sequence (S > 1) runs every held expert densely over every token
+    and lets the combine weights select: a chunk's tokens hit all of
+    them, and ``valid`` changes nothing that is computed. A decode step
+    (S == 1) computes only the held experts that a ``valid`` row picked
+    (:func:`_hit_experts`): a row that is not valid routes nowhere and
+    its routed part is zero — nothing reads a dead row's output, and
+    routing is dropless, so no live row depends on what a dead row
+    holds. The tape's hit column is that loop's trip count."""
     B, S, D = x.shape
     T = B * S
     E, K = cfg.num_experts, cfg.num_experts_per_tok
     F = cfg.moe_intermediate_size
     xt = x.reshape(T, D)
+    step = S == 1
     with jax.named_scope("layer/moe_route"):
         # the pick is discrete: a rounded logit picks another expert,
         # which is another model, so the router runs at full precision
@@ -272,22 +312,30 @@ def moe(p: dict, i: int, x: jax.Array, cfg: Qwen3NextConfig,
         held = jax.nn.one_hot(topi - cfg.held_from, E,
                               dtype=jnp.float32)  # [T, K, E]
         weights = jnp.einsum("tke,tk->te", held, topv)
-        if tape is not None:
+        if tape is not None or step:
+            # (traced in the order the chunk programs' compile-cache
+            # keys were taken in: tests/test_cache_keys.py)
             real = (jnp.ones((T,), jnp.float32) if valid is None
                     else valid.reshape(T).astype(jnp.float32))
             placed = jnp.einsum("tke,t->e", held, real).astype(jnp.int32)
-            tape.append(jnp.concatenate([
-                placed,
-                jnp.zeros((1,), jnp.int32),  # dropped: there is no fence
-                (jnp.sum(real) * K).astype(jnp.int32)[None],
-                jnp.sum(placed > 0).astype(jnp.int32)[None]]))
+            dropped = jnp.zeros((1,), jnp.int32)  # there is no fence
+            routed = (jnp.sum(real) * K).astype(jnp.int32)[None]
+            # ONE value: the tape's hit column and the loop's bound
+            n_hit = jnp.sum(placed > 0).astype(jnp.int32)
+        if tape is not None:
+            tape.append(jnp.concatenate(
+                [placed, dropped, routed, n_hit[None]]))
     with jax.named_scope("layer/moe_experts"):
-        gate = jax.nn.silu(llama._matmul(p, f"l{i}.experts_gate", xt))
-        up = llama._matmul(p, f"l{i}.experts_up", xt)
-        h = (gate * up).astype(jnp.float32).reshape(T, E, F)
-        h = (h * weights[:, :, None]).astype(x.dtype).reshape(T, E * F)
-        out = jnp.dot(h, p[f"l{i}.experts_down"],
-                      preferred_element_type=jnp.float32)
+        if step:
+            out = _hit_experts(p, i, xt, weights * real[:, None],
+                               placed > 0, n_hit, cfg)
+        else:
+            gate = jax.nn.silu(llama._matmul(p, f"l{i}.experts_gate", xt))
+            up = llama._matmul(p, f"l{i}.experts_up", xt)
+            h = (gate * up).astype(jnp.float32).reshape(T, E, F)
+            h = (h * weights[:, :, None]).astype(x.dtype).reshape(T, E * F)
+            out = jnp.dot(h, p[f"l{i}.experts_down"],
+                          preferred_element_type=jnp.float32)
     with jax.named_scope("layer/moe_shared"):
         sh = jax.nn.silu(llama._matmul(p, f"l{i}.shared_gate", xt)) \
             * llama._matmul(p, f"l{i}.shared_up", xt)
@@ -637,6 +685,14 @@ def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
     n_valid = active.astype(jnp.int32)
 
     def linear(i, j, h):
+        # the state pool comes to the layer WITH the layer's input: the
+        # expert loops between two layers hide from the compiler that
+        # every reader of the pool runs before the next layer updates
+        # it in place, and it would copy the whole pool to be safe
+        # (604 MB, twice a step at the published widths;
+        # tests/test_pallas_tpu_aot.py)
+        h, slots["gdn_state"] = lax.optimization_barrier(
+            (h, slots["gdn_state"]))
         mixed, z, beta, g = _gdn_project(p, i, h, cfg)
         y, tail = _gdn_conv(p, i, mixed, slots["gdn_conv"][j], n_valid)
         q, k, v = _gdn_heads(y, cfg)

@@ -1,9 +1,11 @@
 """Child of tests/test_cache_keys.py: builds the serving engine for a
-tiny model of each family that was here before the per-slot state pool
-(``tiny-random``: llama, ``tiny-moe``: mixtral), lowers the programs
+tiny model of each family (``tiny-random``: llama, ``tiny-moe``:
+mixtral, ``tiny-qwen3-next``: the hybrid family), lowers the programs
 the engine itself dispatches — its jitted prefill, chunk and
 decode-window wrappers, not the model functions — and prints the hash
-JAX's persistent compile cache takes of each computation.
+JAX's persistent compile cache takes of each computation. The hybrid
+family's decode programs are left out: they are what changes when its
+decode step changes, and no golden holds them.
 
     python tests/engine_keys_child.py [<checkout>]
 
@@ -37,7 +39,7 @@ def main(argv: list[str]) -> int:
         return h.hexdigest()
 
     out = {"jax": jax.__version__}
-    for model in ("tiny-random", "tiny-moe"):
+    for model in ("tiny-random", "tiny-moe", "tiny-qwen3-next"):
         spec = get_model_spec(model)
         fns = family_fns(spec.family)
         params = fns.init_params(jax.random.PRNGKey(0), spec.config)
@@ -52,13 +54,17 @@ def main(argv: list[str]) -> int:
         pt = jnp.zeros((G, P), i32)
         lens = jnp.zeros((G,), i32)
         toks = jnp.zeros((G, S), i32)
+        # a per-slot-state family's rows name their decode slots
+        slot_kw = eng.slot_kw([], rows=G)
         out[f"{model}.prefill"] = key_of(eng._prefill_fn.lower(
             eng.params, eng.lora_params, toks, lens, eng.kv_cache, pt,
-            *sampling))
+            *sampling, **slot_kw))
         out[f"{model}.prefill_suffix"] = key_of(
             eng._prefill_suffix_fn.lower(
                 eng.params, eng.lora_params, toks, lens, lens,
-                eng.kv_cache, pt, *sampling))
+                eng.kv_cache, pt, *sampling, **slot_kw))
+        if slot_kw:  # the hybrid family: its decode programs have no golden
+            continue
         state = eng._build_device_state(bucket=P)
         for lean in (True, False):
             out[f"{model}.decode.lean={lean}"] = key_of(

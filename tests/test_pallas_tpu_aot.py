@@ -93,3 +93,60 @@ def test_w8a16_matmul_compiles_at_every_weight_shape(v5e, geometry):
         assert qmatmul.supported(B, k, n), (k, n)
         _compile(qmatmul._w8a16_matmul, v5e, ((B, k), jnp.bfloat16),
                  ((k, n), jnp.int8), ((1, n), jnp.float32))
+
+
+def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e):
+    """No Pallas here, but the same kind of fact only the chip's
+    compiler knows. The Qwen3-Next decode window (a scan of
+    ``decode_step``) at the published widths, 12 layers, 128 experts
+    held, 32 slots: the loop over the experts the live rows hit slices
+    each expert's blocks out of the flat matrices where they lie, and
+    the DeltaNet state pool is updated in place from layer to layer.
+    Both have failed silently: with the expert loops between the
+    layers the compiler could no longer order the pool's readers and
+    copied all 604 MB of it twice a step (3.4 ms of a 23 ms step on the
+    chip) until ``decode_step`` handed the pool to each layer with its
+    input. A copy of a pool or of an expert matrix is an instruction
+    of that shape in the compiled program."""
+    import re
+
+    from aigw_tpu.models import qwen3_next as qn
+
+    cfg = qn.Qwen3NextConfig(num_hidden_layers=12, num_experts=128,
+                             router_experts=512, vocab_size=37984)
+    slots, pages, page = 32, 32, 128
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    p = sds(jax.eval_shape(
+        lambda: qn.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(lambda: cfg.cache_spec().make(
+        (slots * pages + 1) * page, slots, "bfloat16")))
+    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
+
+    def window(p, cache, tokens, positions, page_table, active):
+        def body(carry, _):
+            cache, tokens, positions = carry
+            logits, cache, moe = qn.decode_step(
+                p, cfg, tokens, positions, cache, page_table, page, active,
+                moe_stats=True)
+            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (cache, tokens, positions + 1), (tokens, moe)
+
+        return jax.lax.scan(body, (cache, tokens, positions), None,
+                            length=2)
+
+    text = jax.jit(window, donate_argnums=(1,)).lower(
+        p, cache, i32, i32,
+        jax.ShapeDtypeStruct((slots, pages), jnp.int32, sharding=v5e),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
+    ).compile().as_text()
+    copied = set(re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text))
+    held = {f"{a.dtype.name.replace('float', 'f')}"
+            f"[{','.join(map(str, a.shape))}]"
+            for a in (*jax.tree_util.tree_leaves(cache),
+                      p["l0.experts_gate"], p["l0.experts_down"])}
+    assert copied and not copied & held, sorted(copied & held)

@@ -338,6 +338,14 @@ def test_a_missing_output_gate_fails_the_tolerance(monkeypatch):
     assert _err(got[0], ref_logits(p, cfg, toks)[-1]) > 3 * TOL
 
 
+def _lax_with(**over):
+    """``jax.lax`` as the model sees it, with some functions replaced."""
+    shim = types.SimpleNamespace(**{n: getattr(jax.lax, n)
+                                    for n in dir(jax.lax)})
+    vars(shim).update(over)
+    return shim
+
+
 def test_a_dropped_assignment_fails_the_tolerance(monkeypatch):
     """One token's largest expert assignment lost in every layer, as a
     capacity fence would lose it: after the renormalisation, so nothing
@@ -350,11 +358,101 @@ def test_a_dropped_assignment_fails_the_tolerance(monkeypatch):
         vals, idx = jax.lax.top_k(x, k)
         return vals, idx.at[59, 0].set(10 ** 6)  # an id nobody holds
 
-    shim = types.SimpleNamespace(**{n: getattr(jax.lax, n)
-                                    for n in dir(jax.lax)})
-    shim.top_k = top_k
-    monkeypatch.setattr(qn, "lax", shim)
+    monkeypatch.setattr(qn, "lax", _lax_with(top_k=top_k))
     got, _ = qn.prefill(
         p, cfg, jnp.asarray(toks[None]), jnp.asarray([60]),
         make_cache(cfg, 8, PS, 1), _page_table([[1, 2, 3, 4]]), PS)
     assert _err(got[0], ref_logits(p, cfg, toks)[-1]) > 3 * TOL
+
+
+# -- the decode step computes the experts its live rows hit, and no other --
+#: every row picks all four experts of a four-wide router
+ALL_HIT = dataclasses.replace(qn.TINY, num_experts=4, router_experts=4)
+#: four experts "held" beyond a 16-wide router: no pick ever lands here
+NONE_HIT = dataclasses.replace(qn.TINY, num_experts=4, router_experts=16,
+                               held_from=16)
+
+
+def _dense_moe(p, i, x, cfg, valid=None, tape=None, _moe=qn.moe):
+    """A decode step's rows through the dense pass (as one sequence of
+    B tokens): every held expert for every row, live or not — what the
+    decode program computed before it looped."""
+    B = x.shape[0]
+    return _moe(p, i, x.reshape(1, B, -1), cfg,
+                None if valid is None else valid.reshape(1, B),
+                tape).reshape(x.shape)
+
+
+@pytest.mark.parametrize("case,cfg", [
+    ("dead_rows_route_elsewhere", SHARE), ("no_held_expert_hit", NONE_HIT),
+    ("every_held_expert_hit", ALL_HIT)])
+def test_decode_step_loops_over_the_experts_live_rows_hit(
+        case, cfg, monkeypatch):
+    """Slots 1 and 3 of four decode; 0 and 2 are dead and hold tokens
+    and a state of their own. Live logits: the reference's and the
+    dense pass's, and the same to the bit whatever the dead rows hold;
+    the loop's trips a layer are the tape's hit column are the held
+    experts that LIVE rows picked."""
+    p = make_params(cfg)
+    lens, live = [40, 23], (1, 3)
+    seqs = [_tokens(cfg, n + 1, seed=20 + i) for i, n in enumerate(lens)]
+    rows = [[], [1, 2, 3], [], [4, 5, 6]]
+    tokens = np.zeros((2, 48), np.int32)
+    for r, (s, n) in enumerate(zip(seqs, lens)):
+        tokens[r, :n] = s[:n]
+    _, cache = _prefill(cfg)(
+        p, tokens=jnp.asarray(tokens), seq_lens=jnp.asarray(lens, jnp.int32),
+        cache=make_cache(cfg, 8, PS, 4),
+        page_table=_page_table([rows[1], rows[3]], 4),
+        slot_ids=jnp.asarray(live, jnp.int32))
+    cache = StateCache(cache.kv, {
+        k: v.at[:, 0].add(3.0).at[:, 2].add(5.0)
+        for k, v in cache.slots.items()})
+    pos = np.zeros((4,), np.int32)
+    pos[list(live)] = lens
+    trips: list[int] = []
+
+    def fori_loop(lower, upper, body, init):
+        trips.append(int(upper))
+        return jax.lax.fori_loop(lower, upper, body, init)
+
+    def step(dead_tokens, active, moe=None):
+        toks = np.zeros((4,), np.int32)
+        toks[[0, 2]] = dead_tokens
+        toks[list(live)] = [s[n] for s, n in zip(seqs, lens)]
+        with monkeypatch.context() as m:
+            m.setattr(qn, "lax", _lax_with(fori_loop=fori_loop))
+            if moe is not None:
+                m.setattr(qn, "moe", moe)
+            del trips[:]
+            logits, _, stats = qn.decode_step(
+                p, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
+                _page_table(rows, 4), PS, jnp.asarray(active),
+                moe_stats=True)
+        return (np.asarray(logits)[list(live)], np.asarray(stats),
+                list(trips))
+
+    only_live = [False, True, False, True]
+    got, stats, looped = step([7, 300], only_live)
+    E = cfg.num_experts
+    for r in range(2):
+        assert _err(got[r], ref_logits(p, cfg, seqs[r])[-1]) < TOL
+    dense, dense_stats, none = step([7, 300], only_live, moe=_dense_moe)
+    assert none == [] and _err(got, dense) < 2e-5
+    np.testing.assert_array_equal(stats, dense_stats)
+    # other tokens in the dead rows: not a bit of a live row moves
+    again, stats_again, looped_again = step([411, 52], only_live)
+    np.testing.assert_array_equal(got, again)
+    np.testing.assert_array_equal(stats, stats_again)
+    assert looped == looped_again == list(stats[:, E + 2]) == list(
+        (stats[:, :E] > 0).sum(1))
+    assert (stats[:, E + 1] == 2 * cfg.num_experts_per_tok).all()
+    if case == "dead_rows_route_elsewhere":
+        # were they live, the dead rows would add experts to the list
+        _, everyone, more = step([7, 300], [True] * 4)
+        assert sum(more) > sum(looped) > 0
+        assert ((everyone[:, :E] > 0) >= (stats[:, :E] > 0)).all()
+    elif case == "no_held_expert_hit":
+        assert looped == [0] * cfg.num_hidden_layers  # the shared expert
+    else:
+        assert looped == [E] * cfg.num_hidden_layers
