@@ -1,6 +1,9 @@
 # Developer entry points (tests name the CPU fake-chip platform through
 # tests/conftest.py; bench and chip-smoke run on the TPU or fail).
 
+# one cell of BENCHMARK.json, as the driver runs it
+CELL ?= qwen2-7b.chat-steady
+
 .PHONY: test test-fast native bench gateway-bench chip-smoke chaos docs dist clean lint
 
 # aigw-check (ISSUE 15): the invariant lint suite — jit-surface
@@ -22,8 +25,10 @@ test-fast: native
 native:
 	$(MAKE) -C native
 
+# BENCHMARK.json's command for CELL=<name>: the last stdout line is
+# the result. Exits 3 where there is no TPU.
 bench:
-	python bench.py
+	python3 cellbench/run.py --workload $(CELL) --seed 2147493001 --seconds 50 --trace 0
 
 gateway-bench:
 	python benchmarks/gateway_overhead.py

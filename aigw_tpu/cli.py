@@ -232,11 +232,6 @@ def main(argv: list[str] | None = None) -> int:
                          help="pin the decode window at "
                               "--decode-steps-per-tick instead of "
                               "adapting it to queue pressure")
-    p_serve.add_argument("--sync-transfers", action="store_true",
-                         help="fetch decode-window tokens with a "
-                              "blocking device_get at drain time "
-                              "instead of an async copy issued at "
-                              "dispatch (debug/A-B knob)")
     p_serve.add_argument("--warm-prefill-buckets", type=int, default=0,
                          help="pre-compile batched-prefill programs "
                               "for the N smallest prompt buckets at "
@@ -249,13 +244,6 @@ def main(argv: list[str] | None = None) -> int:
                               "first admission at any covered length "
                               "never compiles a decode program on the "
                               "hot path (0 = only the quiesced bucket)")
-    p_serve.add_argument("--no-first-token-fast-path", action="store_true",
-                         help="disable the first-token fast path "
-                              "(async prefill-token host copy, 1ms "
-                              "lone-arrival admission probe, inline "
-                              "first-frame detokenize) — debug/A-B "
-                              "knob; token streams are byte-identical "
-                              "either way")
     p_serve.add_argument("--prefill-bucket-rungs", type=int, default=2,
                          choices=[1, 2, 4],
                          help="prefill bucket rungs per octave: 1 = "
@@ -946,10 +934,8 @@ async def _run_tpuserve(args: argparse.Namespace) -> int:
         ragged_chunk_tokens=args.ragged_chunk_tokens,
         logprobs_topk=args.logprobs,
         adaptive_decode_window=not args.no_adaptive_window,
-        async_transfers=not args.sync_transfers,
         warm_prefill_buckets=args.warm_prefill_buckets,
         warm_decode_buckets=args.warm_decode_buckets,
-        first_token_fast_path=not args.no_first_token_fast_path,
         prefill_bucket_rungs=args.prefill_bucket_rungs,
         flight_entries=args.flight_entries,
         enable_profile_endpoint=args.enable_profile_endpoint,

@@ -362,8 +362,8 @@ class Backend:
     kv_fleet: bool = True
     # Fleet observability plane (ISSUE 12): feed the live SLO burn-rate
     # monitor from the polled TTFT histograms and record every routing
-    # decision in the /debug/decisions audit ring. False is the A/B
-    # control (bench --ab fleet_obs); /fleet/state and /fleet/metrics
+    # decision in the /debug/decisions audit ring. False turns both
+    # off; /fleet/state and /fleet/metrics
     # stay served either way (health machine + rollups are ~free).
     fleet_obs: bool = True
     # SLO burn-rate monitor knobs: the availability objective the error

@@ -42,8 +42,8 @@ Every lifecycle action lands in the controller's bounded event ring
 ``aigw_ctl_*`` gauges on ``/fleet/metrics``.
 
 The in-tree launcher is :class:`LocalProcessLauncher` — a subprocess
-per replica through ``benchmarks/serve_child.py`` (exactly the bench
-harness topology, which is also how tpuserve deploys on one host).
+per replica through ``python -m aigw_tpu.tpuserve.child`` (how
+tpuserve deploys on one host).
 Production launchers (k8s, GCE MIGs) implement the same two-method
 interface and are out of scope here.
 """
@@ -74,8 +74,8 @@ logger = logging.getLogger(__name__)
 @dataclass
 class ControllerConfig:
     """Knobs for one backend pool's lifecycle manager. Defaults are
-    deliberately conservative — production ticks in seconds; tests and
-    the bench shrink everything."""
+    deliberately conservative — production ticks in seconds; tests
+    shrink everything."""
 
     enabled: bool = True
     #: pool size envelope: failover replaces below min, scale-out stops
@@ -166,11 +166,11 @@ class ReplicaLauncher:
 
 
 class LocalProcessLauncher(ReplicaLauncher):
-    """Subprocess-per-replica launcher over the bench harness's
-    ``benchmarks/serve_child.py`` topology: one tpuserve process per
-    launch, serving the spec's model on a fresh port. SIGTERM on
-    terminate rides tpuserve's graceful drain handler, SIGKILL only
-    after ``term_grace_s``.
+    """Subprocess-per-replica launcher: one tpuserve process per
+    launch (``python -m aigw_tpu.tpuserve.child '<spec>'``, or the
+    script at ``child_path`` where a deployment names one), serving the
+    spec's model on a fresh port. SIGTERM on terminate rides tpuserve's
+    graceful drain handler, SIGKILL only after ``term_grace_s``.
 
     One process per chip: with ``chips`` = N the launcher owns chips
     0..N-1 of this host and confines each replica to the lowest free
@@ -184,11 +184,9 @@ class LocalProcessLauncher(ReplicaLauncher):
                  env: dict | None = None, boot_timeout_s: float = 1200.0,
                  term_grace_s: float = 30.0, chips: int = 0):
         self.spec = dict(spec)
-        if not child_path:
-            here = os.path.dirname(os.path.abspath(__file__))
-            child_path = os.path.normpath(os.path.join(
-                here, "..", "..", "benchmarks", "serve_child.py"))
-        self.child_path = child_path
+        self.child_argv = [sys.executable] + (
+            [child_path] if child_path
+            else ["-m", "aigw_tpu.tpuserve.child"])
         self.env = dict(env or {})
         self.boot_timeout_s = boot_timeout_s
         self.term_grace_s = term_grace_s
@@ -216,7 +214,7 @@ class LocalProcessLauncher(ReplicaLauncher):
     def _wait_port(self, proc: subprocess.Popen) -> int:
         """Blocking SERVE_PORT= parse (runs on a worker thread); the
         select loop keeps a wedged-but-alive child from holding the
-        read forever — same discipline as the bench harness."""
+        read forever."""
         import select
 
         fd = proc.stdout.fileno()
@@ -261,7 +259,7 @@ class LocalProcessLauncher(ReplicaLauncher):
             self._chip_of[booting] = chip
         try:
             proc = subprocess.Popen(
-                [sys.executable, self.child_path, json.dumps(self.spec)],
+                self.child_argv + [json.dumps(self.spec)],
                 stdout=subprocess.PIPE, text=True, env=env,
             )
             try:

@@ -114,6 +114,18 @@ def get_model_spec(name: str) -> ModelSpec:
     )
 
 
+def family_config_class(family: str) -> type:
+    """The configuration dataclass of a model family, as a registered
+    model of that family carries it: what turns a document's fields
+    into a ``ModelSpec.config`` without naming the family's module."""
+    for spec in _REGISTRY.values():
+        if spec.family == family:
+            return type(spec.config)
+    raise KeyError(
+        f"unknown model family {family!r}; registered: "
+        f"{sorted({s.family for s in _REGISTRY.values()})}")
+
+
 register_model(ModelSpec("tiny-random", "llama", llama.TINY))
 
 

@@ -278,8 +278,8 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     # class. Queued = never-shed backlog + host-parked preempted
     # sessions; active = decode slots it holds (≤ the batch_slot_frac
     # ceiling); preemptions/resumed = the park→resume churn interactive
-    # arrivals drive; tokens = the idle-slot-soak volume the bench's
-    # batch_tier A/B prices against measured idle capacity.
+    # arrivals drive; tokens = the volume the idle slots soaked up
+    # (what the tier earned).
     ("batch_queued", "tpuserve_batch_queued"),
     ("batch_active", "tpuserve_batch_active"),
     ("batch_preemptions", "tpuserve_batch_preemptions_total"),

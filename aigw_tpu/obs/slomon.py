@@ -1,10 +1,9 @@
 """Live SLO burn-rate monitor — goodput accounting in the gateway.
 
-PR 8 computed goodput-under-SLO only inside ``bench.py`` (cumulative
-server-side TTFT histogram bucket deltas over a capture window). This
-module generalizes that machinery into a LIVE monitor the gateway runs
-against the histogram snapshots the endpoint picker already polls from
-every replica's ``/state`` (``ttft_hist_buckets``):
+Goodput under an SLO from cumulative server-side TTFT histogram
+bucket deltas: a LIVE monitor the gateway runs against the histogram
+snapshots the endpoint picker already polls from every replica's
+``/state`` (``ttft_hist_buckets``):
 
 - per sliding window of ``window_s`` seconds, the delta of the
   cumulative TTFT buckets gives ``served`` (requests finishing their

@@ -247,11 +247,9 @@ class XlaBucketedBackend(AttentionBackend):
             jnp.asarray(keys), jnp.asarray(temp), jnp.asarray(top_p),
             jnp.asarray(top_k), jnp.asarray(bias), jnp.asarray(adapter),
             **eng.slot_kw([it[1] for it in items], G2))
-        if cfg.first_token_fast_path:
-            # token 0's device→host copy starts at dispatch and overlaps
-            # the prefill's remaining on-device compute (async-transfer
-            # machinery; values are identical to the blocking fetch)
-            eng._start_host_copy(next_tok)
+        # token 0's device→host copy starts at dispatch and overlaps
+        # the prefill's remaining on-device compute
+        eng._start_host_copy(next_tok)
         loop.enter(PREFILL_BLOCK)  # host blocked on the sampled tokens
         lp_data = None
         if cfg.logprobs_topk and isinstance(next_tok, tuple):
@@ -667,7 +665,7 @@ class RaggedPrefillBackend(AttentionBackend):
                     s.req.trace.event(
                         "prefill_chunk", tokens=_take,
                         consumed=s.start + s.done)
-            if finished and cfg.first_token_fast_path:
+            if finished:
                 eng._start_host_copy(next_tok)
         # intermediate budget-boundary device steps ride the same gauge
         # as the bucketed chunk loop

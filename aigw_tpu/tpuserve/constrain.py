@@ -938,7 +938,7 @@ def parse_tool_envelope(text: str,
 
 
 # ---------------------------------------------------------------------------
-# subset instance validator (bench + tests assert 100% schema validity
+# subset instance validator (tests assert 100% schema validity
 # without a jsonschema dependency)
 # ---------------------------------------------------------------------------
 

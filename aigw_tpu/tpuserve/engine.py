@@ -126,8 +126,8 @@ def device_memory_stats_all() -> list[dict]:
 def _per_device_bytes(tree: Any) -> dict[int, int]:
     """Bytes each device holds of ``tree``'s array leaves, from the
     arrays' real shard layout (an unsharded array is one shard on one
-    device). The measured half of the bench's per-device-param-bytes ≈
-    total/tp claim."""
+    device): under tensor parallelism each device holds ≈ total/tp
+    (tests/test_mesh_serving.py)."""
     per: dict[int, int] = {}
     for leaf in jax.tree_util.tree_leaves(tree):
         if getattr(leaf, "is_deleted", lambda: False)():
@@ -210,13 +210,6 @@ class EngineConfig:
     adaptive_decode_window: bool = True
     # Small window used under pressure. 0 = auto: max(1, K // 4).
     min_decode_steps_per_tick: int = 0
-    # Async device→host token transfers: the sampled-token fetch for a
-    # decode window is started at dispatch time (copy_to_host_async)
-    # and resolved at drain time, so the copy overlaps the next
-    # on-device window instead of blocking the engine thread. False
-    # restores the blocking device_get at drain — token streams are
-    # byte-identical either way (tests/test_serving_overlap.py).
-    async_transfers: bool = True
     # Idle-burst coalescing: when the engine is COMPLETELY idle and a
     # request arrives, wait this long for the rest of its burst before
     # admitting, so B near-simultaneous arrivals prefill as ONE batched
@@ -224,16 +217,6 @@ class EngineConfig:
     # few ms of event-loop scheduling). Busy engines never wait —
     # arrivals already coalesce between decode windows. 0 disables.
     admission_coalesce_ms: float = 3.0
-    # First-token fast path: token 0 is sampled by the prefill step
-    # itself, so (a) its device→host copy is started at prefill dispatch
-    # (copy_to_host_async — the same machinery as async_transfers) so
-    # the host never pays a separate fetch round-trip after the compute
-    # lands, and (b) a LONE arrival to an idle engine prefills
-    # immediately instead of riding the admission_coalesce_ms timer
-    # (coalescing only pays when a second request is already queued).
-    # False restores the round-6 behavior; token streams are
-    # byte-identical either way (tests/test_serving_overlap.py).
-    first_token_fast_path: bool = True
     # Pre-compile the batched-prefill programs for the N smallest
     # prompt buckets at warmup (all power-of-two group sizes up to
     # max_batch_size): a traffic burst must not pay an XLA prefill
@@ -615,8 +598,8 @@ class EngineStats:
     # batch_preemptions the sessions parked off-device because an
     # interactive arrival wanted the slot, batch_resumed the parked
     # sessions re-admitted (byte-identical continuation), batch_tokens
-    # the tokens the class has generated — the idle-slot-soak volume
-    # the bench's batch_tier A/B prices.
+    # the tokens the class has generated (the volume the idle slots
+    # soaked up).
     batch_queued: int = 0
     batch_active: int = 0
     batch_preemptions: int = 0
@@ -1148,8 +1131,8 @@ class Engine:
         self._steady_ticks = 0
 
         # per-device accounting (ISSUE 10): bytes of model weights each
-        # device actually holds (measured from shard layouts — the
-        # bench's per-device-bytes ≈ total/tp claim), the analytical
+        # device actually holds (measured from shard layouts:
+        # ≈ total/tp under tensor parallelism), the analytical
         # per-device ICI collective volume of one decoded token, and
         # the rolling per-device stats list _refresh_stats maintains
         self.param_bytes_by_device = _per_device_bytes(self.params)
@@ -3069,7 +3052,7 @@ class Engine:
                 wait_ms = self.cfg.admission_coalesce_ms
                 loop = self.stats.loop
                 outer = loop.enter(ADMIT_WAIT)
-                if self.cfg.first_token_fast_path and len(pending) == 1:
+                if len(pending) == 1:
                     probe = min(1.0, wait_ms)
                     time.sleep(probe / 1e3)
                     try:
@@ -3802,9 +3785,8 @@ class Engine:
         elif chain_keys:
             # page-eligible prompt, nothing reusable cached
             self.stats.prefix_cache_misses += 1
-        if self.cfg.first_token_fast_path:
-            # start token 0's host copy under the prefill's compute
-            self._start_host_copy(next_tok)
+        # start token 0's host copy under the prefill's compute
+        self._start_host_copy(next_tok)
         # (every branch above left the ledger in prefill_block: host
         # blocked on the sampled token)
         first_lp = None
@@ -4403,8 +4385,8 @@ class Engine:
 
     @engine_thread_only
     def _drain_inflight(self) -> None:
-        """Settle the in-flight window: resolve its (already started,
-        under async_transfers) device→host copy, emit tokens, and apply
+        """Settle the in-flight window: resolve its (already started)
+        device→host copy, emit tokens, and apply
         the page frees it was carrying."""
         w, self._inflight = self._inflight, None
         if w is None:
@@ -4636,10 +4618,9 @@ class Engine:
         sampled, self._device_state, self.kv_cache, moe = decode_fn(
             self.params, self.lora_params, self.kv_cache, self._device_state
         )
-        if self.cfg.async_transfers:
-            # start the device→host token copy now; it overlaps this
-            # window's on-device compute and is resolved at drain time
-            self._start_host_copy(sampled)
+        # start the device→host token copy now; it overlaps this
+        # window's on-device compute and is resolved at drain time
+        self._start_host_copy(sampled)
         # process the PREVIOUS window while this one runs on-device
         self._drain_inflight()
         self._inflight = _Window(sampled=sampled, members=members, k=k,

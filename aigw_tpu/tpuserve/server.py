@@ -2272,8 +2272,8 @@ class TPUServeServer:
                 # local device's memory/KV/param share (not just
                 # device 0), the worst-device memory fraction the
                 # picker scores, the measured per-device parameter
-                # bytes (≈ total/tp under tensor parallelism — the
-                # bench's memory-split claim), and the analytical ICI
+                # bytes (≈ total/tp under tensor parallelism:
+                # tests/test_mesh_serving.py), and the analytical ICI
                 # collective volume per decoded token
                 # long-context serving surface: the advertised context
                 # length + sp axis (the gateway picker's over-length
@@ -2363,7 +2363,7 @@ class TPUServeServer:
                 "prefix_cache_misses": s.prefix_cache_misses,
                 "prefix_cache_evictions": s.prefix_cache_evictions,
                 # speculative decoding surface: acceptance telemetry
-                # for dashboards and the bench --ab spec_decode leg
+                # for dashboards
                 "spec_accepted": s.spec_accepted,
                 "spec_drafted": s.spec_drafted,
                 "spec_accept_rate": round(s.spec_accept_rate, 4),
@@ -2402,9 +2402,9 @@ class TPUServeServer:
                 "weights_init_ms": self.weights_init_ms,
                 "weights_quantize_ms": self.weights_quantize_ms,
                 # serving-phase latency distributions (p50/p95/p99 per
-                # ENGINE_HISTOGRAMS phase; -1 = no observations yet) —
-                # the bench reads TTFT/per-token spreads from here
-                # instead of recomputing them client-side
+                # ENGINE_HISTOGRAMS phase; -1 = no observations yet):
+                # the picker's TTFT prediction reads them
+                # (gateway/picker.py)
                 "phase_percentiles": self.engine.phases.percentiles(),
                 # ICI topology: the picker's same-slice preference term
                 # (gateway/picker.py) keys on this
@@ -2886,10 +2886,8 @@ async def run_tpuserve(
     ragged_chunk_tokens: int = 256,
     logprobs_topk: int = 0,
     adaptive_decode_window: bool = True,
-    async_transfers: bool = True,
     warm_prefill_buckets: int = 0,
     warm_decode_buckets: int = 0,
-    first_token_fast_path: bool = True,
     prefill_bucket_rungs: int = 2,
     flight_entries: int = 256,
     enable_profile_endpoint: bool = False,
@@ -2917,10 +2915,8 @@ async def run_tpuserve(
             ragged_chunk_tokens=ragged_chunk_tokens,
             logprobs_topk=logprobs_topk,
             adaptive_decode_window=adaptive_decode_window,
-            async_transfers=async_transfers,
             warm_prefill_buckets=warm_prefill_buckets,
             warm_decode_buckets=warm_decode_buckets,
-            first_token_fast_path=first_token_fast_path,
             prefill_bucket_rungs=prefill_bucket_rungs,
             tenant_slot_cap=tenant_slot_cap,
             migration_young_tokens=migration_young_tokens,

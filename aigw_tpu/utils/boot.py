@@ -1,7 +1,7 @@
 """Process boot for everything that opens an accelerator.
 
-One rule, used by ``aigw_tpu tpuserve``, ``benchmarks/serve_child.py``,
-``bench.py``'s live path and the kernel parity child: **the platform is
+One rule, used by ``aigw_tpu tpuserve``, the launcher's replica child
+(``aigw_tpu.tpuserve.child``) and the kernel parity child: **the platform is
 the one somebody named** (``--platform``, else ``JAX_PLATFORMS``). When
 nobody named one, a TPU is required and boot fails naming what JAX found
 instead — JAX's own default would quietly hand back the CPU, and a

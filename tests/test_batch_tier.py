@@ -267,7 +267,7 @@ def test_window_shrink_leaves_batch_stream_identical(eng):
 
 
 def test_cancelled_batch_stream_always_finalizes(eng):
-    """Liveness (a hang the --ab leg caught live): a batch stream
+    """Liveness (a hang once caught live): a batch stream
     cancelled in ANY state — decoding in a slot, waiting in _batch_q
     behind the ceiling, or parked host-side — must still deliver a
     terminal event. Without it the batch runner's _collect blocks

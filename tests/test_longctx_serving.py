@@ -237,13 +237,17 @@ def test_three_way_byte_identical_mixed_features():
     assert ch.sp_resume_prefills >= 1    # the offset resume
     mono = engines["mono"].stats
     assert mono.sp_prefills >= 1 and mono.sp_chunked_prefills == 0
+    # the padding tax: the chunk ladder pads a tail rung, the
+    # monolithic program the whole top rung, on the same real tokens
+    assert ch.prefill_tokens_real == mono.prefill_tokens_real
+    assert ch.prefill_tokens_padded < mono.prefill_tokens_padded, (
+        ch.prefill_tokens_padded, mono.prefill_tokens_padded)
 
 
 def test_interactive_admission_mid_prefill():
     """Decode liveness: a short arrival queued while a long chunked-sp
     prefill is in flight must admit at a chunk boundary and stream its
-    first token BEFORE the long prompt's — the mechanism behind the
-    longctx bench leg's interactive-TTFT claim. The boundary hook makes
+    first token BEFORE the long prompt's. The boundary hook makes
     the ordering deterministic: the engine thread pauses at the first
     chunk boundary until the short request is queued."""
     eng = _mk_engine(8)

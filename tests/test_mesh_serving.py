@@ -4,8 +4,7 @@ The tensor-parallel engine must be the SAME engine: in the
 deterministic f32 rig, a tp=8 mesh over 8 virtual CPU devices (the
 suite-wide conftest sets ``--xla_force_host_platform_device_count=8``
 before jax initializes — the same topology the driver's
-``dryrun_multichip`` and the bench's ``--ab mesh`` subprocess children
-use) must stream BYTE-IDENTICAL tokens to a single-device engine across
+``dryrun_multichip`` uses) must stream BYTE-IDENTICAL tokens to a single-device engine across
 the whole mixed-feature batch — greedy, seeded sampling, repetition
 penalties, speculating slots, prefix-cache resume, and a
 grammar-constrained slot — with ZERO pipeline-draining state rebuilds
@@ -163,7 +162,7 @@ def test_mixed_batch_byte_identical_mesh_vs_single(pair):
 def test_param_and_kv_bytes_split_across_devices(pair):
     """Measured memory split: every device holds ≈ total/8 of the
     parameters and exactly 1/8 of the head-sharded KV pool (n_kv_heads
-    8 ÷ tp 8) — the /state signal behind the bench's ±10% claim."""
+    8 ÷ tp 8) — the /state signal of the memory split."""
     single, mesh = pair
     per = mesh.param_bytes_by_device
     assert len(per) == 8

@@ -31,7 +31,7 @@ PREFIX_STATE_FIELDS = manifest.state_fields("prefix")
 PREFIX_GAUGES = manifest.gauge_names("prefix")
 
 # speculative-decoding surface (ISSUE 4): a renamed EngineStats field
-# must not silently drop a dashboard signal or the bench A/B's inputs
+# must not silently drop a dashboard signal
 SPEC_STATE_FIELDS = manifest.state_fields("spec")
 
 SPEC_GAUGES = manifest.gauge_names("spec")
@@ -107,8 +107,8 @@ def test_metrics_export_prefix_gauges(smoke_url):
 
 def test_state_and_metrics_export_spec_gauges(smoke_url):
     """Every tpuserve_spec_* gauge must appear on /state and /metrics —
-    even with speculation off (constant 0), so dashboards and the
-    bench A/B never silently lose the surface."""
+    even with speculation off (constant 0), so dashboards never
+    silently lose the surface."""
     state = json.loads(asyncio.run(_get(smoke_url, "/state")))
     for field in SPEC_STATE_FIELDS:
         assert field in state, f"/state lost {field}"
@@ -315,7 +315,7 @@ ADAPTER_GAUGES = manifest.gauge_names("adapter")
 def test_state_and_metrics_export_adapter_gauges(smoke_url):
     """The adapter/tenant surface (ISSUE 7) must appear on /state and
     /metrics even with no adapters loaded (constant 0 / empty lists) —
-    dashboards and the bench --ab lora leg read these."""
+    dashboards read these."""
     state = json.loads(asyncio.run(_get(smoke_url, "/state")))
     for field in ADAPTER_STATE_FIELDS:
         assert field in state, f"/state lost {field}"
@@ -446,7 +446,7 @@ def test_ragged_backend_zero_hot_compiles_any_geometry():
 
 # prefill/decode disaggregation surface (ISSUE 8): a renamed field here
 # silently breaks the gateway's migration orchestrator (polls
-# migratable_slots) or the bench --ab disagg leg (reads the counters)
+# migratable_slots)
 MIGRATION_STATE_FIELDS = manifest.state_fields("migration")
 
 MIGRATION_GAUGES = manifest.gauge_names("migration")
@@ -464,8 +464,7 @@ def test_state_and_metrics_export_migration_gauges(smoke_url):
 
 
 # grammar-constrained decoding surface (ISSUE 9): a renamed field here
-# silently breaks the bench --ab structured leg (reads the counters),
-# the gateway's capability merge (constrained_decoding/capabilities),
+# silently breaks the gateway's capability merge (constrained_decoding/capabilities),
 # or the picker's measured memory signal (device_memory_frac)
 CONSTRAINT_STATE_FIELDS = manifest.state_fields("constraint")
 
@@ -583,8 +582,8 @@ def test_device_gauges_map_matches_engine_device_stats():
 
 
 # KV memory hierarchy surface (ISSUE 11): a renamed field here silently
-# breaks the gateway's fleet index (polls kv_chains), the fleet-fetch
-# presence probe, or the bench --ab kv_tier leg (reads the counters)
+# breaks the gateway's fleet index (polls kv_chains) or the fleet-fetch
+# presence probe
 KVTIER_STATE_FIELDS = manifest.state_fields("kvtier")
 
 KVTIER_GAUGES = manifest.gauge_names("kvtier")

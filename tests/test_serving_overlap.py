@@ -1,11 +1,10 @@
-"""Serving-path overlap: chunked-prefill interleave under live decodes,
-async-vs-blocking transfer equivalence, and the adaptive decode window
+"""Serving-path overlap: chunked-prefill interleave under live decodes
+and the adaptive decode window
 (engine.py _decode_tick / _choose_window / _apply_row_updates).
 
 CPU-backend engine tests for the round-6 hot-path overhaul:
 - a long prompt admitted mid-stream must not stall in-flight decodes
   beyond one chunk (decode ticks interleave the chunk loop),
-- token streams are byte-identical with async_transfers on and off,
 - the adaptive window shrinks under queue pressure / young streams and
   regrows to the full throughput window when the batch is steady.
 """
@@ -98,65 +97,6 @@ def test_long_prompt_does_not_stall_inflight_decode():
         ra.cancelled.set()  # A served its purpose; don't decode 160 out
     finally:
         eng.stop()
-
-
-@pytest.mark.slow
-
-
-def test_async_transfer_tokens_identical_to_blocking():
-    """copy_to_host_async at dispatch vs blocking device_get at drain:
-    same computation, byte-identical token streams — greedy and seeded
-    sampling, two concurrent streams."""
-    results: dict[bool, list[list[int]]] = {}
-    for async_on in (False, True):
-        eng = _engine(async_transfers=async_on)
-        eng.start()
-        try:
-            s1, s2 = _Stream(), _Stream()
-            eng.submit(_req([3, 1, 4, 1, 5, 9, 2, 6], 24, s1))
-            eng.submit(_req([2, 7, 1, 8, 2, 8], 24, s2, seed=123,
-                            temp=0.8))
-            assert s1.done.wait(timeout=600)
-            assert s2.done.wait(timeout=600)
-            results[async_on] = [s1.toks, s2.toks]
-        finally:
-            eng.stop()
-    assert results[True] == results[False]
-    assert len(results[True][0]) > 0
-
-
-@pytest.mark.slow
-
-
-def test_first_token_fast_path_tokens_identical():
-    """first_token_fast_path on vs off: the knob moves host latency
-    (async token-0 copy, 1ms lone-arrival probe, first_emit
-    accounting), never values — greedy and seeded sampling streams are
-    byte-identical, for both the lone-arrival and burst admission
-    shapes."""
-    results: dict[bool, list[list[int]]] = {}
-    for fast in (False, True):
-        eng = _engine(first_token_fast_path=fast)
-        eng.start()
-        try:
-            # lone arrival (exercises the 1ms probe path)
-            s0 = _Stream()
-            eng.submit(_req([9, 4, 2, 7], 12, s0))
-            assert s0.done.wait(timeout=600)
-            # burst (exercises the batched-prefill fast path)
-            s1, s2 = _Stream(), _Stream()
-            eng.submit(_req([3, 1, 4, 1, 5, 9, 2, 6], 24, s1))
-            eng.submit(_req([2, 7, 1, 8, 2, 8], 24, s2, seed=123,
-                            temp=0.8))
-            assert s1.done.wait(timeout=600)
-            assert s2.done.wait(timeout=600)
-            results[fast] = [s0.toks, s1.toks, s2.toks]
-            if fast:
-                assert eng.stats.first_emit_ms > 0
-        finally:
-            eng.stop()
-    assert results[True] == results[False]
-    assert all(len(t) > 0 for t in results[True])
 
 
 @pytest.mark.slow
@@ -257,7 +197,7 @@ def test_fixed_window_when_adaptive_disabled():
 
 def test_phase_breakdown_accumulates():
     """The serving-path phase stats (prefill/transfer/emit ms) must
-    accumulate — bench.py and /state surface them."""
+    accumulate — /state surfaces them."""
     eng = _engine()
     eng.start()
     try:

@@ -3,16 +3,16 @@
 
 The TokenSim lesson (arxiv 2503.08415): a serving-system claim is only
 verified against injected churn, not a quiet pool. This module is the
-churn: the primitives the ``--ab fleet_ctl`` bench leg and the chaos
-test matrix drive against a live fleet —
+churn: the primitives the chaos test matrix
+(``tests/test_fleet_controller.py``) drives against a live fleet —
 
 - :func:`spawn_replica` / :class:`ReplicaProc` — a tpuserve child
-  (``benchmarks/serve_child.py``, the deployment topology) whose pid is
-  in hand, so :meth:`ReplicaProc.kill9` can ``SIGKILL`` it mid-decode
+  (``python -m aigw_tpu.tpuserve.child``, the launcher's own) whose pid
+  is in hand, so :meth:`ReplicaProc.kill9` can ``SIGKILL`` it mid-decode
   (the crash case: no drain, no goodbye, sockets torn) while
   :meth:`ReplicaProc.term` exercises the graceful-drain path.
 - slow-start injection: ``slow_start_s`` stalls the child before it
-  boots (the ``AIGW_CHAOS_SLOW_START_S`` hook in serve_child) — the
+  boots (the ``AIGW_CHAOS_SLOW_START_S`` hook in the child) — the
   controller's launch path must tolerate replicas that take arbitrarily
   long to report a port without blocking or double-launching.
 - :class:`TornStateProxy` — a replica-shaped proxy that forwards
@@ -42,7 +42,8 @@ import time
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(_HERE)
-SERVE_CHILD = os.path.join(_REPO, "benchmarks", "serve_child.py")
+#: the launcher's own replica child (gateway/controller.py), by module
+REPLICA_CHILD = "aigw_tpu.tpuserve.child"
 
 
 class ReplicaProc:
@@ -90,14 +91,14 @@ class ReplicaProc:
 def spawn_replica(spec: dict, env: dict | None = None,
                   slow_start_s: float = 0.0,
                   boot_timeout_s: float = 1200.0) -> ReplicaProc:
-    """Boot a tpuserve child from a serve_child spec and wait for its
+    """Boot a tpuserve child from a replica spec and wait for its
     SERVE_PORT line. ``slow_start_s`` injects a pre-boot stall (the
     slow-start replica case)."""
     child_env = dict(os.environ, JAX_PLATFORMS="cpu", **(env or {}))
     if slow_start_s > 0:
         child_env["AIGW_CHAOS_SLOW_START_S"] = str(slow_start_s)
     proc = subprocess.Popen(
-        [sys.executable, SERVE_CHILD, json.dumps(spec)],
+        [sys.executable, "-m", REPLICA_CHILD, json.dumps(spec)],
         cwd=_REPO, stdout=subprocess.PIPE, text=True, env=child_env,
     )
     import select

@@ -4,7 +4,7 @@
 Renders ``GET /fleet/state`` (the ISSUE 12 fleet observability plane)
 as a per-replica table: health, slots, queue, worst KV / HBM pressure,
 SLO burn rate, and telemetry staleness — the terminal companion for
-bench runs and the MULTICHIP dryrun, where tailing N replica ``/state``
+benchmark runs and the MULTICHIP dryrun, where tailing N replica ``/state``
 endpoints by hand stops scaling at N=2.
 
 ``--tenants`` switches to the usage-metering view (ISSUE 20): one row
@@ -17,7 +17,7 @@ Usage:
     python tools/fleetwatch.py http://127.0.0.1:1975 --once
     python tools/fleetwatch.py http://127.0.0.1:1975 --tenants --once
 
-stdlib-only (urllib) on purpose: it must run anywhere the bench runs,
+stdlib-only (urllib) on purpose: it must run anywhere the gateway runs,
 including bare containers without aiohttp installed for the client.
 """
 
