@@ -159,9 +159,6 @@ JIT_WARM_SURFACE: dict[str, str] = {
     "aigw_tpu/ops/pallas/decode_fused.py::fused_paged_decode": (
         "the fused decode rung dispatched inside the registered decode "
         "programs; pre-compiled by warmup()'s ladder"),
-    "aigw_tpu/ops/pallas/decode_fused.py::paged_decode_walk_spmd": (
-        "shard_map wrapper constructed inside the registered decode "
-        "program (fused-xla-spmd rung); compiled with it at warmup"),
 }
 
 #: module path prefixes the ``jit-registry`` pass scans — the serving

@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from aigw_tpu.ops import paged_walk
+
 #: valid EngineConfig.kv_cache_dtype values
 KV_DTYPES = ("bfloat16", "float32", "int8", "int4")
 QUANT_DTYPES = ("int8", "int4")
@@ -171,6 +173,30 @@ def gather_kv(kv: Any, layer: int, gslot: jax.Array):
     v = dequantize_rows(kv["q"][layer, 1][gslot],
                         kv["scale"][layer, 1][gslot])
     return k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+
+
+def walk_plan(kv: Any, lengths: jax.Array, n_cols: int, page_size: int,
+              mesh=None) -> paged_walk.WalkPlan:
+    """How this decode step's page walk is cut up (one plan a step,
+    shared by every layer): block sizes from the shapes of the pool as
+    one device holds it — under a mesh a head shard of it."""
+    shards = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
+    return paged_walk.walk_plan(
+        lengths, n_cols, page_size, paged_walk.pair_bytes(
+            kv["q"] if is_quantized(kv) else kv, page_size) // shards)
+
+
+def walk_kv(kv: Any, layer: int, q: jax.Array, page_table: jax.Array,
+            lengths: jax.Array, page_size: int,
+            plan: paged_walk.WalkPlan, mesh=None) -> jax.Array:
+    """The decode step's read of ``layer``: attention of each live
+    row's roped ``q`` [B, H, D] over the pages the row holds, its new
+    K/V already scattered (ops/paged_walk.py: whole pages, live rows
+    only, dequantizing at the read). Returns [B, H, D]."""
+    pool, scale = (kv["q"], kv["scale"]) if is_quantized(kv) else (kv, None)
+    return paged_walk.paged_decode_walk(
+        q, pool, layer, page_table, lengths, page_size=page_size,
+        scale=scale, plan=plan, mesh=mesh)
 
 
 def layer_pool(kv: Any, layer: int, which: int):

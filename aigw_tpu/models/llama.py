@@ -524,7 +524,8 @@ def decode_step(
     lora=None,  # stacked adapters (models/lora.py)
     adapter_idx=None,  # [B] int32 adapter row per slot
     attn_impl: str = "",  # see below
-    mesh=None,  # jax Mesh — required by attn_impl="fused" on a mesh
+    mesh=None,  # jax Mesh — the walk then runs per head shard
+    walk=None,  # this step's paged_walk.WalkPlan
 ) -> tuple[jax.Array, jax.Array]:
     """One continuous-batching decode step; returns (logits [B, V], cache).
 
@@ -532,18 +533,22 @@ def decode_step(
     drop). ``attn_impl`` selects the decode-attention rung (resolved by
     tpuserve/attention.py's fallback matrix, never directly by users):
 
-    - ``""`` — XLA gather: the full padded window [B, T_max] is
-      gathered per slot and runs dense attention (dequantizing at the
-      gather when the pool is int8/int4).
+    - ``""`` — the page walk (ops/paged_walk.py; ``xla-walk`` and,
+      where ``--decode-backend fused`` cannot run its kernel,
+      ``fused-xla`` on /state, each ``-spmd`` on a mesh): scatter
+      (quantizing in-pass), then an online-softmax loop over the whole
+      pages the LIVE rows hold — nothing padded is gathered, int8/int4
+      pages dequantize at the read. ``walk`` is this step's
+      ``paged_walk.walk_plan`` (made here when the caller has none;
+      the engine makes it, to count what the loops read). With
+      ``mesh`` the walk runs per head-shard inside shard_map: each
+      device walks its LOCAL pool shard — no GSPMD gather.
+    - ``"gather"`` — the full padded window [B, T_max] is gathered per
+      slot and runs dense attention: the one rung that needs no whole
+      head shard per device (a mesh whose head counts do not divide tp).
     - ``"pallas"`` — the chained ragged paged-attention kernel
       (ops/pallas/paged_attention.py): scatter first, kernel reads the
       pool. Native-dtype pools only.
-    - ``"fused"`` — the fused-step XLA reference
-      (ops/pallas/decode_fused.paged_decode_walk): scatter (quantizing
-      in-pass), then online-softmax page walk — memory bounded at
-      [B, page], never the padded window. With ``mesh`` the walk runs
-      per head-shard inside shard_map: each device walks its LOCAL
-      pool shard — no GSPMD gather.
     - ``"fused-pallas"`` — ONE kernel per dispatch
       (ops/pallas/decode_fused.fused_paged_decode): RoPE + quantized
       append + paged attention fused; requires the engine's reserved
@@ -563,12 +568,13 @@ def decode_step(
 
     use_pallas = attn_impl == "pallas"
     use_fused_kernel = attn_impl == "fused-pallas"
-    use_fused_walk = attn_impl == "fused"
+    use_gather = attn_impl == "gather"
     if use_pallas and kvq.is_quantized(kv_cache):
         raise NotImplementedError(
             "the chained Pallas decode kernel has no quantized-pool "
             "rung — the fallback matrix resolves int8/int4 to fused")
-    if not (use_pallas or use_fused_kernel or use_fused_walk):
+    lengths = jnp.where(active, positions + 1, 0)
+    if use_gather:
         # gather the full (padded) KV window for each slot
         t_idx = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, 0)
         gslot = page_table[:, :, None] * page_size + jnp.arange(
@@ -576,26 +582,18 @@ def decode_step(
         )
         gslot = gslot.reshape(B, T)  # [B, T] flat cache indices
         attend = t_idx <= pos1  # causal within the sequence window
-    elif use_pallas:
+    elif use_pallas or use_fused_kernel:
         from aigw_tpu.ops.pallas._compat import is_tpu_backend
-        from aigw_tpu.ops.pallas.paged_attention import (
-            paged_attention_decode_v2,
-        )
-
-        lengths = jnp.where(active, positions + 1, 0)
-        interp = not is_tpu_backend()
-    elif use_fused_walk:
-        from aigw_tpu.ops.pallas.decode_fused import (
-            paged_decode_walk,
-            paged_decode_walk_spmd,
-        )
-
-        lengths = jnp.where(active, positions + 1, 0)
-    else:
-        from aigw_tpu.ops.pallas._compat import is_tpu_backend
-        from aigw_tpu.ops.pallas.decode_fused import fused_paged_decode
 
         interp = not is_tpu_backend()
+        if use_pallas:
+            from aigw_tpu.ops.pallas.paged_attention import (
+                paged_attention_decode_v2,
+            )
+        else:
+            from aigw_tpu.ops.pallas.decode_fused import fused_paged_decode
+    elif walk is None:
+        walk = kvq.walk_plan(kv_cache, lengths, max_pages, page_size, mesh)
 
     HD = cfg.n_heads * cfg.head_dim
     x = _embed_rows(p, tokens[:, None])  # [B, 1, dim]
@@ -624,23 +622,13 @@ def decode_step(
                         q[:, 0], kv_cache[i, 0], kv_cache[i, 1], page_table,
                         lengths, page_size=page_size, interpret=interp,
                     ).reshape(B, 1, HD)
-            elif use_fused_walk:
-                kr, ksc = kvq.layer_pool(kv_cache, i, 0)
-                vr, vsc = kvq.layer_pool(kv_cache, i, 1)
-                with jax.named_scope("layer/attn"):
-                    if mesh is not None:
-                        attn = paged_decode_walk_spmd(
-                            q[:, 0], kr, vr, page_table, lengths,
-                            mesh=mesh, page_size=page_size,
-                            k_scale=ksc, v_scale=vsc)
-                    else:
-                        attn = paged_decode_walk(
-                            q[:, 0], kr, vr, page_table, lengths,
-                            page_size=page_size, k_scale=ksc, v_scale=vsc)
-                attn = attn.reshape(B, 1, HD)
-            else:
+            elif use_gather:
                 k_all, v_all = _gather_kv(kv_cache, i, gslot)
                 attn = _attention(q, k_all, v_all, attend[:, None, :])
+            else:
+                attn = kvq.walk_kv(kv_cache, i, q[:, 0], page_table,
+                                   lengths, page_size, walk,
+                                   mesh).reshape(B, 1, HD)
         x = x + _wo_project(p, i, attn, lora, adapter_idx)
         h = rms_norm(x, p[f"l{i}.mlp_norm"], cfg.norm_eps)
         x = x + (mlp(p, i, h) if mlp is not None

@@ -235,12 +235,12 @@ def prefill_ragged(p, cfg: MixtralConfig, tokens, row_seq, positions,
 
 def decode_step(p, cfg: MixtralConfig, tokens, positions, kv_cache,
                 page_table, page_size, active, lora=None, adapter_idx=None,
-                attn_impl="", mesh=None, moe_stats=False):
+                attn_impl="", mesh=None, walk=None, moe_stats=False):
     tape: list | None = [] if moe_stats else None
     out = llama.decode_step(p, cfg.as_llama(), tokens, positions, kv_cache,
                             page_table, page_size, active,
                             mlp=_mlp_fn(cfg, tape), attn_impl=attn_impl,
-                            mesh=mesh)
+                            mesh=mesh, walk=walk)
     return _with_moe(out, tape) if moe_stats else out
 
 

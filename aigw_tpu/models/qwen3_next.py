@@ -499,11 +499,15 @@ def _attn_project(p, i, h, positions, cfg):
     return q, gate, k, v
 
 
-@jax.named_scope("layer/attn_gated")
-def _attn_out(p, i, q, k, v, mask, gate):
-    attn = llama._attention(q, k.astype(q.dtype), v.astype(q.dtype), mask)
+def _gated_out(p, i, attn, gate):
     attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn.dtype)
     return llama._matmul(p, f"l{i}.o_proj", attn)
+
+
+@jax.named_scope("layer/attn_gated")
+def _attn_out(p, i, q, k, v, mask, gate):
+    return _gated_out(p, i, llama._attention(
+        q, k.astype(q.dtype), v.astype(q.dtype), mask), gate)
 
 
 def _window_slots(page_table, page_size):
@@ -662,11 +666,14 @@ def hidden_states(p, cfg: Qwen3NextConfig, tokens, seq_lens):
 
 def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
                 page_table, page_size, active, lora=None, adapter_idx=None,
-                attn_impl="", mesh=None, moe_stats=False):
+                attn_impl="", mesh=None, walk=None, moe_stats=False):
     """One continuous-batching step; row ``b`` IS decode slot ``b``.
     Inactive rows leave their state, their convolution tail and the
-    pages as they are. ``attn_impl`` must be the XLA gather rung (the
-    fallback matrix resolves this family to it)."""
+    pages as they are. The full-attention layers read the pool through
+    the page walk every family's decode step shares (ops/paged_walk.py;
+    ``walk``: this step's plan, made here when the caller has none);
+    ``attn_impl`` may name no other rung: the Pallas kernels fuse
+    full-width rotary and know no q/k norm or output gate."""
     if attn_impl:
         raise NotImplementedError(
             f"decode attention rung {attn_impl!r}: the Pallas kernels "
@@ -675,12 +682,13 @@ def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
     B = tokens.shape[0]
     kv, slots = cache.kv, dict(cache.slots)
     pos1 = positions[:, None]
-    T = page_table.shape[1] * page_size
     slot = jnp.where(active[:, None], jnp.take_along_axis(
         page_table, pos1 // page_size, axis=1) * page_size
         + pos1 % page_size, kvq.n_slots(kv))
-    gslot = _window_slots(page_table, page_size)
-    attend = (jnp.arange(T, dtype=jnp.int32)[None, :] <= pos1)[:, None, :]
+    lengths = jnp.where(active, positions + 1, 0)
+    if walk is None:
+        walk = kvq.walk_plan(kv, lengths, page_table.shape[1], page_size,
+                             mesh)
     act = active.astype(jnp.float32)[:, None]
     n_valid = active.astype(jnp.int32)
 
@@ -707,8 +715,10 @@ def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
         nonlocal kv
         q, gate, k, v = _attn_project(p, i, h, pos1, cfg)
         kv = kvq.scatter_kv(kv, j, slot, k, v)
-        k_all, v_all = llama._gather_kv(kv, j, gslot)
-        return _attn_out(p, i, q, k_all, v_all, attend, gate)
+        attn = kvq.walk_kv(kv, j, q[:, 0], page_table, lengths, page_size,
+                           walk, mesh)
+        with jax.named_scope("layer/attn_gated"):
+            return _gated_out(p, i, attn.reshape(B, 1, -1), gate)
 
     x = _blocks(p, cfg, llama._embed_rows(p, tokens[:, None]), linear, full,
                 active[:, None], tape)

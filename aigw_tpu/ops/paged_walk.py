@@ -1,0 +1,252 @@
+"""The decode step's attention read: an online-softmax walk over the
+pages the live rows hold.
+
+One function for every family's decode step (models/llama.py,
+models/qwen3_next.py; mixtral through llama's ``mlp=``). Nothing
+padded is materialised: the pool is viewed as a list of pages and
+read one whole page per (row, page id),
+rows that are not live are not walked, and no row block reads past its
+own longest row.
+
+How a step is cut up (``walk_plan``, once a step, shared by every
+layer): the rows are sorted longest first (dead rows, length 0, last)
+and walked in blocks of ``R`` rows; a block makes as many trips as its
+longest row needs, ``G`` pages of each of its rows a trip. ``R`` and
+``G`` are static and follow the program's shapes alone
+(``walk_blocks``); the number of blocks and each block's trips are
+traced loop bounds, so a page bucket is still one program. The same
+``trips`` array bounds the loops and feeds the ``decode_kv_pages_read``
+counter (tpuserve/engine.py): the count IS the trip count.
+
+Precision: K, V and q stay in the pool's dtype as matmul operands,
+products accumulate in float32 (``preferred_element_type``), the
+softmax statistics (running max, sum) and the output accumulator are
+float32 across trips. int8/int4 pools dequantise at the read exactly
+as ``kvq.gather_kv`` does (float32 product with the row's scale,
+rounded to bfloat16).
+
+The matmuls keep K and V pages in the layout they are stored in. The
+pool is ``[.., n_slots, Hkv, D]``: a token's heads lie side by side,
+and turning a page into per-head ``[page, D]`` matrices is the
+re-layout the window gather paid for (a copy of every gathered window
+a layer). Here a page is read as it lies, ``page*Hkv`` rows of
+(token, head) by ``D``, and every query head is multiplied against
+every row: ``[R, H, D] x [R, G*page*Hkv, D]^T`` for the logits,
+``[R, H, G*page*Hkv] x [R, G*page*Hkv, D]`` for the values, with the
+rows of the other KV heads masked out of the softmax. With H <= 128
+those rows ride in the MXU's padding: no transpose of a page, and the
+whole pool goes to the loops as ONE ``[L*2*n_pages, page*Hkv, D]``
+list of pages (a layer sliced out of it would be a copy).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+#: K and V bytes a trip should move: enough that the loop's own
+#: bookkeeping (a few microseconds a trip) stays under the read itself
+_TRIP_BYTES = 4 << 20
+
+
+class WalkPlan(NamedTuple):
+    """How one decode step's walk is cut up (see the module docstring)."""
+
+    order: jax.Array  # [n_blk * R] int32 rows, longest first; B = no row
+    trips: jax.Array  # [n_blk] int32 trips of each block, 0 past the live
+    n_blocks: jax.Array  # int32: blocks holding a live row
+    rows: int  # R, static
+    pages: int  # G, static
+
+    @property
+    def pages_read(self) -> jax.Array:
+        """(row, page) pairs the walk of ONE layer reads this step."""
+        return jnp.sum(self.trips) * (self.rows * self.pages)
+
+
+def pair_bytes(pool: jax.Array, page_size: int) -> int:
+    """K and V bytes one (row, page) pair holds in a layer of this
+    pool [L, 2, n_slots, Hkv, D]."""
+    *_, hkv, d = pool.shape
+    return 2 * page_size * hkv * d * pool.dtype.itemsize
+
+
+def walk_blocks(n_rows: int, n_cols: int, pair: int) -> tuple[int, int]:
+    """(rows a block R, pages a trip G) from the program's shapes:
+    rows, page bucket, ``pair_bytes`` of the pool a device holds. A
+    trip costs about 6 us of bookkeeping and 3 us a MiB on a v5e
+    (PERF.md, PR 31), so it moves about ``_TRIP_BYTES``. A row's pages
+    come a quarter of its page bucket a trip, at most eight (what a row
+    reads is rounded up to whole trips, and a block is done in four),
+    and the rest of the trip is rows: a program with a wide page bucket
+    serves long rows and reads more pages of fewer rows a trip — the
+    rows that pad a step's last block are read for nothing, and two
+    live rows of thirty-two is the common step where windows are long
+    — a narrow one the reverse."""
+    pairs = max(1, _TRIP_BYTES // max(pair, 1))
+    g = max(1, min(n_cols // 4, 8, pairs))
+    return max(1, min(n_rows, pairs // g)), g
+
+
+def walk_plan(lengths: jax.Array, n_cols: int, page_size: int,
+              pair: int) -> WalkPlan:
+    """Cut a step up. ``lengths`` [B]: tokens each row attends to (its
+    new one included), 0 for a row that is not live; ``pair``: the
+    pool's ``pair_bytes``."""
+    B = lengths.shape[0]
+    R, G = walk_blocks(B, n_cols, pair)
+    n_blk = -(-B // R)
+    need = jnp.minimum(-(-lengths // page_size), n_cols).astype(jnp.int32)
+    # longest first, ties by row: each row's rank by counting (B is
+    # tens: cheaper than a sort, and the sampler's stays the only one)
+    i = jnp.arange(B, dtype=jnp.int32)
+    ahead = (need[None, :] > need[:, None]) | (
+        (need[None, :] == need[:, None]) & (i[None, :] < i[:, None]))
+    order = jnp.zeros((B,), jnp.int32).at[
+        jnp.sum(ahead, axis=1, dtype=jnp.int32)].set(i)
+    pad = n_blk * R - B
+    held = jnp.pad(need[order], (0, pad)).reshape(n_blk, R)
+    trips = -(-jnp.max(held, axis=1) // G)
+    return WalkPlan(
+        order=jnp.pad(order, (0, pad), constant_values=B),
+        trips=trips.astype(jnp.int32),
+        n_blocks=jnp.sum(trips > 0).astype(jnp.int32),
+        rows=R, pages=G)
+
+
+def pages_live(lengths: jax.Array, page_size: int) -> jax.Array:
+    """Pages the live rows hold this step (``decode_kv_pages_live``)."""
+    return jnp.sum(-(-lengths // page_size)).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "R", "G"))
+def _walk(q, pool, scale, layer, page_table, lengths, order, trips,
+          n_blocks, *, page_size, R, G):
+    """The walk itself, jitted on its own so that a decode program
+    traces and lowers it ONCE for all its layers (``layer`` is traced):
+    a replica traces every program at every boot to look it up in the
+    compile cache, and 28 unrolled copies of two nested loops cost a
+    warm boot half a second a program (PERF.md, PR 31)."""
+    B, H, D = q.shape
+    L, _, n_slots, Hkv, _ = pool.shape
+    grp = H // Hkv
+    P = page_table.shape[1]
+    n_pages = n_slots // page_size
+    rpp = page_size * Hkv  # (token, head) rows of a page
+    T = G * rpp
+    quant = scale is not None
+    # operands in the pool's dtype (a quantised pool reads as bfloat16,
+    # as at the gather); a float32 q against a bfloat16 pool promotes
+    cdt = jnp.promote_types(q.dtype, jnp.bfloat16 if quant else pool.dtype)
+
+    # the WHOLE pool, every layer's K and V pages in one list: nothing
+    # is sliced out of it (a slice handed to a loop is a copy), and
+    # merging (token, head) is no re-layout where a page's rows lie
+    pages = pool.reshape(L * 2 * n_pages, page_size, Hkv, D)
+    if quant:
+        scales = scale.reshape(L * 2 * n_pages, page_size, Hkv)
+
+    def read(which, ids):
+        ids = (layer * 2 + which) * n_pages + ids
+        x = jnp.take(pages, ids, axis=0, mode="clip")  # [R,G,page,Hkv,D]
+        if quant:
+            s = jnp.take(scales, ids, axis=0, mode="clip")
+            x = (x.astype(jnp.float32) * s[..., None]).astype(jnp.bfloat16)
+        return x.reshape(R, T, D).astype(cdt)
+
+    qc = q.astype(cdt)
+    pt = jnp.pad(page_table, ((0, 0), (0, -P % G)))
+    row = jnp.arange(T, dtype=jnp.int32)
+    # row (token, j) of a trip is query head n's to see where j is n's
+    # KV head; the other rows ride along in the MXU's padding
+    own = (row % Hkv)[None, :] == (
+        jnp.arange(H, dtype=jnp.int32) // grp)[:, None]  # [H, T]
+    inv = 1.0 / math.sqrt(D)
+
+    def block(blk, out):
+        rows = lax.dynamic_slice(order, (blk * R,), (R,))
+        src = jnp.minimum(rows, B - 1)
+        qb = qc[src]  # [R, H, D]
+        len_r = jnp.where(rows < B, lengths[src], 0)
+        pt_r = pt[src]
+
+        def trip(t, carry):
+            m, l, acc = carry
+            ids = lax.dynamic_slice(pt_r, (0, t * G), (R, G))
+            k = read(0, ids)
+            v = read(1, ids)
+            s = jnp.einsum("rnd,rtd->rnt", qb, k,
+                           preferred_element_type=jnp.float32) * inv
+            live = (t * (G * page_size) + row // Hkv)[None, :] \
+                < len_r[:, None]  # [R, T]
+            s = jnp.where(own[None] & live[:, None, :], s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=2))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[:, :, None])
+            l = alpha * l + jnp.sum(p, axis=2)
+            pv = jnp.einsum("rnt,rtd->rnd", p.astype(cdt), v,
+                            preferred_element_type=jnp.float32)
+            return m_new, l, acc * alpha[:, :, None] + pv
+
+        m0 = jnp.full((R, H), -1e30, jnp.float32)
+        l0 = jnp.zeros((R, H), jnp.float32)
+        acc0 = jnp.zeros((R, H, D), jnp.float32)
+        _, l, acc = lax.fori_loop(0, trips[blk], trip, (m0, l0, acc0))
+        o = acc / jnp.maximum(l, 1e-30)[:, :, None]
+        dst = jnp.where(len_r > 0, rows, B)  # not live: written nowhere
+        return out.at[dst].set(o.astype(q.dtype), mode="drop")
+
+    return lax.fori_loop(0, n_blocks, block, jnp.zeros((B, H, D), q.dtype))
+
+
+@jax.named_scope("layer/kv_walk")
+def paged_decode_walk(
+    q: jax.Array,  # [B, H, D] roped query
+    pool: jax.Array,  # [L, 2, n_slots, Hkv, D] (native or int8/int4)
+    layer: int,
+    page_table: jax.Array,  # [B, P] int32
+    lengths: jax.Array,  # [B] int32 — rows to attend (incl. new token)
+    *,
+    page_size: int,
+    scale: jax.Array | None = None,  # [L, 2, n_slots, Hkv] f32 (quantised)
+    plan: WalkPlan | None = None,
+    mesh=None,
+) -> jax.Array:
+    """Attention of each live row's query over the pages it holds in
+    ``layer`` of the pool; the new token's K/V are already scattered
+    (``lengths`` includes them). Returns [B, H, D] in q's dtype; rows
+    with ``lengths == 0`` are not walked and come back zero. ``plan``:
+    this step's ``walk_plan`` (made here when the caller has none).
+    With ``mesh`` the walk runs under shard_map, each device over ITS
+    head shard of the pool (heads on ``tp``) — local reads, no
+    collective inside attention; H and Hkv must divide the axis
+    (tpuserve/attention.resolve_decode_backend guards that)."""
+    if plan is None:
+        plan = walk_plan(lengths, page_table.shape[1], page_size,
+                         pair_bytes(pool, page_size))
+    layer = jnp.asarray(layer, jnp.int32)
+    static = dict(page_size=page_size, R=plan.rows, G=plan.pages)
+    if mesh is None:
+        return _walk(q, pool, scale, layer, page_table, lengths, plan.order,
+                     plan.trips, plan.n_blocks, **static)
+    from jax.sharding import PartitionSpec as Ps
+
+    rep, axis = Ps(), "tp"
+    scales = () if scale is None else (scale,)
+
+    def local(q_, pool_, *rest):
+        *plan_, sc = rest if scales else (*rest, None)
+        return _walk(q_, pool_, sc, *plan_, **static)
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(Ps(None, axis, None), Ps(None, None, None, axis, None))
+        + (rep,) * 6 + (Ps(None, None, None, axis),) * len(scales),
+        out_specs=Ps(None, axis, None), check_vma=False,
+    )(q, pool, layer, page_table, lengths, plan.order, plan.trips,
+      plan.n_blocks, *scales)

@@ -37,9 +37,10 @@ the padded-window gather is the largest. This kernel collapses them:
   column as they stream through VMEM — the packed layout never
   round-trips through HBM at full width.
 
-Semantics match ``paged_decode_walk`` below (the XLA fused reference
-the engine runs off-TPU and, under a mesh, per head-shard inside
-shard_map): scatter-then-walk attends pool rows ``<= position`` where
+Semantics match ``ops/paged_walk.paged_decode_walk`` (the XLA page
+walk: every family's default decode rung, and what this request runs
+off-TPU and, under a mesh, per head-shard inside shard_map):
+scatter-then-walk attends pool rows ``<= position`` where
 row ``position`` holds the freshly appended (round-tripped) values —
 identical numbers to walk-then-fold. Parity is asserted in
 tests/test_pallas_ops.py at production shapes.
@@ -410,94 +411,3 @@ def fused_paged_decode(
     if quant:
         return attn, k_out, v_out, outs[3], outs[4]
     return attn, k_out, v_out
-
-
-def paged_decode_walk(
-    q: jax.Array,  # [B, H, D] roped query
-    k_rows: jax.Array,  # [n_slots, Hkv, D] pool (native or int8/int4)
-    v_rows: jax.Array,
-    page_table: jax.Array,  # [B, P] int32
-    lengths: jax.Array,  # [B] int32 — rows to attend (incl. new token)
-    *,
-    page_size: int,
-    k_scale: jax.Array | None = None,
-    v_scale: jax.Array | None = None,
-) -> jax.Array:
-    """XLA fused-decode reference: online-softmax paged attention,
-    one page per loop step — the fused kernel's math with memory
-    bounded at [B, page, Hkv, D] instead of the gather path's full
-    padded [B, T] window. The new token's K/V are already scattered
-    (``lengths`` includes them), so walk-then-read equals the kernel's
-    walk-then-fold. Quantized pools dequantize at the read. Off-TPU
-    this IS the serving path; on a mesh it runs per head-shard inside
-    shard_map (paged_decode_walk_spmd). Returns [B, H, D] in q's
-    dtype."""
-    B, H, D = q.shape
-    Hkv = k_rows.shape[1]
-    grp = H // Hkv
-    P = page_table.shape[1]
-    qf = q.astype(jnp.float32).reshape(B, Hkv, grp, D) / math.sqrt(D)
-    offs = jnp.arange(page_size, dtype=jnp.int32)
-
-    def body(p, carry):
-        m, l, acc = carry
-        slots = page_table[:, p][:, None] * page_size + offs[None, :]
-        k = k_rows[slots].astype(jnp.float32)  # [B, page, Hkv, D]
-        v = v_rows[slots].astype(jnp.float32)
-        if k_scale is not None:
-            k = k * k_scale[slots][..., None]
-            v = v * v_scale[slots][..., None]
-        logits = jnp.einsum("bhgd,bshd->bhgs", qf, k)
-        kpos = p * page_size + offs
-        mask = kpos[None, :] < lengths[:, None]  # [B, page]
-        logits = jnp.where(mask[:, None, None, :], logits, -1e30)
-        m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        probs = jnp.exp(logits - m_new)
-        l_new = alpha * l + probs.sum(-1, keepdims=True)
-        acc_new = acc * alpha + jnp.einsum("bhgs,bshd->bhgd", probs, v)
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((B, Hkv, grp, 1), -1e30, jnp.float32)
-    l0 = jnp.zeros((B, Hkv, grp, 1), jnp.float32)
-    acc0 = jnp.zeros((B, Hkv, grp, D), jnp.float32)
-    # traced upper bound — the XLA analogue of the ragged DMA skip
-    p_hi = jnp.clip((jnp.max(lengths) - 1) // page_size + 1, 0, P)
-    _, l, acc = jax.lax.fori_loop(0, p_hi, body, (m0, l0, acc0))
-    out = acc / jnp.maximum(l, 1e-30)
-    return out.reshape(B, H, D).astype(q.dtype)
-
-
-def paged_decode_walk_spmd(
-    q, k_rows, v_rows, page_table, lengths, *, mesh, page_size,
-    k_scale=None, v_scale=None, axis: str = "tp",
-):
-    """The fused walk under shard_map: each device walks ITS local
-    head shard of the pool — per-device local reads, no GSPMD gather,
-    no cross-device collective inside attention (the layer all-reduce
-    after wo is unchanged). Requires H and Hkv divisible by the axis
-    size (the resolution matrix guards this)."""
-    from jax.sharding import PartitionSpec as Ps
-
-    heads = Ps(None, axis, None)
-    quant = k_scale is not None
-
-    if quant:
-        def local(q_, k_, v_, ks_, vs_, pt_, ln_):
-            return paged_decode_walk(
-                q_, k_, v_, pt_, ln_, page_size=page_size,
-                k_scale=ks_, v_scale=vs_)
-
-        in_specs = (heads, heads, heads, Ps(None, axis), Ps(None, axis),
-                    Ps(None, None), Ps(None))
-        args = (q, k_rows, v_rows, k_scale, v_scale, page_table,
-                lengths)
-    else:
-        def local(q_, k_, v_, pt_, ln_):
-            return paged_decode_walk(
-                q_, k_, v_, pt_, ln_, page_size=page_size)
-
-        in_specs = (heads, heads, heads, Ps(None, None), Ps(None))
-        args = (q, k_rows, v_rows, page_table, lengths)
-    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                         out_specs=heads, check_vma=False)(*args)

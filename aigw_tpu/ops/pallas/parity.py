@@ -25,10 +25,8 @@ import numpy as np
 
 from aigw_tpu.models import kvq, llama
 from aigw_tpu.ops.pallas import qmatmul
-from aigw_tpu.ops.pallas.decode_fused import (
-    fused_paged_decode,
-    paged_decode_walk,
-)
+from aigw_tpu.ops.paged_walk import paged_decode_walk
+from aigw_tpu.ops.pallas.decode_fused import fused_paged_decode
 from aigw_tpu.ops.pallas.paged_attention import (
     paged_attention_decode_v2,
     paged_attention_verify,
@@ -267,8 +265,9 @@ def _twin_step(q, kn, vn, pools, pt, positions, active, qdt, ps, theta):
             v_s = v_s.at[slot[b]].set(sv[b])
     with _exact_reference():
         want = paged_decode_walk(
-            qr, k_pool, v_pool, pt, jnp.where(active, positions + 1, 0),
-            page_size=ps, k_scale=k_s, v_scale=v_s)
+            qr, jnp.stack([k_pool, v_pool])[None], 0, pt,
+            jnp.where(active, positions + 1, 0), page_size=ps,
+            scale=jnp.stack([k_s, v_s])[None] if qdt else None)
     return want, (k_pool, v_pool, k_s, v_s), slot, knr
 
 

@@ -847,8 +847,9 @@ def resolve_decode_backend(cfg, model_cfg, mesh,
 
     | requested          | mesh | TPU | kv dtype  | resolved        |
     |--------------------|------|-----|-----------|-----------------|
-    | auto/chained       | any  | any | native    | xla-gather      |
-    | auto/chained       | any  | any | int8/int4 | xla-gather (dequant at the gather) |
+    | auto/chained       | no   | any | any       | xla-walk        |
+    | auto/chained       | yes  | any | any       | xla-walk-spmd   |
+    | auto/chained, heads % tp != 0   | any       | xla-gather (narrowed) |
     | chained+pallas_attn| no   | any | native    | pallas (chained kernel; interpret off-TPU) |
     | chained+pallas_attn| no   | any | int8/int4 | fused rung (chained kernel has no quantized rung) |
     | chained+pallas_attn| yes  | any | any       | fused-xla-spmd  |
@@ -856,76 +857,88 @@ def resolve_decode_backend(cfg, model_cfg, mesh,
     | fused              | no   | no  | any       | fused-xla       |
     | fused              | yes  | any | any       | fused-xla-spmd  |
     | fused, heads % tp != 0          | any       | xla-gather (narrowed) |
-    | any, ``fns.decode_kernels`` false (qwen3_next)  | xla-gather    |
+    | a kernel rung, ``fns.decode_kernels`` false (qwen3_next) | the auto/chained row |
+
+    ``xla-walk`` is the default of every family (ISSUE 31): after the
+    scatter, an online-softmax loop over the whole pages the LIVE rows
+    hold (ops/paged_walk.py) — no padded window is gathered, int8/int4
+    pages dequantize at the read, and ``decode_kv_pages_read`` /
+    ``decode_kv_pages_live`` on /state say what it read. On a mesh it
+    runs inside shard_map, each device over its LOCAL head shard of the
+    pool (``-spmd``). ``fused-xla`` / ``fused-xla-spmd`` are the SAME
+    program under the name the request gave (``--decode-backend
+    fused`` where the fused Pallas kernel cannot run). ``xla-gather``
+    — the padded-window gather — is left with one row, geometric: head
+    counts that do not divide the tp axis (the shard_map walk needs
+    whole head shards per device; the GSPMD gather keeps reads
+    head-local). A family whose ``ModelFns.decode_kernels`` is false
+    (qwen3_next: q/k RMSNorm, rotary on a part of each head, an output
+    gate — which no Pallas rung knows) takes the default row whatever
+    kernel was requested, and the reason says so.
 
     The fused rung has no model-family exception (ISSUE 18): MoE
     families run the same fused decode programs as dense ones — the
     expert dispatch/combine einsums live in the MLP, outside the
-    attention rung entirely. The one narrowed row left is geometric:
-    head counts that do not divide the tp axis.
-
-    The old ``pallas_attn × mesh → xla-gather`` row (the PR 10 "GSPMD
-    gather path" export) is GONE: a mesh now walks each device's LOCAL
-    head shard of the pool inside shard_map (fused-xla-spmd) — no
-    gather, no padded-window HBM traffic — whenever the head counts
-    divide the tp axis. The one remaining gather-on-mesh row is the
-    narrowed indivisible-heads case, exported with its own reason. The
-    speculative VERIFY step keeps the chained path at every rung
-    (its multi-position kernel has no fused port; quantized pools run
-    gather-dequant), which `Engine.verify_attn_impl` exports.
+    attention rung entirely. The speculative VERIFY step and the
+    prefill chunk/tail programs keep the window gather at every rung
+    ([B, D+1] and [1, S] queries: another shape of problem; quantized
+    pools run gather-dequant), which `Engine.verify_attn_impl` exports.
 
     ``AIGW_DECODE_FUSED_IMPL`` in {xla, pallas} overrides the
     kernel-vs-reference choice for A/B and interpret-mode parity runs,
     exactly like AIGW_RAGGED_PREFILL_IMPL on the prefill side."""
     from aigw_tpu.ops.pallas._compat import is_tpu_backend
 
-    if fns is not None and not fns.decode_kernels:
-        # the family row, as the family's ModelFns declares it (today:
-        # qwen3_next's gated attention with q/k RMSNorm and rotary on a
-        # part of each head, which no kernel rung knows)
-        return ("xla-gather",
-                "this family's decode_step takes no kernel rung "
-                "(ModelFns.decode_kernels is false): the window gather "
-                "serves every request")
     quant = cfg.kv_cache_dtype in ("int8", "int4")
     req = "chained" if cfg.decode_backend == "auto" else cfg.decode_backend
     wants_fused = req == "fused" or (
         req == "chained" and cfg.pallas_attn and (quant or mesh is not None))
+    family = ""
+    if fns is not None and not fns.decode_kernels and (
+            wants_fused or cfg.pallas_attn):
+        # the family row, as the family's ModelFns declares it
+        wants_fused = False
+        family = ("this family's decode_step takes no kernel rung "
+                  "(ModelFns.decode_kernels is false); ")
+    tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
+    splits = tp > 1 and (model_cfg.n_heads % tp
+                         or model_cfg.n_kv_heads % tp)
+    narrowed = (f"heads ({model_cfg.n_heads}q/{model_cfg.n_kv_heads}kv) "
+                f"do not divide tp={tp}: the shard_map local walk needs "
+                "whole head shards per device; the GSPMD gather keeps "
+                "reads head-local (narrowed row)")
     if not wants_fused:
-        if cfg.pallas_attn and mesh is None:
+        if cfg.pallas_attn and mesh is None and not family:
             return "pallas", "pallas_attn requested, single chip"
-        if quant:
-            return ("xla-gather",
-                    f"default chained path; {cfg.kv_cache_dtype} KV "
-                    "pages dequantize against their per-page scales at "
-                    "the window gather")
-        return "xla-gather", "default (pallas_attn off)"
+        if splits:
+            return "xla-gather", f"{family}default, but {narrowed}"
+        why = (f"{family}default: the page walk reads the whole pages "
+               "the live rows hold"
+               + (f"; {cfg.kv_cache_dtype} KV pages dequantize against "
+                  "their per-page scales at the read" if quant else ""))
+        if mesh is not None:
+            return ("xla-walk-spmd",
+                    f"{why}, each device its LOCAL head shard of the "
+                    "pool inside shard_map")
+        return "xla-walk", why
     why = ("decode_backend=fused" if req == "fused" else
            ("pallas_attn requested with "
             f"{cfg.kv_cache_dtype} KV pages: the chained kernel has no "
             "quantized rung" if quant else
             "pallas_attn requested on a mesh"))
     if mesh is not None:
-        tp = int(mesh.shape.get("tp", 1))
-        if tp > 1 and (model_cfg.n_heads % tp
-                       or model_cfg.n_kv_heads % tp):
-            return ("xla-gather",
-                    f"{why}, but heads ({model_cfg.n_heads}q/"
-                    f"{model_cfg.n_kv_heads}kv) do not divide tp={tp}: "
-                    "the shard_map local walk needs whole head shards "
-                    "per device; the GSPMD gather keeps reads "
-                    "head-local (narrowed row)")
+        if splits:
+            return "xla-gather", f"{why}, but {narrowed}"
         return ("fused-xla-spmd",
-                f"{why}: each device walks its LOCAL head shard of the "
-                "paged pool inside shard_map — the GSPMD gather row is "
-                "deleted")
+                f"{why}: the page walk, each device over its LOCAL head "
+                "shard of the paged pool inside shard_map")
     impl_env = os.environ.get("AIGW_DECODE_FUSED_IMPL", "").lower()
     if impl_env == "pallas" or (impl_env != "xla" and is_tpu_backend()):
         return ("fused-pallas",
                 f"{why}: fused Pallas kernel (RoPE + append + paged "
                 "attention in one dispatch, single-chip TPU)")
     return ("fused-xla",
-            f"{why}: XLA fused reference (online-softmax page walk; "
+            f"{why}: the XLA page walk (the default rung's program; "
             "no TPU backend — interpret mode is too slow to serve)")
 
 

@@ -40,6 +40,7 @@ import numpy as np
 from aigw_tpu.analysis.registry import engine_thread_only
 from aigw_tpu.models import kvq, llama
 from aigw_tpu.models.cache import spec_of
+from aigw_tpu.ops import paged_walk
 from aigw_tpu.obs.flight import (
     ADMIT,
     ADMIT_WAIT,
@@ -689,6 +690,14 @@ class EngineStats:
     # (top_k > 0 or top_p < 1): the steps in which `sample` pays its
     # vocabulary-wide sort. Over decode_steps: the share that pay it
     sample_sort_steps: int = 0
+    # what the decode programs read of the page pool, a layer, counted
+    # on the device per step (Engine._kv_pages): (row, page) pairs the
+    # step's program reads — on the page walk the loops' own trip
+    # bounds x rows x pages a trip, elsewhere the whole [B, P] window —
+    # and the pages its live rows hold. read / live is the read
+    # amplification; 1.0 reads exactly what is live
+    decode_kv_pages_read: int = 0
+    decode_kv_pages_live: int = 0
     prefix_cache_hits: int = 0
     prefix_tokens_reused: int = 0
     # prefix-cache surface (ISSUE 3): misses counted over page-eligible
@@ -886,6 +895,9 @@ class _Window:
     # accumulators at DRAIN, when the window's results are fetched
     # anyway — reading it at dispatch would force a device sync
     moe: Any = None
+    # device [2] int32: (pages the window's steps read a layer, pages
+    # their live rows held), folded at drain like ``moe``
+    kv_pages: Any = (0, 0)
 
 
 class Engine:
@@ -1165,13 +1177,11 @@ class Engine:
                            self.decode_attn_reason)
         # decode_step's attn_impl argument + whether it needs the mesh
         attn_impl = {
-            "xla-gather": "",
+            "xla-gather": "gather",
             "pallas": "pallas",
-            "fused-xla": "fused",
-            "fused-xla-spmd": "fused",
             "fused-pallas": "fused-pallas",
-        }[self.decode_attn_impl]
-        decode_mesh = mesh if self.decode_attn_impl == "fused-xla-spmd" \
+        }.get(self.decode_attn_impl, "")  # "": the page walk
+        decode_mesh = mesh if self.decode_attn_impl.endswith("-spmd") \
             else None
         # the speculative verify step keeps the chained path at every
         # rung: its multi-position kernel has no fused port, and the
@@ -1334,6 +1344,25 @@ class Engine:
             self._prefill_sp_suffix_fn = jax.jit(
                 _prefill_sp_suffix_step, donate_argnums=(5,))
 
+        walks = not attn_impl  # the default rung: the page walk
+
+        def _kv_pages(kv, st, act, pages, walks=walks):
+            """One decode step's KV read, counted on the device:
+            ``pages`` [2] gains (pages the step's program reads a
+            layer, pages its live rows hold). On the walk rung the
+            first IS the loops' bound — the plan returned here goes to
+            the model's decode_step as ``walk`` — on the others
+            (window gather, the Pallas grids, the verify step: no
+            plan) it is the whole [B, P] window they address."""
+            lengths = jnp.where(act, st["positions"] + 1, 0)
+            B, P = st["page_table"].shape
+            plan = kvq.walk_plan(kv.kv if stateful else kv, lengths, P,
+                                 ps, decode_mesh) if walks else None
+            read = plan.pages_read if walks else B * P
+            return plan, pages + jnp.stack([
+                jnp.asarray(read, jnp.int32),
+                paged_walk.pages_live(lengths, ps)])
+
         def _decode_scan(k: int, lean: bool = False):
             """Factory: k fused decode+sample steps; sampled tokens feed
             forward on-device (no host round-trip inside the window).
@@ -1354,13 +1383,15 @@ class Engine:
             lp_k = cfg.logprobs_topk
 
             def body(params, lora, carry):
-                kv, st, macc = carry
+                kv, st, macc, pages = carry
                 act = st["active"] & (st["positions"] < st["limits"])
+                walk, pages = _kv_pages(kv, st, act, pages)
                 logits, kv, moe = _moe_split(model_decode(
                     params, mc, st["tokens"], st["positions"], kv,
                     st["page_table"], ps, act,
                     lora=lora, adapter_idx=st["adapter_idx"],
-                    attn_impl=attn_impl, mesh=decode_mesh, **moe_kw))
+                    attn_impl=attn_impl, mesh=decode_mesh, walk=walk,
+                    **moe_kw))
                 macc = macc if moe is None else macc + moe
                 if lean:
                     logits = logits + st["bias"]
@@ -1392,18 +1423,19 @@ class Engine:
                         logits.astype(jnp.float32), axis=-1)
                     chosen = logp[jnp.arange(B), sampled]
                     tk_vals, tk_ids = jax.lax.top_k(logp, lp_k)
-                    return (kv, new, macc), (sampled, chosen, tk_ids,
-                                             tk_vals)
-                return (kv, new, macc), sampled
+                    return (kv, new, macc, pages), (
+                        sampled, chosen, tk_ids, tk_vals)
+                return (kv, new, macc, pages), sampled
 
             def scan_k(params, lora, kv, state):
                 macc0 = (jnp.zeros((mc.n_layers, tape_width),
                                    jnp.int32) if is_moe else None)
-                (kv, state, macc), sampled = jax.lax.scan(
+                (kv, state, macc, pages), sampled = jax.lax.scan(
                     lambda c, _: body(params, lora, c),
-                    (kv, state, macc0), None, length=k
+                    (kv, state, macc0, jnp.zeros((2,), jnp.int32)), None,
+                    length=k
                 )
-                return sampled, _pin_state(state), kv, macc
+                return sampled, _pin_state(state), kv, macc, pages
 
             return scan_k
 
@@ -1436,8 +1468,9 @@ class Engine:
             D1 = D + 1
 
             def body(params, lora, carry):
-                kv, st, macc = carry
+                kv, st, macc, pages = carry
                 act = st["active"] & (st["positions"] < st["limits"])
+                _, pages = _kv_pages(kv, st, act, pages, walks=False)
                 # penalty and sampling slots advance exactly one token
                 # per step (see speculation.py module docstring):
                 # poison their drafts
@@ -1518,15 +1551,16 @@ class Engine:
                 n_prop = jnp.sum(jnp.cumprod(
                     (drafts >= 0).astype(jnp.int32), axis=1), axis=1)
                 n_prop = jnp.where(act, n_prop, 0)
-                return (kv, new, macc), (sampled, n_emit, n_prop)
+                return (kv, new, macc, pages), (sampled, n_emit, n_prop)
 
             def scan_k(params, lora, kv, state):
                 macc0 = (jnp.zeros((mc.n_layers, tape_width),
                                    jnp.int32) if is_moe else None)
-                (kv, state, macc), out = jax.lax.scan(
+                (kv, state, macc, pages), out = jax.lax.scan(
                     lambda c, _: body(params, lora, c),
-                    (kv, state, macc0), None, length=k_steps)
-                return out, _pin_state(state), kv, macc
+                    (kv, state, macc0, jnp.zeros((2,), jnp.int32)), None,
+                    length=k_steps)
+                return out, _pin_state(state), kv, macc, pages
 
             return scan_k
 
@@ -2274,7 +2308,7 @@ class Engine:
             for k in self._window_ladder():
                 for lean in (True, False):
                     state = self._build_device_state(bucket=P)
-                    _, _, self.kv_cache, _ = self._decode_fn_for(
+                    _, _, self.kv_cache, _, _ = self._decode_fn_for(
                         k, lean)(
                         self.params, self.lora_params, self.kv_cache,
                         state
@@ -2283,7 +2317,7 @@ class Engine:
                     if d == 0:
                         continue
                     state = self._build_device_state(bucket=P)
-                    _, _, self.kv_cache, _ = self._decode_fn_for(
+                    _, _, self.kv_cache, _, _ = self._decode_fn_for(
                         k, False, d)(
                         self.params, self.lora_params, self.kv_cache,
                         state
@@ -4422,6 +4456,9 @@ class Engine:
         # the window's routing-stats leaf settles with the window — a
         # dispatch-time read would sync against the running program
         self._fold_moe(w.moe, decode=True)
+        read, live = np.asarray(w.kv_pages, np.int64)
+        self.stats.decode_kv_pages_read += int(read)
+        self.stats.decode_kv_pages_live += int(live)
         for seq_id in w.frees:
             self.allocator.free(seq_id)
 
@@ -4615,19 +4652,20 @@ class Engine:
                 DECODE_DISPATCH,
                 {"k": k, "slots": len(members), "draft": draft,
                  "pages": self._state_bucket})
-        sampled, self._device_state, self.kv_cache, moe = decode_fn(
-            self.params, self.lora_params, self.kv_cache, self._device_state
-        )
-        # start the device→host token copy now; it overlaps this
-        # window's on-device compute and is resolved at drain time
-        self._start_host_copy(sampled)
+        sampled, self._device_state, self.kv_cache, moe, kv_pages = (
+            decode_fn(self.params, self.lora_params, self.kv_cache,
+                      self._device_state))
+        # start the device→host copy of the tokens (and of the two
+        # page counts) now; it overlaps this window's on-device compute
+        # and is resolved at drain time
+        self._start_host_copy((sampled, kv_pages))
         # process the PREVIOUS window while this one runs on-device
         self._drain_inflight()
         self._inflight = _Window(sampled=sampled, members=members, k=k,
                                  frees=frees, draft=draft,
                                  draft_lens=draft_lens,
                                  cn_epochs=cn_epochs, moe=moe,
-                                 sorts=sorts)
+                                 kv_pages=kv_pages, sorts=sorts)
         for _i, _req in members:
             if _req.trace is not None:
                 _req.trace.decode_window(k, lean, draft)

@@ -2335,6 +2335,8 @@ class TPUServeServer:
                 "tokens_generated": s.tokens_generated,
                 "decode_steps": s.decode_steps,
                 "sample_sort_steps": s.sample_sort_steps,
+                "decode_kv_pages_read": s.decode_kv_pages_read,
+                "decode_kv_pages_live": s.decode_kv_pages_live,
                 "decode_window": s.decode_window,
                 "prefill_ms": round(s.prefill_ms, 3),
                 "transfer_ms": round(s.transfer_ms, 3),

@@ -3,9 +3,7 @@ tiny model of each family (``tiny-random``: llama, ``tiny-moe``:
 mixtral, ``tiny-qwen3-next``: the hybrid family), lowers the programs
 the engine itself dispatches — its jitted prefill, chunk and
 decode-window wrappers, not the model functions — and prints the hash
-JAX's persistent compile cache takes of each computation. The hybrid
-family's decode programs are left out: they are what changes when its
-decode step changes, and no golden holds them.
+JAX's persistent compile cache takes of each computation.
 
     python tests/engine_keys_child.py [<checkout>]
 
@@ -63,8 +61,6 @@ def main(argv: list[str]) -> int:
             eng._prefill_suffix_fn.lower(
                 eng.params, eng.lora_params, toks, lens, lens,
                 eng.kv_cache, pt, *sampling, **slot_kw))
-        if slot_kw:  # the hybrid family: its decode programs have no golden
-            continue
         state = eng._build_device_state(bucket=P)
         for lean in (True, False):
             out[f"{model}.decode.lean={lean}"] = key_of(

@@ -1,21 +1,25 @@
-"""The programs the engine dispatches for the llama and mixtral
-families hash, for JAX's persistent compile cache, to what they hashed
-before the per-slot state pool, the cache description and the
-``slot_ids`` argument existed: a replica of those families finds its
-compiled programs in the cache it filled before this change.
+"""The programs the engine dispatches hash, for JAX's persistent compile
+cache, to what they hashed before: a replica finds its compiled
+programs in the cache it filled before a change that was not meant to
+touch them.
 
-The hybrid family's ``prefill`` and ``prefill_suffix`` programs are
-held beside them since its decode step began to loop over the experts
-its live rows hit: a chunk bypasses that loop (it runs the dense pass
-it ran), so its programs keep their keys; the family's decode programs
-changed with it and have no golden.
+The prefill, chunk and tail programs (``prefill``, ``prefill_suffix``)
+of all three families keep the keys they had at 6150a62 (the hybrid
+family's: 861624e) — through the per-slot state pool, the cache
+description, the ``slot_ids`` argument, the hybrid family's expert loop
+(a chunk bypasses it) and ISSUE 31's page walk (a decode step's: a
+chunk keeps its window gather). That they stand here byte for byte is
+the proof that those programs did not change.
 
-The goldens were taken from the parent commit (6150a62; the hybrid
-family's from 861624e) by
-``python tests/engine_keys_child.py <checkout of the parent>`` under the
+The decode goldens of the three families were re-taken from the tree
+of ISSUE 31, whose decode step walks the page pool: every decode
+program's key moved once with it (one cold compile a deployment), and
+the hybrid family's decode programs, which had no golden, have one now.
+
+Taken by ``python tests/engine_keys_child.py [<checkout>]`` under the
 JAX named below. Another JAX lowers to other text and the comparison
 then says nothing, so it is skipped BY NAME of that condition; the
-second test still holds the two lean/full decode programs apart."""
+second test still holds the lean/full decode programs apart."""
 
 from __future__ import annotations
 
@@ -35,21 +39,25 @@ GOLDEN = {
     "tiny-random.prefill_suffix":
         "19a6afad6854c5c8fe11cf03417798b96cb3ae53895151e9896692bb9ae268fd",
     "tiny-random.decode.lean=True":
-        "0888f9a44abe9cabd3b9bf4c036c2535ee6e091993040b3d8a56aded22cdb55b",
+        "07a38b81ea10ead04115b36d84c626771ac67703982d5d53df34ee8c71b60d73",
     "tiny-random.decode.lean=False":
-        "e4c7c8ff5665c5f260e9e001b2d34b827330e8f5503382208aa3be3fd42ef0c9",
+        "4eb91fb75bd4ef5708dbfdc87a23df1b891a2b4b4a8a06d2ee0a6faf3ec3e57d",
     "tiny-moe.prefill":
         "13e1709b8ca64f0371b94219a8bc4ce92f1172315ce362a18416bb65c4537263",
     "tiny-moe.prefill_suffix":
         "284de1ca630b9e535842d620d29b02cb1cb4df4930bd3c954c77a28386bc5754",
     "tiny-moe.decode.lean=True":
-        "3800c551d3a6d2c48cbc44e7e3020b541a022ed19eccbce9edbbdc10bb395cc0",
+        "a518d8e5f27c9761b5960cab050c0a755761a2e0be8425f5292acca96db3787b",
     "tiny-moe.decode.lean=False":
-        "a5adef1a048e166953f76a693652d3af2e66ae12a75220d715cbcb09c88cd0d3",
+        "1b225e11bba8cff736c2bc75c38a19a4776fcdc1039efbe5d0a8967fc646c0e8",
     "tiny-qwen3-next.prefill":
         "27722e95630f8db99a3ed566f6e2f930108d9fb1bbeda62b2c787d70c34d52ae",
     "tiny-qwen3-next.prefill_suffix":
         "7af8902797042320b451ce03ecfd320c9a7c81aa2c6a81ea56965151b399f382",
+    "tiny-qwen3-next.decode.lean=True":
+        "8a22cc8912b41ff35d436a7464ff86f9440367dce1197a87fdfb124de6033e70",
+    "tiny-qwen3-next.decode.lean=False":
+        "4a1db35ed98bd24fc7b1c88a6d0eb56b0829eb3a6d04de665c6590d865d36e9c",
 }
 
 

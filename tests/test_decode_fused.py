@@ -130,7 +130,7 @@ def test_fused_byte_identical_to_chained_full_mix():
                     warm_prefill_buckets=2, warm_decode_buckets=3,
                     decode_backend="fused")
     assert fused.decode_attn_impl == "fused-xla"
-    assert chained.decode_attn_impl == "xla-gather"
+    assert chained.decode_attn_impl == "xla-walk"
     for e in (chained, fused):
         e.warmup()
         e.start()
@@ -220,8 +220,7 @@ def test_teacher_forced_quality_smoke(qdt):
         lf, native = llama.decode_step(params, cfg, tok, positions,
                                        native, pt, ps, active)
         lq, quant = llama.decode_step(params, cfg, tok, positions,
-                                      quant, pt, ps, active,
-                                      attn_impl="fused")
+                                      quant, pt, ps, active)
         a, b = np.asarray(lf, np.float32), np.asarray(lq, np.float32)
         corrs.append(np.corrcoef(a.ravel(), b.ravel())[0, 1])
         for r in range(a.shape[0]):

@@ -34,14 +34,14 @@ HYBRID = {"embed", "layer/gdn_proj", "layer/gdn_conv", "layer/attn_gated",
           "layer/moe_shared", "lm_head", "sample"}
 #: program -> the scopes its lowered text must carry
 WANT = {
-    "llama.decode": DENSE | {"layer/kv_gather"},
+    "llama.decode": DENSE | {"layer/kv_walk"},
     "llama.prefill": DENSE,
     "llama.prefill_suffix": DENSE | {"layer/kv_gather"},
-    "mixtral.decode": MOE | {"layer/kv_gather"},
+    "mixtral.decode": MOE | {"layer/kv_walk"},
     "mixtral.prefill": MOE,
     "mixtral.prefill_suffix": MOE | {"layer/kv_gather"},
-    "qwen3_next.decode": HYBRID | {"layer/gdn_recurrent",
-                                   "layer/kv_gather"},
+    "qwen3_next.decode": (HYBRID - {"layer/attn"}) | {
+        "layer/gdn_recurrent", "layer/kv_walk"},
     "qwen3_next.prefill": HYBRID | {"layer/gdn_chunk"},
     "qwen3_next.prefill_suffix": HYBRID | {"layer/gdn_chunk",
                                            "layer/kv_gather"},
@@ -116,6 +116,7 @@ def test_scopes_live_only_in_the_programs_and_the_ledger():
                         found.setdefault(word, set()).add(rel)
     assert found == {
         "named_scope(": {"models/llama.py", "models/mixtral.py",
-                         "models/qwen3_next.py", "tpuserve/sampling.py"},
+                         "models/qwen3_next.py", "ops/paged_walk.py",
+                         "tpuserve/sampling.py"},
         "TraceAnnotation(": {"obs/flight.py"},
     }

@@ -329,14 +329,15 @@ def test_prefill_bucket_divisibility_guard(pair):
 
 
 def test_decode_attn_resolution_exported(pair):
-    """The PR 10 ``pallas_attn × mesh → xla-gather`` fallback row is
-    DELETED (ISSUE 13): a kernel request on a mesh now resolves to the
-    fused per-device local-shard walk, exported with its reason. The
-    narrowed row — heads not divisible by tp — still gathers, with its
-    own reason."""
+    """The default rung is the page walk (ISSUE 31), on a mesh each
+    device over its local head shard; a kernel request on a mesh
+    resolves to the same per-device walk under the name it asked for
+    (ISSUE 13), exported with its reason. The narrowed row — heads not
+    divisible by tp — still gathers, with its own reason."""
     single, mesh = pair
-    assert mesh.decode_attn_impl == "xla-gather"
-    assert single.decode_attn_impl == "xla-gather"
+    assert mesh.decode_attn_impl == "xla-walk-spmd"
+    assert "LOCAL head shard" in mesh.decode_attn_reason
+    assert single.decode_attn_impl == "xla-walk"
     eng = _mk_engine(True, pallas_attn=True, spec_tokens=0)
     assert eng.decode_attn_impl == "fused-xla-spmd"
     assert "LOCAL head shard" in eng.decode_attn_reason
@@ -350,6 +351,9 @@ def test_decode_attn_resolution_exported(pair):
     impl, why = resolve_decode_backend(
         EngineConfig(decode_backend="fused"), llama.TINY,
         make_mesh(MeshSpec(dp=1, tp=8)))
+    assert impl == "xla-gather" and "narrowed" in why
+    impl, why = resolve_decode_backend(
+        EngineConfig(), llama.TINY, make_mesh(MeshSpec(dp=1, tp=8)))
     assert impl == "xla-gather" and "narrowed" in why
 
 
@@ -479,7 +483,7 @@ class TestMeshServerState:
         assert state["ici_bytes_per_token"] > 0
         assert state["migration"] is True
         assert state["attention_backend_reason"]
-        assert state["decode_attn_impl"] == "xla-gather"
+        assert state["decode_attn_impl"] == "xla-walk-spmd"
         # per-device labeled gauges render next to the scalar set
         assert 'tpuserve_device_param_bytes{device="0"}' in metrics
         assert 'tpuserve_device_param_bytes{device="1"}' in metrics

@@ -200,7 +200,7 @@ def test_moe_full_mix_byte_identical_zero_hot_compiles():
                     spec_tokens=3, spec_adaptive=False,
                     warm_prefill_buckets=2, warm_decode_buckets=3)
     assert child.decode_attn_impl == "fused-xla"
-    assert control.decode_attn_impl == "xla-gather"
+    assert control.decode_attn_impl == "xla-walk"
     for e in (control, child):
         e.warmup()
         e.start()

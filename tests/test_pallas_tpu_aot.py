@@ -95,7 +95,8 @@ def test_w8a16_matmul_compiles_at_every_weight_shape(v5e, geometry):
                  ((k, n), jnp.int8), ((1, n), jnp.float32))
 
 
-def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e):
+@pytest.mark.parametrize("pages", [16, 32])
+def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e, pages):
     """No Pallas here, but the same kind of fact only the chip's
     compiler knows. The Qwen3-Next decode window (a scan of
     ``decode_step``) at the published widths, 12 layers, 128 experts
@@ -107,14 +108,14 @@ def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e):
     copied all 604 MB of it twice a step (3.4 ms of a 23 ms step on the
     chip) until ``decode_step`` handed the pool to each layer with its
     input. A copy of a pool or of an expert matrix is an instruction
-    of that shape in the compiled program."""
-    import re
-
+    of that shape in the compiled program. Since ISSUE 31 neither is
+    a gathered ``[32, P*128, 2, 256]`` window: the cell's two page
+    buckets (16 and 32) are the two programs compiled here."""
     from aigw_tpu.models import qwen3_next as qn
 
     cfg = qn.Qwen3NextConfig(num_hidden_layers=12, num_experts=128,
                              router_experts=512, vocab_size=37984)
-    slots, pages, page = 32, 32, 128
+    slots, page = 32, 128
 
     def sds(tree):
         return jax.tree_util.tree_map(
@@ -124,7 +125,7 @@ def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e):
     p = sds(jax.eval_shape(
         lambda: qn.init_params(jax.random.PRNGKey(0), cfg)))
     cache = sds(jax.eval_shape(lambda: cfg.cache_spec().make(
-        (slots * pages + 1) * page, slots, "bfloat16")))
+        (slots * 32 + 1) * page, slots, "bfloat16")))
     i32 = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
 
     def window(p, cache, tokens, positions, page_table, active):
@@ -144,9 +145,71 @@ def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e):
         jax.ShapeDtypeStruct((slots, pages), jnp.int32, sharding=v5e),
         jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
     ).compile().as_text()
+    _no_window_and_no_pool_copy(
+        text, (slots, pages * page, cfg.num_key_value_heads, cfg.head_dim),
+        (*jax.tree_util.tree_leaves(cache),
+         p["l0.experts_gate"], p["l0.experts_down"]))
+
+
+def _no_window_and_no_pool_copy(text, window, held):
+    """The compiled decode window holds no array of a gathered
+    ``[B, P*page, Hkv, D]`` window (the page walk reads whole pages of
+    live rows and materialises nothing padded) and copies none of
+    ``held`` (a pool handed to the walk's loops stays where it is)."""
+    import re
+
+    def name(shape, dtype=r"\w+"):
+        return rf"{dtype}\[{','.join(map(str, shape))}\]"
+
+    assert not re.findall(name(window), text)
     copied = set(re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text))
-    held = {f"{a.dtype.name.replace('float', 'f')}"
-            f"[{','.join(map(str, a.shape))}]"
-            for a in (*jax.tree_util.tree_leaves(cache),
-                      p["l0.experts_gate"], p["l0.experts_down"])}
-    assert copied and not copied & held, sorted(copied & held)
+    pools = {f"{a.dtype.name.replace('float', 'f')}"
+             f"[{','.join(map(str, a.shape))}]" for a in held}
+    assert copied and not copied & pools, sorted(copied & pools)
+
+
+@pytest.mark.parametrize("pages", [8, 16])
+def test_dense_decode_window_gathers_no_window_and_copies_no_pool(v5e, pages):
+    """The llama skeleton's decode window at ``qwen2-7b-1chip``'s
+    geometry (16 slots, pages of 128 tokens, 28/4 heads of 128; four of
+    its layers, bfloat16 weights: the property is a layer's): the walk
+    reads the pool where it lies. A layer sliced out of the pool for the
+    walk's loops was a copy of it (67 MB a layer) in this PR's first
+    version, and a page re-laid to ``[page, Hkv*D]`` another."""
+    import dataclasses
+
+    from aigw_tpu.models import llama
+    from aigw_tpu.models.registry import get_model_spec
+
+    cfg = dataclasses.replace(get_model_spec("qwen2-7b").config, n_layers=4)
+    slots, page = 16, 128
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    p = sds(jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg)))
+    kv = jax.ShapeDtypeStruct(
+        (cfg.n_layers, 2, (slots * 16 + 1) * page, cfg.n_kv_heads,
+         cfg.head_dim), jnp.bfloat16, sharding=v5e)
+    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
+
+    def window(p, kv, tokens, positions, page_table, active):
+        def body(carry, _):
+            kv, tokens, positions = carry
+            logits, kv = llama.decode_step(
+                p, cfg, tokens, positions, kv, page_table, page, active)
+            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (kv, tokens, positions + 1), tokens
+
+        return jax.lax.scan(body, (kv, tokens, positions), None, length=2)
+
+    text = jax.jit(window, donate_argnums=(1,)).lower(
+        p, kv, i32, i32,
+        jax.ShapeDtypeStruct((slots, pages), jnp.int32, sharding=v5e),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
+    ).compile().as_text()
+    _no_window_and_no_pool_copy(
+        text, (slots, pages * page, cfg.n_kv_heads, cfg.head_dim), (kv,))

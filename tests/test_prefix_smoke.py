@@ -519,8 +519,8 @@ def test_state_and_metrics_export_memory_signals(smoke_url):
     assert state["kv_cache_dtype"] == "bfloat16"
     assert state["decode_backend"] == "auto"
     assert state["decode_attn_impl"] in (
-        "xla-gather", "pallas", "fused-xla", "fused-pallas",
-        "fused-xla-spmd")
+        "xla-walk", "xla-walk-spmd", "xla-gather", "pallas", "fused-xla",
+        "fused-pallas", "fused-xla-spmd")
     text = asyncio.run(_get(smoke_url, "/metrics")).decode()
     for gauge in MEMORY_GAUGES:
         assert gauge in text, f"/metrics lost {gauge}"
@@ -554,7 +554,7 @@ def test_state_and_metrics_export_mesh_signals(smoke_url):
     assert state["param_bytes_per_device"]
     assert state["ici_bytes_per_token"] == 0  # unsharded: no ICI
     assert state["migration"] is True
-    assert state["decode_attn_impl"] in ("xla-gather", "pallas")
+    assert state["decode_attn_impl"] in ("xla-walk", "pallas")
     text = asyncio.run(_get(smoke_url, "/metrics")).decode()
     for gauge in MESH_GAUGES:
         assert gauge in text, f"/metrics lost {gauge}"

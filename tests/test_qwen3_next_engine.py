@@ -141,7 +141,7 @@ def test_cache_description_and_what_is_off():
     assert not eng.migratable
     assert eng._spec_rungs == (0,)
     assert eng.attn.name == "xla-bucketed"
-    assert eng.decode_attn_impl == "xla-gather"
+    assert eng.decode_attn_impl == "xla-walk"
 
 
 def test_other_families_keep_everything_on():
@@ -174,12 +174,12 @@ def test_lora_refuses_at_start_up(kwargs):
 @pytest.mark.parametrize("requested", [
     dict(decode_backend="fused"), dict(pallas_attn=True),
     dict(decode_backend="fused", kv_cache_dtype="int8")])
-def test_decode_kernels_fall_back_to_the_gather(requested):
+def test_decode_kernels_fall_back_to_the_walk(requested):
     cfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=16,
                        **requested)
     impl, why = resolve_decode_backend(cfg, SHARE, None,
                                        family_fns("qwen3_next"))
-    assert impl == "xla-gather" and "no kernel rung" in why
+    assert impl == "xla-walk" and "no kernel rung" in why
     # the family's ModelFns says so, not the shape of its config
     assert resolve_decode_backend(
         cfg, SHARE, None, family_fns("llama")) == resolve_decode_backend(

@@ -223,9 +223,14 @@ class TestSpecEdges:
 
 
 class TestVerifyStep:
-    def test_matches_sequential_decode(self):
+    @pytest.mark.parametrize("rung,tol", [("gather", 2e-2), ("", 3e-2)],
+                             ids=["gather", "walk"])
+    def test_matches_sequential_decode(self, rung, tol):
         """verify_step's logits at every position equal running
-        decode_step one token at a time over the same inputs."""
+        decode_step one token at a time over the same inputs: on the
+        window gather, which is verify_step's own attention, and — a
+        bfloat16 rounding further, since the walk accumulates the
+        values in float32 across pages — on the page walk."""
         cfg = llama.TINY
         params = llama.init_params(jax.random.PRNGKey(1), cfg)
         ps = 16
@@ -246,7 +251,7 @@ class TestVerifyStep:
             lg, kv = llama.decode_step(
                 params, cfg, jnp.asarray([tok], jnp.int32),
                 jnp.asarray([5 + d], jnp.int32), kv, page_table, ps,
-                jnp.asarray([True]))
+                jnp.asarray([True]), attn_impl=rung)
             seq_logits.append(np.asarray(lg[0]))
 
         # one verify step
@@ -260,7 +265,7 @@ class TestVerifyStep:
         ver = np.asarray(ver[0])
         for d in range(len(inputs)):
             np.testing.assert_allclose(ver[d], seq_logits[d],
-                                       rtol=2e-2, atol=2e-2)
+                                       rtol=tol, atol=tol)
 
     def test_limit_fence_blocks_writes(self):
         """Positions at/past `limits` must not be written (page safety)."""

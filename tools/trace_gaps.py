@@ -11,7 +11,7 @@ directory its reply names, or the ``.xplane.pb`` inside it) and prints
   ``slots``, prefill ``bucket``/``tokens``, ``pages`` of a row update)
   and the ``request/*`` marks that fall inside it;
 - device seconds per named scope of the programs (``embed``,
-  ``layer/attn``, ``layer/kv_gather``, ``layer/mlp``,
+  ``layer/attn``, ``layer/kv_walk``, ``layer/kv_gather``, ``layer/mlp``,
   ``layer/moe_route``, ``layer/moe_experts``, ``lm_head``, ``sample``),
   as SELF time: an operation that contains others (the decode scan's
   ``while``) is charged only what its children leave;
