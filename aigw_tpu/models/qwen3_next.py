@@ -38,6 +38,7 @@ source that is not served (ROADMAP.md M6).
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 
@@ -50,6 +51,10 @@ from aigw_tpu.models.cache import CacheSpec, StateCache
 
 #: tokens per block of the chunked (WY) DeltaNet form
 GDN_CHUNK = 64
+
+#: state bytes a trip of the decode step's live-row loop should move
+#: (``state_rows``)
+_STATE_TRIP_BYTES = 4 << 20
 
 _HI = lax.Precision.HIGHEST
 
@@ -150,6 +155,14 @@ class Qwen3NextConfig:
         assignment the router made (held or absent), then how many held
         experts got at least one."""
         return self.num_experts + 3
+
+    @property
+    def decode_tape_width(self) -> int:
+        """A DECODE step's row is two columns wider: slots whose state
+        the layer's live-row loop read, and live rows (0 in a
+        full-attention layer's row). The sequence programs keep
+        ``moe_tape_width``, and with it their compile-cache keys."""
+        return self.moe_tape_width + 2
 
     def cache_spec(self) -> CacheSpec:
         return CacheSpec(
@@ -409,6 +422,63 @@ def _gdn_recurrent(q, k, v, g, beta, state):
     u = (v - kv) * beta[..., None]
     state = state + k[..., :, None] * u[..., None, :]
     return jnp.sum(state * q[..., :, None], axis=-2), state
+
+
+def state_rows(n_rows: int, row_bytes: int) -> int:
+    """Rows a trip of :func:`_gdn_live_rows` takes (``Rs``), from the
+    program's shapes alone: the decode rows and the bytes of one slot's
+    state in a layer. On a v5e a row of 2 MiB costs 13-15 us (read
+    twice, written once) and a trip 3 us of its own; a lane that pads
+    a step's last trip costs a row — and two or three live rows of
+    thirty-two is the common step — so a trip moves about
+    ``_STATE_TRIP_BYTES`` (PERF.md, PR 35)."""
+    return max(1, min(n_rows, _STATE_TRIP_BYTES // max(row_bytes, 1)))
+
+
+@jax.named_scope("layer/gdn_recurrent")
+@functools.partial(jax.jit, static_argnames=("Rs",))
+def _gdn_live_rows(q, k, v, g, beta, pool, layer, order, n_live, n_trips,
+                   *, Rs):
+    """One token of the gated delta rule for a decode step's LIVE rows
+    only. q, k [B,H,dk]; v [B,H,dv]; g, beta [B,H]; ``pool`` is the
+    whole state pool [L,B,H,dk,dv]; ``order`` ranks the ``n_live`` live
+    rows first (the step's ``WalkPlan.order``) and ``n_trips`` blocks
+    of ``Rs`` rows hold them. A trip takes its rows one after another:
+    a row's state is read out of ``pool[layer]`` where it lies (the
+    slice is an operand of :func:`_gdn_recurrent`'s sums, no copy),
+    updated and stored back in place. A lane past the live rows redoes
+    the trip's first row and stores what it read. A slot that is not
+    live is neither read nor written, and its output row is zero.
+    Returns (out [B,H,dv], pool).
+
+    Jitted on its own so that a decode program traces the loop ONCE for
+    all its DeltaNet layers (``layer`` is traced), as
+    ``paged_walk._walk`` is; and the WHOLE pool goes through the loop,
+    viewed as a list of slots: a layer sliced out of it would be a copy
+    of that layer's 67 MB."""
+    B = q.shape[0]
+    L, N = pool.shape[:2]
+    flat = pool.reshape(L * N, *pool.shape[2:])
+
+    def trip(t, carry):
+        flat, out = carry
+        for lane in range(Rs):
+            live = t * Rs + lane < n_live
+            row = order[jnp.where(live, t * Rs + lane, t * Rs)]
+            at = (layer * N + row, 0, 0, 0)
+            old = lax.dynamic_slice(flat, at, (1, *flat.shape[1:]))
+            *qkvgb, o_old = (lax.dynamic_slice_in_dim(x, row, 1, 0)
+                             for x in (q, k, v, g, beta, out))
+            o, new = _gdn_recurrent(*qkvgb, old)
+            flat = lax.dynamic_update_slice(
+                flat, jnp.where(live, new, old), at)
+            out = lax.dynamic_update_slice(
+                out, jnp.where(live, o, o_old), (row, 0, 0))
+        return flat, out
+
+    flat, out = lax.fori_loop(
+        0, n_trips, trip, (flat, jnp.zeros((B, *v.shape[1:]), jnp.float32)))
+    return out, flat.reshape(pool.shape)
 
 
 @jax.named_scope("layer/gdn_chunk")
@@ -689,8 +759,15 @@ def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
     if walk is None:
         walk = kvq.walk_plan(kv, lengths, page_table.shape[1], page_size,
                              mesh)
-    act = active.astype(jnp.float32)[:, None]
     n_valid = active.astype(jnp.int32)
+    # the DeltaNet layers' loop over the live rows' state: the plan
+    # ranks them first; ONE trip count bounds every layer's loop and
+    # is what the tape counts as read
+    pool = slots["gdn_state"]
+    Rs = state_rows(B, math.prod(pool.shape[2:]) * pool.dtype.itemsize)
+    n_live = jnp.sum(n_valid)
+    n_trips = -(-n_live // Rs)
+    state_read = jnp.stack([n_trips * Rs, n_live])
 
     def linear(i, j, h):
         # the state pool comes to the layer WITH the layer's input: the
@@ -704,10 +781,10 @@ def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
         mixed, z, beta, g = _gdn_project(p, i, h, cfg)
         y, tail = _gdn_conv(p, i, mixed, slots["gdn_conv"][j], n_valid)
         q, k, v = _gdn_heads(y, cfg)
-        o, state = _gdn_recurrent(
-            q[:, 0], k[:, 0], v[:, 0], g[:, 0] * act, beta[:, 0] * act,
-            slots["gdn_state"][j])
-        slots["gdn_state"] = slots["gdn_state"].at[j].set(state)
+        o, slots["gdn_state"] = _gdn_live_rows(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+            slots["gdn_state"], jnp.asarray(j, jnp.int32), walk.order,
+            n_live, n_trips, Rs=Rs)
         slots["gdn_conv"] = slots["gdn_conv"].at[j].set(tail)
         return _gdn_out(p, i, o[:, None], z, cfg, h.dtype)
 
@@ -722,5 +799,10 @@ def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
 
     x = _blocks(p, cfg, llama._embed_rows(p, tokens[:, None]), linear, full,
                 active[:, None], tape)
+    if moe_stats:
+        # decode_tape_width: what each DeltaNet layer's loop read
+        tape = [jnp.concatenate(
+            [row, state_read if kind == "linear" else jnp.zeros_like(
+                state_read)]) for row, kind in zip(tape, cfg.layer_kinds)]
     return _finish(_logits(p, x[:, 0]), StateCache(kv, slots), tape,
                    moe_stats)

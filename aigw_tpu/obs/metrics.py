@@ -129,6 +129,8 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     ("sample_sort_steps", "tpuserve_sample_sort_steps_total"),
     ("decode_kv_pages_read", "tpuserve_decode_kv_pages_read_total"),
     ("decode_kv_pages_live", "tpuserve_decode_kv_pages_live_total"),
+    ("decode_state_rows_read", "tpuserve_decode_state_rows_read_total"),
+    ("decode_state_rows_live", "tpuserve_decode_state_rows_live_total"),
     ("decode_window", "tpuserve_decode_window_steps"),
     ("window_shrinks", "tpuserve_decode_window_shrinks_total"),
     ("window_grows", "tpuserve_decode_window_grows_total"),

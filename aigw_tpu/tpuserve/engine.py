@@ -698,6 +698,13 @@ class EngineStats:
     # amplification; 1.0 reads exactly what is live
     decode_kv_pages_read: int = 0
     decode_kv_pages_live: int = 0
+    # what the decode programs of a per-slot-state family read of the
+    # state pool, a layer, counted on the device per step: slots whose
+    # state a DeltaNet layer's live-row loop read (its trips x rows a
+    # trip: ONE value bounds the loop and is counted) and the live
+    # rows. read / live is the state read amplification (0 elsewhere)
+    decode_state_rows_read: int = 0
+    decode_state_rows_live: int = 0
     prefix_cache_hits: int = 0
     prefix_tokens_reused: int = 0
     # prefix-cache surface (ISSUE 3): misses counted over page-eligible
@@ -1218,6 +1225,10 @@ class Engine:
             max(int(model_cfg.n_layers), 1), np.int64)
 
         tape_width = self._moe_tape_width
+        # a decode step's row may be wider than a sequence program's
+        # (a per-slot-state family adds what its state loops read)
+        decode_tape_width = int(getattr(
+            model_cfg, "decode_tape_width", tape_width))
         # per-slot-state families: each prefill row names the decode
         # slot whose state it continues (a leafless None elsewhere, so
         # the other families' programs and cache keys are unchanged)
@@ -1428,7 +1439,7 @@ class Engine:
                 return (kv, new, macc, pages), sampled
 
             def scan_k(params, lora, kv, state):
-                macc0 = (jnp.zeros((mc.n_layers, tape_width),
+                macc0 = (jnp.zeros((mc.n_layers, decode_tape_width),
                                    jnp.int32) if is_moe else None)
                 (kv, state, macc, pages), sampled = jax.lax.scan(
                     lambda c, _: body(params, lora, c),
@@ -4467,21 +4478,29 @@ class Engine:
         """Fold one program's [L, width] routing-stats leaf (per-expert
         placed counts + capacity drops per layer; a family that holds a
         share of its experts adds every assignment routed and the held
-        experts hit) into the numpy accumulators behind the /state MoE
-        surface. ``decode``: the leaf is a decode window's. No-op
-        (None) on dense families — call sites stay uniform."""
+        experts hit, and in a decode window the slots whose state its
+        loops read and the live rows) into the numpy accumulators behind
+        the /state MoE surface. ``decode``: the leaf is a decode
+        window's. No-op (None) on dense families — call sites stay
+        uniform."""
         if moe is None:
             return
         arr = np.asarray(moe, np.int64)
         E = self._moe_experts
         self._moe_expert_tokens += arr[:, :E].sum(axis=0)
         self._moe_layer_drops += arr[:, E]
+        st = self.stats
         if arr.shape[1] > E + 1:
-            st = self.stats
             st.moe_local_assignments += int(arr[:, :E].sum())
             st.moe_total_assignments += int(arr[:, E + 1].sum())
             if decode:
                 st.moe_held_hits_decode += int(arr[:, E + 2].sum())
+        if arr.shape[1] > E + 3:
+            # a decode window's two state columns: every DeltaNet
+            # layer's loop ran the step's one trip count, so the
+            # largest row is a layer's (the others hold 0)
+            st.decode_state_rows_read += int(arr[:, E + 3].max())
+            st.decode_state_rows_live += int(arr[:, E + 4].max())
 
     def moe_expert_load(self) -> list[int]:
         """Per-expert placed-token totals [E] for /state and the

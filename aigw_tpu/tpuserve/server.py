@@ -2337,6 +2337,8 @@ class TPUServeServer:
                 "sample_sort_steps": s.sample_sort_steps,
                 "decode_kv_pages_read": s.decode_kv_pages_read,
                 "decode_kv_pages_live": s.decode_kv_pages_live,
+                "decode_state_rows_read": s.decode_state_rows_read,
+                "decode_state_rows_live": s.decode_state_rows_live,
                 "decode_window": s.decode_window,
                 "prefill_ms": round(s.prefill_ms, 3),
                 "transfer_ms": round(s.transfer_ms, 3),

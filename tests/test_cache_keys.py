@@ -15,6 +15,10 @@ The decode goldens of the three families were re-taken from the tree
 of ISSUE 31, whose decode step walks the page pool: every decode
 program's key moved once with it (one cold compile a deployment), and
 the hybrid family's decode programs, which had no golden, have one now.
+ISSUE 35 moved those two alone (the DeltaNet state update over the
+live rows, two more columns on the decode tape): the llama and mixtral
+decode programs and the hybrid family's prefill, chunk and tail
+programs stand as they stood at ec98829.
 
 Taken by ``python tests/engine_keys_child.py [<checkout>]`` under the
 JAX named below. Another JAX lowers to other text and the comparison
@@ -55,9 +59,9 @@ GOLDEN = {
     "tiny-qwen3-next.prefill_suffix":
         "7af8902797042320b451ce03ecfd320c9a7c81aa2c6a81ea56965151b399f382",
     "tiny-qwen3-next.decode.lean=True":
-        "8a22cc8912b41ff35d436a7464ff86f9440367dce1197a87fdfb124de6033e70",
+        "21dda6cac9173a81019de529b8b623591b628af3bbc30a46e223c25d809da644",
     "tiny-qwen3-next.decode.lean=False":
-        "4a1db35ed98bd24fc7b1c88a6d0eb56b0829eb3a6d04de665c6590d865d36e9c",
+        "7b3d133a74f4bdee021df1dbcc5a2c0d8cf357d076a93830a10d8814062896d3",
 }
 
 

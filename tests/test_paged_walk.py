@@ -236,8 +236,9 @@ def test_hybrid_decode_step_leaves_dead_rows_state_and_pages():
 
 # -- the counters that say what the decode programs read ---------------------
 
-@pytest.mark.parametrize("key", ["decode_kv_pages_read",
-                                 "decode_kv_pages_live"])
+@pytest.mark.parametrize("key", [
+    "decode_kv_pages_read", "decode_kv_pages_live",
+    "decode_state_rows_read", "decode_state_rows_live"])
 def test_counter_is_a_gauge_and_a_state_key(key):
     from aigw_tpu.obs.metrics import ENGINE_GAUGES, render_engine_gauges
     from aigw_tpu.tpuserve.engine import EngineStats
@@ -247,6 +248,41 @@ def test_counter_is_a_gauge_and_a_state_key(key):
     setattr(stats, key, 12)
     assert (f"\ntpuserve_{key}_total 12\n".encode()
             in render_engine_gauges(stats))
+
+
+@pytest.mark.parametrize("metric,read_key,live_key", [
+    ("decode_kv_read_amp", "decode_kv_pages_read", "decode_kv_pages_live"),
+    ("decode_state_read_amp", "decode_state_rows_read",
+     "decode_state_rows_live")])
+def test_read_amp_metric_reads_the_two_counters_or_says_nothing(
+        metric, read_key, live_key):
+    """The per-layer metric as its data file has it, through the reader
+    the file names: the ratio of the counters' deltas over the window,
+    and nothing (no ``KeyError``) on a /state without them — a commit
+    from before the counters, whose traced run must still give a line."""
+    import importlib
+    import json
+    import os
+
+    from aigw_tpu.tpuserve.engine import EngineStats
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "cellbench", "layer_metrics",
+                           metric + ".json")) as f:
+        spec = json.load(f)
+    assert spec["args"] == {"num": [read_key], "den": [live_key]}
+    assert hasattr(EngineStats(), read_key)
+    assert hasattr(EngineStats(), live_key)
+    reader = importlib.import_module("cellbench.readers." + spec["reader"])
+
+    def ctx(s0, s1):
+        return {"snap0": {"state": s0}, "snap1": {"state": s1}}
+
+    got = reader.read(ctx({read_key: 10, live_key: 8},
+                          {read_key: 250, live_key: 168}), spec["args"])
+    assert got == pytest.approx(1.5)
+    assert reader.read(ctx({"decode_steps": 0}, {"decode_steps": 9}),
+                       spec["args"]) is None
 
 
 def _stream(eng, prompt, n):

@@ -110,7 +110,10 @@ def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e, pages):
     input. A copy of a pool or of an expert matrix is an instruction
     of that shape in the compiled program. Since ISSUE 31 neither is
     a gathered ``[32, P*128, 2, 256]`` window: the cell's two page
-    buckets (16 and 32) are the two programs compiled here."""
+    buckets (16 and 32) are the two programs compiled here. Since
+    ISSUE 35 no array of a whole layer's state is in it either: the
+    DeltaNet update reads the live rows' ``[1, 32, 128, 128]`` slices
+    out of the pool where they lie and stores them back in place."""
     from aigw_tpu.models import qwen3_next as qn
 
     cfg = qn.Qwen3NextConfig(num_hidden_layers=12, num_experts=128,
@@ -149,6 +152,9 @@ def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e, pages):
         text, (slots, pages * page, cfg.num_key_value_heads, cfg.head_dim),
         (*jax.tree_util.tree_leaves(cache),
          p["l0.experts_gate"], p["l0.experts_down"]))
+    layer_state = cache.slots["gdn_state"].shape[1:]
+    assert layer_state == (32, 32, 128, 128)
+    assert f"f32[{','.join(map(str, layer_state))}]" not in text
 
 
 def _no_window_and_no_pool_copy(text, window, held):

@@ -27,6 +27,7 @@ import pytest
 
 from aigw_tpu.models import qwen3_next as qn
 from aigw_tpu.models.cache import StateCache
+from aigw_tpu.ops import paged_walk
 from aigw_tpu.models.reference import qwen3_next_ref as ref
 from qwen3_next_util import SHARE, make_cache, make_params, ref_logits
 
@@ -413,7 +414,10 @@ def test_decode_step_loops_over_the_experts_live_rows_hit(
     trips: list[int] = []
 
     def fori_loop(lower, upper, body, init):
-        trips.append(int(upper))
+        # (the DeltaNet layers' live-row loop is jitted on its own: its
+        # bound is a tracer here, and not this test's)
+        if not isinstance(upper, jax.core.Tracer):
+            trips.append(int(upper))
         return jax.lax.fori_loop(lower, upper, body, init)
 
     def step(dead_tokens, active, moe=None):
@@ -456,3 +460,141 @@ def test_decode_step_loops_over_the_experts_live_rows_hit(
         assert looped == [0] * cfg.num_hidden_layers  # the shared expert
     else:
         assert looped == [E] * cfg.num_hidden_layers
+
+
+# -- the decode step updates the state of its live rows, and no other ------
+def _pool_wide(q, k, v, g, beta, pool, layer, order, n_live, n_trips, *,
+               Rs):
+    """The formulation before ISSUE 35: ``_gdn_recurrent`` over every
+    slot of the layer, a row that is not live decayed by ``exp(0)`` and
+    given ``0``, and the whole layer stored back."""
+    B = q.shape[0]
+    act = jnp.zeros((B + 1,), jnp.float32).at[order].set(
+        (jnp.arange(order.shape[0]) < n_live).astype(jnp.float32))[:B, None]
+    o, state = qn._gdn_recurrent(q, k, v, g * act, beta * act, pool[layer])
+    return o, pool.at[layer].set(state)
+
+
+LIVE_SETS = {"none": [], "one": [5], "three_scattered": [6, 0, 3],
+             "five": [1, 2, 4, 6, 7], "all": list(range(8))}
+
+
+@pytest.mark.parametrize("Rs", [1, 2, 3])
+@pytest.mark.parametrize("live", list(LIVE_SETS))
+def test_live_row_update_is_the_pool_wide_pass_on_live_rows(live, Rs):
+    """Eight slots, three layers of state; the rows in ``live`` decode.
+    Their new state and output are the pool-wide pass's, every other
+    slot of every layer keeps its bits, and the loop makes
+    ``ceil(live / Rs)`` trips (five rows are no multiple of 2 or 3)."""
+    L, B, H, dk, dv = 3, 8, 4, 16, 16
+    rows = LIVE_SETS[live]
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    q, k = (jax.random.normal(ks[i], (B, H, dk), jnp.float32) * 0.3
+            for i in (0, 1))
+    v = jax.random.normal(ks[2], (B, H, dv), jnp.float32)
+    g = -jax.random.uniform(ks[3], (B, H), jnp.float32)
+    beta = jax.random.uniform(ks[4], (B, H), jnp.float32)
+    pool = jax.random.normal(ks[5], (L, B, H, dk, dv), jnp.float32)
+    lengths = np.zeros((B,), np.int32)
+    lengths[rows] = [17 + 29 * r for r in rows]  # the plan ranks by these
+    plan = paged_walk.walk_plan(jnp.asarray(lengths), 16, PS, 1 << 12)
+    n_live = jnp.asarray(len(rows), jnp.int32)
+    n_trips = -(-n_live // Rs)
+    layer = jnp.asarray(1, jnp.int32)
+    args = (q, k, v, g, beta, pool, layer, plan.order, n_live, n_trips)
+    want_o, want = _pool_wide(*args, Rs=Rs)
+    got_o, got = qn._gdn_live_rows(*args, Rs=Rs)
+    got, got_o, before = np.asarray(got), np.asarray(got_o), np.asarray(pool)
+    dead = [r for r in range(B) if r not in rows]
+    np.testing.assert_allclose(got[1, rows], np.asarray(want)[1, rows],
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(got_o[rows], np.asarray(want_o)[rows],
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_array_equal(got[1, dead], before[1, dead])
+    np.testing.assert_array_equal(got[[0, 2]], before[[0, 2]])
+    assert not got_o[dead].any()
+    if rows:  # a live row did move
+        assert np.abs(got[1, rows] - before[1, rows]).max() > 1e-3
+
+
+def test_state_rows_follow_the_shapes():
+    """``Rs`` from the decode rows and one slot's bytes a layer: 2 at
+    the published widths (2 MiB a slot), never more than the rows."""
+    pub = qn.Qwen3NextConfig()
+    row = (pub.linear_num_value_heads * pub.linear_key_head_dim
+           * pub.linear_value_head_dim * 4)
+    assert row == 2 << 20 and qn.state_rows(32, row) == 2
+    assert qn.state_rows(32, 8 * row) == 1
+    assert qn.state_rows(4, 4096) == 4
+
+
+@pytest.mark.parametrize("active", [
+    [False, True, True, True], [False, False, False, True],
+    [True, True, True, True], [False, False, False, False]],
+    ids=["three_of_four", "one", "all", "none"])
+def test_decode_steps_update_live_slots_only_and_decode_the_same_tokens(
+        active, monkeypatch):
+    """Four slots prefilled, ``active`` of them decode twelve greedy
+    steps in blocks of two rows (``Rs`` forced under the slot count:
+    three live rows are no multiple of it). Against the pool-wide
+    pass: the same tokens, logits within float32 rounding, the live
+    slots' state too; a slot that does not decode keeps its state and
+    its convolution tail to the bit; the tape's two state columns are
+    the loop's trips x ``Rs`` and the live rows in every DeltaNet
+    layer's row, and 0 in a full-attention layer's."""
+    cfg = SHARE
+    p = make_params(cfg)
+    lens = [21, 40, 9, 33]
+    tokens = np.zeros((4, 48), np.int32)
+    for r, n in enumerate(lens):
+        tokens[r, :n] = _tokens(cfg, n, seed=30 + r)
+    rows = [list(range(1 + 4 * r, 5 + 4 * r)) for r in range(4)]
+    logits, cache0 = _prefill(cfg)(
+        p, tokens=jnp.asarray(tokens), seq_lens=jnp.asarray(lens, jnp.int32),
+        cache=make_cache(cfg, 16, PS, 4), page_table=_page_table(rows, 4))
+    first = jnp.argmax(logits, -1).astype(jnp.int32)
+    row_bytes = int(np.prod(cache0.slots["gdn_state"].shape[2:])) * 4
+    monkeypatch.setattr(qn, "_STATE_TRIP_BYTES", 2 * row_bytes)
+    act = jnp.asarray(active)
+    n_live, E = sum(active), cfg.num_experts
+
+    def run(steps=12):
+        step = jax.jit(partial(qn.decode_step, cfg=cfg, page_size=PS,
+                               moe_stats=True))
+        cache, toks, pos = cache0, first, jnp.asarray(lens, jnp.int32)
+        out = []
+        for _ in range(steps):
+            logits, cache, tape = step(
+                p, tokens=toks, positions=pos, cache=cache,
+                page_table=_page_table(rows, 4), active=act)
+            toks = jnp.where(act, jnp.argmax(logits, -1).astype(jnp.int32),
+                             toks)
+            pos = pos + act
+            out.append((np.asarray(toks), np.asarray(logits),
+                        np.asarray(tape)))
+        return out, cache
+
+    got, cache = run()
+    monkeypatch.setattr(qn, "_gdn_live_rows", _pool_wide)
+    want, parent = run()
+    for (t, lg, tape), (t_w, lg_w, _) in zip(got, want):
+        np.testing.assert_array_equal(t, t_w)
+        if n_live:
+            assert _err(lg[np.asarray(active)],
+                        lg_w[np.asarray(active)]) < 2e-5
+        assert tape.shape == (cfg.num_hidden_layers, cfg.decode_tape_width)
+        lin = np.asarray([kind == "linear" for kind in cfg.layer_kinds])
+        assert (tape[lin, E + 3] == 2 * -(-n_live // 2)).all()
+        assert (tape[lin, E + 4] == n_live).all()
+        assert not tape[~lin, E + 3:].any()
+    for name in ("gdn_state", "gdn_conv"):
+        new, old, ref_ = (np.asarray(c.slots[name])
+                          for c in (cache, cache0, parent))
+        for s, live in enumerate(active):
+            if live:
+                # rounding of twelve steps through eight layers
+                np.testing.assert_allclose(new[:, s], ref_[:, s],
+                                           rtol=1e-4, atol=2e-5)
+                assert not np.array_equal(new[:, s], old[:, s])
+            else:
+                np.testing.assert_array_equal(new[:, s], old[:, s])

@@ -14,6 +14,7 @@ only is off for the family, by what the family is."""
 from __future__ import annotations
 
 import threading
+import time
 
 import jax
 import numpy as np
@@ -204,3 +205,53 @@ def test_registered_preset_and_config_surface():
     assert (cfg.n_layers, cfg.n_experts, cfg.router_width) == (12, 128, 512)
     assert cfg.n_full_layers == 3 and cfg.n_linear_layers == 9
     assert cfg.moe_tape_width == 131
+
+
+def _settled(st, quiet: float = 1.0) -> tuple[int, int]:
+    """(rows_read, rows_live) once no window has been folded for
+    ``quiet`` seconds: a window's tape is folded after its tokens are
+    emitted, so a finished stream may still have one to come."""
+    seen, since = None, time.monotonic()
+    while time.monotonic() - since < quiet:
+        now = (st.decode_state_rows_read, st.decode_state_rows_live)
+        if now != seen:
+            seen, since = now, time.monotonic()
+        time.sleep(0.05)
+    return seen
+
+
+def test_engine_counts_the_state_rows_its_decode_windows_read(monkeypatch):
+    """Four slots, blocks of two rows (``Rs`` forced under the slot
+    count). One request alone, then three together. ``rows_live``: a
+    live row for every decode step a request took — its first token is
+    the prefill's, and the device may run one step past its last.
+    ``rows_read``: the loops' trips x 2 — twice the live rows while one
+    decodes, between the live rows and a block more than them a step
+    while three do."""
+    cfg = SHARE
+    row = (cfg.linear_num_value_heads * cfg.linear_key_head_dim
+           * cfg.linear_value_head_dim * 4)
+    monkeypatch.setattr(qn, "_STATE_TRIP_BYTES", 2 * row)
+    eng = _engine(cfg, max_batch_size=4, logprobs_topk=0)
+    eng.start()
+    try:
+        alone = _Stream(cfg, 20, 14, seed=5)
+        eng.submit(alone.req)
+        assert alone.done.wait(600)
+        st = eng.stats
+        read1, live1 = _settled(st)
+        assert live1 in (13, 14) and read1 == 2 * live1
+        steps = st.decode_steps
+        three = [_Stream(cfg, 18 + i, 9 + 4 * i, seed=6 + i)
+                 for i in range(3)]
+        for s in three:
+            eng.submit(s.req)
+        for s in three:
+            assert s.done.wait(600)
+        read, live = _settled(st)
+    finally:
+        eng.stop()
+    read, live = read - read1, live - live1
+    assert [len(s.tokens) for s in three] == [9, 13, 17]
+    assert 8 + 12 + 16 <= live <= 9 + 13 + 17
+    assert read % 2 == 0 and live <= read <= live + (st.decode_steps - steps)
