@@ -1182,14 +1182,14 @@ class Engine:
                 and self.decode_attn_impl == "xla-gather":
             logger.warning("decode backend fell back to xla-gather: %s",
                            self.decode_attn_reason)
-        # decode_step's attn_impl argument + whether it needs the mesh
+        # decode_step's attn_impl argument
         attn_impl = {
             "xla-gather": "gather",
             "pallas": "pallas",
             "fused-pallas": "fused-pallas",
         }.get(self.decode_attn_impl, "")  # "": the page walk
-        decode_mesh = mesh if self.decode_attn_impl.endswith("-spmd") \
-            else None
+        # (every rung hands the mesh on: the -spmd walks run under it,
+        # and a family whose decode MLP differs under one must see it)
         # the speculative verify step keeps the chained path at every
         # rung: its multi-position kernel has no fused port, and the
         # gather-dequant path serves quantized pools
@@ -1368,7 +1368,7 @@ class Engine:
             lengths = jnp.where(act, st["positions"] + 1, 0)
             B, P = st["page_table"].shape
             plan = kvq.walk_plan(kv.kv if stateful else kv, lengths, P,
-                                 ps, decode_mesh) if walks else None
+                                 ps, mesh) if walks else None
             read = plan.pages_read if walks else B * P
             return plan, pages + jnp.stack([
                 jnp.asarray(read, jnp.int32),
@@ -1401,7 +1401,7 @@ class Engine:
                     params, mc, st["tokens"], st["positions"], kv,
                     st["page_table"], ps, act,
                     lora=lora, adapter_idx=st["adapter_idx"],
-                    attn_impl=attn_impl, mesh=decode_mesh, walk=walk,
+                    attn_impl=attn_impl, mesh=mesh, walk=walk,
                     **moe_kw))
                 macc = macc if moe is None else macc + moe
                 if lean:

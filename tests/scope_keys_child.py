@@ -2,8 +2,8 @@
 prefill step of each model family (tiny widths, W8A16 so the Pallas
 matmul is in the program) and prints, per program, the hash JAX's
 persistent compile cache takes of the computation, the named scopes
-its text carries and the name stacks of the operations inside a
-conditional's branches. With ``--no-scopes`` ``jax.named_scope`` is a no-op
+its text carries, which of them hold a loop, and the name stacks of
+the operations inside a conditional's branches. With ``--no-scopes`` ``jax.named_scope`` is a no-op
 from before the first import of the program, which is the tree without
 this PR's scopes. With ``--tpu`` the programs are lowered for a
 described (not attached) TPU v5e, Mosaic kernels included.
@@ -144,10 +144,18 @@ def main(argv: list[str]) -> int:
                 st for st in stacks
                 if re.search(r"(?:^|/)cond/branch_\d+_fun/", st)
                 and not st.endswith("_fun/jit")})
+            # the scopes that hold a loop of their own: an operation of
+            # a body is named "…layer/moe_experts/while/body/dot_general",
+            # or the scope calls the one jitted expert loop that every
+            # layer of a mixtral decode step shares ("…/jit(_hit_experts)";
+            # its body's names are relative to that call)
+            loops = sorted({m for st in stacks for m in re.findall(
+                r"(?:^|/)(layer/[a-z_]+)/(?:while/body/|jit\(_hit_experts\)$)",
+                st)})
             out[f"{fam}.{name}"] = {
                 "key": h.hexdigest(), "scopes": scopes,
                 "mosaic": "tpu_custom_call" in text,
-                "in_cond": in_cond,
+                "in_cond": in_cond, "loops": loops,
                 "sorts": text.count("stablehlo.sort")}
     print(json.dumps(out))
     return 0

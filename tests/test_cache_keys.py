@@ -18,7 +18,12 @@ the hybrid family's decode programs, which had no golden, have one now.
 ISSUE 35 moved those two alone (the DeltaNet state update over the
 live rows, two more columns on the decode tape): the llama and mixtral
 decode programs and the hybrid family's prefill, chunk and tail
-programs stand as they stood at ec98829.
+programs stand as they stood at ec98829. ISSUE 41 moved mixtral's two
+decode programs alone (the loop over the experts live rows hit, two
+more columns on the decode tape); its prefill and chunk programs keep
+their keys through the move of ``moe_mlp``'s router and fence into
+helpers the decode step shares, which is the proof that the move
+changed nothing a sequence computes.
 
 Taken by ``python tests/engine_keys_child.py [<checkout>]`` under the
 JAX named below. Another JAX lowers to other text and the comparison
@@ -51,9 +56,9 @@ GOLDEN = {
     "tiny-moe.prefill_suffix":
         "284de1ca630b9e535842d620d29b02cb1cb4df4930bd3c954c77a28386bc5754",
     "tiny-moe.decode.lean=True":
-        "a518d8e5f27c9761b5960cab050c0a755761a2e0be8425f5292acca96db3787b",
+        "2ce402c0f801ade893f66b140d06bafe61c1bc45d9992ca721634fd978b34a40",
     "tiny-moe.decode.lean=False":
-        "1b225e11bba8cff736c2bc75c38a19a4776fcdc1039efbe5d0a8967fc646c0e8",
+        "4cf04bb77f1a463dfe420456984a0e75e4a5a1b11eb411c2579e7e3257aa529a",
     "tiny-qwen3-next.prefill":
         "27722e95630f8db99a3ed566f6e2f930108d9fb1bbeda62b2c787d70c34d52ae",
     "tiny-qwen3-next.prefill_suffix":

@@ -94,6 +94,20 @@ def test_guarded_sort_keeps_the_sample_scope(lowered, program):
 
 
 @pytest.mark.parametrize("program", sorted(WANT))
+def test_expert_layer_loops_in_a_decode_step_alone(lowered, program):
+    """A single-chip decode step of an MoE family runs one trip of a
+    loop for each expert its live rows hit (ISSUE 41: mixtral's, as the
+    hybrid family's), and that loop is the step's ONE expert path: no
+    conditional stands in the expert layer with a dense pass in its
+    other branch. A sequence program hits every expert and has no such
+    loop."""
+    got = lowered["scoped"][program]
+    assert ("layer/moe_experts" in got["loops"]) == (
+        program in ("mixtral.decode", "qwen3_next.decode"))
+    assert [st for st in got["in_cond"] if "layer/moe" in st] == []
+
+
+@pytest.mark.parametrize("program", sorted(WANT))
 def test_compile_cache_key_ignores_the_scopes(lowered, program):
     assert lowered["scoped"][program]["key"] == \
         lowered["bare"][program]["key"]

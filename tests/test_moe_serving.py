@@ -435,3 +435,31 @@ def test_moe_routing_stats_fold_and_refresh():
         int(x) for x in eng._moe_expert_tokens]
     assert eng.moe_layer_drops() == [
         int(x) for x in eng._moe_layer_drops]
+
+
+def test_decode_windows_count_the_experts_their_live_rows_hit():
+    """ISSUE 41: a single-chip decode step loops over the experts its
+    live rows hit, and the trip count rides the decode tape into
+    ``moe_held_hits_decode`` (the counter behind /state's key and the
+    benchmark's ``moe_held_experts_hit.mixtral``). One request is one
+    live row: every layer of every step in which it is live hits its
+    top-k experts and nothing else, whatever the other three slots
+    hold, and the fence drops nothing."""
+    eng = _engine()
+    eng.start()
+    try:
+        toks = _run(eng, [3, 1, 4, 1, 5, 9, 2, 6] * 2, mt=9)
+        _run(eng, [2, 7, 1, 8] * 3, mt=5)
+    finally:
+        eng.stop()  # (a window's fold settles after its last emit)
+    st = eng.stats
+    K, L = CFG.experts_per_token, CFG.n_layers
+    # a request's first token is its prefill's; the others are decode
+    # steps of one live row
+    assert len(toks) == 9
+    assert st.moe_held_hits_decode == K * L * (8 + 4)
+    # one live row: the experts hit are the assignments routed, and
+    # all were placed
+    assert st.moe_total_assignments == st.moe_held_hits_decode
+    assert st.moe_local_assignments == st.moe_total_assignments
+    assert st.decode_steps >= 12

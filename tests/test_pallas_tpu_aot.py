@@ -157,6 +157,71 @@ def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e, pages):
     assert f"f32[{','.join(map(str, layer_state))}]" not in text
 
 
+def test_mixtral_decode_window_holds_no_expert_slab(v5e):
+    """The mixtral decode window at ``mixtral-8x7b-1chip``'s geometry
+    (published widths, 8 layers, W8A16, 16 slots, pages of 128): the
+    loop over the experts the live rows hit slices one expert's int8
+    slab out of the stacked ``[8, in, out]`` weight where it lies, and
+    the convert-and-scale sits on the operand of the product that reads
+    it. A slab sliced, copied or dequantised into HBM first would be an
+    array of a slab's shape among the program's temporaries: they stay
+    under one expert's int8 bytes (3 x 4096 x 14336 = 176 MB; 161 MB
+    with the loop and 159 MB without it, nearly all of it the
+    ``lm_head`` re-laid for its Mosaic call), and no copy has the shape
+    of a slab or of a stacked weight. The loop is the expert layer's
+    one path (ISSUE 41): a ``while`` a layer carries its name, and the
+    program holds no conditional, so no dense pass is compiled, loaded
+    or kept warm beside it."""
+    import re
+
+    from aigw_tpu.models import mixtral
+    from aigw_tpu.models.quant import quantize_tensor
+
+    cfg = mixtral.MixtralConfig(n_layers=8)
+    slots, page, pages = 16, 128, 16
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    p = sds(jax.eval_shape(lambda: mixtral.init_params(
+        jax.random.PRNGKey(0), cfg,
+        finish=lambda n, w: quantize_tensor(n, w, "int8"))))
+    kv = jax.ShapeDtypeStruct(
+        (cfg.n_layers, 2, (slots * pages + 1) * page, cfg.n_kv_heads,
+         cfg.head_dim), jnp.bfloat16, sharding=v5e)
+    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
+
+    def window(p, kv, tokens, positions, page_table, active):
+        def body(carry, _):
+            kv, tokens, positions = carry
+            logits, kv, moe = mixtral.decode_step(
+                p, cfg, tokens, positions, kv, page_table, page, active,
+                moe_stats=True)
+            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (kv, tokens, positions + 1), (tokens, moe)
+
+        return jax.lax.scan(body, (kv, tokens, positions), None, length=2)
+
+    compiled = jax.jit(window, donate_argnums=(1,)).lower(
+        p, kv, i32, i32,
+        jax.ShapeDtypeStruct((slots, pages), jnp.int32, sharding=v5e),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
+    ).compile()
+    D, F, E = cfg.dim, cfg.ffn_dim, cfg.n_experts
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * D * F
+    text = compiled.as_text()
+    assert " conditional(" not in text
+    assert len(re.findall(
+        r' while\(.*op_name="[^"]*layer/moe_experts/[^"]*while"', text)
+    ) == cfg.n_layers
+    copied = set(re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", text))
+    slabs = {",".join(map(str, lead + dims))
+             for dims in ((D, F), (F, D)) for lead in ((), (1,), (E,))}
+    assert copied and not copied & slabs, sorted(copied & slabs)
+
+
 def _no_window_and_no_pool_copy(text, window, held):
     """The compiled decode window holds no array of a gathered
     ``[B, P*page, Hkv, D]`` window (the page walk reads whole pages of
