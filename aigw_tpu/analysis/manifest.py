@@ -170,6 +170,18 @@ GROUPS: dict[str, Group] = {
     # engine-truth usage metering (ISSUE 20): the MeterRecord counter
     # family the gateway's ledger reconciles against
     "meter": Group(prefixes=("meter_",)),
+    # the compile surface (ISSUE 42), all numeric gauges: the boot
+    # timeline boot_<phase>_ms (import, backend, weights, engine,
+    # warmup, listen: self time from the process's start) and their
+    # sum boot_ready_ms; the load ledger's totals by stage
+    # xla_trace_ms / xla_lower_ms / xla_retrieval_ms beside
+    # xla_compile_ms; and what requests waited for, xla_late_loads and
+    # xla_late_ms with its parts xla_late_{trace,lower,retrieval}_ms.
+    # boot_weights_ms is weights_init_ms + weights_quantize_ms.
+    "boot": Group(
+        prefixes=("boot_", "xla_"),
+        exact=("warmup_ms", "warm_programs", "weights_init_ms",
+               "weights_quantize_ms", "compile_cache_dir")),
 }
 
 #: /metrics substrings a group's smoke must also assert on but that are

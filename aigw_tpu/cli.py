@@ -13,6 +13,9 @@ to <dir>/aigw-status.json (config/controller.py).
 
 from __future__ import annotations
 
+# first: where there is no /proc, the boot timeline starts at this import
+import aigw_tpu.utils.boot  # noqa: F401, I001
+
 import argparse
 import asyncio
 import logging

@@ -178,6 +178,11 @@ class FlightRecorder:
                         return cand
         return None
 
+    def in_flight(self) -> list[FlightEntry]:
+        """The ring's entries that have not finished."""
+        with self._lock:
+            return [e for e in self._ring.values() if not e.finish]
+
     def snapshot(self) -> dict[str, Any]:
         with self._lock:
             recent = [e.summary() for e in
@@ -223,7 +228,7 @@ class RequestTrace:
 
     def _instant(self, name: str) -> None:
         if self.loop is not None:  # a no-op unless a capture runs
-            self.loop.instant(name, self.entry.rid)
+            self.loop.instant(name, rid=self.entry.rid)
 
     @property
     def trace_id(self) -> str:
@@ -441,11 +446,11 @@ class LoopLedger:
     def prefill_ns(self) -> int:
         return self.ns[PREFILL_DISPATCH] + self.ns[PREFILL_BLOCK]
 
-    def instant(self, name: str, rid: str) -> None:
+    def instant(self, name: str, **facts: Any) -> None:
         """A zero-length mark on the profiler's clock (engine thread,
         inside the running phase's span)."""
         if self._ann is not None:
-            a = _trace_annotation(name, rid=rid)
+            a = _trace_annotation(name, **facts)
             a.__enter__()
             a.__exit__(None, None, None)
 

@@ -181,6 +181,30 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     # is a LOAD when the cache served it — these tell the two apart
     ("xla_cache_hits", "tpuserve_xla_cache_hits_total"),
     ("xla_cache_misses", "tpuserve_xla_cache_misses_total"),
+    # the load ledger (ISSUE 42, obs/xla_events.py): what getting
+    # programs cost by stage, process-wide — Python tracing, lowering,
+    # and of the backend span above the compile cache's read +
+    # deserialize_and_load — and the LATE loads, the ones that came
+    # after the server called itself ready, so a request waited for
+    # them: tpuserve_xla_late_loads_total growing is the alert
+    ("xla_trace_ms", "tpuserve_xla_trace_ms_total"),
+    ("xla_lower_ms", "tpuserve_xla_lower_ms_total"),
+    ("xla_retrieval_ms", "tpuserve_xla_retrieval_ms_total"),
+    ("xla_late_loads", "tpuserve_xla_late_loads_total"),
+    ("xla_late_ms", "tpuserve_xla_late_ms_total"),
+    ("xla_late_trace_ms", "tpuserve_xla_late_trace_ms_total"),
+    ("xla_late_lower_ms", "tpuserve_xla_late_lower_ms_total"),
+    ("xla_late_retrieval_ms", "tpuserve_xla_late_retrieval_ms_total"),
+    # the boot timeline (ISSUE 42, utils/boot.py): self time of each
+    # phase from the process's start as the OS has it to the first
+    # moment /health would answer ok, and their sum
+    ("boot_import_ms", "tpuserve_boot_import_ms"),
+    ("boot_backend_ms", "tpuserve_boot_backend_ms"),
+    ("boot_weights_ms", "tpuserve_boot_weights_ms"),
+    ("boot_engine_ms", "tpuserve_boot_engine_ms"),
+    ("boot_warmup_ms", "tpuserve_boot_warmup_ms"),
+    ("boot_listen_ms", "tpuserve_boot_listen_ms"),
+    ("boot_ready_ms", "tpuserve_boot_ready_ms"),
     # adapter serving subsystem (ISSUE 7, tpuserve/adapters.py): hot
     # loads into the stacked LoRA rows, LRU evictions under row
     # pressure, resident adapters, and live slots decoding through a

@@ -19,6 +19,9 @@ launcher named in ``JAX_PLATFORMS``; with none named a TPU is required
 
 from __future__ import annotations
 
+# first: where there is no /proc, the boot timeline starts at this import
+import aigw_tpu.utils.boot  # noqa: F401, I001
+
 import asyncio
 import dataclasses
 import json
@@ -69,9 +72,8 @@ def _lora_zoo(lora: dict, cfg) -> dict:
 
 async def _serve(spec: dict, model_spec, engine_cfg) -> None:
     import jax
-    from aiohttp import web
 
-    from aigw_tpu.tpuserve.server import TPUServeServer
+    from aigw_tpu.tpuserve.server import TPUServeServer, listen
 
     lora = spec.get("lora") or {}
     server = TPUServeServer(
@@ -83,11 +85,7 @@ async def _serve(spec: dict, model_spec, engine_cfg) -> None:
     if spec.get("param_dtype", "") == "float32":
         server.engine.params = jax.tree_util.tree_map(
             lambda x: x.astype("float32"), server.engine.params)
-    runner = web.AppRunner(server.app)
-    await runner.setup()
-    site = web.TCPSite(runner, "127.0.0.1", 0)
-    await site.start()
-    port = site._server.sockets[0].getsockname()[1]
+    runner, port = await listen(server, "127.0.0.1", 0)
     print(f"SERVE_PORT={port}", flush=True)
     stop = asyncio.Event()
     server.install_signal_drain(stop, grace_s=float(

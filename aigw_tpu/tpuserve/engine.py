@@ -56,7 +56,7 @@ from aigw_tpu.obs.flight import (
     WINDOW_FETCH,
     LoopLedger,
 )
-from aigw_tpu.obs.metrics import EnginePhases
+from aigw_tpu.obs.metrics import LOOP_PHASES, EnginePhases
 from aigw_tpu.obs import xla_events
 from aigw_tpu.obs.xla_events import CompileTracker
 from aigw_tpu.tpuserve import constrain, speculation
@@ -791,6 +791,28 @@ class EngineStats:
     # LOADED from the cache utils/boot.py placed and how many were built
     xla_cache_hits: int = 0
     xla_cache_misses: int = 0
+    # the load ledger's process-wide totals by stage (trace, lowering,
+    # and of the backend span the cache read + deserialize_and_load),
+    # and what requests paid for: programs loaded after the server
+    # called itself ready, with their milliseconds by stage
+    xla_trace_ms: float = 0.0
+    xla_lower_ms: float = 0.0
+    xla_retrieval_ms: float = 0.0
+    xla_late_loads: int = 0
+    xla_late_ms: float = 0.0
+    xla_late_trace_ms: float = 0.0
+    xla_late_lower_ms: float = 0.0
+    xla_late_retrieval_ms: float = 0.0
+    # the process's boot timeline (utils/boot.py BootLedger), written
+    # once by the server when it is ready: self time per phase from
+    # the process's start, and their sum
+    boot_import_ms: float = 0.0
+    boot_backend_ms: float = 0.0
+    boot_weights_ms: float = 0.0
+    boot_engine_ms: float = 0.0
+    boot_warmup_ms: float = 0.0
+    boot_listen_ms: float = 0.0
+    boot_ready_ms: float = 0.0
     # prefill rate the gateway prices prompt length with (/state
     # prefill_ms_per_token): a token-decayed average rather than the
     # process-lifetime mean, so a traffic-mix change (chunked-sp long
@@ -926,10 +948,14 @@ class Engine:
         # lora_params/adapter_names form above (kept for fixed-stack
         # deployments and tests).
         adapter_store: Any = None,
+        # the server's obs.flight.FlightRecorder: a program loaded late
+        # on the engine thread is written into the requests in flight
+        flight: Any = None,
     ):
         from aigw_tpu.models.registry import family_fns
 
         self.fns = fns or family_fns("llama")
+        self.flight = flight
         # multi-LoRA: stacked adapters + name→row map; the LAST row of the
         # stack is the all-zeros base-model row (models/lora.py). With an
         # AdapterStore the stack and the name→row map are DYNAMIC — the
@@ -2925,6 +2951,10 @@ class Engine:
         # remainder is ``other``
         loop = self.stats.loop
         loop.start()
+        # a program this thread has to load from here on is one a
+        # request waits for (obs/xla_events.py)
+        xla_events.LEDGER.late_hooks[threading.get_ident()] = (
+            self._on_late_load)
         while not self._stop.is_set():
             try:
                 loop.enter(REAP)
@@ -2959,6 +2989,21 @@ class Engine:
             pass
         loop.enter(OTHER)  # settles the last phase (and closes its span)
         logger.info("engine loop stopped")
+
+    @engine_thread_only
+    def _on_late_load(self, load: dict) -> None:
+        """A program was loaded on this thread after the server was
+        ready: name the loop phase it fell in, tell every request in
+        flight what it waited for, and mark a running capture."""
+        loop = self.stats.loop
+        load["phase"] = LOOP_PHASES[loop.cur]
+        ms = round(load["trace_ms"] + load["lower_ms"]
+                   + load["backend_ms"], 3)
+        loop.instant("xla/late_load", fn=load["fn"], ms=ms)
+        if self.flight is not None:
+            for entry in self.flight.in_flight():
+                entry.event("program_load", fn=load["fn"], ms=ms,
+                            hit=load["hit"])
 
     @engine_thread_only
     def _abort_all(self, reason: str) -> None:
@@ -4788,11 +4833,8 @@ class Engine:
             self.stats.prefill_padded_frac = round(
                 1.0 - self.stats.prefill_tokens_real
                 / self.stats.prefill_tokens_padded, 4)
-        self.stats.xla_compiles = self.compile_tracker.compiles()
-        self.stats.xla_compile_ms = round(
-            self.compile_tracker.compiles_total_ms(), 3)
-        self.stats.xla_cache_hits, self.stats.xla_cache_misses = (
-            xla_events.cache_counts())
+        for key, value in self.compile_tracker.totals().items():
+            setattr(self.stats, key, value)
         self.stats.kv_pages_free = self.allocator.free_pages
         self.stats.kv_occupancy = self.allocator.occupancy
         if self._stateful:

@@ -54,7 +54,7 @@ from aigw_tpu.translate.structured import (
     parse_response_format,
 )
 from aigw_tpu.tpuserve import constrain
-from aigw_tpu.utils.boot import compile_cache_dir
+from aigw_tpu.utils.boot import BOOT, compile_cache_dir
 from aigw_tpu.utils.net import set_tcp_nodelay
 from aigw_tpu.tpuserve.engine import (
     Engine,
@@ -325,6 +325,7 @@ class TPUServeServer:
             mesh=mesh,
             fns=self.fns,
             adapter_store=adapter_store,
+            flight=self.flight,
         )
         # jitted embeddings path (bucketed like prefill)
         hidden = self.fns.hidden_states
@@ -393,6 +394,7 @@ class TPUServeServer:
         self.app.router.add_get("/debug/requests", self._debug_requests)
         self.app.router.add_get("/debug/requests/{rid}",
                                 self._debug_request)
+        self.app.router.add_get("/debug/programs", self._debug_programs)
         self.app.router.add_get("/debug/profile", self._debug_profile)
         self.app.on_startup.append(self._on_start)
         self.app.on_cleanup.append(self._on_stop)
@@ -414,6 +416,9 @@ class TPUServeServer:
             sharding_of = param_sharding_fn(self.model_cfg, mesh)
         key = jax.random.PRNGKey(0)
         quant_s = 0.0
+        # the boot timeline's ``weights`` phase is cut where the two
+        # observables below are, so that they add up to it
+        outer = BOOT.enter("weights")
         t0 = time.monotonic()
         if self.weights == "random":
             logger.info("initializing random weights for %s", spec.name)
@@ -447,6 +452,7 @@ class TPUServeServer:
             raise ValueError(f"unsupported weight source {self.weights}")
         jax.block_until_ready(params)
         total_s = time.monotonic() - t0
+        BOOT.enter(outer)
         self.weights_init_ms = round(1e3 * (total_s - quant_s), 1)
         self.weights_quantize_ms = round(1e3 * quant_s, 1)
         if quantize:
@@ -485,8 +491,20 @@ class TPUServeServer:
         # before the listener accepts, so nothing is serving yet either
         # way; to_thread only keeps the event loop's signal handling
         # live during the (long) compile.
+        BOOT.enter("warmup")
         await asyncio.to_thread(self.engine.warmup)
+        BOOT.enter("listen")
         self.engine.start()
+
+    def mark_ready(self) -> None:
+        """The listener accepts: ``/health`` would answer ``ok``. Closes
+        the boot timeline (utils/boot.py) onto ``/state`` and tells the
+        load ledger (obs/xla_events.py) that whatever is loaded from
+        here on, a request waits for."""
+        BOOT.ready()
+        for key, value in BOOT.flat().items():
+            setattr(self.engine.stats, key, value)
+        xla_events.mark_ready()
 
     async def _on_stop(self, _app) -> None:
         for task in self._batch_tasks.values():
@@ -2399,12 +2417,34 @@ class TPUServeServer:
                 # process-wide so weight-init programs count too
                 "xla_cache_hits": s.xla_cache_hits,
                 "xla_cache_misses": s.xla_cache_misses,
+                # the load ledger's totals by stage, process-wide
+                # (trace, lowering, and of the backend span the cache
+                # read + deserialize_and_load), and the loads that came
+                # after ready: what requests waited for. The table and
+                # the log behind them are on /debug/programs
+                "xla_trace_ms": s.xla_trace_ms,
+                "xla_lower_ms": s.xla_lower_ms,
+                "xla_retrieval_ms": s.xla_retrieval_ms,
+                "xla_late_loads": s.xla_late_loads,
+                "xla_late_ms": s.xla_late_ms,
+                "xla_late_trace_ms": s.xla_late_trace_ms,
+                "xla_late_lower_ms": s.xla_late_lower_ms,
+                "xla_late_retrieval_ms": s.xla_late_retrieval_ms,
                 "compile_cache_dir": compile_cache_dir(),
                 # boot observables: where the weights came from and
                 # what creating / quantizing them cost
                 "weights": self.weights,
                 "weights_init_ms": self.weights_init_ms,
                 "weights_quantize_ms": self.weights_quantize_ms,
+                # the boot timeline (utils/boot.py): self time per
+                # phase from the process's start to ready, and the sum
+                "boot_import_ms": s.boot_import_ms,
+                "boot_backend_ms": s.boot_backend_ms,
+                "boot_weights_ms": s.boot_weights_ms,
+                "boot_engine_ms": s.boot_engine_ms,
+                "boot_warmup_ms": s.boot_warmup_ms,
+                "boot_listen_ms": s.boot_listen_ms,
+                "boot_ready_ms": s.boot_ready_ms,
                 # serving-phase latency distributions (p50/p95/p99 per
                 # ENGINE_HISTOGRAMS phase; -1 = no observations yet):
                 # the picker's TTFT prediction reads them
@@ -2790,6 +2830,13 @@ class TPUServeServer:
                 content_type="application/json")
         return web.json_response(entry.detail())
 
+    async def _debug_programs(self, _request: web.Request) -> web.Response:
+        """What every program of this process cost to get: the load
+        ledger's table by program name, its log of load events (the
+        newest 256: stage times, cache hit, late, loop phase) and the
+        totals ``/state`` carries (obs/xla_events.py)."""
+        return web.json_response(xla_events.LEDGER.snapshot())
+
     #: hard cap on one /debug/profile capture window
     _PROFILE_MAX_SECONDS = 30.0
 
@@ -2937,9 +2984,19 @@ async def run_tpuserve(
         flight_entries=flight_entries,
         enable_profile_endpoint=enable_profile_endpoint,
     )
+    runner, port = await listen(server, host, port)
+    logger.info("tpuserve listening on %s:%d (model=%s)", host, port, model)
+    return runner
+
+
+async def listen(server: TPUServeServer, host: str, port: int
+                 ) -> tuple[web.AppRunner, int]:
+    """Warm the server up (the app's start-up hook), bind it and call it
+    ready; returns the runner and the port bound (``port`` 0 asks for a
+    free one). What both entry points end their boot with."""
     runner = web.AppRunner(server.app)
     await runner.setup()
     site = web.TCPSite(runner, host, port)
     await site.start()
-    logger.info("tpuserve listening on %s:%d (model=%s)", host, port, model)
-    return runner
+    server.mark_ready()
+    return runner, site._server.sockets[0].getsockname()[1]

@@ -184,7 +184,7 @@ def test_flag_down_constructs_nothing(clock, annotations):
     for phase in (ADMIT, PREFILL_DISPATCH, PREFILL_BLOCK, DECODE_DISPATCH):
         clock.now += 5
         led.enter(phase, {"k": 2})
-    led.instant("request/first_token", "r1")
+    led.instant("request/first_token", rid="r1")
     assert annotations.made == [] and led.captured["capture_ns"] == 0
 
 
