@@ -481,13 +481,62 @@ def _gdn_live_rows(q, k, v, g, beta, pool, layer, order, n_live, n_trips,
     return out, flat.reshape(pool.shape)
 
 
+def _unit_lower_inverse(a):
+    """``(I + A)⁻¹`` for ``A`` [..., C, C] strictly lower, ``C`` a power
+    of two: a blocked inverse, merged from single rows up. Two
+    neighbouring diagonal blocks with inverses ``T11``, ``T22`` and
+    ``A21`` below the first make ``[[T11, 0], [−T22 · A21 · T11, T22]]``;
+    on the whole matrix that is ``T − T · (off · T)``, ``off`` being
+    ``A`` where row and column lie in the two halves of one block of the
+    next size and ``T`` block-diagonal so far. Blocks of 1 (``T = I``)
+    merge without a product; 2 → 4 → … → C take two each: ten dependent
+    ``C × C`` products for ``C`` = 64 and nothing else — no loop, no
+    slice, no change of layout. A zero row of ``A`` (a padded position)
+    stays the identity's row through every merge, exactly.
+
+    Not ``lax.linalg.triangular_solve``: the chip's compiler makes it
+    one custom call that takes 0.33 ms for 128 matrices of 64 rows, two
+    thirds of ``layer/gdn_chunk``, where these products take 0.11 ms.
+    Not forward substitution written out row by row within blocks of
+    16 either (ISSUE 43's first form): elementwise it needs a
+    coefficient a lane, and the layouts the compiler picks for that
+    made the scope slower than the solve (PERF.md, PR 43). And not the
+    doubling product ``(I − A)(I + A²)(I + A⁴)…``, the same matrix on
+    paper: in float32 it loses the result to cancellation when a
+    block's keys share a direction (relative error 2e-2 at ``max|A|``
+    0.84 where this form, like substitution, reads 2e-7), and the
+    state passes through it.
+    """
+    C = a.shape[-1]
+    if C & (C - 1):
+        raise ValueError(f"{C} rows: not a power of two")
+    idx = jnp.arange(C)
+
+    def off(R):  # A between the halves (R rows each) of the blocks of 2R
+        return jnp.where(
+            (idx[:, None] // R) ^ (idx[None, :] // R) == 1, a, 0.0)
+
+    t = jnp.eye(C, dtype=a.dtype) - off(1)
+    R = 2
+    while R < C:
+        t = t - jnp.einsum("...ij,...jk,...kl->...il", t, off(R), t,
+                           precision=_HI)
+        R *= 2
+    return t
+
+
 @jax.named_scope("layer/gdn_chunk")
 def _gdn_chunk(q, k, v, g, beta, state):
     """The same rule over a sequence in blocks of ``GDN_CHUNK`` (the WY
     form): q, k [B,S,H,dk]; v [B,S,H,dv]; g, beta [B,S,H]; state
     [B,H,dk,dv]. Positions with ``beta == 0`` and ``g == 0`` (padding)
-    neither decay nor write. float32 at the highest matmul precision:
-    the state passes through these products."""
+    neither decay nor write: such a row of ``A`` is zero, so its row of
+    ``T = (I + A)⁻¹`` is the identity's (:func:`_unit_lower_inverse`,
+    which also says why ``T`` is formed blockwise and neither solved for
+    nor doubled). float32 at the highest matmul precision: the state
+    passes through these products. The scan over blocks carries the
+    state and nothing else: every factor that does not depend on it is
+    made for all blocks at once before the loop."""
     B, S, H, dk = q.shape
     dv = v.shape[-1]
     C = GDN_CHUNK
@@ -511,27 +560,24 @@ def _gdn_chunk(q, k, v, g, beta, state):
     mm = lambda eq, a, b: jnp.einsum(eq, a, b, precision=_HI)  # noqa: E731
     a_mat = jnp.where(jnp.tril(lower, -1),
                       mm("...id,...jd->...ij", kb, k) * decay, 0.0)
-    eye = jnp.eye(C, dtype=jnp.float32)
-    # (I + A)⁻¹, A strictly lower: the forward substitution of the WY form
-    t_mat = lax.linalg.triangular_solve(
-        a_mat + eye, jnp.broadcast_to(eye, a_mat.shape), left_side=True,
-        lower=True, unit_diagonal=True)
+    t_mat = _unit_lower_inverse(a_mat)
     u = mm("...ij,...jd->...id", t_mat, vb)
     w = mm("...ij,...jd->...id", t_mat, kb * jnp.exp(gc)[..., None])
     local = mm("...id,...jd->...ij", q, k) * decay
+    last = gc[..., -1:]  # [N,B,H,1]
+    q_in = q * jnp.exp(gc)[..., None]  # reads the state a block enters with
+    k_out = k * jnp.exp(last - gc)[..., None]  # writes the one it leaves
+    keep = jnp.exp(last)[..., None]
 
     def step(st, xs):
-        q_n, k_n, u_n, w_n, gc_n, local_n = xs
+        q_n, k_n, u_n, w_n, keep_n, local_n = xs
         v_new = u_n - mm("...cd,...dv->...cv", w_n, st)
-        out = mm("...cd,...dv->...cv", q_n * jnp.exp(gc_n)[..., None], st) \
+        out = mm("...cd,...dv->...cv", q_n, st) \
             + mm("...ij,...jv->...iv", local_n, v_new)
-        last = gc_n[..., -1]
-        st = st * jnp.exp(last)[..., None, None] + mm(
-            "...cd,...cv->...dv",
-            k_n * jnp.exp(last[..., None] - gc_n)[..., None], v_new)
+        st = st * keep_n + mm("...cd,...cv->...dv", k_n, v_new)
         return st, out
 
-    state, out = lax.scan(step, state, (q, k, u, w, gc, local))
+    state, out = lax.scan(step, state, (q_in, k_out, u, w, keep, local))
     out = jnp.moveaxis(out, 0, 2).reshape(B, H, N * C, dv)  # [B,H,S,dv]
     return jnp.moveaxis(out, 1, 2)[:, :S], state
 

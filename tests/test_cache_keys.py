@@ -23,7 +23,11 @@ decode programs alone (the loop over the experts live rows hit, two
 more columns on the decode tape); its prefill and chunk programs keep
 their keys through the move of ``moe_mlp``'s router and fence into
 helpers the decode step shares, which is the proof that the move
-changed nothing a sequence computes.
+changed nothing a sequence computes. ISSUE 43 moved the hybrid family's
+``prefill`` and ``prefill_suffix`` alone (``_gdn_chunk`` inverts its
+triangular matrix by block merges instead of a solve): its two decode
+programs, which run no chunk, and every llama and mixtral program
+stand as they stood at 5971f59.
 
 Taken by ``python tests/engine_keys_child.py [<checkout>]`` under the
 JAX named below. Another JAX lowers to other text and the comparison
@@ -60,9 +64,9 @@ GOLDEN = {
     "tiny-moe.decode.lean=False":
         "4cf04bb77f1a463dfe420456984a0e75e4a5a1b11eb411c2579e7e3257aa529a",
     "tiny-qwen3-next.prefill":
-        "27722e95630f8db99a3ed566f6e2f930108d9fb1bbeda62b2c787d70c34d52ae",
+        "333eea910eeff3d9582dfd042ef8086b35d25fa058befefcd7a50d313274ea2f",
     "tiny-qwen3-next.prefill_suffix":
-        "7af8902797042320b451ce03ecfd320c9a7c81aa2c6a81ea56965151b399f382",
+        "a1932d984daded9cb1806e4b7c4fb6728b66b14ba21aca29d3ef4274af9cd895",
     "tiny-qwen3-next.decode.lean=True":
         "21dda6cac9173a81019de529b8b623591b628af3bbc30a46e223c25d809da644",
     "tiny-qwen3-next.decode.lean=False":

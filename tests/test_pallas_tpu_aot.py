@@ -95,6 +95,30 @@ def test_w8a16_matmul_compiles_at_every_weight_shape(v5e, geometry):
                  ((k, n), jnp.int8), ((1, n), jnp.float32))
 
 
+HYBRID_SLOTS = 32
+
+
+def _hybrid_cell(v5e):
+    """``qwen3-next-80b-a3b-1chip`` as shapes on the described chip: the
+    model module, its configuration (published widths, 12 layers, 128
+    of 512 experts held), the parameters and the cache of 32 slots."""
+    from aigw_tpu.models import qwen3_next as qn
+
+    cfg = qn.Qwen3NextConfig(num_hidden_layers=12, num_experts=128,
+                             router_experts=512, vocab_size=37984)
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    p = sds(jax.eval_shape(
+        lambda: qn.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(lambda: cfg.cache_spec().make(
+        (HYBRID_SLOTS * 32 + 1) * PAGE, HYBRID_SLOTS, "bfloat16")))
+    return qn, cfg, p, cache
+
+
 @pytest.mark.parametrize("pages", [16, 32])
 def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e, pages):
     """No Pallas here, but the same kind of fact only the chip's
@@ -114,21 +138,8 @@ def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e, pages):
     ISSUE 35 no array of a whole layer's state is in it either: the
     DeltaNet update reads the live rows' ``[1, 32, 128, 128]`` slices
     out of the pool where they lie and stores them back in place."""
-    from aigw_tpu.models import qwen3_next as qn
-
-    cfg = qn.Qwen3NextConfig(num_hidden_layers=12, num_experts=128,
-                             router_experts=512, vocab_size=37984)
-    slots, page = 32, 128
-
-    def sds(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
-
-    p = sds(jax.eval_shape(
-        lambda: qn.init_params(jax.random.PRNGKey(0), cfg)))
-    cache = sds(jax.eval_shape(lambda: cfg.cache_spec().make(
-        (slots * 32 + 1) * page, slots, "bfloat16")))
+    qn, cfg, p, cache = _hybrid_cell(v5e)
+    slots, page = HYBRID_SLOTS, PAGE
     i32 = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e)
 
     def window(p, cache, tokens, positions, page_table, active):
@@ -155,6 +166,41 @@ def test_hybrid_decode_window_copies_no_pool_and_no_expert_matrix(v5e, pages):
     layer_state = cache.slots["gdn_state"].shape[1:]
     assert layer_state == (32, 32, 128, 128)
     assert f"f32[{','.join(map(str, layer_state))}]" not in text
+
+
+def test_hybrid_chunk_program_inverts_by_products_alone(v5e):
+    """The Qwen3-Next chunk program (``prefill_suffix``, one row of 256
+    tokens over a 16-page window, the cell's widths and 12 layers): the
+    chunked DeltaNet form inverts its 64-row unit triangular matrix by
+    merging blocks with plain products (``_unit_lower_inverse``), so
+    under ``layer/gdn_chunk`` the compiled program holds one loop a
+    DeltaNet layer — the scan over the blocks, which carries the state
+    — and no triangular solve in any dress. Until ISSUE 43
+    ``lax.linalg.triangular_solve`` stood there, which this compiler
+    makes one custom call (``InvertDiagBlocksLowerTriangular``, not the
+    ``while`` of 64 row steps other backends expand it into): 0.33 of
+    that scope's 0.49 ms a layer-call on the chip."""
+    import re
+
+    qn, cfg, p, cache = _hybrid_cell(v5e)
+    page, pages, S = PAGE, 16, 256
+    i32 = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=v5e)
+
+    def chunk(p, cache, tokens, prefix_lens, seq_lens, page_table, slot_ids):
+        return qn.prefill_suffix(p, cfg, tokens, prefix_lens, seq_lens,
+                                 cache, page_table, page, slot_ids=slot_ids)
+
+    text = jax.jit(chunk, donate_argnums=(1,)).lower(
+        p, cache, jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=v5e),
+        i32, i32, jax.ShapeDtypeStruct((1, pages), jnp.int32, sharding=v5e),
+        i32).compile().as_text()
+    for solve in ("triangular-solve", "triangular_solve", "InvertDiagBlocks"):
+        assert solve not in text
+    loops = [m.group(1) for m in re.finditer(
+        r' while\(.*op_name="([^"]*layer/gdn_chunk[^"]*)"', text)]
+    n_linear = sum(kind != "full" for kind in cfg.layer_kinds)
+    assert n_linear == 9 and len(loops) == n_linear, loops
+    assert all(name.endswith("layer/gdn_chunk/while") for name in loops), loops
 
 
 def test_mixtral_decode_window_holds_no_expert_slab(v5e):

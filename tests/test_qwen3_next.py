@@ -230,6 +230,97 @@ def test_chunked_form_agrees_with_the_recurrence():
     assert _err(state, st) < 2e-5
 
 
+def _gdn_inputs(rng, B, S, H, dk, dv):
+    """Random inputs of the delta rule as ``_gdn_heads`` hands them
+    over: L2-normalised keys, decay in (-0.3, 0], beta in [0, 1)."""
+    q, k = (rng.normal(size=(B, S, H, dk)).astype(np.float32)
+            for _ in range(2))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.normal(size=(B, S, H, dv)).astype(np.float32)
+    g = -rng.uniform(0.0, 0.3, (B, S, H)).astype(np.float32)
+    beta = rng.uniform(0.0, 1.0, (B, S, H)).astype(np.float32)
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("S", [40, 64, 256, 300])
+def test_chunked_form_carries_the_state_over_three_chunks(S):
+    """Three chunks of ``S`` tokens, the state handed from one to the
+    next as ``prefill_suffix`` does, the last with a padded tail in one
+    row, against the token rule over the real tokens: S below one
+    block, one block, the cell's four, and four and a padded fifth."""
+    rng = np.random.default_rng(S)
+    B, H, dk, dv = 2, 3, 16, 8
+    real = np.array([[S, S, S], [S, S, S - 17]])  # row, chunk
+    st_c = st_r = jnp.asarray(
+        rng.normal(size=(B, H, dk, dv)).astype(np.float32))
+    for c in range(3):
+        q, k, v, g, beta = _gdn_inputs(rng, B, S, H, dk, dv)
+        live = (np.arange(S)[None, :] < real[:, c, None])[..., None]
+        g, beta = g * live, beta * live
+        out, st_c = qn._gdn_chunk(
+            *map(jnp.asarray, (q, k, v, g, beta)), st_c)
+        for t in range(S):
+            o, st_r = qn._gdn_recurrent(q[:, t], k[:, t], v[:, t], g[:, t],
+                                        beta[:, t], st_r)
+            for b in range(B):
+                if t < real[b, c]:
+                    assert _err(out[b, t], o[b]) < 2e-5, (c, t, b)
+        assert _err(st_c, st_r) < 2e-5, c
+
+
+def _wy_matrix(k, g, beta):
+    """``A`` of one block as ``_gdn_chunk`` forms it: strictly lower,
+    ``beta_i <k_i, k_j> exp(gc_i - gc_j)``. k [M,C,dk]; g, beta [M,C]."""
+    gc = np.cumsum(g, axis=-1)
+    a = np.einsum("mid,mjd->mij", k * beta[..., None], k) \
+        * np.exp(gc[:, :, None] - gc[:, None, :])
+    return np.tril(a, -1).astype(np.float32)
+
+
+def _inverse_case(case):
+    rng = np.random.default_rng(7)
+    M, C, dk = 6, qn.GDN_CHUNK, 16
+    k = rng.normal(size=(M, C, dk)).astype(np.float32)
+    g = -rng.uniform(0.0, 0.3, (M, C)).astype(np.float32)
+    beta = rng.uniform(0.0, 1.0, (M, C)).astype(np.float32)
+    if case == "shared_direction":
+        # the doubling form's failure: correlated keys, little decay
+        k = 0.35 * k + rng.normal(size=(M, 1, dk)).astype(np.float32)
+        g, beta = g * 0.01, 0.9 + 0.1 * beta
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    if case == "padded_rows":
+        g[:, 40:], beta[:, 40:] = 0.0, 0.0
+    if case == "all_zero":
+        beta[:] = 0.0
+    return _wy_matrix(k, g, beta)
+
+
+@pytest.mark.parametrize("case", ["independent", "shared_direction",
+                                  "padded_rows", "all_zero"])
+def test_blocked_inverse_is_the_triangular_solve(case):
+    """``_unit_lower_inverse`` against ``lax.linalg.triangular_solve``
+    on the same float32 ``I + A``: 1e-6 of the largest entry. A padded
+    position's row of ``A`` is zero and its row of the inverse is the
+    identity's, to the bit."""
+    a = _inverse_case(case)
+    C = a.shape[-1]
+    eye = np.eye(C, dtype=np.float32)
+    if case == "shared_direction":
+        assert np.abs(a).max() >= 0.8
+    want = np.asarray(jax.lax.linalg.triangular_solve(
+        jnp.asarray(a + eye), jnp.broadcast_to(eye, a.shape),
+        left_side=True, lower=True, unit_diagonal=True))
+    got = np.asarray(qn._unit_lower_inverse(jnp.asarray(a)))
+    assert np.abs(got - want).max() <= 1e-6 * np.abs(want).max()
+    exact = np.linalg.inv((a + eye).astype(np.float64))
+    assert np.abs(got - exact).max() <= 1e-6 * np.abs(exact).max()
+    assert (np.triu(got, 1) == 0).all()
+    if case == "padded_rows":
+        assert (got[:, 40:] == eye[40:]).all()
+    if case == "all_zero":
+        assert (got == eye).all()
+
+
 def test_the_four_shares_add_up_to_the_whole_layer():
     """What each of four chips computes of one expert layer (its 4 of
     16 experts), plus the shared expert counted once, is the uncut
