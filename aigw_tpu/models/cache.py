@@ -6,7 +6,13 @@ Two kinds of memory can live side by side:
 - the **page pool** ``[kv_layers, 2, rows, n_kv_heads, head_dim]`` of
   models/kvq.py — only the layers that attend over keys and values have
   pages (every layer of the llama and mixtral families; every fourth of
-  a hybrid linear-attention family);
+  a hybrid linear-attention family) — or, for a family whose token
+  leaves ONE row a layer that every head reads (latent attention,
+  models/axk1.py), the **latent pool** ``[kv_layers, width, rows]``:
+  no K/V pair and no head axis, a token's values down a column and
+  tokens along the lanes (where the width is no multiple of the chip's
+  128 lanes its compiler lays a ``[rows, width]`` pool out so anyway,
+  copying it there and back in every program: PERF.md section 6, PR 45);
 - **per-slot state**: leaves ``[layers, slots, ...]`` indexed by decode
   slot and not paged — a recurrent layer's state does not grow with the
   context, so a sequence owns exactly one row of each for its lifetime.
@@ -20,8 +26,10 @@ rides the same donation chain and the decode scan's carry.
 What moves pages only (prefix-cache hits, the host KV tier, parking and
 migration of a live sequence, fleet fetch, speculative verify) cannot
 serve a family with per-slot state: a page without the state that goes
-with it is half a sequence. The engine switches those off by asking
-``spec.stateful`` — by what the family is, not by a flag.
+with it is half a sequence. Nor, yet, a family whose pages are latent
+rows: the movers' programs and checks know K and V planes alone. The
+engine switches them off by asking ``spec.pinned`` — by what the family
+is, not by a flag.
 """
 
 from __future__ import annotations
@@ -50,12 +58,31 @@ class CacheSpec:
     #: per-slot leaves: (name, layers, shape of one slot's row, dtype
     #: name, or ``activation``)
     slot_state: tuple[tuple[str, int, tuple[int, ...], str], ...] = ()
+    #: a token leaves ``head_dim`` values a layer (one column of the
+    #: pool), which every head reads: no K/V pair and no head axis
+    latent: bool = False
 
     @property
     def stateful(self) -> bool:
         return bool(self.slot_state)
 
+    @property
+    def pinned(self) -> str:
+        """Why a sequence of this family cannot be moved, shared or
+        verified page by page (``features_off``'s reason); empty for
+        the K/V pool every page mover knows."""
+        if self.stateful:
+            return ("the family keeps per-slot recurrent state beside its "
+                    "pages (ROADMAP.md M4: state snapshots)")
+        if self.latent:
+            return ("the family's pages hold one latent row a token, not "
+                    "K and V planes, which is all the page movers and the "
+                    "verify step know (ROADMAP.md M3)")
+        return ""
+
     def kv_shape(self, n_rows: int) -> tuple[int, ...]:
+        if self.latent:
+            return (self.kv_layers, self.head_dim, n_rows)
         return (self.kv_layers, 2, n_rows, self.n_kv_heads, self.head_dim)
 
     def kv_page_bytes(self, page_size: int, kv_cache_dtype: str) -> int:
@@ -63,7 +90,8 @@ class CacheSpec:
         (quantized pools: packed elements plus the f32 scale rows)."""
         per_elt = kvq.bytes_per_kv_element(kv_cache_dtype)
         scale = 4 if kvq.is_quantized_dtype(kv_cache_dtype) else 0
-        return int(self.kv_layers * 2 * page_size * self.n_kv_heads
+        rows = 1 if self.latent else 2 * self.n_kv_heads
+        return int(self.kv_layers * rows * page_size
                    * (self.head_dim * per_elt + scale))
 
     def state_bytes_per_slot(self, kv_cache_dtype: str) -> int:

@@ -261,8 +261,7 @@ def _rope_partial(x: jax.Array, positions: jax.Array, theta: float,
 
 # -- the expert layer -------------------------------------------------------
 def _hit_experts(p: dict, i: int, xt: jax.Array, weights: jax.Array,
-                 hit: jax.Array, n_hit: jax.Array,
-                 cfg: Qwen3NextConfig) -> jax.Array:
+                 hit: jax.Array, n_hit: jax.Array, cfg) -> jax.Array:
     """The routed mixture of a decode step, one trip of a loop for each
     held expert that ``hit`` [E] marks (``n_hit`` of them): a trip
     reads that expert's ``[D, F]`` gate and up columns and ``[F, D]``
@@ -292,24 +291,11 @@ def _hit_experts(p: dict, i: int, xt: jax.Array, weights: jax.Array,
 def moe(p: dict, i: int, x: jax.Array, cfg: Qwen3NextConfig,
         valid: jax.Array | None = None,
         tape: list | None = None) -> jax.Array:
-    """Shared expert + the held experts' part of the routed mixture.
-    x: [B, S, D]. ``valid`` [B, S] marks real tokens for the stats
-    ``tape`` (one ``[moe_tape_width]`` int32 row a layer).
-
-    A sequence (S > 1) runs every held expert densely over every token
-    and lets the combine weights select: a chunk's tokens hit all of
-    them, and ``valid`` changes nothing that is computed. A decode step
-    (S == 1) computes only the held experts that a ``valid`` row picked
-    (:func:`_hit_experts`): a row that is not valid routes nowhere and
-    its routed part is zero — nothing reads a dead row's output, and
-    routing is dropless, so no live row depends on what a dead row
-    holds. The tape's hit column is that loop's trip count."""
+    """Shared expert + the held experts' part of the routed mixture:
+    this family's router (a softmax over the WHOLE width, top-k,
+    renormalised) in front of :func:`held_experts`. x: [B, S, D]."""
     B, S, D = x.shape
-    T = B * S
-    E, K = cfg.num_experts, cfg.num_experts_per_tok
-    F = cfg.moe_intermediate_size
-    xt = x.reshape(T, D)
-    step = S == 1
+    xt = x.reshape(B * S, D)
     with jax.named_scope("layer/moe_route"):
         # the pick is discrete: a rounded logit picks another expert,
         # which is another model, so the router runs at full precision
@@ -317,9 +303,39 @@ def moe(p: dict, i: int, x: jax.Array, cfg: Qwen3NextConfig,
                          p[f"l{i}.router"].astype(jnp.float32),
                          precision=_HI)
         probs = jax.nn.softmax(logits, axis=-1)  # over the WHOLE width
-        topv, topi = lax.top_k(probs, K)  # [T, K]
+        topv, topi = lax.top_k(probs, cfg.num_experts_per_tok)  # [T, K]
         if cfg.norm_topk_prob:
             topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    return held_experts(p, i, xt, topv, topi, cfg, valid, tape,
+                        step=S == 1).reshape(B, S, D)
+
+
+def held_experts(p: dict, i: int, xt: jax.Array, topv: jax.Array,
+                 topi: jax.Array, cfg, valid: jax.Array | None = None,
+                 tape: list | None = None, step: bool = False,
+                 shared_gate: bool = True) -> jax.Array:
+    """What a family's router leaves to do, for every family whose
+    expert layer holds a share (models/axk1.py calls it too):
+    the shared expert (behind its sigmoid gate, ``shared_gate``) + the
+    held experts' part of the mixture ``topv`` [T, K] over expert ids
+    ``topi`` [T, K] of the router's whole width. ``cfg`` names the
+    experts held (``num_experts`` from ``held_from``), their width
+    (``moe_intermediate_size``) and ``num_experts_per_tok``. xt: the
+    tokens [T, D]. ``valid`` [T] marks real tokens for the stats
+    ``tape`` (one ``[num_experts + 3]`` int32 row a layer).
+
+    A sequence runs every held expert densely over every token and
+    lets the combine weights select: a chunk's tokens hit all of them,
+    and ``valid`` changes nothing that is computed. A decode ``step``
+    computes only the held experts that a ``valid`` row picked
+    (:func:`_hit_experts`): a row that is not valid routes nowhere and
+    its routed part is zero — nothing reads a dead row's output, and
+    routing is dropless, so no live row depends on what a dead row
+    holds. The tape's hit column is that loop's trip count."""
+    T = xt.shape[0]
+    E, K = cfg.num_experts, cfg.num_experts_per_tok
+    F = cfg.moe_intermediate_size
+    with jax.named_scope("layer/moe_route"):
         # combine weights over the experts held here; an absent
         # expert's id falls outside [0, E) and one-hots to nothing
         held = jax.nn.one_hot(topi - cfg.held_from, E,
@@ -346,7 +362,7 @@ def moe(p: dict, i: int, x: jax.Array, cfg: Qwen3NextConfig,
             gate = jax.nn.silu(llama._matmul(p, f"l{i}.experts_gate", xt))
             up = llama._matmul(p, f"l{i}.experts_up", xt)
             h = (gate * up).astype(jnp.float32).reshape(T, E, F)
-            h = (h * weights[:, :, None]).astype(x.dtype).reshape(T, E * F)
+            h = (h * weights[:, :, None]).astype(xt.dtype).reshape(T, E * F)
             out = jnp.dot(h, p[f"l{i}.experts_down"],
                           preferred_element_type=jnp.float32)
     with jax.named_scope("layer/moe_shared"):
@@ -354,11 +370,12 @@ def moe(p: dict, i: int, x: jax.Array, cfg: Qwen3NextConfig,
             * llama._matmul(p, f"l{i}.shared_up", xt)
         sh = jnp.dot(sh, p[f"l{i}.shared_down"],
                      preferred_element_type=jnp.float32)
-        sgate = jax.nn.sigmoid(jnp.dot(
-            xt, p[f"l{i}.shared_expert_gate"],
-            preferred_element_type=jnp.float32))
-        out = out + sh * sgate
-    return out.astype(x.dtype).reshape(B, S, D)
+        if shared_gate:
+            sh = sh * jax.nn.sigmoid(jnp.dot(
+                xt, p[f"l{i}.shared_expert_gate"],
+                preferred_element_type=jnp.float32))
+        out = out + sh
+    return out.astype(xt.dtype)
 
 
 # -- Gated DeltaNet ---------------------------------------------------------

@@ -17,7 +17,7 @@ from aigw_tpu.models import llama
 @dataclass(frozen=True)
 class ModelSpec:
     name: str
-    family: str  # "llama" | "mixtral" | "qwen3_next"
+    family: str  # "llama" | "mixtral" | "qwen3_next" | "axk1"
     config: Any
     weights: str = "random"  # "random" | "orbax:<dir>" | "hf:<dir>"
     tokenizer: str = "byte"  # "byte" | path to tokenizer.json
@@ -96,6 +96,16 @@ def family_fns(family: str) -> ModelFns:
                         qwen3_next.decode_step, qwen3_next.hidden_states,
                         prefill_suffix=qwen3_next.prefill_suffix,
                         decode_kernels=False, moe_stats=True)
+    if family == "axk1":
+        from aigw_tpu.models import axk1
+
+        # no verify_step (speculation is off for the family), no
+        # sequence-parallel and no ragged prefill, no decode kernel
+        # rung: no Pallas kernel reads a latent row
+        return ModelFns(axk1.init_params, axk1.prefill, axk1.decode_step,
+                        axk1.hidden_states,
+                        prefill_suffix=axk1.prefill_suffix,
+                        decode_kernels=False, moe_stats=True)
     raise KeyError(f"unknown model family {family!r}")
 
 
@@ -149,6 +159,15 @@ def _register_qwen3_next() -> None:
 
 
 _register_qwen3_next()
+
+
+def _register_axk1() -> None:
+    from aigw_tpu.models import axk1
+
+    register_model(ModelSpec("tiny-axk1", "axk1", axk1.TINY))
+
+
+_register_axk1()
 register_model(ModelSpec("llama-3-8b", "llama", llama.LLAMA3_8B,
                          weights="orbax:checkpoints/llama-3-8b"))
 register_model(ModelSpec("qwen2-7b", "llama", llama.QWEN2_7B,

@@ -131,6 +131,12 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     ("decode_kv_pages_live", "tpuserve_decode_kv_pages_live_total"),
     ("decode_state_rows_read", "tpuserve_decode_state_rows_read_total"),
     ("decode_state_rows_live", "tpuserve_decode_state_rows_live_total"),
+    # a group-limited router over a share of its experts: kept groups
+    # that hold a held expert / groups kept, summed over the expert
+    # layers; a latent family's prefill attention pairs (0 elsewhere)
+    ("moe_groups_kept_hits", "tpuserve_moe_groups_kept_hits_total"),
+    ("moe_group_slots", "tpuserve_moe_group_slots_total"),
+    ("prefill_keys_attended", "tpuserve_prefill_keys_attended_total"),
     ("decode_window", "tpuserve_decode_window_steps"),
     ("window_shrinks", "tpuserve_decode_window_shrinks_total"),
     ("window_grows", "tpuserve_decode_window_grows_total"),
@@ -526,7 +532,8 @@ CAPTURE_COUNTERS: tuple[str, ...] = (
     "decode_steps", "tokens_generated", "prefill_tokens_real",
     "prefill_tokens_padded", "prefill_calls",
     "moe_local_assignments", "moe_total_assignments",
-    "moe_held_hits_decode",
+    "moe_held_hits_decode", "decode_kv_pages_live",
+    "prefill_keys_attended",
 )
 
 #: the loop ledger's flat surface: key of ``LoopLedger.flat()`` (spread

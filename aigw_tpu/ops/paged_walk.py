@@ -37,6 +37,13 @@ rows of the other KV heads masked out of the softmax. With H <= 128
 those rows ride in the MXU's padding: no transpose of a page, and the
 whole pool goes to the loops as ONE ``[L*2*n_pages, page*Hkv, D]``
 list of pages (a layer sliced out of it would be a copy).
+
+A latent pool ``[L, W, n_slots]`` (models/cache.py: ``W`` values a token
+a layer, down a column, which every head reads; latent attention in its
+ABSORBED form) takes the same plan through :func:`latent_decode_walk`: a
+page ``[W, page]`` is read ONCE a trip and serves as keys at its whole
+width and as values at its first ``rank`` rows, with no rows of other
+heads to mask.
 """
 
 from __future__ import annotations
@@ -71,7 +78,10 @@ class WalkPlan(NamedTuple):
 
 def pair_bytes(pool: jax.Array, page_size: int) -> int:
     """K and V bytes one (row, page) pair holds in a layer of this
-    pool [L, 2, n_slots, Hkv, D]."""
+    pool [L, 2, n_slots, Hkv, D] — or the page's one column a token of
+    a latent pool [L, W, n_slots]."""
+    if pool.ndim == 3:
+        return page_size * pool.shape[1] * pool.dtype.itemsize
     *_, hkv, d = pool.shape
     return 2 * page_size * hkv * d * pool.dtype.itemsize
 
@@ -250,3 +260,96 @@ def paged_decode_walk(
         out_specs=Ps(None, axis, None), check_vma=False,
     )(q, pool, layer, page_table, lengths, plan.order, plan.trips,
       plan.n_blocks, *scales)
+
+
+def latent_pages(pool: jax.Array, layer, ids: jax.Array,
+                 page_size: int) -> jax.Array:
+    """Pages ``ids`` [R, G] of ``layer`` of a latent pool [L, W,
+    n_slots], each row's ``G`` side by side along the lanes → [R, W,
+    G*page]: whole pages sliced out of the WHOLE pool (a layer sliced
+    out of it would be a copy), as they lie."""
+    W = pool.shape[1]
+    R, G = ids.shape
+    return jnp.stack([jnp.concatenate([lax.dynamic_slice(
+        pool, (layer, 0, ids[r, g] * page_size), (1, W, page_size))[0]
+        for g in range(G)], axis=1) for r in range(R)])
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("page_size", "R", "G", "rank", "scale"))
+def _walk_latent(q, pool, layer, page_table, lengths, order, trips,
+                 n_blocks, *, page_size, R, G, rank, scale):
+    """:func:`_walk` over a latent pool: the same blocks, trips and
+    online softmax; a trip reads ``G`` pages of each of its ``R`` rows
+    ONCE, ``[R, W, G*page]`` as they lie (tokens along the lanes),
+    multiplies every head's absorbed query against the whole width for
+    the logits and the probabilities against the first ``rank`` rows
+    (the latent) for the values."""
+    B, H, W = q.shape
+    P = page_table.shape[1]
+    T = G * page_size
+    cdt = jnp.promote_types(q.dtype, pool.dtype)
+    qc = q.astype(cdt)
+    pt = jnp.pad(page_table, ((0, 0), (0, -P % G)))
+    at = jnp.arange(T, dtype=jnp.int32)
+
+    def block(blk, out):
+        rows = lax.dynamic_slice(order, (blk * R,), (R,))
+        src = jnp.minimum(rows, B - 1)
+        qb = qc[src]  # [R, H, W]
+        len_r = jnp.where(rows < B, lengths[src], 0)
+        pt_r = pt[src]
+
+        def trip(t, carry):
+            m, l, acc = carry
+            ids = lax.dynamic_slice(pt_r, (0, t * G), (R, G))
+            x = latent_pages(pool, layer, ids, page_size).astype(cdt)
+            s = jnp.einsum("rnd,rdt->rnt", qb, x,
+                           preferred_element_type=jnp.float32) * scale
+            live = (t * T + at)[None, :] < len_r[:, None]  # [R, T]
+            s = jnp.where(live[:, None, :], s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=2))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[:, :, None])
+            l = alpha * l + jnp.sum(p, axis=2)
+            pv = jnp.einsum("rnt,rct->rnc", p.astype(cdt), x[:, :rank],
+                            preferred_element_type=jnp.float32)
+            return m_new, l, acc * alpha[:, :, None] + pv
+
+        m0 = jnp.full((R, H), -1e30, jnp.float32)
+        l0 = jnp.zeros((R, H), jnp.float32)
+        acc0 = jnp.zeros((R, H, rank), jnp.float32)
+        _, l, acc = lax.fori_loop(0, trips[blk], trip, (m0, l0, acc0))
+        o = acc / jnp.maximum(l, 1e-30)[:, :, None]
+        dst = jnp.where(len_r > 0, rows, B)  # not live: written nowhere
+        return out.at[dst].set(o.astype(q.dtype), mode="drop")
+
+    return lax.fori_loop(0, n_blocks, block,
+                         jnp.zeros((B, H, rank), q.dtype))
+
+
+@jax.named_scope("layer/kv_walk")
+def latent_decode_walk(
+    q: jax.Array,  # [B, H, W] absorbed query: q~ over the latent | q_rope
+    pool: jax.Array,  # [L, W, n_slots] latent columns: c_kv | k_rope
+    layer: int,
+    page_table: jax.Array,  # [B, P] int32
+    lengths: jax.Array,  # [B] int32 — rows to attend (incl. new token)
+    *,
+    page_size: int,
+    rank: int,  # the latent's width: a column's value part
+    scale: float,  # the softmax scale (not 1/sqrt(W): the family's)
+    plan: WalkPlan | None = None,
+) -> jax.Array:
+    """Absorbed latent attention of each live row's query over the
+    pages it holds in ``layer``; the new token's column is already
+    written. Returns the attended latent [B, H, rank] in q's dtype
+    (``W_kvb``'s value half is the caller's to apply); rows with
+    ``lengths == 0`` are not walked and come back zero."""
+    if plan is None:
+        plan = walk_plan(lengths, page_table.shape[1], page_size,
+                         pair_bytes(pool, page_size))
+    return _walk_latent(
+        q, pool, jnp.asarray(layer, jnp.int32), page_table, lengths,
+        plan.order, plan.trips, plan.n_blocks, page_size=page_size,
+        R=plan.rows, G=plan.pages, rank=rank, scale=float(scale))

@@ -812,14 +812,15 @@ def resolve_attention_backend(engine: "Engine") -> tuple[str, str]:
     | pallas-ragged | no   | no  | any       | pallas-ragged | XLA windowed        |
     | pallas-ragged | yes  | any | any       | pallas-ragged | XLA windowed (SPMD) |
 
-    | pallas-ragged | any  | any | any, qwen3_next | xla-bucketed | XLA dense (bucketed) |
+    | pallas-ragged | any  | any | any, qwen3_next, axk1 | xla-bucketed | XLA dense (bucketed) |
 
     The llama and mixtral families provide a ragged prefill entry
     point. ``qwen3_next`` does not (its DeltaNet state would have to
     reset at every packed segment's start and its convolution would
-    have to stop at it; ROADMAP.md M4), so the last row routes it to
-    the bucketed backend — as it does hand-built ``ModelFns`` with
-    ``prefill_ragged=None``.
+    have to stop at it; ROADMAP.md M4), nor does ``axk1`` (its chunk
+    attention walks ONE row's page window; ROADMAP.md M3), so the last
+    row routes them to the bucketed backend — as it does hand-built
+    ``ModelFns`` with ``prefill_ragged=None``.
 
     The Pallas kernel itself stays single-chip TPU (its scalar-prefetch
     page walk addresses one local pool); a mesh keeps the RAGGED
@@ -857,7 +858,7 @@ def resolve_decode_backend(cfg, model_cfg, mesh,
     | fused              | no   | no  | any       | fused-xla       |
     | fused              | yes  | any | any       | fused-xla-spmd  |
     | fused, heads % tp != 0          | any       | xla-gather (narrowed) |
-    | a kernel rung, ``fns.decode_kernels`` false (qwen3_next) | the auto/chained row |
+    | a kernel rung, ``fns.decode_kernels`` false (qwen3_next, axk1) | the auto/chained row |
 
     ``xla-walk`` is the default of every family (ISSUE 31): after the
     scatter, an online-softmax loop over the whole pages the LIVE rows
@@ -873,7 +874,8 @@ def resolve_decode_backend(cfg, model_cfg, mesh,
     whole head shards per device; the GSPMD gather keeps reads
     head-local). A family whose ``ModelFns.decode_kernels`` is false
     (qwen3_next: q/k RMSNorm, rotary on a part of each head, an output
-    gate — which no Pallas rung knows) takes the default row whatever
+    gate; axk1: latent rows that every head reads — which no Pallas
+    rung knows) takes the default row whatever
     kernel was requested, and the reason says so.
 
     The fused rung has no model-family exception (ISSUE 18): MoE

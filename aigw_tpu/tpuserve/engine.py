@@ -705,6 +705,16 @@ class EngineStats:
     # rows. read / live is the state read amplification (0 elsewhere)
     decode_state_rows_read: int = 0
     decode_state_rows_live: int = 0
+    # a family's own routing-tape columns (``model_cfg.tape_extra``),
+    # each summed over its layers (0 elsewhere). A group-limited router
+    # over a share of its experts: of the groups real tokens kept
+    # (``moe_group_slots``), those that hold a held expert — hits /
+    # slots is what the group limit does to this share's load. A latent
+    # family's prefill programs: (query, key) pairs their real queries
+    # attended to, what their attention's work grows with
+    moe_groups_kept_hits: int = 0
+    moe_group_slots: int = 0
+    prefill_keys_attended: int = 0
     prefix_cache_hits: int = 0
     prefix_tokens_reused: int = 0
     # prefix-cache surface (ISSUE 3): misses counted over page-eligible
@@ -982,18 +992,21 @@ class Engine:
         # for the layers that attend over keys and values and, for a
         # family with recurrent layers, per-slot state beside them.
         # What moves PAGES ONLY cannot serve such a family — a page
-        # without the state that goes with it is half a sequence — so
-        # it is off by what the family is: prefix-cache hits (and with
-        # them the host KV tier, parking, migration and fleet fetch,
-        # which all need the content-addressed allocator), speculation
-        # (a rejected draft would need the state rolled back) and LoRA.
-        # Chunked prefill stays: a chunk resumes from the slot's state.
+        # without the state that goes with it is half a sequence — nor,
+        # yet, a family whose page holds one latent row a token and no
+        # K and V planes, so it is off by what the family is
+        # (``CacheSpec.pinned``): prefix-cache hits (and with them the
+        # host KV tier, parking, migration and fleet fetch, which all
+        # need the content-addressed allocator), speculation (a
+        # rejected draft would need the state rolled back; no verify
+        # step reads a latent row) and LoRA. Chunked prefill stays: a
+        # chunk resumes from the slot's state, or from the rows behind it.
         self.cache_spec = spec_of(model_cfg)
         self._stateful = self.cache_spec.stateful
+        why = self.cache_spec.pinned
+        self._pinned = bool(why)
         self.features_off: dict[str, str] = {}
-        if self._stateful:
-            why = ("the family keeps per-slot recurrent state beside its "
-                   "pages (ROADMAP.md M4: state snapshots)")
+        if self._pinned:
             self.features_off = dict.fromkeys(
                 ("prefix_cache", "kv_host_tier", "migration",
                  "batch_parking", "kv_fleet_fetch", "speculation", "lora"),
@@ -1002,12 +1015,17 @@ class Engine:
                 raise ValueError(f"LoRA serving is off: {why}")
             if mesh is not None:
                 raise ValueError(
-                    "mesh serving is off for this family: its state pool "
-                    "and expert share have no partition specs yet")
+                    "mesh serving is off for this family: its cache and "
+                    "expert share have no partition specs yet")
+            if kvq.is_quantized_dtype(cfg.kv_cache_dtype) \
+                    and self.cache_spec.latent:
+                raise ValueError(
+                    f"kv_cache_dtype {cfg.kv_cache_dtype!r} is off for "
+                    "this family: a latent row has no per-head scale")
             if cfg.kv_host_bytes > 0 or cfg.spec_tokens > 0:
                 logger.warning(
                     "kv_host_bytes / spec_tokens ignored: %s", why)
-        if (cfg.enable_prefix_cache and not self._stateful
+        if (cfg.enable_prefix_cache and not self._pinned
                 and self.fns.prefill_suffix is not None):
             self.allocator = RefcountedAllocator(cfg.num_pages, cfg.page_size)
             self.prefix_cache = PrefixCache(self.allocator, cfg.page_size)
@@ -1255,6 +1273,10 @@ class Engine:
         # (a per-slot-state family adds what its state loops read)
         decode_tape_width = int(getattr(
             model_cfg, "decode_tape_width", tape_width))
+        # the counters that a family's own columns behind the share's
+        # three feed, each summed over its layers (the hybrid family
+        # names none: its decode rows' two state columns are a layer's)
+        self._tape_extra = tuple(getattr(model_cfg, "tape_extra", ()))
         # per-slot-state families: each prefill row names the decode
         # slot whose state it continues (a leafless None elsewhere, so
         # the other families' programs and cache keys are unchanged)
@@ -2398,8 +2420,8 @@ class Engine:
         # mid-traffic — round-trip page 0 through the host exactly as a
         # real migration does (idempotent rewrites of page 0's own
         # content; nothing is serving yet)
-        # (nothing moves pages alone for a family with per-slot state)
-        if not self._stateful:
+        # (nothing moves pages alone for a pinned family)
+        if not self._pinned:
             rows = kvq.page_to_host(self._export_page_dev(0))
             for r in self._import_rungs():
                 self._import_pages_dev([0] * r, [rows] * r)
@@ -4523,8 +4545,10 @@ class Engine:
         """Fold one program's [L, width] routing-stats leaf (per-expert
         placed counts + capacity drops per layer; a family that holds a
         share of its experts adds every assignment routed and the held
-        experts hit, and in a decode window the slots whose state its
-        loops read and the live rows) into the numpy accumulators behind
+        experts hit, then either the family's own columns,
+        ``tape_extra``, or in the hybrid's decode window the slots whose
+        state its loops read and the live rows) into the numpy
+        accumulators behind
         the /state MoE surface. ``decode``: the leaf is a decode
         window's. No-op (None) on dense families — call sites stay
         uniform."""
@@ -4540,7 +4564,11 @@ class Engine:
             st.moe_total_assignments += int(arr[:, E + 1].sum())
             if decode:
                 st.moe_held_hits_decode += int(arr[:, E + 2].sum())
-        if arr.shape[1] > E + 3:
+        if self._tape_extra:
+            for name, col in zip(self._tape_extra,
+                                 arr[:, E + 3:].sum(axis=0)):
+                setattr(st, name, getattr(st, name) + int(col))
+        elif arr.shape[1] > E + 3:
             # a decode window's two state columns: every DeltaNet
             # layer's loop ran the step's one trip count, so the
             # largest row is a layer's (the others hold 0)

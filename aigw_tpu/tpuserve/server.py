@@ -2357,6 +2357,9 @@ class TPUServeServer:
                 "decode_kv_pages_live": s.decode_kv_pages_live,
                 "decode_state_rows_read": s.decode_state_rows_read,
                 "decode_state_rows_live": s.decode_state_rows_live,
+                "moe_groups_kept_hits": s.moe_groups_kept_hits,
+                "moe_group_slots": s.moe_group_slots,
+                "prefill_keys_attended": s.prefill_keys_attended,
                 "decode_window": s.decode_window,
                 "prefill_ms": round(s.prefill_ms, 3),
                 "transfer_ms": round(s.transfer_ms, 3),

@@ -37,7 +37,7 @@ def main(argv: list[str]) -> int:
     import jax.numpy as jnp
     from jax._src import cache_key
 
-    from aigw_tpu.models import llama, mixtral, quant, qwen3_next
+    from aigw_tpu.models import axk1, llama, mixtral, quant, qwen3_next
     from aigw_tpu.tpuserve.sampling import sample
 
     sharding = None
@@ -63,10 +63,12 @@ def main(argv: list[str]) -> int:
             ffn_dim=512, n_experts=4, experts_per_token=2)),
         # bfloat16 as served: the family has no quantized weights
         "qwen3_next": (qwen3_next, qwen3_next.TINY),
+        # bfloat16 too: latent pages, one row a token a layer, no state
+        "axk1": (axk1, axk1.TINY),
     }
     out: dict[str, dict] = {}
     for fam, (mod, cfg) in fams.items():
-        if fam == "qwen3_next":
+        if fam in ("qwen3_next", "axk1"):
             params = jax.eval_shape(
                 lambda: mod.init_params(jax.random.PRNGKey(0), cfg))
             # pages for the full-attention layers, per-slot state beside

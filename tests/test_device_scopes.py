@@ -1,5 +1,5 @@
 """Names inside the device programs: every decode and prefill program
-of the three model families carries each ``jax.named_scope`` of its blocks,
+of the four model families carries each ``jax.named_scope`` of its blocks,
 and the scopes are operation metadata only — the hash JAX's persistent
 compile cache takes of a program is the same with and without them, so
 no serving program recompiles for having been named.
@@ -32,8 +32,18 @@ MOE = {"embed", "layer/attn", "layer/moe_route", "layer/moe_experts",
 HYBRID = {"embed", "layer/gdn_proj", "layer/gdn_conv", "layer/attn_gated",
           "layer/attn", "layer/moe_route", "layer/moe_experts",
           "layer/moe_shared", "lm_head", "sample"}
+#: the latent-attention family: the absorbed query and the page row's
+#: projections, attention over cached rows (a chunk's blocks; a decode
+#: step's walk), the value half and the output projection; the leading
+#: dense layer's ``layer/mlp`` and the expert layer's three parts
+LATENT = {"embed", "layer/mla_q", "layer/mla_kv", "layer/mla_out",
+          "layer/mlp", "layer/moe_route", "layer/moe_experts",
+          "layer/moe_shared", "lm_head", "sample"}
 #: program -> the scopes its lowered text must carry
 WANT = {
+    "axk1.decode": LATENT | {"layer/kv_walk"},
+    "axk1.prefill": LATENT | {"layer/mla_attn"},
+    "axk1.prefill_suffix": LATENT | {"layer/mla_attn"},
     "llama.decode": DENSE | {"layer/kv_walk"},
     "llama.prefill": DENSE,
     "llama.prefill_suffix": DENSE | {"layer/kv_gather"},
@@ -103,7 +113,7 @@ def test_expert_layer_loops_in_a_decode_step_alone(lowered, program):
     loop."""
     got = lowered["scoped"][program]
     assert ("layer/moe_experts" in got["loops"]) == (
-        program in ("mixtral.decode", "qwen3_next.decode"))
+        program in ("mixtral.decode", "qwen3_next.decode", "axk1.decode"))
     assert [st for st in got["in_cond"] if "layer/moe" in st] == []
 
 
@@ -129,7 +139,8 @@ def test_scopes_live_only_in_the_programs_and_the_ledger():
                     if word in text:
                         found.setdefault(word, set()).add(rel)
     assert found == {
-        "named_scope(": {"models/llama.py", "models/mixtral.py",
+        "named_scope(": {"models/axk1.py", "models/llama.py",
+                         "models/mixtral.py",
                          "models/qwen3_next.py", "ops/paged_walk.py",
                          "tpuserve/sampling.py"},
         "TraceAnnotation(": {"obs/flight.py"},
