@@ -23,9 +23,11 @@ Quantization is symmetric round-to-nearest-even in float32:
 
 with qmax 127 (int8) / 7 (int4; -8 unused keeps the grid symmetric).
 Dequantization is ``q * scale`` in float32 — done *in-kernel* by the
-fused decode kernel (ops/pallas/decode_fused.py) and at the gather site
-by the XLA paths, so the quantized layout never round-trips through HBM
-at full width.
+fused decode kernel (ops/pallas/decode_fused.py) and at the read by the
+XLA paths — the decode walk's trips (``walk_kv``) and a chunk's, tail's
+or verify program's window (``window_kv``), both of which take whole
+pages out of the pool viewed as one list of pages — so the quantized
+layout never round-trips through HBM at full width.
 
 Byte math per token across the stack (D = head_dim):
     native bf16:  L * 2 * Hkv * D * 2
@@ -159,19 +161,26 @@ def scatter_kv(kv: Any, layer: int, flat: jax.Array, k: jax.Array,
     return {"q": pool, "scale": scale}
 
 
-def gather_kv(kv: Any, layer: int, gslot: jax.Array):
-    """Read K/V rows at flat slot indices. Native: the exact
-    pre-quantization gather (pool dtype out). Quantized: gathers the
-    int rows + their scales, dequantizes in f32 at the gather site
-    (HBM traffic is the packed bytes) and rounds to bf16 — the serving
+def window_kv(kv: Any, layer: int, page_table: jax.Array,
+              page_size: int):
+    """Read every row's page window of ``layer``: (k, v), each
+    ``[B, P*page, Hkv, D]`` for ``page_table`` [B, P] — the read of
+    the chunk, tail and verify programs. Whole pages out of the pool
+    as one list of pages (``paged_walk.window_pages``: the view the
+    decode walk reads by), never token rows out of a layer's slice.
+    Native: pool dtype out. Quantized: the pages of int rows and of
+    their scales are read the same way, dequantized in f32 at the read
+    (HBM traffic is the packed bytes) and rounded to bf16 — the serving
     compute dtype, so a quantized pool never silently promotes the
     activation stack to f32."""
+    def pages(pool, which):
+        return paged_walk.window_pages(pool, layer, which, page_table,
+                                       page_size)
+
     if not is_quantized(kv):
-        return kv[layer, 0][gslot], kv[layer, 1][gslot]
-    k = dequantize_rows(kv["q"][layer, 0][gslot],
-                        kv["scale"][layer, 0][gslot])
-    v = dequantize_rows(kv["q"][layer, 1][gslot],
-                        kv["scale"][layer, 1][gslot])
+        return pages(kv, 0), pages(kv, 1)
+    k = dequantize_rows(pages(kv["q"], 0), pages(kv["scale"], 0))
+    v = dequantize_rows(pages(kv["q"], 1), pages(kv["scale"], 1))
     return k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
 
 

@@ -267,11 +267,13 @@ def _matmul(p: dict[str, jax.Array], key: str, x: jax.Array) -> jax.Array:
 
 
 @jax.named_scope("layer/kv_gather")
-def _gather_kv(kv_cache, i, gslot):
-    """Layer i's K/V window read out of the page pool (dequantizing
+def _gather_kv(kv_cache, i, page_table, page_size):
+    """Layer i's K/V window ``[B, P*page, Hkv, D]`` read out of the
+    page pool a whole page at a time (``kvq.window_kv``; dequantizing
     when the pool is int8/int4), under a scope of its own: in a trace
-    it is the decode step's HBM-bound half."""
-    return kvq.gather_kv(kv_cache, i, gslot)
+    it is what a chunk, a tail or a verify program pays to see what
+    its rows have cached."""
+    return kvq.window_kv(kv_cache, i, page_table, page_size)
 
 
 @jax.named_scope("layer/attn")
@@ -469,8 +471,7 @@ def prefill_sp_suffix(
     """
     from aigw_tpu.ops.ring_attention import ring_attention_prefix
 
-    B, S = tokens.shape
-    T = page_table.shape[1] * page_size
+    S = tokens.shape[1]
     n_slots = kvq.n_slots(kv_cache)
     positions = prefix_lens[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     valid = positions < seq_lens[:, None]  # [B, S]
@@ -482,17 +483,12 @@ def prefill_sp_suffix(
     )
     flat = jnp.where(valid, slot, n_slots)  # OOB → dropped by scatter
 
-    gslot = page_table[:, :, None] * page_size + jnp.arange(
-        page_size, dtype=jnp.int32
-    )
-    gslot = gslot.reshape(B, T)
-
     x = _embed_rows(p, tokens)
     for i in range(cfg.n_layers):
         h = rms_norm(x, p[f"l{i}.attn_norm"], cfg.norm_eps)
         q, k, v = _project_qkv(p, i, h, positions, cfg, lora, adapter_idx)
         kv_cache = kvq.scatter_kv(kv_cache, i, flat, k, v)
-        k_all, v_all = _gather_kv(kv_cache, i, gslot)
+        k_all, v_all = _gather_kv(kv_cache, i, page_table, page_size)
         with jax.named_scope("layer/attn"):
             attn = ring_attention_prefix(
                 q, k.astype(q.dtype), v.astype(q.dtype),
@@ -577,10 +573,6 @@ def decode_step(
     if use_gather:
         # gather the full (padded) KV window for each slot
         t_idx = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, 0)
-        gslot = page_table[:, :, None] * page_size + jnp.arange(
-            page_size, dtype=jnp.int32
-        )
-        gslot = gslot.reshape(B, T)  # [B, T] flat cache indices
         attend = t_idx <= pos1  # causal within the sequence window
     elif use_pallas or use_fused_kernel:
         from aigw_tpu.ops.pallas._compat import is_tpu_backend
@@ -623,7 +615,8 @@ def decode_step(
                         lengths, page_size=page_size, interpret=interp,
                     ).reshape(B, 1, HD)
             elif use_gather:
-                k_all, v_all = _gather_kv(kv_cache, i, gslot)
+                k_all, v_all = _gather_kv(kv_cache, i, page_table,
+                                          page_size)
                 attn = _attention(q, k_all, v_all, attend[:, None, :])
             else:
                 attn = kvq.walk_kv(kv_cache, i, q[:, 0], page_table,
@@ -682,10 +675,6 @@ def verify_step(
             "the Pallas verify kernel has no quantized-pool rung — the "
             "fallback matrix keeps int8/int4 on the gather-dequant path")
     if not use_pallas:
-        gslot = page_table[:, :, None] * page_size + jnp.arange(
-            page_size, dtype=jnp.int32
-        )
-        gslot = gslot.reshape(B, T)
         t_idx = jnp.arange(T, dtype=jnp.int32)[None, :]
     else:
         from aigw_tpu.ops.pallas._compat import is_tpu_backend
@@ -710,7 +699,7 @@ def verify_step(
                     page_size=page_size, interpret=interp,
                 ).reshape(B, S, cfg.n_heads * cfg.head_dim)
         else:
-            k_all, v_all = _gather_kv(kv_cache, i, gslot)
+            k_all, v_all = _gather_kv(kv_cache, i, page_table, page_size)
             mask = (t_idx[:, None, :] <= positions[:, :, None]) \
                 & valid[..., None]
             attn = _attention(q, k_all, v_all, mask)
@@ -925,7 +914,7 @@ def prefill_suffix(
     suffix itself under a global causal mask. With ``prefix_lens == 0``
     this degenerates to (a gather-based) full prefill.
     """
-    B, S = tokens.shape
+    S = tokens.shape[1]
     T = page_table.shape[1] * page_size
     n_slots = kvq.n_slots(kv_cache)
     positions = prefix_lens[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -937,11 +926,6 @@ def prefill_suffix(
         + positions % page_size
     )
     flat = jnp.where(valid, slot, n_slots)  # OOB → dropped by scatter
-
-    gslot = page_table[:, :, None] * page_size + jnp.arange(
-        page_size, dtype=jnp.int32
-    )
-    gslot = gslot.reshape(B, T)
     t_idx = jnp.arange(T, dtype=jnp.int32)[None, :]
 
     x = _embed_rows(p, tokens)
@@ -949,7 +933,7 @@ def prefill_suffix(
         h = rms_norm(x, p[f"l{i}.attn_norm"], cfg.norm_eps)
         q, k, v = _project_qkv(p, i, h, positions, cfg, lora, adapter_idx)
         kv_cache = kvq.scatter_kv(kv_cache, i, flat, k, v)
-        k_all, v_all = _gather_kv(kv_cache, i, gslot)
+        k_all, v_all = _gather_kv(kv_cache, i, page_table, page_size)
         # causal over global positions; padded queries masked by `valid`
         mask = (t_idx[:, None, :] <= positions[:, :, None]) & valid[..., None]
         attn = _attention(q, k_all, v_all, mask)

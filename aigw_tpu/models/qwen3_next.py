@@ -643,14 +643,6 @@ def _attn_out(p, i, q, k, v, mask, gate):
         q, k.astype(q.dtype), v.astype(q.dtype), mask), gate)
 
 
-def _window_slots(page_table, page_size):
-    """Flat pool rows of every row's page window, [B, P*page] (the
-    index ``llama._gather_kv`` reads the window by)."""
-    B, P = page_table.shape
-    return (page_table[:, :, None] * page_size + jnp.arange(
-        page_size, dtype=jnp.int32)[None, None, :]).reshape(B, P * page_size)
-
-
 # -- the block skeleton -----------------------------------------------------
 def _blocks(p, cfg, x, linear, full, valid, tape):
     """Every layer of the stack; ``linear(i, j, h)`` / ``full(i, j, h)``
@@ -710,7 +702,6 @@ def _sequence(p, cfg, tokens, prefix_lens, seq_lens, cache, page_table,
         fresh = prefix_lens == 0
     if from_pages:
         T = page_table.shape[1] * page_size
-        gslot = _window_slots(page_table, page_size)
         mask = (jnp.arange(T, dtype=jnp.int32)[None, None, :]
                 <= positions[:, :, None]) & valid[..., None]
     else:
@@ -747,7 +738,7 @@ def _sequence(p, cfg, tokens, prefix_lens, seq_lens, cache, page_table,
         if kv is not None:
             kv = kvq.scatter_kv(kv, j, flat, k, v)
         if from_pages:
-            k, v = llama._gather_kv(kv, j, gslot)
+            k, v = llama._gather_kv(kv, j, page_table, page_size)
         return _attn_out(p, i, q, k, v, mask, gate)
 
     x = _blocks(p, cfg, llama._embed_rows(p, tokens), linear, full, valid,

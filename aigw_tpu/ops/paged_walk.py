@@ -22,21 +22,32 @@ Precision: K, V and q stay in the pool's dtype as matmul operands,
 products accumulate in float32 (``preferred_element_type``), the
 softmax statistics (running max, sum) and the output accumulator are
 float32 across trips. int8/int4 pools dequantise at the read exactly
-as ``kvq.gather_kv`` does (float32 product with the row's scale,
+as ``kvq.window_kv`` does (float32 product with the row's scale,
 rounded to bfloat16).
 
 The matmuls keep K and V pages in the layout they are stored in. The
 pool is ``[.., n_slots, Hkv, D]``: a token's heads lie side by side,
-and turning a page into per-head ``[page, D]`` matrices is the
-re-layout the window gather paid for (a copy of every gathered window
-a layer). Here a page is read as it lies, ``page*Hkv`` rows of
-(token, head) by ``D``, and every query head is multiplied against
+and turning a page into per-head ``[page, D]`` matrices is a re-layout
+(a copy of every window read, a layer: what a chunk, whose attention
+is per-head products over its whole window, still pays after
+:func:`window_pages` has read the window's pages — on the pages it
+read, never on the pool). Here a page is read as it lies,
+``page*Hkv`` rows of (token, head) by ``D``, and every query head is
+multiplied against
 every row: ``[R, H, D] x [R, G*page*Hkv, D]^T`` for the logits,
 ``[R, H, G*page*Hkv] x [R, G*page*Hkv, D]`` for the values, with the
 rows of the other KV heads masked out of the softmax. With H <= 128
 those rows ride in the MXU's padding: no transpose of a page, and the
 whole pool goes to the loops as ONE ``[L*2*n_pages, page*Hkv, D]``
-list of pages (a layer sliced out of it would be a copy).
+list of pages (:func:`page_list`; a layer sliced out of it would be a
+copy).
+
+The chunk, tail and verify programs read their window through the
+same list (:func:`window_pages`, ISSUE 46): every row's whole page
+window ``[B, P*page, Hkv, D]`` as ``B*P`` page reads. Before that they
+indexed ``kv[layer, w]`` by token — and what that cost was not the
+8192 one-token rows but the layer's K and V sliced out of the pool
+first, a copy of 1/L of the pool a layer-call (PERF.md, PR 46).
 
 A latent pool ``[L, W, n_slots]`` (models/cache.py: ``W`` values a token
 a layer, down a column, which every head reads; latent attention in its
@@ -55,6 +66,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 #: K and V bytes a trip should move: enough that the loop's own
 #: bookkeeping (a few microseconds a trip) stays under the read itself
@@ -134,6 +146,40 @@ def pages_live(lengths: jax.Array, page_size: int) -> jax.Array:
     return jnp.sum(-(-lengths // page_size)).astype(jnp.int32)
 
 
+def page_list(pool: jax.Array, page_size: int) -> tuple[jax.Array, int]:
+    """The WHOLE pool ``[L, 2, n_slots, ...]`` (data, or a quantised
+    pool's scales) as ONE list of pages ``[L*2*n_pages, page, ...]``,
+    and ``n_pages``: page ``i`` of K (``which`` 0) or V (1) of
+    ``layer`` is entry ``(layer*2 + which)*n_pages + i``. A reshape of
+    leading dimensions, so nothing moves; nothing is sliced out of the
+    pool (a slice handed to a loop or a gather is a copy)."""
+    L, _, n_slots = pool.shape[:3]
+    n_pages = n_slots // page_size
+    return pool.reshape(L * 2 * n_pages, page_size, *pool.shape[3:]), n_pages
+
+
+def window_pages(pool: jax.Array, layer, which: int, page_table: jax.Array,
+                 page_size: int) -> jax.Array:
+    """Every row's page window of K (``which`` 0) or V (1) of ``layer``:
+    pages ``page_table`` [B, P] read whole out of the one list of pages
+    → ``[B, P*page, ...]``, token ``t`` of the window at ``t``. What
+    ``pool[layer, which][page_table*page + arange(page)]`` gives, bit
+    for bit, without the layer slice that indexing copies first.
+
+    The pages read are PINNED to the order they lie in: left free, the
+    chip's compiler may choose the order the attention behind the read
+    wants for them and then re-lay the read's OPERAND to match — a copy
+    of the whole pool a layer-call (seen at B >= 2 with two KV heads of
+    256; PERF.md, PR 46). Pinned, that re-layout is of the pages read."""
+    B, P = page_table.shape
+    pages, n_pages = page_list(pool, page_size)
+    x = jnp.take(pages, (layer * 2 + which) * n_pages + page_table, axis=0,
+                 mode="clip")  # [B, P, page, ...]
+    x = with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+    return x.reshape(B, P * page_size, *x.shape[3:])
+
+
 @functools.partial(jax.jit, static_argnames=("page_size", "R", "G"))
 def _walk(q, pool, scale, layer, page_table, lengths, order, trips,
           n_blocks, *, page_size, R, G):
@@ -143,10 +189,9 @@ def _walk(q, pool, scale, layer, page_table, lengths, order, trips,
     compile cache, and 28 unrolled copies of two nested loops cost a
     warm boot half a second a program (PERF.md, PR 31)."""
     B, H, D = q.shape
-    L, _, n_slots, Hkv, _ = pool.shape
+    Hkv = pool.shape[3]
     grp = H // Hkv
     P = page_table.shape[1]
-    n_pages = n_slots // page_size
     rpp = page_size * Hkv  # (token, head) rows of a page
     T = G * rpp
     quant = scale is not None
@@ -157,9 +202,9 @@ def _walk(q, pool, scale, layer, page_table, lengths, order, trips,
     # the WHOLE pool, every layer's K and V pages in one list: nothing
     # is sliced out of it (a slice handed to a loop is a copy), and
     # merging (token, head) is no re-layout where a page's rows lie
-    pages = pool.reshape(L * 2 * n_pages, page_size, Hkv, D)
+    pages, n_pages = page_list(pool, page_size)
     if quant:
-        scales = scale.reshape(L * 2 * n_pages, page_size, Hkv)
+        scales, _ = page_list(scale, page_size)
 
     def read(which, ids):
         ids = (layer * 2 + which) * n_pages + ids

@@ -3,13 +3,13 @@ cache, to what they hashed before: a replica finds its compiled
 programs in the cache it filled before a change that was not meant to
 touch them.
 
-The prefill, chunk and tail programs (``prefill``, ``prefill_suffix``)
-of all three families keep the keys they had at 6150a62 (the hybrid
-family's: 861624e) — through the per-slot state pool, the cache
-description, the ``slot_ids`` argument, the hybrid family's expert loop
-(a chunk bypasses it) and ISSUE 31's page walk (a decode step's: a
-chunk keeps its window gather). That they stand here byte for byte is
-the proof that those programs did not change.
+The whole-prompt programs (``prefill``) of the llama and mixtral
+families keep the keys they had at 6150a62 — through the per-slot
+state pool, the cache description, the ``slot_ids`` argument, the
+hybrid family's expert loop (a chunk bypasses it), ISSUE 31's page walk
+(a decode step's) and ISSUE 46's page read (a chunk's: a whole prompt
+has no window behind it). That they stand here byte for byte is the
+proof that those programs did not change.
 
 The decode goldens of the three families were re-taken from the tree
 of ISSUE 31, whose decode step walks the page pool: every decode
@@ -27,7 +27,14 @@ changed nothing a sequence computes. ISSUE 43 moved the hybrid family's
 ``prefill`` and ``prefill_suffix`` alone (``_gdn_chunk`` inverts its
 triangular matrix by block merges instead of a solve): its two decode
 programs, which run no chunk, and every llama and mixtral program
-stand as they stood at 5971f59.
+stand as they stood at 5971f59. ISSUE 46 moved the three families'
+``prefill_suffix`` alone (the chunk, tail and prefix-hit program reads
+its page window a whole page at a time, ``kvq.window_kv``, where it
+indexed a layer's rows by token; one cold compile a deployment): every
+``prefill`` and every decode program stands as it stood at bc6b3be —
+the decode walk now takes its one-list-of-pages view from the helper
+the chunk's read shares (``paged_walk.page_list``), and lowers to the
+same text.
 
 Taken by ``python tests/engine_keys_child.py [<checkout>]`` under the
 JAX named below. Another JAX lowers to other text and the comparison
@@ -50,7 +57,7 @@ GOLDEN = {
     "tiny-random.prefill":
         "63446f95883beb2185b1d06c53d2aa2c07228f1b47a82e11905a671823a9c562",
     "tiny-random.prefill_suffix":
-        "19a6afad6854c5c8fe11cf03417798b96cb3ae53895151e9896692bb9ae268fd",
+        "0d7a67337dba23ed3bb53768e6872510f68f013fa3061cc7adfd912572836926",
     "tiny-random.decode.lean=True":
         "07a38b81ea10ead04115b36d84c626771ac67703982d5d53df34ee8c71b60d73",
     "tiny-random.decode.lean=False":
@@ -58,7 +65,7 @@ GOLDEN = {
     "tiny-moe.prefill":
         "13e1709b8ca64f0371b94219a8bc4ce92f1172315ce362a18416bb65c4537263",
     "tiny-moe.prefill_suffix":
-        "284de1ca630b9e535842d620d29b02cb1cb4df4930bd3c954c77a28386bc5754",
+        "c7dd23cbbf6fa891a2e095b62408a1ba0717a9db5e724ab2f2cb135f8ff8af64",
     "tiny-moe.decode.lean=True":
         "2ce402c0f801ade893f66b140d06bafe61c1bc45d9992ca721634fd978b34a40",
     "tiny-moe.decode.lean=False":
@@ -66,7 +73,7 @@ GOLDEN = {
     "tiny-qwen3-next.prefill":
         "333eea910eeff3d9582dfd042ef8086b35d25fa058befefcd7a50d313274ea2f",
     "tiny-qwen3-next.prefill_suffix":
-        "a1932d984daded9cb1806e4b7c4fb6728b66b14ba21aca29d3ef4274af9cd895",
+        "aa051e79688b5172ddf12d694c5d4c46fafcac38cf605c5e1fcfd8b5dfc0a7fe",
     "tiny-qwen3-next.decode.lean=True":
         "21dda6cac9173a81019de529b8b623591b628af3bbc30a46e223c25d809da644",
     "tiny-qwen3-next.decode.lean=False":

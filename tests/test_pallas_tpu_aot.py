@@ -330,3 +330,60 @@ def test_dense_decode_window_gathers_no_window_and_copies_no_pool(v5e, pages):
     ).compile().as_text()
     _no_window_and_no_pool_copy(
         text, (slots, pages * page, cfg.n_kv_heads, cfg.head_dim), (kv,))
+
+
+@pytest.mark.parametrize("rows,queries", [(1, 256), (4, 16), (32, 5)],
+                         ids=["chunk", "group", "verify"])
+def test_window_read_leaves_the_pool_where_it_lies(v5e, rows, queries):
+    """A chunk's, a tail's and a verify program's window read
+    (``llama._gather_kv`` over ``kvq.window_kv``, ISSUE 46) with the
+    attention behind it, at the hybrid cell's pool (3 layers of 2 KV
+    heads of 256, 1025 pages of 128 tokens) and its 32-page bucket:
+    whole pages are gathered out of the pool viewed as one list of
+    pages, and nothing of the pool's size is copied on the way. Two
+    forms did copy, and only the chip's compiler shows it: indexing
+    ``kv[layer, w]`` by token copied that layer's K and V out of the
+    pool before it gathered (a ``[131200, 2, 256]`` slice, 2.7 ms a
+    chunk call on the chip), and the page gather with its output left
+    free re-laid the WHOLE pool to the order the attention wants as
+    soon as the program had two rows (7.6 ms a call at four rows; 164
+    ms a verify-shaped step at qwen2's depth). The pages read are
+    pinned to the order they lie in, so what is re-laid is the pages
+    read."""
+    import re
+
+    from aigw_tpu.models import llama
+
+    L, n_pages, hkv, hd, heads, pages = 3, 1025, 2, 256, 16, 32
+    bf16, i32 = jnp.bfloat16, jnp.int32
+
+    def read_and_attend(kv, q, page_table, positions):
+        out = 0.0
+        for layer in range(L):
+            k, v = llama._gather_kv(kv, layer, page_table, PAGE)
+            mask = jnp.arange(pages * PAGE, dtype=i32)[None, None, :] \
+                <= positions[:, :, None]
+            out = out + llama._attention(q, k, v, mask)
+        return out
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    pool = (L, 2, n_pages * PAGE, hkv, hd)
+    compiled = jax.jit(read_and_attend).lower(
+        sds(pool, bf16), sds((rows, queries, heads, hd), bf16),
+        sds((rows, pages), i32), sds((rows, queries), i32)).compile()
+    text = compiled.as_text()
+    big = {",".join(map(str, s)) for s in (
+        pool, pool[2:], (L * 2 * n_pages, PAGE, hkv, hd),
+        (L * 2 * n_pages * PAGE, hkv, hd))}
+    made = set(re.findall(
+        r"= \(?\w+\[([\d,]*)\]\S* (?:copy|fusion|slice|reshape)\(", text))
+    assert not made & big, sorted(made & big)
+    # a page is gathered whole: [rows*pages or rows, pages, 128, 2, 256]
+    assert re.search(rf"bf16\[(?:{rows * pages}|{rows},{pages}),"
+                     rf"{PAGE},{hkv},{hd}\]\S* fusion\(", text)
+    window = rows * pages * PAGE * hkv * hd * 2  # one of K, V: bytes
+    logits = rows * heads * queries * pages * PAGE * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * window + 3 * logits
