@@ -12,10 +12,20 @@ Two kinds of memory can live side by side:
   no K/V pair and no head axis, a token's values down a column and
   tokens along the lanes (where the width is no multiple of the chip's
   128 lanes its compiler lays a ``[rows, width]`` pool out so anyway,
-  copying it there and back in every program: PERF.md section 6, PR 45);
+  copying it there and back in every program: PERF.md section 6, PR 45).
+  A family whose keys are wider than its values (models/mimo_v2.py:
+  192 over 128) keeps a token's heads flattened in that one row, ``v |
+  k`` — 1280 values a token a layer, no plane padded to the other's
+  width — in the same column pool: along a column a head's rows start
+  on a sublane tile (PERF.md section 6, PR 47);
 - **per-slot state**: leaves ``[layers, slots, ...]`` indexed by decode
   slot and not paged — a recurrent layer's state does not grow with the
   context, so a sequence owns exactly one row of each for its lifetime.
+  So does what a SLIDING-WINDOW attention layer keeps: the last
+  ``window`` tokens' keys and values, a ring a slot written at
+  ``position % window`` (models/mimo_v2.py's five window layers of
+  seven; ``CacheSpec.window``). Such a layer owns no pages, whatever
+  the context.
 
 A family with no per-slot state keeps the bare pool of models/kvq.py:
 its programs, their pytrees and their compile-cache keys are what they
@@ -26,10 +36,10 @@ rides the same donation chain and the decode scan's carry.
 What moves pages only (prefix-cache hits, the host KV tier, parking and
 migration of a live sequence, fleet fetch, speculative verify) cannot
 serve a family with per-slot state: a page without the state that goes
-with it is half a sequence. Nor, yet, a family whose pages are latent
-rows: the movers' programs and checks know K and V planes alone. The
-engine switches them off by asking ``spec.pinned`` — by what the family
-is, not by a flag.
+with it is half a sequence — a recurrent state, or a slot's window
+keys. Nor, yet, a family whose pages are latent rows: the movers'
+programs and checks know K and V planes alone. The engine switches them
+off by asking ``spec.pinned`` — by what the family is, not by a flag.
 """
 
 from __future__ import annotations
@@ -61,6 +71,11 @@ class CacheSpec:
     #: a token leaves ``head_dim`` values a layer (one column of the
     #: pool), which every head reads: no K/V pair and no head axis
     latent: bool = False
+    #: tokens a sliding-window layer attends over, its own among them:
+    #: such layers are not among ``kv_layers`` — what they keep is a
+    #: ``slot_state`` leaf ``window`` tokens long, whatever the context
+    #: (0: every attending layer attends over its whole context)
+    window: int = 0
 
     @property
     def stateful(self) -> bool:
@@ -71,6 +86,11 @@ class CacheSpec:
         """Why a sequence of this family cannot be moved, shared or
         verified page by page (``features_off``'s reason); empty for
         the K/V pool every page mover knows."""
+        if self.window:
+            return ("a slot's window keys live beside its pages: the "
+                    f"window layers keep a slot's last {self.window} "
+                    "tokens and own no pages (ROADMAP.md M2, M4: a ring "
+                    "snapshot at a page boundary)")
         if self.stateful:
             return ("the family keeps per-slot recurrent state beside its "
                     "pages (ROADMAP.md M4: state snapshots)")

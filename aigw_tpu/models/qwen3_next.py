@@ -313,10 +313,11 @@ def moe(p: dict, i: int, x: jax.Array, cfg: Qwen3NextConfig,
 def held_experts(p: dict, i: int, xt: jax.Array, topv: jax.Array,
                  topi: jax.Array, cfg, valid: jax.Array | None = None,
                  tape: list | None = None, step: bool = False,
-                 shared_gate: bool = True) -> jax.Array:
+                 shared_gate: bool | None = True) -> jax.Array:
     """What a family's router leaves to do, for every family whose
-    expert layer holds a share (models/axk1.py calls it too):
-    the shared expert (behind its sigmoid gate, ``shared_gate``) + the
+    expert layer holds a share (models/axk1.py and models/mimo_v2.py
+    call it too): the shared expert (behind its sigmoid gate,
+    ``shared_gate``; None: the family has no shared expert) + the
     held experts' part of the mixture ``topv`` [T, K] over expert ids
     ``topi`` [T, K] of the router's whole width. ``cfg`` names the
     experts held (``num_experts`` from ``held_from``), their width
@@ -365,6 +366,8 @@ def held_experts(p: dict, i: int, xt: jax.Array, topv: jax.Array,
             h = (h * weights[:, :, None]).astype(xt.dtype).reshape(T, E * F)
             out = jnp.dot(h, p[f"l{i}.experts_down"],
                           preferred_element_type=jnp.float32)
+    if shared_gate is None:
+        return out.astype(xt.dtype)
     with jax.named_scope("layer/moe_shared"):
         sh = jax.nn.silu(llama._matmul(p, f"l{i}.shared_gate", xt)) \
             * llama._matmul(p, f"l{i}.shared_up", xt)

@@ -17,7 +17,8 @@ from aigw_tpu.models import llama
 @dataclass(frozen=True)
 class ModelSpec:
     name: str
-    family: str  # "llama" | "mixtral" | "qwen3_next" | "axk1"
+    #: "llama" | "mixtral" | "qwen3_next" | "axk1" | "mimo_v2"
+    family: str
     config: Any
     weights: str = "random"  # "random" | "orbax:<dir>" | "hf:<dir>"
     tokenizer: str = "byte"  # "byte" | path to tokenizer.json
@@ -106,6 +107,17 @@ def family_fns(family: str) -> ModelFns:
                         axk1.hidden_states,
                         prefill_suffix=axk1.prefill_suffix,
                         decode_kernels=False, moe_stats=True)
+    if family == "mimo_v2":
+        from aigw_tpu.models import mimo_v2
+
+        # no verify_step (speculation is off for the family), no
+        # sequence-parallel and no ragged prefill (a packed segment
+        # would have to start its ring afresh), no decode kernel rung:
+        # no Pallas kernel knows two widths, a band or a sink
+        return ModelFns(mimo_v2.init_params, mimo_v2.prefill,
+                        mimo_v2.decode_step, mimo_v2.hidden_states,
+                        prefill_suffix=mimo_v2.prefill_suffix,
+                        decode_kernels=False, moe_stats=True)
     raise KeyError(f"unknown model family {family!r}")
 
 
@@ -168,6 +180,15 @@ def _register_axk1() -> None:
 
 
 _register_axk1()
+
+
+def _register_mimo_v2() -> None:
+    from aigw_tpu.models import mimo_v2
+
+    register_model(ModelSpec("tiny-mimo-v2", "mimo_v2", mimo_v2.TINY))
+
+
+_register_mimo_v2()
 register_model(ModelSpec("llama-3-8b", "llama", llama.LLAMA3_8B,
                          weights="orbax:checkpoints/llama-3-8b"))
 register_model(ModelSpec("qwen2-7b", "llama", llama.QWEN2_7B,

@@ -137,6 +137,12 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     ("moe_groups_kept_hits", "tpuserve_moe_groups_kept_hits_total"),
     ("moe_group_slots", "tpuserve_moe_group_slots_total"),
     ("prefill_keys_attended", "tpuserve_prefill_keys_attended_total"),
+    # a share with no shared expert: real tokens none of whose picks is
+    # held here; sliding-window layers' decode steps: keys their
+    # softmax saw / keys the rows' contexts hold (0 elsewhere)
+    ("moe_unserved_tokens", "tpuserve_moe_unserved_tokens_total"),
+    ("swa_keys_attended", "tpuserve_swa_keys_attended_total"),
+    ("swa_keys_in_context", "tpuserve_swa_keys_in_context_total"),
     ("decode_window", "tpuserve_decode_window_steps"),
     ("window_shrinks", "tpuserve_decode_window_shrinks_total"),
     ("window_grows", "tpuserve_decode_window_grows_total"),
@@ -533,7 +539,8 @@ CAPTURE_COUNTERS: tuple[str, ...] = (
     "prefill_tokens_padded", "prefill_calls",
     "moe_local_assignments", "moe_total_assignments",
     "moe_held_hits_decode", "decode_kv_pages_live",
-    "prefill_keys_attended",
+    "prefill_keys_attended", "decode_state_rows_live",
+    "swa_keys_attended",
 )
 
 #: the loop ledger's flat surface: key of ``LoopLedger.flat()`` (spread

@@ -320,17 +320,19 @@ def latent_pages(pool: jax.Array, layer, ids: jax.Array,
         for g in range(G)], axis=1) for r in range(R)])
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("page_size", "R", "G", "rank", "scale"))
+@functools.partial(jax.jit, static_argnames=(
+    "page_size", "R", "G", "rank", "scale", "keys_from"))
 def _walk_latent(q, pool, layer, page_table, lengths, order, trips,
-                 n_blocks, *, page_size, R, G, rank, scale):
+                 n_blocks, *, page_size, R, G, rank, scale, keys_from=0):
     """:func:`_walk` over a latent pool: the same blocks, trips and
     online softmax; a trip reads ``G`` pages of each of its ``R`` rows
     ONCE, ``[R, W, G*page]`` as they lie (tokens along the lanes),
     multiplies every head's absorbed query against the whole width for
     the logits and the probabilities against the first ``rank`` rows
-    (the latent) for the values."""
-    B, H, W = q.shape
+    (the latent) for the values. ``keys_from``: a column's keys are its
+    rows from there on (a flattened ``v | k`` row, models/mimo_v2.py:
+    two products of two widths over one page read), not the whole."""
+    B, H, _ = q.shape
     P = page_table.shape[1]
     T = G * page_size
     cdt = jnp.promote_types(q.dtype, pool.dtype)
@@ -349,7 +351,8 @@ def _walk_latent(q, pool, layer, page_table, lengths, order, trips,
             m, l, acc = carry
             ids = lax.dynamic_slice(pt_r, (0, t * G), (R, G))
             x = latent_pages(pool, layer, ids, page_size).astype(cdt)
-            s = jnp.einsum("rnd,rdt->rnt", qb, x,
+            s = jnp.einsum("rnd,rdt->rnt", qb,
+                           x[:, keys_from:] if keys_from else x,
                            preferred_element_type=jnp.float32) * scale
             live = (t * T + at)[None, :] < len_r[:, None]  # [R, T]
             s = jnp.where(live[:, None, :], s, -1e30)
@@ -385,6 +388,7 @@ def latent_decode_walk(
     rank: int,  # the latent's width: a column's value part
     scale: float,  # the softmax scale (not 1/sqrt(W): the family's)
     plan: WalkPlan | None = None,
+    keys_from: int = 0,  # a column's key part starts here (q: that wide)
 ) -> jax.Array:
     """Absorbed latent attention of each live row's query over the
     pages it holds in ``layer``; the new token's column is already
@@ -397,4 +401,5 @@ def latent_decode_walk(
     return _walk_latent(
         q, pool, jnp.asarray(layer, jnp.int32), page_table, lengths,
         plan.order, plan.trips, plan.n_blocks, page_size=page_size,
-        R=plan.rows, G=plan.pages, rank=rank, scale=float(scale))
+        R=plan.rows, G=plan.pages, rank=rank, scale=float(scale),
+        keys_from=keys_from)

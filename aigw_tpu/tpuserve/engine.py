@@ -715,6 +715,16 @@ class EngineStats:
     moe_groups_kept_hits: int = 0
     moe_group_slots: int = 0
     prefill_keys_attended: int = 0
+    # a share with no shared expert (models/mimo_v2.py): real tokens
+    # none of whose picks is held here, summed over the expert layers —
+    # they leave the layer unchanged here (over moe_total_assignments /
+    # picks a token). Sliding-window layers, per decode step, summed
+    # over live rows and window layers: keys a window layer's softmax
+    # saw / keys the row's context holds — the share rises the moment
+    # a window layer reads beyond its window
+    moe_unserved_tokens: int = 0
+    swa_keys_attended: int = 0
+    swa_keys_in_context: int = 0
     prefix_cache_hits: int = 0
     prefix_tokens_reused: int = 0
     # prefix-cache surface (ISSUE 3): misses counted over page-eligible
@@ -992,7 +1002,8 @@ class Engine:
         # for the layers that attend over keys and values and, for a
         # family with recurrent layers, per-slot state beside them.
         # What moves PAGES ONLY cannot serve such a family — a page
-        # without the state that goes with it is half a sequence — nor,
+        # without the state that goes with it is half a sequence, be
+        # the state recurrent or a slot's sliding-window keys — nor,
         # yet, a family whose page holds one latent row a token and no
         # K and V planes, so it is off by what the family is
         # (``CacheSpec.pinned``): prefix-cache hits (and with them the

@@ -2360,6 +2360,9 @@ class TPUServeServer:
                 "moe_groups_kept_hits": s.moe_groups_kept_hits,
                 "moe_group_slots": s.moe_group_slots,
                 "prefill_keys_attended": s.prefill_keys_attended,
+                "moe_unserved_tokens": s.moe_unserved_tokens,
+                "swa_keys_attended": s.swa_keys_attended,
+                "swa_keys_in_context": s.swa_keys_in_context,
                 "decode_window": s.decode_window,
                 "prefill_ms": round(s.prefill_ms, 3),
                 "transfer_ms": round(s.transfer_ms, 3),

@@ -37,7 +37,8 @@ def main(argv: list[str]) -> int:
     import jax.numpy as jnp
     from jax._src import cache_key
 
-    from aigw_tpu.models import axk1, llama, mixtral, quant, qwen3_next
+    from aigw_tpu.models import (axk1, llama, mimo_v2, mixtral, quant,
+                                 qwen3_next)
     from aigw_tpu.tpuserve.sampling import sample
 
     sharding = None
@@ -65,10 +66,12 @@ def main(argv: list[str]) -> int:
         "qwen3_next": (qwen3_next, qwen3_next.TINY),
         # bfloat16 too: latent pages, one row a token a layer, no state
         "axk1": (axk1, axk1.TINY),
+        # bfloat16: pages for the global layers, a ring a slot beside
+        "mimo_v2": (mimo_v2, mimo_v2.TINY),
     }
     out: dict[str, dict] = {}
     for fam, (mod, cfg) in fams.items():
-        if fam in ("qwen3_next", "axk1"):
+        if fam in ("qwen3_next", "axk1", "mimo_v2"):
             params = jax.eval_shape(
                 lambda: mod.init_params(jax.random.PRNGKey(0), cfg))
             # pages for the full-attention layers, per-slot state beside

@@ -1,5 +1,5 @@
 """Names inside the device programs: every decode and prefill program
-of the four model families carries each ``jax.named_scope`` of its blocks,
+of the five model families carries each ``jax.named_scope`` of its blocks,
 and the scopes are operation metadata only — the hash JAX's persistent
 compile cache takes of a program is the same with and without them, so
 no serving program recompiles for having been named.
@@ -39,8 +39,22 @@ HYBRID = {"embed", "layer/gdn_proj", "layer/gdn_conv", "layer/attn_gated",
 LATENT = {"embed", "layer/mla_q", "layer/mla_kv", "layer/mla_out",
           "layer/mlp", "layer/moe_route", "layer/moe_experts",
           "layer/moe_shared", "lm_head", "sample"}
+#: the window-and-global family: the fused projection and the rotary
+#: of both kinds of layer, a window layer's attention (a chunk's band
+#: over ring and chunk; a step's loop over the live rows' rings) and
+#: its ring write, a global layer's page read (a chunk's blocks, a
+#: step's walk), the output projection; the leading dense layer's
+#: ``layer/mlp`` and the expert layer's two parts (no shared expert)
+WINDOW = {"embed", "layer/qkv", "layer/rope", "layer/attn_window",
+          "layer/ring_write", "layer/attn_out", "layer/mlp",
+          "layer/moe_route", "layer/moe_experts", "lm_head", "sample"}
 #: program -> the scopes its lowered text must carry
 WANT = {
+    "mimo_v2.decode": WINDOW | {"layer/kv_walk"},
+    # (a whole prompt has no page window behind it to read)
+    "mimo_v2.prefill": WINDOW | {"layer/attn_global"},
+    "mimo_v2.prefill_suffix": WINDOW | {"layer/kv_gather",
+                                        "layer/attn_global"},
     "axk1.decode": LATENT | {"layer/kv_walk"},
     "axk1.prefill": LATENT | {"layer/mla_attn"},
     "axk1.prefill_suffix": LATENT | {"layer/mla_attn"},
@@ -113,7 +127,8 @@ def test_expert_layer_loops_in_a_decode_step_alone(lowered, program):
     loop."""
     got = lowered["scoped"][program]
     assert ("layer/moe_experts" in got["loops"]) == (
-        program in ("mixtral.decode", "qwen3_next.decode", "axk1.decode"))
+        program in ("mixtral.decode", "qwen3_next.decode", "axk1.decode",
+                    "mimo_v2.decode"))
     assert [st for st in got["in_cond"] if "layer/moe" in st] == []
 
 
@@ -140,7 +155,7 @@ def test_scopes_live_only_in_the_programs_and_the_ledger():
                         found.setdefault(word, set()).add(rel)
     assert found == {
         "named_scope(": {"models/axk1.py", "models/llama.py",
-                         "models/mixtral.py",
+                         "models/mimo_v2.py", "models/mixtral.py",
                          "models/qwen3_next.py", "ops/paged_walk.py",
                          "tpuserve/sampling.py"},
         "TraceAnnotation(": {"obs/flight.py"},
