@@ -630,8 +630,7 @@ def decode_step(p, cfg: AXK1Config, tokens, positions, cache, page_table,
         + positions % page_size, kv.shape[2])
     lengths = jnp.where(active, positions + 1, 0)
     if walk is None:
-        walk = kvq.walk_plan(kv, lengths, page_table.shape[1], page_size,
-                             mesh)
+        walk = kvq.walk_plan(kv, lengths, page_table, page_size, mesh)
     inv_freq = yarn_inv_freq(cfg)
 
     def attn(i, h):

@@ -184,20 +184,27 @@ def window_kv(kv: Any, layer: int, page_table: jax.Array,
     return k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
 
 
-def walk_plan(kv: Any, lengths: jax.Array, n_cols: int, page_size: int,
-              mesh=None) -> paged_walk.WalkPlan:
+def walk_plan(kv: Any, lengths: jax.Array, page_table: jax.Array,
+              page_size: int, mesh=None):
     """How this decode step's page walk is cut up (one plan a step,
-    shared by every layer): block sizes from the shapes of the pool as
-    one device holds it — under a mesh a head shard of it."""
+    shared by every layer), by the pool's format: a K/V pool takes the
+    flat list of live (row, page) pairs (``paged_walk.PairPlan``), a
+    latent pool, whose per-row accumulator is as large as the page it
+    came from, row blocks (``paged_walk.WalkPlan``). Sized from the
+    shapes of the pool as one device holds it — under a mesh a head
+    shard of it."""
     shards = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
-    return paged_walk.walk_plan(
-        lengths, n_cols, page_size, paged_walk.pair_bytes(
-            kv["q"] if is_quantized(kv) else kv, page_size) // shards)
+    pool = kv["q"] if is_quantized(kv) else kv
+    pair = paged_walk.pair_bytes(pool, page_size) // shards
+    if pool.ndim == 3:
+        return paged_walk.walk_plan(lengths, page_table.shape[1], page_size,
+                                    pair)
+    return paged_walk.pair_plan(lengths, page_table, page_size, pair)
 
 
 def walk_kv(kv: Any, layer: int, q: jax.Array, page_table: jax.Array,
             lengths: jax.Array, page_size: int,
-            plan: paged_walk.WalkPlan, mesh=None) -> jax.Array:
+            plan: paged_walk.PairPlan, mesh=None) -> jax.Array:
     """The decode step's read of ``layer``: attention of each live
     row's roped ``q`` [B, H, D] over the pages the row holds, its new
     K/V already scattered (ops/paged_walk.py: whole pages, live rows

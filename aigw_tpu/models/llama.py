@@ -521,7 +521,7 @@ def decode_step(
     adapter_idx=None,  # [B] int32 adapter row per slot
     attn_impl: str = "",  # see below
     mesh=None,  # jax Mesh — the walk then runs per head shard
-    walk=None,  # this step's paged_walk.WalkPlan
+    walk=None,  # this step's paged_walk.PairPlan
 ) -> tuple[jax.Array, jax.Array]:
     """One continuous-batching decode step; returns (logits [B, V], cache).
 
@@ -535,7 +535,7 @@ def decode_step(
       (quantizing in-pass), then an online-softmax loop over the whole
       pages the LIVE rows hold — nothing padded is gathered, int8/int4
       pages dequantize at the read. ``walk`` is this step's
-      ``paged_walk.walk_plan`` (made here when the caller has none;
+      ``paged_walk.pair_plan`` (made here when the caller has none;
       the engine makes it, to count what the loops read). With
       ``mesh`` the walk runs per head-shard inside shard_map: each
       device walks its LOCAL pool shard — no GSPMD gather.
@@ -585,7 +585,7 @@ def decode_step(
         else:
             from aigw_tpu.ops.pallas.decode_fused import fused_paged_decode
     elif walk is None:
-        walk = kvq.walk_plan(kv_cache, lengths, max_pages, page_size, mesh)
+        walk = kvq.walk_plan(kv_cache, lengths, page_table, page_size, mesh)
 
     HD = cfg.n_heads * cfg.head_dim
     x = _embed_rows(p, tokens[:, None])  # [B, 1, dim]

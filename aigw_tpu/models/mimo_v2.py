@@ -757,8 +757,7 @@ def decode_step(p, cfg: MiMoV2Config, tokens, positions, cache, page_table,
         + positions % page_size, kv.shape[2])
     lengths = jnp.where(active, positions + 1, 0)
     if walk is None:
-        walk = kvq.walk_plan(kv, lengths, page_table.shape[1], page_size,
-                             mesh)
+        walk = kvq.walk_plan(kv, lengths, page_table, page_size, mesh)
     n_live = jnp.sum(active.astype(jnp.int32))
     Gg, Gw = cfg.num_key_value_heads, cfg.swa_num_key_value_heads
 

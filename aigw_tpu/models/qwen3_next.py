@@ -462,7 +462,7 @@ def _gdn_live_rows(q, k, v, g, beta, pool, layer, order, n_live, n_trips,
     """One token of the gated delta rule for a decode step's LIVE rows
     only. q, k [B,H,dk]; v [B,H,dv]; g, beta [B,H]; ``pool`` is the
     whole state pool [L,B,H,dk,dv]; ``order`` ranks the ``n_live`` live
-    rows first (the step's ``WalkPlan.order``) and ``n_trips`` blocks
+    rows first (the step's ``PairPlan.order``) and ``n_trips`` blocks
     of ``Rs`` rows hold them. A trip takes its rows one after another:
     a row's state is read out of ``pool[layer]`` where it lies (the
     slice is an operand of :func:`_gdn_recurrent`'s sums, no copy),
@@ -814,8 +814,7 @@ def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
         + pos1 % page_size, kvq.n_slots(kv))
     lengths = jnp.where(active, positions + 1, 0)
     if walk is None:
-        walk = kvq.walk_plan(kv, lengths, page_table.shape[1], page_size,
-                             mesh)
+        walk = kvq.walk_plan(kv, lengths, page_table, page_size, mesh)
     n_valid = active.astype(jnp.int32)
     # the DeltaNet layers' loop over the live rows' state: the plan
     # ranks them first; ONE trip count bounds every layer's loop and

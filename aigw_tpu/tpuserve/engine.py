@@ -692,8 +692,8 @@ class EngineStats:
     sample_sort_steps: int = 0
     # what the decode programs read of the page pool, a layer, counted
     # on the device per step (Engine._kv_pages): (row, page) pairs the
-    # step's program reads — on the page walk the loops' own trip
-    # bounds x rows x pages a trip, elsewhere the whole [B, P] window —
+    # step's program reads — on the page walk the loop's own trip
+    # bound x pairs a trip, elsewhere the whole [B, P] window —
     # and the pages its live rows hold. read / live is the read
     # amplification; 1.0 reads exactly what is live
     decode_kv_pages_read: int = 0
@@ -1426,8 +1426,8 @@ class Engine:
             plan) it is the whole [B, P] window they address."""
             lengths = jnp.where(act, st["positions"] + 1, 0)
             B, P = st["page_table"].shape
-            plan = kvq.walk_plan(kv.kv if stateful else kv, lengths, P,
-                                 ps, mesh) if walks else None
+            plan = kvq.walk_plan(kv.kv if stateful else kv, lengths,
+                                 st["page_table"], ps, mesh) if walks else None
             read = plan.pages_read if walks else B * P
             return plan, pages + jnp.stack([
                 jnp.asarray(read, jnp.int32),

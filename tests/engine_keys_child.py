@@ -1,6 +1,8 @@
 """Child of tests/test_cache_keys.py: builds the serving engine for a
 tiny model of each family (``tiny-random``: llama, ``tiny-moe``:
-mixtral, ``tiny-qwen3-next``: the hybrid family), lowers the programs
+mixtral, ``tiny-qwen3-next``: the hybrid family; ``tiny-axk1`` and
+``tiny-mimo-v2``, the latent and the window-and-global family, for
+their decode programs alone), lowers the programs
 the engine itself dispatches — its jitted prefill, chunk and
 decode-window wrappers, not the model functions — and prints the hash
 JAX's persistent compile cache takes of each computation.
@@ -37,7 +39,11 @@ def main(argv: list[str]) -> int:
         return h.hexdigest()
 
     out = {"jax": jax.__version__}
-    for model in ("tiny-random", "tiny-moe", "tiny-qwen3-next"):
+    # the latent and the window-and-global family: their decode
+    # programs alone (the walk over a latent pool)
+    decode_only = ("tiny-axk1", "tiny-mimo-v2")
+    for model in ("tiny-random", "tiny-moe", "tiny-qwen3-next",
+                  *decode_only):
         spec = get_model_spec(model)
         fns = family_fns(spec.family)
         params = fns.init_params(jax.random.PRNGKey(0), spec.config)
@@ -54,13 +60,14 @@ def main(argv: list[str]) -> int:
         toks = jnp.zeros((G, S), i32)
         # a per-slot-state family's rows name their decode slots
         slot_kw = eng.slot_kw([], rows=G)
-        out[f"{model}.prefill"] = key_of(eng._prefill_fn.lower(
-            eng.params, eng.lora_params, toks, lens, eng.kv_cache, pt,
-            *sampling, **slot_kw))
-        out[f"{model}.prefill_suffix"] = key_of(
-            eng._prefill_suffix_fn.lower(
-                eng.params, eng.lora_params, toks, lens, lens,
-                eng.kv_cache, pt, *sampling, **slot_kw))
+        if model not in decode_only:
+            out[f"{model}.prefill"] = key_of(eng._prefill_fn.lower(
+                eng.params, eng.lora_params, toks, lens, eng.kv_cache, pt,
+                *sampling, **slot_kw))
+            out[f"{model}.prefill_suffix"] = key_of(
+                eng._prefill_suffix_fn.lower(
+                    eng.params, eng.lora_params, toks, lens, lens,
+                    eng.kv_cache, pt, *sampling, **slot_kw))
         state = eng._build_device_state(bucket=P)
         for lean in (True, False):
             out[f"{model}.decode.lean={lean}"] = key_of(

@@ -483,7 +483,7 @@ def test_decode_walk_reads_live_rows_only():
         outs.append(np.asarray(paged_walk.latent_decode_walk(
             q_abs[:, 0], kv, 1, jnp.asarray(table), lengths, page_size=PS,
             rank=cfg.kv_lora_rank, scale=cfg.softmax_scale,
-            plan=kvq.walk_plan(kv, lengths, P, PS))))
+            plan=kvq.walk_plan(kv, lengths, jnp.asarray(table), PS))))
     assert np.array_equal(outs[0], outs[1])
     assert not outs[0][[0, 2, 3]].any() and outs[0][1].any()
 

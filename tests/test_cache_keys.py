@@ -34,7 +34,15 @@ indexed a layer's rows by token; one cold compile a deployment): every
 ``prefill`` and every decode program stands as it stood at bc6b3be —
 the decode walk now takes its one-list-of-pages view from the helper
 the chunk's read shares (``paged_walk.page_list``), and lowers to the
-same text.
+same text. ISSUE 49 moved the decode programs of the three families
+that walk a K/V pool (llama, mixtral, the hybrid family: one flat list
+of live (row, page) pairs in place of row blocks; one cold compile a
+deployment) and nothing else: every ``prefill`` and ``prefill_suffix``
+stands as it stood at b404cf1, and so do the decode programs of the
+latent and the window-and-global family (``tiny-axk1``,
+``tiny-mimo-v2``), whose walk over a latent pool keeps its block plan —
+their goldens were taken from b404cf1 BEFORE the change, which is the
+proof that those two did not move.
 
 Taken by ``python tests/engine_keys_child.py [<checkout>]`` under the
 JAX named below. Another JAX lowers to other text and the comparison
@@ -59,25 +67,33 @@ GOLDEN = {
     "tiny-random.prefill_suffix":
         "0d7a67337dba23ed3bb53768e6872510f68f013fa3061cc7adfd912572836926",
     "tiny-random.decode.lean=True":
-        "07a38b81ea10ead04115b36d84c626771ac67703982d5d53df34ee8c71b60d73",
+        "425f29843f1e463db589236172431ea8342b2034501fcb42964efa47050dd32c",
     "tiny-random.decode.lean=False":
-        "4eb91fb75bd4ef5708dbfdc87a23df1b891a2b4b4a8a06d2ee0a6faf3ec3e57d",
+        "02e3e90a9aec5321fad41285dc934c7cb6b5e6422776353abe41719f0f39fa9b",
     "tiny-moe.prefill":
         "13e1709b8ca64f0371b94219a8bc4ce92f1172315ce362a18416bb65c4537263",
     "tiny-moe.prefill_suffix":
         "c7dd23cbbf6fa891a2e095b62408a1ba0717a9db5e724ab2f2cb135f8ff8af64",
     "tiny-moe.decode.lean=True":
-        "2ce402c0f801ade893f66b140d06bafe61c1bc45d9992ca721634fd978b34a40",
+        "5617157d44cd5e23bb5ec8e37c3cc4fc9bc0c321d3e8efdef8162480392108d3",
     "tiny-moe.decode.lean=False":
-        "4cf04bb77f1a463dfe420456984a0e75e4a5a1b11eb411c2579e7e3257aa529a",
+        "c3b96f3959dbd1566ae5399d0efa17036fa4db77da77d9cfdb27187e36764231",
     "tiny-qwen3-next.prefill":
         "333eea910eeff3d9582dfd042ef8086b35d25fa058befefcd7a50d313274ea2f",
     "tiny-qwen3-next.prefill_suffix":
         "aa051e79688b5172ddf12d694c5d4c46fafcac38cf605c5e1fcfd8b5dfc0a7fe",
     "tiny-qwen3-next.decode.lean=True":
-        "21dda6cac9173a81019de529b8b623591b628af3bbc30a46e223c25d809da644",
+        "fd6d25c2a5b5c14588ca6ae4f6dbcb16d7a3ae47a779d0859d960b76e2f993b4",
     "tiny-qwen3-next.decode.lean=False":
-        "7b3d133a74f4bdee021df1dbcc5a2c0d8cf357d076a93830a10d8814062896d3",
+        "141b31fa01a17e49ac059fc1ff28a7302d341375c23f7274fa71461bcc8c157b",
+    "tiny-axk1.decode.lean=True":
+        "09dc1cb2887734bf8cc741e9c86f9429ec6fb07cc884f230f3c2e352f1dd565d",
+    "tiny-axk1.decode.lean=False":
+        "884e19b1776d101d9b62b0e78cb26403b04ce80df16315278399b24495210199",
+    "tiny-mimo-v2.decode.lean=True":
+        "69f19761e4a1b30b797db4a30db49b6e8c55d3a6eb896d8be39f5623cf608618",
+    "tiny-mimo-v2.decode.lean=False":
+        "802dde71965e3f26f1aece10496b7623005aed0601cc697ecf1cbf06c80a7b8a",
 }
 
 

@@ -561,7 +561,8 @@ def test_decode_walk_reads_live_rows_only():
         outs.append(np.asarray(paged_walk.latent_decode_walk(
             mimo_v2._at_own_head(q, 1), cache.kv, 1, jnp.asarray(table),
             lengths, page_size=PS, rank=16, scale=cfg.softmax_scale,
-            plan=kvq.walk_plan(cache.kv, lengths, P, PS), keys_from=16)))
+            plan=kvq.walk_plan(cache.kv, lengths, jnp.asarray(table), PS),
+            keys_from=16)))
     assert np.array_equal(outs[0], outs[1])
     assert not outs[0][[0, 2, 3]].any() and outs[0][1].any()
 

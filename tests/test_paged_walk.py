@@ -59,7 +59,7 @@ def _plain(q, kv, layer, pt, lengths):
 def _walk(q, kv, layer, pt, lengths, **kw):
     return jax.jit(lambda q, kv, pt, ln: kvq.walk_kv(
         kv, layer, q, pt, ln, PAGE,
-        kvq.walk_plan(kv, ln, pt.shape[1], PAGE), **kw))(
+        kvq.walk_plan(kv, ln, pt, PAGE), **kw))(
             q, kv, pt, jnp.asarray(lengths, jnp.int32))
 
 
@@ -75,7 +75,7 @@ def _ragged(rng, B, P):
     return lengths
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8", "int4"])
 @pytest.mark.parametrize("heads", [(4, 128), (8, 128), (2, 256)],
                          ids=lambda h: f"hkv{h[0]}d{h[1]}")
 @pytest.mark.parametrize("P", [8, 16, 32])
@@ -105,19 +105,18 @@ def test_walk_with_no_one_and_every_row_live(live):
     got = np.asarray(_walk(q, kv, 0, pt, lengths))
     np.testing.assert_allclose(got, _plain(q, kv, 0, pt, lengths),
                                atol=1e-5, rtol=1e-5)
-    plan = kvq.walk_plan(kv, jnp.asarray(lengths, jnp.int32), P, PAGE)
+    plan = kvq.walk_plan(kv, jnp.asarray(lengths, jnp.int32), pt, PAGE)
     held = int(paged_walk.pages_live(jnp.asarray(lengths), PAGE))
     assert held == sum(-(-int(n) // PAGE) for n in lengths)
-    # the counter is the loops' bound: trips x rows x pages a trip
-    assert int(plan.pages_read) == int(
-        np.asarray(plan.trips).sum()) * plan.rows * plan.pages
-    assert int(plan.pages_read) >= held
-    assert int(plan.n_blocks) == -(-int((lengths > 0).sum()) // plan.rows)
+    # the counter is the loop's bound: trips x pairs a trip, and what
+    # it reads past the live pairs is less than one trip
+    assert int(plan.pages_read) == int(plan.trips) * plan.pairs
+    assert 0 <= int(plan.pages_read) - held < plan.pairs
     if live == "none":
         assert int(plan.pages_read) == 0
 
 
-@pytest.mark.parametrize("at", [PAGE - 1, PAGE, PAGE + 1, 4 * PAGE,
+@pytest.mark.parametrize("at", [1, PAGE - 1, PAGE, PAGE + 1, 4 * PAGE,
                                 4 * PAGE + 1])
 def test_walk_at_and_past_a_page_edge(at):
     B, P, Hkv, D = 3, 8, 2, 256
@@ -130,14 +129,86 @@ def test_walk_at_and_past_a_page_edge(at):
         _plain(q, kv, 1, pt, lengths), atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("lengths", [
+    [PAGE * 8] * 6,              # 48 pairs: every row spans two trips
+    [1, PAGE * 8, 0, 1, PAGE * 3 + 1, PAGE * 8],
+    [0, 0, PAGE * 5, PAGE * 8, 0, PAGE * 8],
+], ids=["full", "ragged", "dead-first"])
+def test_walk_folds_a_row_that_spans_two_trips(lengths):
+    """The plan is handed a pair 1 MiB wide, so a trip is 4 pairs:
+    rows of 8 pages span two or three trips and share a trip with
+    their neighbours, and a row of one token is one pair among them."""
+    B, P, Hkv, D = 6, 8, 2, 128
+    rng = np.random.default_rng(11)
+    kv, pt = _pool(rng, B, P, Hkv, D, "float32")
+    q = jnp.asarray(rng.normal(size=(B, 4, D)), jnp.float32)
+    ln = jnp.asarray(lengths, jnp.int32)
+    assert paged_walk.pair_plan(ln, pt, PAGE, 1 << 20).pairs == 4
+    got = np.asarray(jax.jit(
+        lambda q, kv, pt, ln: paged_walk.paged_decode_walk(
+            q, kv, 0, pt, ln, page_size=PAGE,
+            plan=paged_walk.pair_plan(ln, pt, PAGE, 1 << 20)))(
+                q, kv, pt, ln))
+    np.testing.assert_allclose(got, _plain(q, kv, 0, pt, lengths),
+                               atol=1e-5, rtol=1e-5)
+    assert not got[np.asarray(lengths) == 0].any()
+
+
+@pytest.mark.parametrize("pair,want", [
+    (256 << 10, 16),   # qwen2-7b-1chip, qwen3-next-80b-a3b-1chip: bf16
+    (512 << 10, 8),    # mixtral-8x7b-1chip
+    (128 << 10, 32),   # qwen2's pool as int8
+    (1 << 10, 4096),   # a tiny pool: every pair in one trip
+    (8 << 20, 1),      # a pair wider than a trip
+])
+def test_pairs_a_trip_follow_the_pools_shapes(pair, want):
+    assert paged_walk.trip_pairs(pair) == want
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_pair_plan_lists_the_live_pairs_in_row_and_column_order(seed):
+    """The property the walk rests on, for any ``lengths``: a dead row
+    gives no pair, row ``b`` gives ``ceil(lengths[b] / page)`` of them,
+    adjacent and in column order, the rows in row order; what is read
+    past the live pairs is less than one trip."""
+    rng = np.random.default_rng(seed)
+    B, P = int(rng.integers(1, 12)), int(rng.integers(1, 9))
+    pair = (4 << 20) // int(rng.integers(1, 20))
+    lengths = rng.integers(0, P * PAGE + 1, size=B)
+    lengths[rng.random(B) < 0.3] = 0
+    if seed == 0:
+        lengths[:] = 0
+    pt = rng.permutation(B * P).reshape(B, P).astype(np.int32)
+    plan = paged_walk.pair_plan(jnp.asarray(lengths, jnp.int32),
+                                jnp.asarray(pt), PAGE, pair)
+    N = plan.pairs
+    need = [-(-int(n) // PAGE) for n in lengths]
+    want = [(b, c) for b in range(B) for c in range(need[b])]
+    row, page, left = (np.asarray(x) for x in (plan.row, plan.page,
+                                               plan.left))
+    assert len(row) % N == 0 and len(row) >= B * P
+    assert row[:len(want)].tolist() == [b for b, _ in want]
+    assert page[:len(want)].tolist() == [int(pt[b, c]) for b, c in want]
+    assert left[:len(want)].tolist() == [
+        int(lengths[b]) - c * PAGE for b, c in want]
+    assert (row[len(want):] == B).all() and not left[len(want):].any()
+    assert int(plan.trips) == -(-len(want) // N)
+    assert 0 <= int(plan.pages_read) - len(want) < N
+    order = np.asarray(plan.order).tolist()
+    live = [b for b in range(B) if need[b]]
+    assert order[:len(live)] == live and sorted(order) == list(range(B))
+
+
 @pytest.mark.parametrize("shapes,want", [
-    # (rows B, page bucket P, K+V bytes of a (row, page)) -> (R, G)
-    ((16, 8, 256 << 10), (8, 2)),    # qwen2-7b-1chip, bucket 8
+    # the latent walk's row blocks:
+    # (rows B, page bucket P, bytes of a (row, page)) -> (R, G)
+    ((16, 8, 256 << 10), (8, 2)),
     ((16, 4, 256 << 10), (16, 1)),
     ((16, 1, 256 << 10), (16, 1)),
-    ((32, 16, 256 << 10), (4, 4)),   # qwen3-next-80b-a3b-1chip
+    ((32, 16, 256 << 10), (4, 4)),
     ((32, 32, 256 << 10), (2, 8)),
-    ((16, 16, 512 << 10), (2, 4)),   # mixtral-8x7b-1chip
+    ((16, 16, 512 << 10), (2, 4)),
+    ((16, 40, 144 << 10), (3, 8)),   # a.x-k1-1chip: a latent page
     ((4, 8, 1 << 10), (4, 2)),       # a tiny pool: the whole batch a block
 ])
 def test_block_sizes_follow_the_programs_shapes(shapes, want):
@@ -154,6 +225,21 @@ def test_plan_sorts_longest_first_and_bounds_each_block_by_its_own():
     assert np.asarray(plan.trips).tolist() == [2, 1]  # ceil(8/4), ceil(1/4)
     assert int(plan.n_blocks) == 2
     assert int(plan.pages_read) == 3 * 4 * 4
+
+
+@pytest.mark.parametrize("latent", [False, True])
+def test_the_pools_format_picks_the_plan(latent):
+    """``kvq.walk_plan``: a latent pool [L, W, n_slots] keeps the row
+    blocks (a carried [B, H, rank] accumulator would be as large as the
+    pages it came from), a K/V pool takes the flat list of pairs."""
+    ln = jnp.asarray([9, 0, 17], jnp.int32)
+    pt = jnp.zeros((3, 4), jnp.int32)
+    kv = (jnp.zeros((2, 24, 13 * PAGE), jnp.bfloat16) if latent
+          else jnp.zeros((2, 2, 13 * PAGE, 2, 16), jnp.bfloat16))
+    plan = kvq.walk_plan(kv, ln, pt, PAGE)
+    assert isinstance(plan, paged_walk.WalkPlan if latent
+                      else paged_walk.PairPlan)
+    assert int(plan.pages_read) >= 5
 
 
 def test_walk_per_head_shard_on_a_mesh_matches_one_device():
@@ -310,10 +396,12 @@ def _stream(eng, prompt, n):
     {}, {"decode_backend": "fused"}, {"pallas_attn": True}],
     ids=["xla-walk", "fused-xla", "pallas"])
 def test_engine_counts_what_its_decode_programs_read(rung):
-    """On the walk the count is the loops' bound (one row of four live:
-    its block of rows, its pages rounded up to a trip); on a kernel
-    rung it is the [B, P] window the program addresses. The pages the
-    live row held are counted the same way on both."""
+    """On the walk the count is the loop's bound (one row of four live:
+    its pairs rounded up to a trip, which at this tiny pool's 4 KiB a
+    pair is the page table of the step's page bucket, and no trip once
+    nothing is live); on a kernel rung it is the [B, P] window the
+    program addresses at every step. The pages the live row held are
+    counted the same way on both."""
     from aigw_tpu.models.registry import get_model_spec
     from aigw_tpu.tpuserve.engine import Engine, EngineConfig
 
@@ -337,7 +425,7 @@ def test_engine_counts_what_its_decode_programs_read(rung):
     assert st.decode_kv_pages_live <= 3 * st.decode_steps
     walks = eng.decode_attn_impl in ("xla-walk", "fused-xla")
     if walks:
-        assert (st.decode_kv_pages_live <= st.decode_kv_pages_read
-                < 4 * 16 * st.decode_steps)
+        assert (st.decode_kv_pages_live < st.decode_kv_pages_read
+                <= 4 * 16 * st.decode_steps)
     else:
         assert st.decode_kv_pages_read > 4 * st.decode_steps

@@ -285,14 +285,18 @@ def _no_window_and_no_pool_copy(text, window, held):
     assert copied and not copied & pools, sorted(copied & pools)
 
 
-@pytest.mark.parametrize("pages", [8, 16])
+@pytest.mark.parametrize("pages", [4, 8, 16])
 def test_dense_decode_window_gathers_no_window_and_copies_no_pool(v5e, pages):
     """The llama skeleton's decode window at ``qwen2-7b-1chip``'s
     geometry (16 slots, pages of 128 tokens, 28/4 heads of 128; four of
     its layers, bfloat16 weights: the property is a layer's): the walk
     reads the pool where it lies. A layer sliced out of the pool for the
-    walk's loops was a copy of it (67 MB a layer) in this PR's first
-    version, and a page re-laid to ``[page, Hkv*D]`` another."""
+    walk's loops was a copy of it (67 MB a layer) in ISSUE 31's first
+    version, and a page re-laid to ``[page, Hkv*D]`` another. Buckets 4
+    and 8 are the ones the two qwen2 cells decode at; since ISSUE 49
+    the walk is one loop over the flat list of live (row, page) pairs,
+    and the program holds no temporary the size of a layer of the pool
+    (a trip is 4 MiB of pages and 229 KB of carried statistics)."""
     import dataclasses
 
     from aigw_tpu.models import llama
@@ -323,13 +327,16 @@ def test_dense_decode_window_gathers_no_window_and_copies_no_pool(v5e, pages):
 
         return jax.lax.scan(body, (kv, tokens, positions), None, length=2)
 
-    text = jax.jit(window, donate_argnums=(1,)).lower(
+    compiled = jax.jit(window, donate_argnums=(1,)).lower(
         p, kv, i32, i32,
         jax.ShapeDtypeStruct((slots, pages), jnp.int32, sharding=v5e),
         jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e)
-    ).compile().as_text()
+    ).compile()
     _no_window_and_no_pool_copy(
-        text, (slots, pages * page, cfg.n_kv_heads, cfg.head_dim), (kv,))
+        compiled.as_text(),
+        (slots, pages * page, cfg.n_kv_heads, cfg.head_dim), (kv,))
+    layer_bytes = 2 * kv.shape[2] * cfg.n_kv_heads * cfg.head_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 @pytest.mark.parametrize("rows,queries", [(1, 256), (4, 16), (32, 5)],

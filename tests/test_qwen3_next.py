@@ -588,7 +588,8 @@ def test_live_row_update_is_the_pool_wide_pass_on_live_rows(live, Rs):
     pool = jax.random.normal(ks[5], (L, B, H, dk, dv), jnp.float32)
     lengths = np.zeros((B,), np.int32)
     lengths[rows] = [17 + 29 * r for r in rows]  # the plan ranks by these
-    plan = paged_walk.walk_plan(jnp.asarray(lengths), 16, PS, 1 << 12)
+    plan = paged_walk.pair_plan(
+        jnp.asarray(lengths), jnp.zeros((B, 16), jnp.int32), PS, 1 << 12)
     n_live = jnp.asarray(len(rows), jnp.int32)
     n_trips = -(-n_live // Rs)
     layer = jnp.asarray(1, jnp.int32)
