@@ -88,6 +88,9 @@ STATE_ONLY: dict[str, str] = {
                        "restoring the weights",
     "weights_quantize_ms": "boot observable: wall time quantizing "
                            "them (0 when unquantized)",
+    "weights_prepared_leaves": "boot observable: leaves the family's "
+                               "serving_params laid out at load (0: "
+                               "the family has none)",
     "param_bytes_per_device": "per-device dict",
     "migration": "capability flag, boolean",
     "max_slots": "EngineConfig echo; the picker derives free slots",
@@ -171,8 +174,9 @@ GROUPS: dict[str, Group] = {
     # family the gateway's ledger reconciles against
     "meter": Group(prefixes=("meter_",)),
     # the compile surface (ISSUE 42), all numeric gauges: the boot
-    # timeline boot_<phase>_ms (import, backend, weights, engine,
-    # warmup, listen: self time from the process's start) and their
+    # timeline boot_<phase>_ms (import, backend, weights,
+    # weights_layout, engine, warmup, listen: self time from the
+    # process's start) and their
     # sum boot_ready_ms; the load ledger's totals by stage
     # xla_trace_ms / xla_lower_ms / xla_retrieval_ms beside
     # xla_compile_ms; and what requests waited for, xla_late_loads and
@@ -181,7 +185,8 @@ GROUPS: dict[str, Group] = {
     "boot": Group(
         prefixes=("boot_", "xla_"),
         exact=("warmup_ms", "warm_programs", "weights_init_ms",
-               "weights_quantize_ms", "compile_cache_dir")),
+               "weights_quantize_ms", "weights_prepared_leaves",
+               "compile_cache_dir")),
 }
 
 #: /metrics substrings a group's smoke must also assert on but that are

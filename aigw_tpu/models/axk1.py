@@ -41,10 +41,35 @@ source's): rotary pairs as halves rather than interleaved, ``wkv_b`` as
 ``[c, head, k_nope | v]``, expert matrices flat (``[D, E*F]``,
 ``[E*F, D]``). ``topk_method: "none"`` is read as: no selection bias,
 and a group's score is its largest expert score.
+
+TWO TREES, and the contract between them. :func:`init_params` gives a
+CHECKPOINT's tree: per layer ``wq_b`` ``[q_lora, H*(nope+rope)]`` and
+``wkv_b`` ``[kv_lora, H*(nope+v)]`` as published (the references, a
+restore's ``like`` tree and cellbench/reference_check_latent.py read
+those names and shapes). :func:`serving_params` turns it ONCE, where a
+replica has loaded its weights (``ModelFns.serving_params``, called by
+``tpuserve/server.py`` ``_load_params`` after every weight source), into
+the tree the programs read, and drops the two published leaves:
+
+  ``wq_nope`` ``[H, nope, q_lora]``, ``wq_rope`` ``[H, rope, q_lora]``
+      (``bsq,hdq->bshd``): ``wq_b`` cut by output columns, head-major
+  ``w_uk`` ``[H, kv_lora, nope]`` (``bshd,hcd->bshc``) and
+  ``w_uv`` ``[H, v, kv_lora]`` (``bshc,hvc->bshv``): ``wkv_b``'s halves
+
+each stored as its product contracts it, so that no compiled program
+re-lays a weight out (read as published, the chip's compiler transposed
+both matrices of every layer in EVERY call of every program, 327 MB a
+call: tests/test_pallas_tpu_aot.py holds it gone). Every output element
+is the same sum of the same products. A loader written later keeps
+handing ``init_params``' tree to ``serving_params``; it never builds the
+serving leaves itself. ``_mla_q``, :func:`absorb` and ``_mla_out`` read
+whichever form the tree they are given holds — which they can see — so
+a tree that still has ``wq_b`` / ``wkv_b`` works, slower on the chip.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any
@@ -353,8 +378,41 @@ def moe(p: dict, i: int, x: jax.Array, cfg: AXK1Config,
 
 
 # -- latent attention -------------------------------------------------------
+@functools.partial(jax.jit, static_argnums=2)
+def _relay(wq_b, wkv_b, cfg):
+    """One layer's four serving leaves out of its two published ones."""
+    H, dn = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    q = wq_b.reshape(cfg.q_lora_rank, H, cfg.qk_head_dim)
+    kv = wkv_b.reshape(cfg.kv_lora_rank, H, dn + cfg.v_head_dim)
+    return (jnp.transpose(q[..., :dn], (1, 2, 0)),
+            jnp.transpose(q[..., dn:], (1, 2, 0)),
+            jnp.transpose(kv[..., :dn], (1, 0, 2)),
+            jnp.transpose(kv[..., dn:], (1, 2, 0)))
+
+
+def serving_params(p: dict, cfg: AXK1Config) -> dict:
+    """The tree the programs read, out of :func:`init_params`' (a
+    checkpoint's) tree: every layer's ``wq_b`` and ``wkv_b`` laid out
+    ONCE as the four operands their products contract (module
+    docstring), a layer at a time, the published pair left out of the
+    tree returned (``p`` is not touched: the caller lets go of it, and
+    until then one copy of the twelve matrices, 0.33 GB at the
+    published widths, stands beside the weights — at load, before any
+    pool). A layer whose pair is not there as plain matrices (already
+    laid out) stays as it is: the programs read whichever form the
+    tree holds."""
+    out = dict(p)
+    for i in range(cfg.num_hidden_layers):
+        wq_b, wkv_b = f"l{i}.wq_b", f"l{i}.wkv_b"
+        if wq_b in out and wkv_b in out:
+            (out[f"l{i}.wq_nope"], out[f"l{i}.wq_rope"], out[f"l{i}.w_uk"],
+             out[f"l{i}.w_uv"]) = _relay(out.pop(wq_b), out.pop(wkv_b), cfg)
+    return out
+
+
 def _kvb(p, i, cfg):
-    """``W_kvb`` as [c, head, k_nope | v]."""
+    """``W_kvb`` as [c, head, k_nope | v], of a tree that holds the
+    published leaf."""
     return llama._w(p, f"l{i}.wkv_b").reshape(
         cfg.kv_lora_rank, cfg.num_attention_heads,
         cfg.qk_nope_head_dim + cfg.v_head_dim)
@@ -365,23 +423,29 @@ def _mla_q(p, i, h, positions, cfg, inv_freq):
     """→ the ABSORBED query [B,S,H,cache_row]: each head's ``q_nope``
     folded through ``W_kvb``'s key half onto the latent, its rotated
     ``q_rope`` beside it."""
-    B, S, _ = h.shape
-    dn = cfg.qk_nope_head_dim
     cq = llama.rms_norm(llama._matmul(p, f"l{i}.wq_a", h),
                         p[f"l{i}.q_norm"], cfg.rms_norm_eps)
-    q = llama._matmul(p, f"l{i}.wq_b", cq).reshape(
-        B, S, cfg.num_attention_heads, cfg.qk_head_dim)
-    return absorb(p, i, q[..., :dn],
-                  _rope(q[..., dn:], positions, inv_freq), cfg)
+    if f"l{i}.wq_nope" in p:
+        q_nope = jnp.einsum("bsq,hdq->bshd", cq, p[f"l{i}.wq_nope"])
+        q_rope = jnp.einsum("bsq,hdq->bshd", cq, p[f"l{i}.wq_rope"])
+    else:
+        q = llama._matmul(p, f"l{i}.wq_b", cq).reshape(
+            *h.shape[:2], cfg.num_attention_heads, cfg.qk_head_dim)
+        q_nope = q[..., :cfg.qk_nope_head_dim]
+        q_rope = q[..., cfg.qk_nope_head_dim:]
+    return absorb(p, i, q_nope, _rope(q_rope, positions, inv_freq), cfg)
 
 
 def absorb(p, i, q_nope, q_rope, cfg):
     """Each head's ``q_nope`` [B,S,H,nope] folded through ``W_kvb``'s
     key half onto the latent, its rotated ``q_rope`` beside it."""
-    q_lat = jnp.einsum(
-        "bshd,chd->bshc", q_nope,
-        _kvb(p, i, cfg)[..., :cfg.qk_nope_head_dim],
-        preferred_element_type=jnp.float32).astype(q_nope.dtype)
+    if f"l{i}.w_uk" in p:
+        eq, w_uk = "bshd,hcd->bshc", p[f"l{i}.w_uk"]
+    else:
+        eq = "bshd,chd->bshc"
+        w_uk = _kvb(p, i, cfg)[..., :cfg.qk_nope_head_dim]
+    q_lat = jnp.einsum(eq, q_nope, w_uk,
+                       preferred_element_type=jnp.float32).astype(q_nope.dtype)
     return jnp.concatenate([q_lat, q_rope], axis=-1)
 
 
@@ -442,8 +506,12 @@ def _mla_out(p, i, o_lat, cfg, dtype):
     """``W_kvb``'s value half on the attended latent [B,S,H,r], then
     the output projection."""
     B, S = o_lat.shape[:2]
-    o = jnp.einsum("bshc,chv->bshv", o_lat.astype(dtype),
-                   _kvb(p, i, cfg)[..., cfg.qk_nope_head_dim:],
+    if f"l{i}.w_uv" in p:
+        eq, w_uv = "bshc,hvc->bshv", p[f"l{i}.w_uv"]
+    else:
+        eq = "bshc,chv->bshv"
+        w_uv = _kvb(p, i, cfg)[..., cfg.qk_nope_head_dim:]
+    o = jnp.einsum(eq, o_lat.astype(dtype), w_uv,
                    preferred_element_type=jnp.float32).astype(dtype)
     return llama._matmul(p, f"l{i}.wo", o.reshape(B, S, -1))
 

@@ -63,6 +63,11 @@ class ModelFns:
     # placed counts + capacity drops per layer). The engine turns it on
     # for MoE families (configs carrying ``n_experts``)
     moe_stats: bool = False
+    # ``serving_params(params, cfg)``: the tree the family's programs
+    # read, out of ``init_params``' (a checkpoint's) tree — weights laid
+    # out once, at load, for the products that read them. None: the
+    # programs read ``init_params``' tree as it is
+    serving_params: Any = None
 
 
 def family_fns(family: str) -> ModelFns:
@@ -106,7 +111,8 @@ def family_fns(family: str) -> ModelFns:
         return ModelFns(axk1.init_params, axk1.prefill, axk1.decode_step,
                         axk1.hidden_states,
                         prefill_suffix=axk1.prefill_suffix,
-                        decode_kernels=False, moe_stats=True)
+                        decode_kernels=False, moe_stats=True,
+                        serving_params=axk1.serving_params)
     if family == "mimo_v2":
         from aigw_tpu.models import mimo_v2
 

@@ -213,6 +213,7 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     ("boot_import_ms", "tpuserve_boot_import_ms"),
     ("boot_backend_ms", "tpuserve_boot_backend_ms"),
     ("boot_weights_ms", "tpuserve_boot_weights_ms"),
+    ("boot_weights_layout_ms", "tpuserve_boot_weights_layout_ms"),
     ("boot_engine_ms", "tpuserve_boot_engine_ms"),
     ("boot_warmup_ms", "tpuserve_boot_warmup_ms"),
     ("boot_listen_ms", "tpuserve_boot_listen_ms"),

@@ -829,6 +829,7 @@ class EngineStats:
     boot_import_ms: float = 0.0
     boot_backend_ms: float = 0.0
     boot_weights_ms: float = 0.0
+    boot_weights_layout_ms: float = 0.0
     boot_engine_ms: float = 0.0
     boot_warmup_ms: float = 0.0
     boot_listen_ms: float = 0.0

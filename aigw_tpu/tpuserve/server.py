@@ -452,6 +452,20 @@ class TPUServeServer:
             raise ValueError(f"unsupported weight source {self.weights}")
         jax.block_until_ready(params)
         total_s = time.monotonic() - t0
+        # the family lays its weights out for the products that read
+        # them (models/registry.py ``serving_params``), whatever the
+        # source above handed over: a phase of its own
+        self.weights_prepared_leaves = 0
+        if self.fns.serving_params is not None:
+            BOOT.enter("weights_layout")
+            t1 = time.monotonic()
+            loaded = set(params)
+            params = jax.block_until_ready(
+                self.fns.serving_params(params, self.model_cfg))
+            self.weights_prepared_leaves = len(set(params) - loaded)
+            logger.info("weights laid out for serving: %d leaves in %.0f ms",
+                        self.weights_prepared_leaves,
+                        1e3 * (time.monotonic() - t1))
         BOOT.enter(outer)
         self.weights_init_ms = round(1e3 * (total_s - quant_s), 1)
         self.weights_quantize_ms = round(1e3 * quant_s, 1)
@@ -2442,11 +2456,15 @@ class TPUServeServer:
                 "weights": self.weights,
                 "weights_init_ms": self.weights_init_ms,
                 "weights_quantize_ms": self.weights_quantize_ms,
+                # leaves the family's ``serving_params`` laid out at
+                # load (0: the family has none)
+                "weights_prepared_leaves": self.weights_prepared_leaves,
                 # the boot timeline (utils/boot.py): self time per
                 # phase from the process's start to ready, and the sum
                 "boot_import_ms": s.boot_import_ms,
                 "boot_backend_ms": s.boot_backend_ms,
                 "boot_weights_ms": s.boot_weights_ms,
+                "boot_weights_layout_ms": s.boot_weights_layout_ms,
                 "boot_engine_ms": s.boot_engine_ms,
                 "boot_warmup_ms": s.boot_warmup_ms,
                 "boot_listen_ms": s.boot_listen_ms,

@@ -36,7 +36,8 @@ DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 
 #: the boot timeline's phases, in the order a replica passes them
-BOOT_PHASES = ("import", "backend", "weights", "engine", "warmup", "listen")
+BOOT_PHASES = ("import", "backend", "weights", "weights_layout", "engine",
+               "warmup", "listen")
 
 
 def _process_age_ns() -> int:

@@ -47,6 +47,11 @@ def main(argv: list[str]) -> int:
         spec = get_model_spec(model)
         fns = family_fns(spec.family)
         params = fns.init_params(jax.random.PRNGKey(0), spec.config)
+        # as the server does at load (a checkout from before ISSUE 50
+        # has no such entry in its table)
+        prepare = getattr(fns, "serving_params", None)
+        if prepare is not None:
+            params = prepare(params, spec.config)
         eng = Engine(params, spec.config, EngineConfig(
             max_batch_size=4, max_seq_len=128, page_size=16,
             min_prefill_bucket=16, decode_steps_per_tick=4), fns=fns)

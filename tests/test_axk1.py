@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -49,12 +50,22 @@ def _tokens(cfg, n, seed=0):
     return np.random.default_rng(seed).integers(0, cfg.vocab_size, n)
 
 
-@pytest.fixture(scope="module", params=list(CONFIGS))
+@pytest.fixture(scope="module",
+                params=[(c, t) for c in CONFIGS
+                        for t in ("published", "serving")],
+                ids="-".join)
 def model(request):
-    cfg = CONFIGS[request.param]
-    p = make_params(cfg)
+    """(cfg, the tree the PROGRAMS read, tokens, the reference's
+    log-probs, the published tree the REFERENCE reads). The programs
+    are held to the reference on both trees they accept: a
+    checkpoint's own leaves, and what ``serving_params`` makes of them
+    at load (the tree a replica serves)."""
+    name, tree = request.param
+    cfg = CONFIGS[name]
+    raw = make_params(cfg)
+    p = axk1.serving_params(raw, cfg) if tree == "serving" else raw
     toks = _tokens(cfg, 100)
-    return cfg, p, toks, _lp(ref_logits(p, cfg, toks))
+    return cfg, p, toks, _lp(ref_logits(raw, cfg, toks)), raw
 
 
 def _chunked(p, cfg, toks, chunk, P=8, n_pages=32, table=None):
@@ -187,7 +198,7 @@ def test_yarn_tables_against_float64_by_hand(position):
 
 # -- the programs against the reference --------------------------------------
 def test_one_shot_prefill_matches_reference(model):
-    cfg, p, toks, want = model
+    cfg, p, toks, want, _ = model
     B, S = 2, 112  # padded; the second row shorter
     t = np.zeros((B, S), np.int32)
     t[0, :100], t[1, :57] = toks, toks[:57]
@@ -203,7 +214,7 @@ def test_chunked_prefill_with_a_padded_tail_matches_reference(model, chunk):
     """100 tokens in chunks of 40 (three, the last padded) and of 64
     (two): every chunk after the first attends over the latent pages
     behind it."""
-    cfg, p, toks, want = model
+    cfg, p, toks, want, _ = model
     outs, *_ = _chunked(p, cfg, toks, chunk)
     assert len(outs) == -(-100 // chunk)
     for at, got in outs:
@@ -215,7 +226,7 @@ def test_prefill_then_decode_with_idle_rows_matches_reference(model):
     2 idle), their pages interleaved in the pool, 20 decode steps
     through the paged latent cache, teacher-forced with drawn tokens;
     the second row stops being active half way."""
-    cfg, p, _, _ = model
+    cfg, p, _, _, raw = model
     B, P = 4, 8
     lens = {3: 70, 1: 44}
     table = {3: np.arange(1, 17, 2), 1: np.arange(2, 18, 2)}
@@ -224,7 +235,7 @@ def test_prefill_then_decode_with_idle_rows_matches_reference(model):
     seqs, want = {}, {}
     for b, n in lens.items():
         seqs[b] = _tokens(cfg, n + 20, seed=10 + b)
-        want[b] = _lp(ref_logits(p, cfg, seqs[b]))
+        want[b] = _lp(ref_logits(raw, cfg, seqs[b]))
         t = np.zeros((1, 80), np.int32)
         t[0, :n] = seqs[b][:n]
         out, kv = run.prefill(
@@ -256,18 +267,100 @@ def test_prefill_then_decode_with_idle_rows_matches_reference(model):
 
 
 def test_hidden_states_is_the_reference_mean(model):
-    cfg, p, toks, _ = model
+    cfg, p, toks, _, raw = model
     t = np.zeros((1, 64), np.int32)
     t[0, :50] = toks[:50]
     got = programs(cfg).hidden_states(
         p, tokens=jnp.asarray(t), seq_lens=jnp.asarray([50]))
     c = ref_cfg(cfg)
     with ref.computed_in(jnp.float32):
-        x = p["embed"][jnp.asarray(toks[:50])]
+        x = raw["embed"][jnp.asarray(toks[:50])]
         for i in range(cfg.num_hidden_layers):
-            x = ref.layer(p, i, c, x)
-        x = ref.rms_norm(x, p["norm_f"], cfg.rms_norm_eps)
+            x = ref.layer(raw, i, c, x)
+        x = ref.rms_norm(x, raw["norm_f"], cfg.rms_norm_eps)
     assert np.abs(np.asarray(got[0]) - np.asarray(x.mean(0))).max() < TOL
+
+
+# -- the serving leaves (ISSUE 50) --------------------------------------------
+GOLDEN_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "data", "axk1_programs_be12620.npz")
+PROGRAMS = ("prefill", "prefill_suffix", "decode_step", "hidden_states")
+
+
+@pytest.fixture(scope="module")
+def parent_and_change():
+    import axk1_golden_child
+
+    return np.load(GOLDEN_FILE), axk1_golden_child.outputs(axk1)
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_serving_leaves_give_the_parents_outputs(parent_and_change, program):
+    """The four programs on ``serving_params(init_params(...))`` against
+    the PARENT's on ``init_params(...)`` (be12620, taken before the
+    change by tests/axk1_golden_child.py): the same picks, the same
+    logits. 2e-5: every element is the same sum of the same float32
+    products, but the CPU's dot sums an operand that lies transposed in
+    another order — observed 4.5e-6 on logits as large as 3.2 (1.4e-6
+    of them), 5e-7 on the pooled states."""
+    parent, change = parent_and_change
+    want, got = parent[program], change[program]
+    assert got.shape == want.shape
+    assert np.array_equal(got.argmax(-1), want.argmax(-1))
+    assert np.allclose(got, want, rtol=0, atol=2e-5), \
+        np.abs(got - want).max()
+
+
+def test_serving_params_drops_what_it_laid_out_and_the_pieces_take_both():
+    """``serving_params`` leaves no ``wq_b`` / ``wkv_b`` behind (the
+    bytes stay what they were), passes a tree it has already laid out
+    through, and ``absorb`` / ``_kvb`` still accept the tree that holds
+    the published leaves — what cellbench/reference_check_latent.py
+    hands them."""
+    cfg = axk1.TINY
+    raw = make_params(cfg)
+    p = axk1.serving_params(raw, cfg)
+    L, H = cfg.num_hidden_layers, cfg.num_attention_heads
+    assert len(set(p) - set(raw)) == 4 * L
+    assert set(raw) - set(p) == {f"l{i}.{w}" for i in range(L)
+                                 for w in ("wq_b", "wkv_b")}
+    assert sum(v.nbytes for v in p.values()) \
+        == sum(v.nbytes for v in raw.values())
+    assert p["l1.wq_nope"].shape == (H, cfg.qk_nope_head_dim,
+                                     cfg.q_lora_rank)
+    assert p["l1.wq_rope"].shape == (H, cfg.qk_rope_head_dim,
+                                     cfg.q_lora_rank)
+    assert p["l1.w_uk"].shape == (H, cfg.kv_lora_rank,
+                                  cfg.qk_nope_head_dim)
+    assert p["l1.w_uv"].shape == (H, cfg.v_head_dim, cfg.kv_lora_rank)
+    again = axk1.serving_params(p, cfg)
+    assert set(again) == set(p) and again["l1.w_uk"] is p["l1.w_uk"]
+    # the pieces on both trees
+    kvb = axk1._kvb(raw, 1, cfg)
+    assert kvb.shape == (cfg.kv_lora_rank, H,
+                         cfg.qk_nope_head_dim + cfg.v_head_dim)
+    assert np.array_equal(
+        np.asarray(p["l1.w_uk"]),
+        np.asarray(kvb[..., :cfg.qk_nope_head_dim]).transpose(1, 0, 2))
+    assert np.array_equal(
+        np.asarray(p["l1.w_uv"]),
+        np.asarray(kvb[..., cfg.qk_nope_head_dim:]).transpose(1, 2, 0))
+    q_nope = jax.random.normal(jax.random.PRNGKey(1),
+                               (2, 3, H, cfg.qk_nope_head_dim))
+    q_rope = jax.random.normal(jax.random.PRNGKey(2),
+                               (2, 3, H, cfg.qk_rope_head_dim))
+    a = axk1.absorb(raw, 1, q_nope, q_rope, cfg)
+    b = axk1.absorb(p, 1, q_nope, q_rope, cfg)
+    assert a.shape == (2, 3, H, cfg.cache_row)
+    assert np.allclose(np.asarray(a), np.asarray(b), rtol=0, atol=1e-5)
+
+
+def test_families_without_serving_params_keep_their_tree():
+    from aigw_tpu.models.registry import family_fns
+
+    assert family_fns("axk1").serving_params is axk1.serving_params
+    for family in ("llama", "mixtral", "qwen3_next", "mimo_v2"):
+        assert family_fns(family).serving_params is None
 
 
 # -- the pieces on the same inputs -------------------------------------------

@@ -94,8 +94,8 @@ def served():
     chunks (3 chunks of 32 and a padded tail) beside a short one, then —
     into the slots they leave — a batched pair admitted together."""
     cfg = SHARE
-    p = make_params(cfg)
-    eng = _engine(cfg, p)
+    p = make_params(cfg)  # the reference's tree; the engine serves the
+    eng = _engine(cfg, axk1.serving_params(p, cfg))  # leaves laid out
     eng.start()
     try:
         first = [_Stream(cfg, 100, 20, seed=1), _Stream(cfg, 21, 28, seed=2)]
@@ -224,3 +224,34 @@ def test_registered_preset_and_config_surface():
     assert (fns.verify_step, fns.prefill_sp, fns.prefill_sp_suffix,
             fns.prefill_ragged) == (None,) * 4
     assert axk1.TINY.layer_kinds == ("dense", "moe", "moe", "moe")
+
+
+@pytest.mark.parametrize("model,leaves", [("tiny-axk1", 16),
+                                          ("tiny-random", 0)])
+def test_the_server_lays_the_weights_out_at_load(model, leaves):
+    """``_load_params`` hands what any weight source gave to the
+    family's ``serving_params`` (ISSUE 50): the latent family's engine
+    holds the serving leaves and none of the published pair, four a
+    layer; a family without the entry keeps ``init_params``' tree."""
+    from aigw_tpu.tpuserve.server import TPUServeServer
+
+    server = TPUServeServer(model, EngineConfig(
+        max_batch_size=2, max_seq_len=128, page_size=16,
+        min_prefill_bucket=16, decode_steps_per_tick=4))
+    try:
+        params = server.engine.params
+        assert server.weights_prepared_leaves == leaves
+        spec = get_model_spec(model)
+        like = jax.eval_shape(lambda: family_fns(spec.family).init_params(
+            jax.random.PRNGKey(0), spec.config))
+        if not leaves:
+            assert set(params) == set(like)
+            return
+        assert set(like) - set(params) == {
+            f"l{i}.{w}" for i in range(spec.config.num_hidden_layers)
+            for w in ("wq_b", "wkv_b")}
+        assert len(set(params) - set(like)) == leaves
+        assert sum(v.nbytes for v in params.values()) == sum(
+            np.prod(v.shape) * v.dtype.itemsize for v in like.values())
+    finally:
+        server.engine.stop()

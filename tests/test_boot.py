@@ -125,7 +125,8 @@ def test_phases_are_contiguous_self_time_and_stand_still_after_ready():
     assert led.enter("engine") == "backend"
     outer = led.enter("weights")      # a phase inside another ...
     time.sleep(0.02)
-    assert led.enter(outer) == "weights"  # ... hands it back
+    assert led.enter("weights_layout") == "weights"  # ... and a third
+    assert led.enter(outer) == "weights_layout"  # ... hands it back
     assert led.cur == "engine"
     led.enter("warmup")
     led.enter("listen")
@@ -247,6 +248,15 @@ def test_weights_phase_is_the_two_weights_observables(booted):
     # and the warm-up phase holds Engine.warmup()
     assert st["boot_warmup_ms"] >= st["warmup_ms"]
     assert st["boot_warmup_ms"] == pytest.approx(st["warmup_ms"], abs=1000)
+
+
+def test_a_family_without_serving_params_lays_nothing_out(booted):
+    """The ``weights_layout`` phase is the family's ``serving_params``
+    (models/registry.py): the llama family has none, so nothing was
+    laid out and the phase was never entered."""
+    st = booted[3]
+    assert st["weights_prepared_leaves"] == 0
+    assert st["boot_weights_layout_ms"] == 0
 
 
 def test_state_and_metrics_carry_the_compile_surfaces_keys(booted):

@@ -42,7 +42,12 @@ stands as it stood at b404cf1, and so do the decode programs of the
 latent and the window-and-global family (``tiny-axk1``,
 ``tiny-mimo-v2``), whose walk over a latent pool keeps its block plan —
 their goldens were taken from b404cf1 BEFORE the change, which is the
-proof that those two did not move.
+proof that those two did not move. ISSUE 50 moved ``tiny-axk1``'s two
+decode programs alone (the child lays the latent family's weights out
+through ``ModelFns.serving_params``, as the server does at load, and
+the programs read the serving leaves; one cold compile a deployment of
+that family): every other program here stands as it stood at be12620,
+the proof that no other family's parameter tree or program changed.
 
 Taken by ``python tests/engine_keys_child.py [<checkout>]`` under the
 JAX named below. Another JAX lowers to other text and the comparison
@@ -87,9 +92,9 @@ GOLDEN = {
     "tiny-qwen3-next.decode.lean=False":
         "141b31fa01a17e49ac059fc1ff28a7302d341375c23f7274fa71461bcc8c157b",
     "tiny-axk1.decode.lean=True":
-        "09dc1cb2887734bf8cc741e9c86f9429ec6fb07cc884f230f3c2e352f1dd565d",
+        "91d2363d72eecab35bbf4eaee266e1a10daefd700b62cdea16cd1788724e5f9c",
     "tiny-axk1.decode.lean=False":
-        "884e19b1776d101d9b62b0e78cb26403b04ce80df16315278399b24495210199",
+        "f86fbb4be43d899964e4cdec210e963a28ec36b07ca8421a9ece32155843f622",
     "tiny-mimo-v2.decode.lean=True":
         "69f19761e4a1b30b797db4a30db49b6e8c55d3a6eb896d8be39f5623cf608618",
     "tiny-mimo-v2.decode.lean=False":

@@ -394,3 +394,191 @@ def test_window_read_leaves_the_pool_where_it_lies(v5e, rows, queries):
     logits = rows * heads * queries * pages * PAGE * 4
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 4 * window + 3 * logits
+
+
+#: operations that only move what they are given
+_MOVES = {"parameter", "bitcast", "copy", "transpose", "reshape", "slice",
+          "concatenate", "constant", "tuple", "get-tuple-element",
+          "dynamic-slice", "pad"}
+#: what a weight may pass through and still be a weight and nothing else
+#: (the compiler's own streaming of a matrix into fast memory, a quarter
+#: at a time, among them)
+_CARRIES = {"bitcast", "copy", "transpose", "reshape", "slice", "fusion",
+            "concatenate", "tuple", "get-tuple-element", "custom-call",
+            "slice-start", "slice-done", "copy-start", "copy-done"}
+
+
+def _weight_moves(text, floor=1 << 20):
+    """``[(name, shape, bytes)]`` of the operations of an optimised HLO
+    module that RE-LAY A WEIGHT OUT: a ``copy``, a ``transpose`` or a
+    fusion that only moves data, of ``floor`` bytes or more, standing
+    on its own in the program (the entry computation, or a loop's body
+    for what the loop carries unchanged) with nothing but parameters of
+    the argument ``p`` behind its operands. What sits INSIDE a
+    product's fusion is how that product reads its operand, not an
+    operation of its own, and is not looked at."""
+    import math
+    import re
+
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = comps.setdefault(head.group(2), {})
+            entry = head.group(2) if head.group(1) else entry
+            continue
+        ins = cur is not None and re.match(
+            r"\s*(ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)(.*)$",
+            line)
+        if not ins:
+            cur = None if line.startswith("}") else cur
+            continue
+        root, name, shape, op, args, rest = ins.groups()
+
+        def attr(pattern, rest=rest):
+            found = re.search(pattern, rest)
+            return found.group(1) if found else ""
+
+        cur[name] = {
+            "name": name, "shape": shape, "op": op, "root": bool(root),
+            "args": re.findall(r"%([\w.\-]+)", args),
+            "calls": attr(r"calls=%([\w.\-]+)"),
+            "body": attr(r"body=%([\w.\-]+)"),
+            "index": attr(r"index=(\d+)"),
+            "of": attr(r'op_name="([^"]*)"')}
+
+    def nbytes(shape):
+        dims = re.match(r"(\w+)\[([\d,]*)\]", shape)
+        if not dims:
+            return 0
+        width = {"bf16": 2, "f16": 2, "s8": 1, "u8": 1, "pred": 1}
+        return width.get(dims.group(1), 4) * math.prod(
+            int(d) for d in dims.group(2).split(",") if d)
+
+    found = []
+
+    def scan(comp, weights):
+        weights = set(weights)
+        for ins in comp.values():  # the text defines before it uses
+            if ins["op"] == "parameter":
+                if comp is comps[entry] and ins["of"].startswith("p["):
+                    weights.add(ins["name"])
+                continue
+            alone = ins["args"] and all(a in weights for a in ins["args"])
+            if ins["op"] == "while" and ins["body"]:
+                carried(comp, ins, weights)
+            if not alone or ins["op"] not in _CARRIES or (
+                    ins["op"] == "fusion" and not all(
+                        i["op"] in _MOVES
+                        for i in comps[ins["calls"]].values())):
+                continue
+            weights.add(ins["name"])
+            if ins["op"] in ("copy", "transpose", "fusion") \
+                    and nbytes(ins["shape"]) >= floor:
+                found.append((ins["name"], ins["shape"],
+                              nbytes(ins["shape"])))
+
+    def carried(comp, loop, weights):
+        """A loop's body, with the weights its carry hands through."""
+        init, body = comp.get(loop["args"][0]), comps[loop["body"]]
+        out = next(i for i in body.values() if i["root"])
+        if not init or init["op"] != "tuple" or out["op"] != "tuple":
+            return
+        arg = next(i["name"] for i in body.values()
+                   if i["op"] == "parameter")
+        scan(body, {
+            g["name"] for g in body.values()
+            if g["op"] == "get-tuple-element" and g["args"] == [arg]
+            and init["args"][int(g["index"])] in weights
+            and out["args"][int(g["index"])] == g["name"]})
+
+    scan(comps[entry], ())
+    return found
+
+
+@pytest.mark.parametrize("program,tree", [
+    ("decode_step", "serving"), ("prefill_suffix", "serving"),
+    ("decode_window", "serving"), ("decode_step", "published")])
+def test_no_latent_program_relays_a_weight(v5e, program, tree):
+    """The latent family's decode step, its 256-token chunk and its
+    two-step decode window at ``a.x-k1-1chip``'s geometry (published
+    widths, 1 dense + 5 expert layers, 12 of 192 experts, 16 slots of
+    64 pages of 128: the shapes of tests/cellbench/
+    test_cellbench_axk1.py ``test_fits_one_v5e_chip``), parameters from
+    ``axk1.serving_params`` by ``eval_shape``: no ``copy``, no
+    ``transpose`` and no slice-only fusion of 1 MB or more whose
+    operands are parameters alone (ISSUE 50).
+
+    The parent (be12620), whose programs read ``wq_b`` and ``wkv_b`` as
+    published, held TWELVE such copies in each of the three programs,
+    two a layer, 327 MB a call: ``bf16[1536,12288]{1,0} -> {0,1}`` (37.7
+    MB) and ``bf16[512,16384]{1,0} -> {0,1}`` (16.8 MB) — the compiler
+    wants the query head-major and ``W_kvb`` contracted with the head as
+    a batch dimension, and got both by transposing the WEIGHT, every
+    call; in the window all twelve stand in the entry computation,
+    hoisted out of the loop. On the chip (PERF.md section 5, PR 50):
+    0.83 ms of every window and 0.41 ms of every chunk call. The twelve
+    ``bf16[512,64,128]`` head-major slices of ``W_kvb``'s halves (100
+    MB) that the same programs show sit INSIDE the products' fusions:
+    how a product reads its operand, no operation of their own — the
+    trace has no event for them — so this test does not count them.
+    The ``published`` case is the control: the same step on
+    ``init_params``' own tree, which the programs still accept, must
+    show the parent's twelve, or the detector sees nothing."""
+    from aigw_tpu.models import axk1
+
+    cfg = axk1.AXK1Config(num_hidden_layers=6, num_experts=12,
+                          router_experts=192, vocab_size=20480)
+    slots, page, pages = 16, PAGE, 64
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    published = jax.eval_shape(
+        lambda: axk1.init_params(jax.random.PRNGKey(0), cfg))
+    p = sds(published if tree == "published" else jax.eval_shape(
+        lambda q: axk1.serving_params(q, cfg), published))
+    cache = sds(jax.eval_shape(lambda: cfg.cache_spec().make(
+        (slots * pages + 1) * page, slots, "bfloat16")))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def step(p, cache, tokens, positions, page_table, active):
+        return axk1.decode_step(p, cfg, tokens, positions, cache,
+                                page_table, page, active, moe_stats=True)
+
+    def window(p, cache, tokens, positions, page_table, active):
+        def body(carry, _):
+            cache, tokens, positions = carry
+            logits, cache, moe = step(p, cache, tokens, positions,
+                                      page_table, active)
+            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (cache, tokens, positions + 1), (tokens, moe)
+
+        return jax.lax.scan(body, (cache, tokens, positions), None,
+                            length=2)
+
+    def chunk(p, cache, tokens, prefix_lens, seq_lens, page_table):
+        return axk1.prefill_suffix(p, cfg, tokens, prefix_lens, seq_lens,
+                                   cache, page_table, page, moe_stats=True)
+
+    rows = (arg((slots,)), arg((slots,)), arg((slots, pages)),
+            arg((slots,), jnp.bool_))
+    fn, args = {
+        "decode_step": (step, rows), "decode_window": (window, rows),
+        "prefill_suffix": (chunk, (arg((1, 256)), arg((1,)), arg((1,)),
+                                   arg((1, pages))))}[program]
+    text = jax.jit(fn, donate_argnums=(1,)).lower(
+        p, cache, *args).compile().as_text()
+    moves = _weight_moves(text)
+    if tree == "published":
+        assert len(moves) == 2 * cfg.num_hidden_layers, moves
+        assert sum(m[2] for m in moves) == 6 * 2 * (
+            1536 * 64 * 192 + 512 * 64 * 256)  # 327 MB
+        return
+    assert not moves, moves
+    # and it is the serving leaves the program reads
+    assert "p[\\'l0.wq_nope\\']" in text and "l0.wq_b" not in text
