@@ -69,7 +69,6 @@ STATE_ONLY: dict[str, str] = {
     "constrained_decoding": "capability flag, boolean",
     "capabilities": "capability dict merged into /v1/models",
     "kv_cache_dtype": "EngineConfig echo, string",
-    "decode_backend": "EngineConfig echo, string",
     "decode_attn_impl": "resolved rung, string; /metrics carries the "
                         "labeled tpuserve_decode_attn_impl info gauge",
     "decode_attn_reason": "resolution explanation, string",
@@ -152,7 +151,7 @@ GROUPS: dict[str, Group] = {
     "memory": Group(
         prefixes=("device_bytes_", "kv_bytes_"),
         exact=("device_memory_frac", "kv_pool_bytes", "kv_quant_bits",
-               "kv_cache_dtype", "decode_backend", "decode_attn_impl",
+               "kv_cache_dtype", "decode_attn_impl",
                "decode_attn_reason")),
     "mesh": Group(
         prefixes=("mesh_", "param_bytes_", "ici_"),

@@ -144,21 +144,12 @@ JIT_WARM_SURFACE: dict[str, str] = {
         "factory only: Engine.__init__ registers the returned callable "
         "with the CompileTracker as 'adapter_load' and warmup() "
         "pre-compiles it via AdapterStore.warm()"),
-    "aigw_tpu/ops/pallas/paged_attention.py::paged_attention_decode_v2": (
-        "dispatched inside the registered decode programs "
-        "(Engine._decode_fn_for); pre-compiled by warmup()'s ladder"),
     "aigw_tpu/ops/pallas/paged_attention.py::ragged_prefill_attention": (
         "dispatched inside the registered 'prefill_ragged' program; "
         "pre-compiled by attn.warm()'s token-budget rungs"),
-    "aigw_tpu/ops/pallas/paged_attention.py::paged_attention_verify": (
-        "dispatched inside the registered verify-ladder programs; "
-        "pre-compiled by warmup()'s draft rungs"),
     "aigw_tpu/ops/pallas/qmatmul.py::_w8a16_matmul": (
         "dispatched inside every registered program of a quantized "
         "deployment; shares their warmup"),
-    "aigw_tpu/ops/pallas/decode_fused.py::fused_paged_decode": (
-        "the fused decode rung dispatched inside the registered decode "
-        "programs; pre-compiled by warmup()'s ladder"),
 }
 
 #: module path prefixes the ``jit-registry`` pass scans — the serving

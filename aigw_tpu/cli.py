@@ -274,11 +274,6 @@ def main(argv: list[str] | None = None) -> int:
     p_serve.add_argument("--no-speculation", action="store_true",
                          help="force speculative decoding off "
                               "(overrides --spec-tokens)")
-    p_serve.add_argument("--pallas-attn", action="store_true",
-                         help="ragged paged-attention Pallas kernels for "
-                              "decode and speculative verify (single-chip; "
-                              "HBM reads scale with actual sequence "
-                              "lengths)")
     p_serve.add_argument("--attention-backend", default="xla-bucketed",
                          choices=["xla-bucketed", "pallas-ragged"],
                          help="prefill attention backend: xla-bucketed "
@@ -291,24 +286,13 @@ def main(argv: list[str] | None = None) -> int:
                               "chunked continuations as start offsets. "
                               "Auto-falls back to XLA attention "
                               "off-TPU and to xla-bucketed on a mesh")
-    p_serve.add_argument("--decode-backend", default="auto",
-                         choices=["auto", "chained", "fused"],
-                         help="decode attention rung: chained (rope → "
-                              "scatter → gather/kernel, the classic "
-                              "path) or fused — ONE program per decode "
-                              "dispatch (RoPE + KV append + paged "
-                              "attention; Pallas kernel on single-chip "
-                              "TPU, XLA page walk off-TPU, shard_map "
-                              "local-shard walk on a mesh). auto = "
-                              "chained; /state exports the resolution")
     p_serve.add_argument("--kv-cache-dtype", default="bfloat16",
                          choices=["bfloat16", "float32", "int8", "int4"],
                          help="KV page element dtype. int8/int4 store "
                               "quantized pages + per-page scale blocks "
                               "(~0.52x / ~0.27x the bf16 KV bytes at "
                               "head_dim 128 — more concurrent sessions "
-                              "per chip), dequantized in-kernel / at "
-                              "the gather")
+                              "per chip), dequantized at the read")
     p_serve.add_argument("--ragged-chunk-tokens", type=int, default=256,
                          help="pallas-ragged padding granule: packed "
                               "totals pad to multiples of this (the "
@@ -930,9 +914,7 @@ async def _run_tpuserve(args: argparse.Namespace) -> int:
         prefill_chunk_tokens=args.prefill_chunk_tokens,
         spec_tokens=0 if args.no_speculation else args.spec_tokens,
         spec_adaptive=not args.no_spec_adaptive,
-        pallas_attn=args.pallas_attn,
         attention_backend=args.attention_backend,
-        decode_backend=args.decode_backend,
         kv_cache_dtype=args.kv_cache_dtype,
         ragged_chunk_tokens=args.ragged_chunk_tokens,
         logprobs_topk=args.logprobs,

@@ -685,11 +685,12 @@ def decode_step(p, cfg: AXK1Config, tokens, positions, cache, page_table,
     """One continuous-batching step; row ``b`` IS decode slot ``b``.
     Inactive rows append nothing and read nothing. ``walk``: this
     step's plan (made here when the caller has none); ``attn_impl`` may
-    name no other rung: no kernel knows a latent row."""
+    name no other rung: the window gather reads K and V rows, and the
+    pool holds latent rows."""
     if attn_impl:
         raise NotImplementedError(
-            f"decode attention rung {attn_impl!r}: the Pallas kernels "
-            "read K and V pages, not latent rows")
+            f"axk1 has no decode attention rung {attn_impl!r}: its pool "
+            "holds latent rows, which only the page walk reads")
     tape: list | None = [] if moe_stats else None
     kv = cache
     pos1 = positions[:, None]

@@ -22,8 +22,7 @@ Quantization is symmetric round-to-nearest-even in float32:
     q     = clip(round(x / scale), -qmax, qmax)
 
 with qmax 127 (int8) / 7 (int4; -8 unused keeps the grid symmetric).
-Dequantization is ``q * scale`` in float32 — done *in-kernel* by the
-fused decode kernel (ops/pallas/decode_fused.py) and at the read by the
+Dequantization is ``q * scale`` in float32 — done at the read by the
 XLA paths — the decode walk's trips (``walk_kv``) and a chunk's, tail's
 or verify program's window (``window_kv``), both of which take whole
 pages out of the pool viewed as one list of pages — so the quantized
@@ -217,23 +216,10 @@ def walk_kv(kv: Any, layer: int, q: jax.Array, page_table: jax.Array,
 
 def layer_pool(kv: Any, layer: int, which: int):
     """(rows [n_slots, Hkv, D], scale [n_slots, Hkv] | None) — the flat
-    per-layer pool view the paged-attention walks/kernels consume."""
+    per-layer pool view the ragged prefill kernel consumes."""
     if not is_quantized(kv):
         return kv[layer, which], None
     return kv["q"][layer, which], kv["scale"][layer, which]
-
-
-def set_layer_pool(kv: Any, layer: int, k_rows, v_rows, k_scale=None,
-                   v_scale=None) -> Any:
-    """Write back a layer's (possibly kernel-updated) pool leaves."""
-    if not is_quantized(kv):
-        kv = kv.at[layer, 0].set(k_rows)
-        return kv.at[layer, 1].set(v_rows)
-    pool = kv["q"].at[layer, 0].set(k_rows)
-    pool = pool.at[layer, 1].set(v_rows)
-    scale = kv["scale"].at[layer, 0].set(k_scale)
-    scale = scale.at[layer, 1].set(v_scale)
-    return {"q": pool, "scale": scale}
 
 
 # -- host-side page helpers (wire / spill / migration) --------------------

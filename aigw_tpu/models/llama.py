@@ -11,7 +11,7 @@ TPU-first design decisions (not a port of any torch implementation):
   ``[L, 2, n_pages * page_size, n_kv_heads, head_dim]``; sequences own
   pages via an int32 page table. Flattening pages makes cache writes one
   scatter and cache reads one gather — both XLA-native ops that fuse well,
-  and the same layout the Pallas paged-attention kernel consumes
+  and the same layout the Pallas ragged-prefill kernel consumes
   (PAPERS.md: Ragged Paged Attention for TPU).
 - **GQA**: K/V heads are kept un-repeated in the cache (HBM bandwidth is
   the bottleneck); Q heads are grouped over KV heads inside attention.
@@ -285,8 +285,7 @@ def _wo_project(p, i, attn, lora=None, adapter_idx=None):
 
 
 @jax.named_scope("layer/attn")
-def _project_qkv(p, i, x, positions, cfg, lora=None, adapter_idx=None,
-                 apply_rope=True):
+def _project_qkv(p, i, x, positions, cfg, lora=None, adapter_idx=None):
     hd = cfg.head_dim
     B, S, _ = x.shape
     q = _matmul(p, f"l{i}.wq", x)
@@ -306,9 +305,8 @@ def _project_qkv(p, i, x, positions, cfg, lora=None, adapter_idx=None,
     q = q.reshape(B, S, cfg.n_heads, hd)
     k = k.reshape(B, S, cfg.n_kv_heads, hd)
     v = v.reshape(B, S, cfg.n_kv_heads, hd)
-    if apply_rope:  # the fused decode kernel ropes Q/K in-kernel
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg.rope_theta)
+    k = rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -527,14 +525,14 @@ def decode_step(
 
     The hot loop: fixed shapes, inactive slots masked (their K/V writes
     drop). ``attn_impl`` selects the decode-attention rung (resolved by
-    tpuserve/attention.py's fallback matrix, never directly by users):
+    tpuserve/attention.py from what the engine can observe, never
+    directly by users):
 
-    - ``""`` — the page walk (ops/paged_walk.py; ``xla-walk`` and,
-      where ``--decode-backend fused`` cannot run its kernel,
-      ``fused-xla`` on /state, each ``-spmd`` on a mesh): scatter
-      (quantizing in-pass), then an online-softmax loop over the whole
-      pages the LIVE rows hold — nothing padded is gathered, int8/int4
-      pages dequantize at the read. ``walk`` is this step's
+    - ``""`` — the page walk (ops/paged_walk.py; ``xla-walk`` on
+      /state, ``xla-walk-spmd`` on a mesh): scatter (quantizing
+      in-pass), then an online-softmax loop over the whole pages the
+      LIVE rows hold — nothing padded is gathered, int8/int4 pages
+      dequantize at the read. ``walk`` is this step's
       ``paged_walk.pair_plan`` (made here when the caller has none;
       the engine makes it, to count what the loops read). With
       ``mesh`` the walk runs per head-shard inside shard_map: each
@@ -542,13 +540,6 @@ def decode_step(
     - ``"gather"`` — the full padded window [B, T_max] is gathered per
       slot and runs dense attention: the one rung that needs no whole
       head shard per device (a mesh whose head counts do not divide tp).
-    - ``"pallas"`` — the chained ragged paged-attention kernel
-      (ops/pallas/paged_attention.py): scatter first, kernel reads the
-      pool. Native-dtype pools only.
-    - ``"fused-pallas"`` — ONE kernel per dispatch
-      (ops/pallas/decode_fused.fused_paged_decode): RoPE + quantized
-      append + paged attention fused; requires the engine's reserved
-      dump page (last pool page) for inactive-slot writes.
     """
     B = tokens.shape[0]
     max_pages = page_table.shape[1]
@@ -562,28 +553,14 @@ def decode_step(
     )  # [B, 1]
     slot = jnp.where(active[:, None], slot, n_slots)  # OOB → dropped
 
-    use_pallas = attn_impl == "pallas"
-    use_fused_kernel = attn_impl == "fused-pallas"
+    if attn_impl not in ("", "gather"):
+        raise ValueError(f"unknown decode attention rung {attn_impl!r}")
     use_gather = attn_impl == "gather"
-    if use_pallas and kvq.is_quantized(kv_cache):
-        raise NotImplementedError(
-            "the chained Pallas decode kernel has no quantized-pool "
-            "rung — the fallback matrix resolves int8/int4 to fused")
     lengths = jnp.where(active, positions + 1, 0)
     if use_gather:
         # gather the full (padded) KV window for each slot
         t_idx = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, 0)
         attend = t_idx <= pos1  # causal within the sequence window
-    elif use_pallas or use_fused_kernel:
-        from aigw_tpu.ops.pallas._compat import is_tpu_backend
-
-        interp = not is_tpu_backend()
-        if use_pallas:
-            from aigw_tpu.ops.pallas.paged_attention import (
-                paged_attention_decode_v2,
-            )
-        else:
-            from aigw_tpu.ops.pallas.decode_fused import fused_paged_decode
     elif walk is None:
         walk = kvq.walk_plan(kv_cache, lengths, page_table, page_size, mesh)
 
@@ -591,37 +568,15 @@ def decode_step(
     x = _embed_rows(p, tokens[:, None])  # [B, 1, dim]
     for i in range(cfg.n_layers):
         h = rms_norm(x, p[f"l{i}.attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(p, i, h, pos1, cfg, lora, adapter_idx,
-                               apply_rope=not use_fused_kernel)
-        if use_fused_kernel:
-            # RoPE + append + attention in ONE kernel; the pool leaves
-            # come back with the new row already written
-            kr, ksc = kvq.layer_pool(kv_cache, i, 0)
-            vr, vsc = kvq.layer_pool(kv_cache, i, 1)
-            with jax.named_scope("layer/attn"):
-                outs = fused_paged_decode(
-                    q[:, 0], k[:, 0], v[:, 0], kr, vr, page_table,
-                    positions, active, k_scale=ksc, v_scale=vsc,
-                    rope_theta=cfg.rope_theta, page_size=page_size,
-                    interpret=interp)
-            attn = outs[0].reshape(B, 1, HD)
-            kv_cache = kvq.set_layer_pool(kv_cache, i, *outs[1:])
+        q, k, v = _project_qkv(p, i, h, pos1, cfg, lora, adapter_idx)
+        kv_cache = kvq.scatter_kv(kv_cache, i, slot, k, v)
+        if use_gather:
+            k_all, v_all = _gather_kv(kv_cache, i, page_table, page_size)
+            attn = _attention(q, k_all, v_all, attend[:, None, :])
         else:
-            kv_cache = kvq.scatter_kv(kv_cache, i, slot, k, v)
-            if use_pallas:
-                with jax.named_scope("layer/attn"):
-                    attn = paged_attention_decode_v2(
-                        q[:, 0], kv_cache[i, 0], kv_cache[i, 1], page_table,
-                        lengths, page_size=page_size, interpret=interp,
-                    ).reshape(B, 1, HD)
-            elif use_gather:
-                k_all, v_all = _gather_kv(kv_cache, i, page_table,
-                                          page_size)
-                attn = _attention(q, k_all, v_all, attend[:, None, :])
-            else:
-                attn = kvq.walk_kv(kv_cache, i, q[:, 0], page_table,
-                                   lengths, page_size, walk,
-                                   mesh).reshape(B, 1, HD)
+            attn = kvq.walk_kv(kv_cache, i, q[:, 0], page_table,
+                               lengths, page_size, walk,
+                               mesh).reshape(B, 1, HD)
         x = x + _wo_project(p, i, attn, lora, adapter_idx)
         h = rms_norm(x, p[f"l{i}.mlp_norm"], cfg.norm_eps)
         x = x + (mlp(p, i, h) if mlp is not None
@@ -643,7 +598,6 @@ def verify_step(
     mlp=None,
     lora=None,
     adapter_idx=None,
-    attn_impl: str = "",  # "" = XLA gather; "pallas" = ragged kernel
 ) -> tuple[jax.Array, jax.Array]:
     """Speculative-decoding verifier: score S candidate positions in one
     step, returning logits at EVERY position ([B, S, V]) so the engine can
@@ -658,7 +612,6 @@ def verify_step(
     B, S = tokens.shape
     T = page_table.shape[1] * page_size
     n_slots = kvq.n_slots(kv_cache)
-    start = positions
     positions = positions[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     valid = active[:, None] & (positions < limits[:, None])  # [B, S]
 
@@ -669,40 +622,17 @@ def verify_step(
     )
     flat = jnp.where(valid, slot, n_slots)  # OOB → dropped by scatter
 
-    use_pallas = attn_impl == "pallas"
-    if use_pallas and kvq.is_quantized(kv_cache):
-        raise NotImplementedError(
-            "the Pallas verify kernel has no quantized-pool rung — the "
-            "fallback matrix keeps int8/int4 on the gather-dequant path")
-    if not use_pallas:
-        t_idx = jnp.arange(T, dtype=jnp.int32)[None, :]
-    else:
-        from aigw_tpu.ops.pallas._compat import is_tpu_backend
-        from aigw_tpu.ops.pallas.paged_attention import (
-            paged_attention_verify,
-        )
-
-        # inactive slots: start <= -(S+1) → zero attendable keys
-        # (the kernel's page gate is pos0 + S - p*page_size)
-        pal_pos = jnp.where(active, start, -(S + 1))
-        interp = not is_tpu_backend()
+    t_idx = jnp.arange(T, dtype=jnp.int32)[None, :]
 
     x = _embed_rows(p, tokens)
     for i in range(cfg.n_layers):
         h = rms_norm(x, p[f"l{i}.attn_norm"], cfg.norm_eps)
         q, k, v = _project_qkv(p, i, h, positions, cfg, lora, adapter_idx)
         kv_cache = kvq.scatter_kv(kv_cache, i, flat, k, v)
-        if use_pallas:
-            with jax.named_scope("layer/attn"):
-                attn = paged_attention_verify(
-                    q, kv_cache[i, 0], kv_cache[i, 1], page_table, pal_pos,
-                    page_size=page_size, interpret=interp,
-                ).reshape(B, S, cfg.n_heads * cfg.head_dim)
-        else:
-            k_all, v_all = _gather_kv(kv_cache, i, page_table, page_size)
-            mask = (t_idx[:, None, :] <= positions[:, :, None]) \
-                & valid[..., None]
-            attn = _attention(q, k_all, v_all, mask)
+        k_all, v_all = _gather_kv(kv_cache, i, page_table, page_size)
+        mask = (t_idx[:, None, :] <= positions[:, :, None]) \
+            & valid[..., None]
+        attn = _attention(q, k_all, v_all, mask)
         x = x + _wo_project(p, i, attn, lora, adapter_idx)
         h = rms_norm(x, p[f"l{i}.mlp_norm"], cfg.norm_eps)
         x = x + (mlp(p, i, h) if mlp is not None

@@ -744,11 +744,13 @@ def decode_step(p, cfg: MiMoV2Config, tokens, positions, cache, page_table,
     neither. ``walk``: this step's plan (made here when the caller has
     none) — the global layers' walk runs on it, and the window layers'
     loop takes its order of the live rows; ``attn_impl`` may name no
-    other rung: no Pallas kernel knows two widths, a band or a sink."""
+    other rung: the window gather knows one width for keys and values,
+    no ring and no sink."""
     if attn_impl:
         raise NotImplementedError(
-            f"decode attention rung {attn_impl!r}: the Pallas kernels "
-            "know one width for keys and values, no band and no sink")
+            f"mimo_v2 has no decode attention rung {attn_impl!r}: only "
+            "the page walk and the ring loop know its two widths, its "
+            "band and its sink")
     tape: list | None = [] if moe_stats else None
     kv, slots = cache.kv, dict(cache.slots)
     pos1 = positions[:, None]

@@ -325,7 +325,7 @@ def _with_moe(out, tape):
 
 # Every entry point of the llama skeleton is delegated with the MoE MLP
 # plugged in — full feature parity (ragged prefill, chunked suffix
-# resume, sequence-parallel prefill, fused decode, spec-decode verify),
+# resume, sequence-parallel prefill, decode, spec-decode verify),
 # no family rows left in the fallback matrices. The static ``moe_stats``
 # kwarg turns on the routing-stats leaf: the engine jits its programs
 # with moe_stats=True for MoE families, so per-expert load and
@@ -417,10 +417,9 @@ def hidden_states(p, cfg: MixtralConfig, tokens, seq_lens):
 
 def verify_step(p, cfg: MixtralConfig, tokens, positions, kv_cache,
                 page_table, page_size, active, limits,
-                lora=None, adapter_idx=None, attn_impl="",
-                moe_stats=False):
+                lora=None, adapter_idx=None, moe_stats=False):
     tape: list | None = [] if moe_stats else None
     out = llama.verify_step(p, cfg.as_llama(), tokens, positions, kv_cache,
                             page_table, page_size, active, limits,
-                            mlp=_mlp_fn(cfg, tape), attn_impl=attn_impl)
+                            mlp=_mlp_fn(cfg, tape))
     return _with_moe(out, tape) if moe_stats else out

@@ -799,12 +799,13 @@ def decode_step(p, cfg: Qwen3NextConfig, tokens, positions, cache,
     pages as they are. The full-attention layers read the pool through
     the page walk every family's decode step shares (ops/paged_walk.py;
     ``walk``: this step's plan, made here when the caller has none);
-    ``attn_impl`` may name no other rung: the Pallas kernels fuse
-    full-width rotary and know no q/k norm or output gate."""
+    ``attn_impl`` may name no other rung: the family has no window
+    gather, and a mesh whose tp does not divide its heads must hear so."""
     if attn_impl:
         raise NotImplementedError(
-            f"decode attention rung {attn_impl!r}: the Pallas kernels "
-            "fuse full-width rotary and know no q/k norm or output gate")
+            f"qwen3_next has no decode attention rung {attn_impl!r}: its "
+            "full-attention layers read the pool through the page walk "
+            "alone")
     tape: list | None = [] if moe_stats else None
     B = tokens.shape[0]
     kv, slots = cache.kv, dict(cache.slots)

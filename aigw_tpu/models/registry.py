@@ -52,12 +52,6 @@ class ModelFns:
     # qwen3_next (its recurrent state would have to reset at every
     # packed segment's start) and hand-built ModelFns
     prefill_ragged: Any = None
-    # whether ``decode_step`` takes the kernel rungs of the decode
-    # fallback matrix as ``attn_impl`` ("pallas", "fused",
-    # "fused-pallas"). False: the family's attention has no kernel rung
-    # and every request resolves to the window gather
-    # (tpuserve/attention.resolve_decode_backend)
-    decode_kernels: bool = True
     # static kwarg contract: entry points accept ``moe_stats=True`` and
     # return a trailing [L, E+1] int32 routing-stats leaf (per-expert
     # placed counts + capacity drops per layer). The engine turns it on
@@ -95,35 +89,30 @@ def family_fns(family: str) -> ModelFns:
 
         # no verify_step (a rejected draft would need the DeltaNet
         # state rolled back), no sequence-parallel prefill, no ragged
-        # prefill: the engine reads each as "off for this family". No
-        # decode kernel rung either: the Pallas kernels fuse full-width
-        # rotary and know no q/k norm or output gate
+        # prefill: the engine reads each as "off for this family"
         return ModelFns(qwen3_next.init_params, qwen3_next.prefill,
                         qwen3_next.decode_step, qwen3_next.hidden_states,
                         prefill_suffix=qwen3_next.prefill_suffix,
-                        decode_kernels=False, moe_stats=True)
+                        moe_stats=True)
     if family == "axk1":
         from aigw_tpu.models import axk1
 
         # no verify_step (speculation is off for the family), no
-        # sequence-parallel and no ragged prefill, no decode kernel
-        # rung: no Pallas kernel reads a latent row
+        # sequence-parallel and no ragged prefill
         return ModelFns(axk1.init_params, axk1.prefill, axk1.decode_step,
                         axk1.hidden_states,
                         prefill_suffix=axk1.prefill_suffix,
-                        decode_kernels=False, moe_stats=True,
-                        serving_params=axk1.serving_params)
+                        moe_stats=True, serving_params=axk1.serving_params)
     if family == "mimo_v2":
         from aigw_tpu.models import mimo_v2
 
         # no verify_step (speculation is off for the family), no
         # sequence-parallel and no ragged prefill (a packed segment
-        # would have to start its ring afresh), no decode kernel rung:
-        # no Pallas kernel knows two widths, a band or a sink
+        # would have to start its ring afresh)
         return ModelFns(mimo_v2.init_params, mimo_v2.prefill,
                         mimo_v2.decode_step, mimo_v2.hidden_states,
                         prefill_suffix=mimo_v2.prefill_suffix,
-                        decode_kernels=False, moe_stats=True)
+                        moe_stats=True)
     raise KeyError(f"unknown model family {family!r}")
 
 

@@ -283,7 +283,7 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     ("device_memory_frac_worst", "tpuserve_device_memory_frac_worst"),
     ("ici_bytes_per_token", "tpuserve_ici_bytes_per_token"),
     ("ici_bytes_total", "tpuserve_ici_bytes_total"),
-    # quantized KV pages + fused decode (ISSUE 13): bits per stored KV
+    # quantized KV pages (ISSUE 13): bits per stored KV
     # element (32/16 native, 8/4 quantized) and the all-layer HBM
     # bytes one cached token costs including its per-page scale share.
     # The RESOLVED decode rung itself is a string — it rides /metrics

@@ -1,10 +1,9 @@
-"""Pallas TPU kernels: paged attention for decode, speculative verify
-and ragged prefill.
+"""Pallas TPU kernel: ragged paged-attention prefill.
 
 Queries attend over a paged KV cache. Design (ragged-paged-attention
 style, PAPERS.md arxiv 2604.15464 — implementation is original):
 
-- Grid ``(B, P)`` — sequence, then pages innermost; one grid step
+- Grid ``(q-blocks, B, P)`` — pages innermost; one grid step
   streams a full pool page (all KV heads). The page table is a
   **scalar-prefetch** argument, so each page's K/V block is DMA'd from
   the HBM pool straight to VMEM by the Pallas pipeline (auto
@@ -15,17 +14,13 @@ style, PAPERS.md arxiv 2604.15464 — implementation is original):
   fixed sequence; the output tile is written on the last page.
 - GQA: each KV head's ``group = H // Hkv`` query heads run as plain 2D
   matmuls against that head's lane window of the page (Mosaic-friendly;
-  K/V stay un-repeated in HBM since bandwidth is the decode
-  bottleneck).
-- **Ragged DMA skip** — the reason this beats the XLA gather path: the
-  gather materializes the FULL padded window per layer regardless of how
-  long each sequence actually is. Here the index map *clamps* page
-  indices past a sequence's last valid page to the last valid page
-  itself, so consecutive grid steps see an unchanged block index and the
-  Pallas pipeline skips the re-fetch — HBM traffic scales with the
-  tokens actually in the cache, not the padded window. (Compute for
-  those steps is already masked by ``pl.when``; it was only the DMA that
-  kept the old kernels at parity with XLA.)
+  K/V stay un-repeated in HBM).
+- **Ragged DMA skip** — the index map *clamps* page indices past a
+  sequence's last valid page to the last valid page itself, so
+  consecutive grid steps see an unchanged block index and the Pallas
+  pipeline skips the re-fetch — HBM traffic scales with the tokens
+  actually in the cache, not the padded window. (Compute for those
+  steps is already masked by ``pl.when``.)
 """
 
 from __future__ import annotations
@@ -42,8 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 def _flash_update(rows, q, k_h, v_h, mask, m_ref, l_ref, acc_ref):
     """One online-softmax step for a row block: fold this page's
     masked logits into the running (max, denom, accumulator) scratch.
-    Shared by the decode, verify and ragged-prefill kernels — they
-    differ only in row layout and mask construction."""
+    ``rows`` selects one KV head's query rows of the scratch."""
     D = q.shape[1]
     logits = jax.lax.dot_general(
         q, k_h,
@@ -65,162 +59,6 @@ def _flash_update(rows, q, k_h, v_h, mask, m_ref, l_ref, acc_ref):
     )
     acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
     m_ref[rows, 0:1] = m_new
-
-
-def _decode_kernel_v2(
-    page_table_ref,  # [B * P] int32
-    lengths_ref,  # [B] int32
-    q_ref,  # [1, H, D]
-    k_ref,  # [page, Hkv * D] — one full pool page, all heads
-    v_ref,  # [page, Hkv * D]
-    o_ref,  # [1, H, D]
-    m_ref,  # [H, 128] f32
-    l_ref,  # [H, 128] f32
-    acc_ref,  # [H, D] f32
-    *,
-    page_size: int,
-    n_pages: int,
-    n_kv_heads: int,
-):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    length = lengths_ref[b]
-    valid = jnp.clip(length - p * page_size, 0, page_size)
-
-    @pl.when(valid > 0)
-    def _attend():
-        H, D = q_ref.shape[1], q_ref.shape[2]
-        page = k_ref.shape[0]
-        group = H // n_kv_heads
-        q = q_ref[0].astype(jnp.float32)  # [H, D]
-        mask = jax.lax.broadcasted_iota(jnp.int32, (group, page), 1) < valid
-        for h in range(n_kv_heads):  # static unroll: one 2D matmul pair/head
-            rows = slice(h * group, (h + 1) * group)
-            k_h = k_ref[:, h * D : (h + 1) * D].astype(jnp.float32)
-            v_h = v_ref[:, h * D : (h + 1) * D].astype(jnp.float32)
-            _flash_update(rows, q[rows], k_h, v_h, mask,
-                          m_ref, l_ref, acc_ref)
-
-    @pl.when(p == n_pages - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def paged_attention_decode_v2(
-    q: jax.Array,  # [B, H, D]
-    k_pool: jax.Array,  # [n_slots, Hkv, D]
-    v_pool: jax.Array,
-    page_table: jax.Array,  # [B, P]
-    lengths: jax.Array,  # [B]
-    *,
-    page_size: int,
-    interpret: bool = False,
-) -> jax.Array:
-    """Returns attention output [B, H, D] (same dtype as q). Grid
-    (B, P): one instance streams a full page (all KV heads)."""
-    B, H, D = q.shape
-    n_slots, Hkv, _ = k_pool.shape
-    P = page_table.shape[1]
-    k2d = k_pool.reshape(n_slots, Hkv * D)
-    v2d = v_pool.reshape(n_slots, Hkv * D)
-    flat_pt = page_table.reshape(-1)
-
-    def kv_index(b, p, pt, ln):
-        # ragged DMA skip (see module docstring)
-        last = jnp.maximum(ln[b] - 1, 0) // page_size
-        return pt[b * P + jnp.minimum(p, last)], 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, p, pt, ln: (b, 0, 0)),
-            pl.BlockSpec((page_size, Hkv * D), kv_index),
-            pl.BlockSpec((page_size, Hkv * D), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, p, pt, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel_v2, page_size=page_size, n_pages=P, n_kv_heads=Hkv
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        interpret=interpret,
-    )(flat_pt, lengths, q, k2d, v2d)
-
-
-def _verify_kernel(
-    page_table_ref,  # [B * P] int32
-    positions_ref,  # [B] int32 — position of query 0; <= -S = slot off
-    q_ref,  # [1, S, H, D]
-    k_ref,  # [page, Hkv * D]
-    v_ref,  # [page, Hkv * D]
-    o_ref,  # [1, S, H, D]
-    m_ref,  # [Hkv * S * group, 128] f32
-    l_ref,  # [Hkv * S * group, 128] f32
-    acc_ref,  # [Hkv * S * group, D] f32
-    *,
-    page_size: int,
-    n_pages: int,
-    n_kv_heads: int,
-):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    pos0 = positions_ref[b]
-    S, H, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    group = H // n_kv_heads
-    # last query sits at pos0 + S - 1; pages past it contribute nothing
-    valid = jnp.clip(pos0 + S - p * page_size, 0, page_size)
-
-    @pl.when(valid > 0)
-    def _attend():
-        page = k_ref.shape[0]
-        # causal per query row: row r = s * group + g attends global key
-        # j <= pos0 + s, with j = p * page_size + column
-        col = jax.lax.broadcasted_iota(jnp.int32, (S * group, page), 1)
-        row_s = jax.lax.broadcasted_iota(
-            jnp.int32, (S * group, page), 0) // group
-        mask = (p * page_size + col) <= (pos0 + row_s)
-        for h in range(n_kv_heads):
-            rows = slice(h * S * group, (h + 1) * S * group)
-            q = q_ref[0, :, h * group:(h + 1) * group, :].reshape(
-                S * group, D).astype(jnp.float32)
-            k_h = k_ref[:, h * D:(h + 1) * D].astype(jnp.float32)
-            v_h = v_ref[:, h * D:(h + 1) * D].astype(jnp.float32)
-            _flash_update(rows, q, k_h, v_h, mask, m_ref, l_ref, acc_ref)
-
-    @pl.when(p == n_pages - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        out = acc_ref[:] / denom  # [Hkv * S * group, D]
-        for h in range(n_kv_heads):
-            rows = slice(h * S * group, (h + 1) * S * group)
-            o_ref[0, :, h * group:(h + 1) * group, :] = (
-                out[rows].reshape(S, group, D).astype(o_ref.dtype)
-            )
 
 
 def _ragged_prefill_kernel(
@@ -332,8 +170,8 @@ def ragged_prefill_attention(
     to a multiple of ``q_block`` — compute scales with TOTAL tokens, not
     per-sequence buckets. Causal flash attention runs against the paged
     KV pool (prefix pages plus the freshly scattered chunk) with the
-    same scalar-prefetch page table + ragged-DMA-skip machinery as the
-    decode/verify kernels; grid (q-blocks, seqs, pages) revisits each
+    scalar-prefetch page table + ragged DMA skip of the module
+    docstring; grid (q-blocks, seqs, pages) revisits each
     query block per overlapping sequence, so a block spanning a sequence
     boundary is handled by masking rather than host-side alignment.
     Returns [T, H, D]."""
@@ -386,56 +224,3 @@ def ragged_prefill_attention(
         interpret=interpret,
     )(cu_seqlens, start_pos, flat_pt, q2d, k2d, v2d)
     return out.reshape(T, H, D)
-
-
-@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def paged_attention_verify(
-    q: jax.Array,  # [B, S, H, D] — S speculative query positions
-    k_pool: jax.Array,  # [n_slots, Hkv, D]
-    v_pool: jax.Array,
-    page_table: jax.Array,  # [B, P]
-    positions: jax.Array,  # [B] int32 position of q[:, 0]; <= -S disables
-    *,
-    page_size: int,
-    interpret: bool = False,
-) -> jax.Array:
-    """Multi-query variant for speculative decoding's verify step: S
-    consecutive query positions per sequence (pending token + drafts)
-    attend the paged cache under a per-query causal mask, with the same
-    ragged DMA skip as the decode kernels. Returns [B, S, H, D]."""
-    B, S, H, D = q.shape
-    n_slots, Hkv, _ = k_pool.shape
-    P = page_table.shape[1]
-    k2d = k_pool.reshape(n_slots, Hkv * D)
-    v2d = v_pool.reshape(n_slots, Hkv * D)
-    flat_pt = page_table.reshape(-1)
-
-    def kv_index(b, p, pt, pos):
-        last = jnp.maximum(pos[b] + S - 1, 0) // page_size
-        return pt[b * P + jnp.minimum(p, last)], 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, S, H, D), lambda b, p, pt, pos: (b, 0, 0, 0)),
-            pl.BlockSpec((page_size, Hkv * D), kv_index),
-            pl.BlockSpec((page_size, Hkv * D), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, S, H, D),
-                               lambda b, p, pt, pos: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv * S * (H // Hkv), 128), jnp.float32),
-            pltpu.VMEM((Hkv * S * (H // Hkv), 128), jnp.float32),
-            pltpu.VMEM((Hkv * S * (H // Hkv), D), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _verify_kernel, page_size=page_size, n_pages=P, n_kv_heads=Hkv
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
-        interpret=interpret,
-    )(flat_pt, positions, q, k2d, v2d)

@@ -39,7 +39,6 @@ replica.
 from __future__ import annotations
 
 import logging
-import os
 import time
 from dataclasses import dataclass
 from typing import Any, TYPE_CHECKING
@@ -56,10 +55,6 @@ logger = logging.getLogger(__name__)
 
 #: valid EngineConfig.attention_backend values
 BACKENDS = ("xla-bucketed", "pallas-ragged")
-
-#: valid EngineConfig.decode_backend values ("auto" = chained today;
-#: the fused rung is opt-in until an on-chip capture flips the default)
-DECODE_BACKENDS = ("auto", "chained", "fused")
 
 
 @dataclass
@@ -838,110 +833,50 @@ def resolve_attention_backend(engine: "Engine") -> tuple[str, str]:
     return "pallas-ragged", engine._ragged_reason
 
 
-def resolve_decode_backend(cfg, model_cfg, mesh,
-                           fns=None) -> tuple[str, str]:
-    """The DECODE half of the fallback matrix (ISSUE 13): (resolved
-    decode-attention impl, WHY), exported verbatim on /state as
-    ``decode_attn_impl`` / ``decode_attn_reason`` — never a silent
-    behavior change. Requested = ``decode_backend`` (+ the legacy
-    ``pallas_attn`` knob, which names the CHAINED kernel rung).
+def resolve_decode_backend(cfg, model_cfg, mesh) -> tuple[str, str]:
+    """The DECODE half of the fallback matrix: (resolved decode-attention
+    impl, WHY), exported verbatim on /state as ``decode_attn_impl`` /
+    ``decode_attn_reason``. Nothing is requested: the rung follows what
+    the engine can observe — the head counts, the mesh's ``tp`` axis
+    and (for the reason) the KV dtype.
 
-    | requested          | mesh | TPU | kv dtype  | resolved        |
-    |--------------------|------|-----|-----------|-----------------|
-    | auto/chained       | no   | any | any       | xla-walk        |
-    | auto/chained       | yes  | any | any       | xla-walk-spmd   |
-    | auto/chained, heads % tp != 0   | any       | xla-gather (narrowed) |
-    | chained+pallas_attn| no   | any | native    | pallas (chained kernel; interpret off-TPU) |
-    | chained+pallas_attn| no   | any | int8/int4 | fused rung (chained kernel has no quantized rung) |
-    | chained+pallas_attn| yes  | any | any       | fused-xla-spmd  |
-    | fused              | no   | yes | any       | fused-pallas    |
-    | fused              | no   | no  | any       | fused-xla       |
-    | fused              | yes  | any | any       | fused-xla-spmd  |
-    | fused, heads % tp != 0          | any       | xla-gather (narrowed) |
-    | a kernel rung, ``fns.decode_kernels`` false (qwen3_next, axk1) | the auto/chained row |
+    | mesh | heads % tp | resolved      |
+    |------|------------|---------------|
+    | no   | —          | xla-walk      |
+    | yes  | 0          | xla-walk-spmd |
+    | yes  | != 0       | xla-gather (narrowed) |
 
-    ``xla-walk`` is the default of every family (ISSUE 31): after the
-    scatter, an online-softmax loop over the whole pages the LIVE rows
-    hold (ops/paged_walk.py) — no padded window is gathered, int8/int4
+    ``xla-walk`` is the rung of every family: after the scatter, an
+    online-softmax loop over the whole pages the LIVE rows hold
+    (ops/paged_walk.py) — no padded window is gathered, int8/int4
     pages dequantize at the read, and ``decode_kv_pages_read`` /
     ``decode_kv_pages_live`` on /state say what it read. On a mesh it
     runs inside shard_map, each device over its LOCAL head shard of the
-    pool (``-spmd``). ``fused-xla`` / ``fused-xla-spmd`` are the SAME
-    program under the name the request gave (``--decode-backend
-    fused`` where the fused Pallas kernel cannot run). ``xla-gather``
-    — the padded-window gather — is left with one row, geometric: head
-    counts that do not divide the tp axis (the shard_map walk needs
-    whole head shards per device; the GSPMD gather keeps reads
-    head-local). A family whose ``ModelFns.decode_kernels`` is false
-    (qwen3_next: q/k RMSNorm, rotary on a part of each head, an output
-    gate; axk1: latent rows that every head reads — which no Pallas
-    rung knows) takes the default row whatever
-    kernel was requested, and the reason says so.
-
-    The fused rung has no model-family exception (ISSUE 18): MoE
-    families run the same fused decode programs as dense ones — the
-    expert dispatch/combine einsums live in the MLP, outside the
-    attention rung entirely. The speculative VERIFY step and the
-    prefill chunk/tail programs keep the window gather at every rung
+    pool (``-spmd``). ``xla-gather`` — the padded-window gather — is
+    left with one row, geometric: head counts that do not divide the tp
+    axis (the shard_map walk needs whole head shards per device; the
+    GSPMD gather keeps reads head-local). MoE families run the same
+    rung as dense ones — the expert einsums live in the MLP, outside
+    attention. The speculative VERIFY step and the prefill chunk/tail
+    programs read their page window a page at a time at every rung
     ([B, D+1] and [1, S] queries: another shape of problem; quantized
-    pools run gather-dequant), which `Engine.verify_attn_impl` exports.
-
-    ``AIGW_DECODE_FUSED_IMPL`` in {xla, pallas} overrides the
-    kernel-vs-reference choice for A/B and interpret-mode parity runs,
-    exactly like AIGW_RAGGED_PREFILL_IMPL on the prefill side."""
-    from aigw_tpu.ops.pallas._compat import is_tpu_backend
-
+    pools run gather-dequant)."""
     quant = cfg.kv_cache_dtype in ("int8", "int4")
-    req = "chained" if cfg.decode_backend == "auto" else cfg.decode_backend
-    wants_fused = req == "fused" or (
-        req == "chained" and cfg.pallas_attn and (quant or mesh is not None))
-    family = ""
-    if fns is not None and not fns.decode_kernels and (
-            wants_fused or cfg.pallas_attn):
-        # the family row, as the family's ModelFns declares it
-        wants_fused = False
-        family = ("this family's decode_step takes no kernel rung "
-                  "(ModelFns.decode_kernels is false); ")
     tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
-    splits = tp > 1 and (model_cfg.n_heads % tp
-                         or model_cfg.n_kv_heads % tp)
-    narrowed = (f"heads ({model_cfg.n_heads}q/{model_cfg.n_kv_heads}kv) "
+    if tp > 1 and (model_cfg.n_heads % tp or model_cfg.n_kv_heads % tp):
+        return ("xla-gather",
+                f"heads ({model_cfg.n_heads}q/{model_cfg.n_kv_heads}kv) "
                 f"do not divide tp={tp}: the shard_map local walk needs "
                 "whole head shards per device; the GSPMD gather keeps "
                 "reads head-local (narrowed row)")
-    if not wants_fused:
-        if cfg.pallas_attn and mesh is None and not family:
-            return "pallas", "pallas_attn requested, single chip"
-        if splits:
-            return "xla-gather", f"{family}default, but {narrowed}"
-        why = (f"{family}default: the page walk reads the whole pages "
-               "the live rows hold"
-               + (f"; {cfg.kv_cache_dtype} KV pages dequantize against "
-                  "their per-page scales at the read" if quant else ""))
-        if mesh is not None:
-            return ("xla-walk-spmd",
-                    f"{why}, each device its LOCAL head shard of the "
-                    "pool inside shard_map")
-        return "xla-walk", why
-    why = ("decode_backend=fused" if req == "fused" else
-           ("pallas_attn requested with "
-            f"{cfg.kv_cache_dtype} KV pages: the chained kernel has no "
-            "quantized rung" if quant else
-            "pallas_attn requested on a mesh"))
+    why = ("the page walk reads the whole pages the live rows hold"
+           + (f"; {cfg.kv_cache_dtype} KV pages dequantize against "
+              "their per-page scales at the read" if quant else ""))
     if mesh is not None:
-        if splits:
-            return "xla-gather", f"{why}, but {narrowed}"
-        return ("fused-xla-spmd",
-                f"{why}: the page walk, each device over its LOCAL head "
-                "shard of the paged pool inside shard_map")
-    impl_env = os.environ.get("AIGW_DECODE_FUSED_IMPL", "").lower()
-    if impl_env == "pallas" or (impl_env != "xla" and is_tpu_backend()):
-        return ("fused-pallas",
-                f"{why}: fused Pallas kernel (RoPE + append + paged "
-                "attention in one dispatch, single-chip TPU)")
-    return ("fused-xla",
-            f"{why}: the XLA page walk (the default rung's program; "
-            "no TPU backend — interpret mode is too slow to serve)")
+        return ("xla-walk-spmd",
+                f"{why}, each device its LOCAL head shard of the "
+                "pool inside shard_map")
+    return "xla-walk", why
 
 
 def make_attention_backend(engine: "Engine") -> AttentionBackend:

@@ -256,23 +256,6 @@ class EngineConfig:
     # occasionally. False pins every eligible slot at spec_tokens —
     # the fixed-D A/B and determinism knob.
     spec_adaptive: bool = True
-    # Ragged paged-attention Pallas kernel for the CHAINED decode loop
-    # (HBM reads scale with actual sequence lengths, not the padded
-    # window). Resolved through the decode fallback matrix
-    # (tpuserve/attention.resolve_decode_backend): single-chip native
-    # pools run the chained kernel; a mesh or a quantized pool
-    # escalates to the fused rung (the PR 10 gather-on-mesh row is
-    # deleted); /state exports the resolution + why.
-    pallas_attn: bool = False
-    # Decode attention rung (ISSUE 13, tpuserve/attention.py):
-    # "auto"/"chained" — the classic per-layer chain (rope → scatter →
-    # window gather / chained Pallas kernel); "fused" — ONE program
-    # per decode dispatch: RoPE + quantized KV append + online-softmax
-    # paged attention (the Pallas kernel on single-chip TPU, an XLA
-    # page-walk reference off-TPU, and a shard_map per-device local
-    # pool walk on a mesh — no GSPMD gather). The resolved impl and
-    # reason export on /state (decode_attn_impl / decode_attn_reason).
-    decode_backend: str = "auto"
     # Prefill attention backend (tpuserve/attention.py):
     # "xla-bucketed" — the classic per-sequence bucket ladder with
     # batched same-bucket groups; "pallas-ragged" — a mixed-length
@@ -301,13 +284,11 @@ class EngineConfig:
     # the deterministic-equivalence test mode), or "int8"/"int4"
     # (ISSUE 13, models/kvq.py): pages store quantized rows plus
     # per-page scale blocks (one f32 absmax scale per token row × KV
-    # head), dequantized in-kernel / at the gather — ~0.52x / ~0.27x
+    # head), dequantized at the read — ~0.52x / ~0.27x
     # the bf16 KV bytes at head_dim 128, which is the
     # concurrent-sessions-per-chip lever. Quantized pages ride the
     # whole stack (spill/revive, migration + fleet fetch at native
-    # dtype + scales, spec verify, CoW); the chained Pallas kernels
-    # have no quantized rung, so the fallback matrix reroutes those
-    # requests (attention.resolve_decode_backend).
+    # dtype + scales, spec verify, CoW).
     kv_cache_dtype: str = "bfloat16"
     # Multi-tenant fairness guard (ISSUE 7): the maximum decode slots
     # any one tenant (GenRequest.tenant; "" is one anonymous tenant) may
@@ -385,16 +366,11 @@ class EngineConfig:
                 f"prefill_bucket_rungs must be 1, 2, or 4 "
                 f"(got {self.prefill_bucket_rungs})")
         from aigw_tpu.models import kvq
-        from aigw_tpu.tpuserve.attention import DECODE_BACKENDS
 
         if self.kv_cache_dtype not in kvq.KV_DTYPES:
             raise ValueError(
                 f"kv_cache_dtype must be one of {kvq.KV_DTYPES} "
                 f"(got {self.kv_cache_dtype!r})")
-        if self.decode_backend not in DECODE_BACKENDS:
-            raise ValueError(
-                f"decode_backend must be one of {DECODE_BACKENDS} "
-                f"(got {self.decode_backend!r})")
         if self.min_decode_steps_per_tick == 0:
             self.min_decode_steps_per_tick = max(
                 1, self.decode_steps_per_tick // 4)
@@ -1121,13 +1097,10 @@ class Engine:
         # device state. With a mesh, weights/cache are laid out with
         # tensor/expert-parallel shardings and every jitted step runs SPMD
         # (GSPMD inserts the collectives; SURVEY.md §2.9). The pool
-        # carries ONE extra page past the allocator's range — the fused
-        # decode kernel's dump page: its output pipeline must write
-        # every slot's append block somewhere, and inactive slots land
-        # here instead of whatever page their stale table row names
-        # (the XLA paths get the same guarantee from OOB-drop
-        # scatters). Never allocated, never referenced by a page
-        # table, excluded from capacity accounting.
+        # carries ONE extra page past the allocator's range: never
+        # allocated, never referenced by a page table, excluded from
+        # capacity accounting. Every program's shapes stand on it, and
+        # axk1 clamps a dead row's write into it (models/axk1.py).
         kv_rows = (cfg.num_pages + 1) * cfg.page_size
         if mesh is not None:
             from aigw_tpu.parallel.sharding import (
@@ -1226,31 +1199,17 @@ class Engine:
 
         mc, ps = model_cfg, cfg.page_size
         K = cfg.decode_steps_per_tick
-        # decode attention rung (the /state-exported half of the
-        # fallback matrix — tpuserve/attention.resolve_decode_backend
-        # documents the full requested × mesh × TPU × kv-dtype table;
+        # decode attention rung, from what the engine can observe
+        # (tpuserve/attention.resolve_decode_backend has the table;
         # resolve_attention_backend documents the prefill half)
         from aigw_tpu.tpuserve.attention import resolve_decode_backend
 
         self.decode_attn_impl, self.decode_attn_reason = (
-            resolve_decode_backend(cfg, model_cfg, mesh, self.fns))
-        if (cfg.pallas_attn or cfg.decode_backend == "fused") \
-                and self.decode_attn_impl == "xla-gather":
-            logger.warning("decode backend fell back to xla-gather: %s",
-                           self.decode_attn_reason)
-        # decode_step's attn_impl argument
-        attn_impl = {
-            "xla-gather": "gather",
-            "pallas": "pallas",
-            "fused-pallas": "fused-pallas",
-        }.get(self.decode_attn_impl, "")  # "": the page walk
-        # (every rung hands the mesh on: the -spmd walks run under it,
-        # and a family whose decode MLP differs under one must see it)
-        # the speculative verify step keeps the chained path at every
-        # rung: its multi-position kernel has no fused port, and the
-        # gather-dequant path serves quantized pools
-        self.verify_attn_impl = (
-            "pallas" if self.decode_attn_impl == "pallas" else "")
+            resolve_decode_backend(cfg, model_cfg, mesh))
+        # decode_step's attn_impl argument: "" is the page walk. Either
+        # rung hands the mesh on: the -spmd walk runs under it, and a
+        # family whose decode MLP differs under one must see it
+        attn_impl = "gather" if self.decode_attn_impl == "xla-gather" else ""
 
         model_prefill = self.fns.prefill
         model_decode = self.fns.decode_step
@@ -1423,8 +1382,8 @@ class Engine:
             layer, pages its live rows hold). On the walk rung the
             first IS the loops' bound — the plan returned here goes to
             the model's decode_step as ``walk`` — on the others
-            (window gather, the Pallas grids, the verify step: no
-            plan) it is the whole [B, P] window they address."""
+            (window gather, the verify step: no plan) it is the whole
+            [B, P] window they address."""
             lengths = jnp.where(act, st["positions"] + 1, 0)
             B, P = st["page_table"].shape
             plan = kvq.walk_plan(kv.kv if stateful else kv, lengths,
@@ -1524,7 +1483,6 @@ class Engine:
         self._spec_max = self._spec_rungs[-1]
         self._accept_prior = speculation.AcceptancePrior()
         model_verify = self.fns.verify_step
-        verify_impl = self.verify_attn_impl
         V = model_cfg.vocab_size
         H = cfg.max_seq_len
 
@@ -1567,7 +1525,7 @@ class Engine:
                     params, mc, inputs, st["positions"], kv,
                     st["page_table"], ps, act, st["limits"],
                     lora=lora, adapter_idx=st["adapter_idx"],
-                    attn_impl=verify_impl, **moe_kw))  # [B, D1, V]
+                    **moe_kw))  # [B, D1, V]
                 macc = macc if moe is None else macc + moe
                 # counts are window-start values: exact at d=0, and later
                 # positions only accept on penalty-free slots where the

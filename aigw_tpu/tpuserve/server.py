@@ -2275,7 +2275,6 @@ class TPUServeServer:
                 "kv_quant_bits": s.kv_quant_bits,
                 "kv_bytes_per_token": s.kv_bytes_per_token,
                 "kv_cache_dtype": self.engine.cfg.kv_cache_dtype,
-                "decode_backend": self.engine.cfg.decode_backend,
                 # MoE serving surface (ISSUE 18): router placement /
                 # capacity-drop scalars plus the per-expert token list
                 # the picker prices (worst-expert discipline — a
@@ -2954,9 +2953,7 @@ async def run_tpuserve(
     prefill_chunk_tokens: int = 256,
     spec_tokens: int = 0,
     spec_adaptive: bool = True,
-    pallas_attn: bool = False,
     attention_backend: str = "xla-bucketed",
-    decode_backend: str = "auto",
     kv_cache_dtype: str = "bfloat16",
     ragged_chunk_tokens: int = 256,
     logprobs_topk: int = 0,
@@ -2983,9 +2980,7 @@ async def run_tpuserve(
             prefill_chunk_tokens=prefill_chunk_tokens,
             spec_tokens=spec_tokens,
             spec_adaptive=spec_adaptive,
-            pallas_attn=pallas_attn,
             attention_backend=attention_backend,
-            decode_backend=decode_backend,
             kv_cache_dtype=kv_cache_dtype,
             ragged_chunk_tokens=ragged_chunk_tokens,
             logprobs_topk=logprobs_topk,

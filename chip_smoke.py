@@ -35,11 +35,12 @@ Phases (one chip; the driver's form)::
              then asked again (prefix hit), a /v1/completions straight
              at the replica; /health, /state placement checks; gateway
              /usage totals == the replica's meter_* counters
-    kernels  every Pallas kernel compiled against its XLA twin
+    kernels  the two Pallas kernels (ragged prefill, W8A16 matmul)
+             compiled against their XLA twins
              (python -m aigw_tpu.ops.pallas.parity)
-    fused    a second boot with --attention-backend pallas-ragged
-             --decode-backend fused: both must resolve to their kernels
-             on /state and answer the same burst
+    ragged   a second boot with --attention-backend pallas-ragged: the
+             prefill must resolve to its kernel and the decode to the
+             page walk on /state, and answer the same burst
 
 ``--chips 4`` (run by the builder on a four-chip host) replaces them with
 ``tp4`` (bf16 weights created already sharded over a tp=4 mesh) and
@@ -597,24 +598,22 @@ def phase_kernels(args, out_dir: str) -> dict:
     return {"kernels": [r["kernel"] for r in results]}
 
 
-def phase_fused(args, out_dir: str) -> dict:
+def phase_ragged(args, out_dir: str) -> dict:
     flags = [*geometry_flags(args),
-             "--attention-backend", "pallas-ragged",
-             "--decode-backend", "fused"]
-    with Stack(args, out_dir, "fused", flags) as stack:
+             "--attention-backend", "pallas-ragged"]
+    with Stack(args, out_dir, "ragged", flags) as stack:
         st = stack.state()
         check_placement(st, args)
         want_reason = ("Pallas kernel (single-chip TPU)" if on_tpu(args)
                        else "XLA windowed fallback: no TPU backend")
-        want_impl = "fused-pallas" if on_tpu(args) else "fused-xla"
         check(st["attention_backend"] == "pallas-ragged"
               and st["attention_backend_reason"] == want_reason,
               f"prefill resolved to {st['attention_backend']!r}: "
               f"{st['attention_backend_reason']!r}")
-        check(st["decode_attn_impl"] == want_impl,
+        check(st["decode_attn_impl"] == "xla-walk",
               f"decode resolved to {st['decode_attn_impl']!r}: "
               f"{st['decode_attn_reason']!r}")
-        result = burst(stack.gateway, args.model, "f1")
+        result = burst(stack.gateway, args.model, "r1")
         stack.check_healthy()
         obs = observations(stack.state())
         obs.update(boot_s=round(stack.boot_s, 1), burst=result)
@@ -721,7 +720,7 @@ def phase_replicas(args, out_dir: str) -> dict:
 
 
 PHASES = {"build": phase_build, "serve": phase_serve,
-          "kernels": phase_kernels, "fused": phase_fused,
+          "kernels": phase_kernels, "ragged": phase_ragged,
           "tp4": phase_tp4, "replicas": phase_replicas}
 
 
@@ -767,7 +766,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--out", default=os.path.join(
         HERE, "chiprun_out", "chip_smoke"))
     args = ap.parse_args(argv)
-    default = (["build", "serve", "kernels", "fused"] if args.chips == 1
+    default = (["build", "serve", "kernels", "ragged"] if args.chips == 1
                else ["build", "tp4", "replicas"])
     args.phases = ([p for p in args.phases.split(",") if p]
                    or default)

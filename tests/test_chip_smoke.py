@@ -77,7 +77,7 @@ def test_full_run_on_a_named_cpu(tmp_path):
     proc = subprocess.run(
         [sys.executable, "chip_smoke.py", "--platform", "cpu",
          "--model", "tiny-random", "--no-quantize",
-         "--phases", "build,serve,fused", "--out", str(tmp_path)],
+         "--phases", "build,serve,ragged", "--out", str(tmp_path)],
         cwd=REPO, capture_output=True, text=True, timeout=900)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])

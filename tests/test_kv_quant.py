@@ -1,14 +1,7 @@
-"""Fused decode step + quantized KV pages (ISSUE 13).
+"""Quantized KV pages (ISSUE 13).
 
-Three layers under test:
+Two layers under test:
 
-- **f32 rig equivalence** — the fused decode rung (XLA page-walk
-  reference on this CPU platform; the Pallas kernel parity lives in
-  test_pallas_ops.py) streams BYTE-IDENTICAL tokens to the chained
-  gather path across the feature mix (greedy, seeded sampling,
-  penalties, logit bias, speculation, prefix-cache resume), with zero
-  hot XLA compiles after warmup and zero pipeline-draining state
-  rebuilds;
 - **quantized pages through the stack** — int8/int4 pools serve,
   spill→revive and the cross-replica /kv/pages wire round-trip pages
   BIT-exactly (scales included), migration moves quantized sessions,
@@ -34,30 +27,23 @@ from aigw_tpu.tpuserve.engine import Engine, EngineConfig, GenRequest
 from aigw_tpu.tpuserve.kvcache import page_chain_hashes
 from aigw_tpu.tpuserve.sampling import SamplingParams
 
-_PARAMS_F32 = None
-_PARAMS_BF16 = None
+_PARAMS = None
 
 
-def _params(f32: bool):
-    global _PARAMS_F32, _PARAMS_BF16
-    if f32:
-        if _PARAMS_F32 is None:
-            _PARAMS_F32 = llama.init_params(
-                jax.random.PRNGKey(0), llama.TINY, jnp.float32)
-        return _PARAMS_F32
-    if _PARAMS_BF16 is None:
-        _PARAMS_BF16 = llama.init_params(jax.random.PRNGKey(0),
-                                         llama.TINY)
-    return _PARAMS_BF16
+def _params():
+    global _PARAMS
+    if _PARAMS is None:
+        _PARAMS = llama.init_params(jax.random.PRNGKey(0), llama.TINY)
+    return _PARAMS
 
 
-def _engine(f32=True, **over) -> Engine:
+def _engine(**over) -> Engine:
     cfg = EngineConfig(**{**dict(
         max_batch_size=2, max_seq_len=256, page_size=16,
         min_prefill_bucket=16, decode_steps_per_tick=4,
-        kv_cache_dtype="float32" if f32 else "bfloat16",
+        kv_cache_dtype="bfloat16",
         adaptive_decode_window=False), **over})
-    return Engine(_params(f32), llama.TINY, cfg, eos_token_ids=(257,))
+    return Engine(_params(), llama.TINY, cfg, eos_token_ids=(257,))
 
 
 def _run(eng: Engine, prompt, mt=8, sp=None):
@@ -78,87 +64,12 @@ def _run(eng: Engine, prompt, mt=8, sp=None):
     return toks
 
 
-_MIX = [
-    ([5, 3, 8, 1, 9, 2, 4], SamplingParams(temperature=0.0)),
-    ([7, 7, 7, 7, 7, 7, 7, 7], SamplingParams(
-        temperature=0.0, logit_bias=((7, 100.0),))),  # spec accepts
-    ([2, 9, 4, 4, 1], SamplingParams(temperature=0.0,
-                                     frequency_penalty=0.6,
-                                     presence_penalty=0.2)),
-    ([3, 1, 4, 1, 5, 9, 2, 6], SamplingParams(temperature=0.8,
-                                              seed=1234)),
-]
-
-
-def _mix_streams(eng: Engine) -> list[list[int]]:
-    out = [_run(eng, p, sp=sp) for p, sp in _MIX]
-    # prefix-cache resume: the repeated ask adopts cached pages
-    out.append(_run(eng, [5, 3, 8, 1, 9, 2, 4] * 6))
-    out.append(_run(eng, [5, 3, 8, 1, 9, 2, 4] * 6))
-    return out
-
-
-def test_fused_byte_identical_quick():
-    """Tier-1 identity probe: fused vs chained, greedy + logit bias,
-    no warmup — the full feature mix + compile tripwire lives in the
-    slow twin below."""
-    chained = _engine()
-    fused = _engine(decode_backend="fused")
-    for e in (chained, fused):
-        e.start()
-    try:
-        reqs = [([5, 3, 8, 1, 9, 2, 4], SamplingParams(temperature=0.0)),
-                ([7, 7, 2, 9], SamplingParams(
-                    temperature=0.0, logit_bias=((7, 4.0),)))]
-        got = [_run(fused, p, mt=6, sp=sp) for p, sp in reqs]
-        want = [_run(chained, p, mt=6, sp=sp) for p, sp in reqs]
-        assert got == want
-    finally:
-        chained.stop()
-        fused.stop()
-
-
-@pytest.mark.slow
-def test_fused_byte_identical_to_chained_full_mix():
-    """Acceptance: fused decode at native KV dtype is byte-identical
-    to the chained XLA path in the deterministic f32 rig across the
-    feature mix, with zero hot compiles after warmup and
-    state_rebuilds == 0 on the fused engine."""
-    chained = _engine(spec_tokens=3, spec_adaptive=False,
-                      warm_prefill_buckets=2, warm_decode_buckets=3)
-    fused = _engine(spec_tokens=3, spec_adaptive=False,
-                    warm_prefill_buckets=2, warm_decode_buckets=3,
-                    decode_backend="fused")
-    assert fused.decode_attn_impl == "fused-xla"
-    assert chained.decode_attn_impl == "xla-walk"
-    for e in (chained, fused):
-        e.warmup()
-        e.start()
-    try:
-        # prime the programs warmup() does not own (the full-prefix
-        # hit's CoW copy_page) on BOTH engines, and run the control
-        # engine first — the compile tracker is process-wide, so
-        # nothing else may land inside the fused tripwire window
-        for e in (chained, fused):
-            _run(e, [5, 3, 8, 1, 9, 2, 4] * 6)
-            _run(e, [5, 3, 8, 1, 9, 2, 4] * 6)
-        want = _mix_streams(chained)
-        cp = fused.compile_tracker.checkpoint()
-        got = _mix_streams(fused)
-        assert got == want
-        assert fused.compile_tracker.compiles_since(cp) == 0, (
-            "fused decode compiled on the hot path")
-        assert fused.stats.state_rebuilds == 0
-    finally:
-        chained.stop()
-        fused.stop()
-
-
 @pytest.mark.parametrize("qdt", ["int8", "int4"])
 def test_quantized_engine_serves_and_accounts(qdt):
     """int8/int4 pools serve end-to-end; /state capacity math matches
     the layout: bytes/token = L*2*Hkv*(D*b + 4), quant bits exported."""
-    eng = _engine(f32=False, kv_cache_dtype=qdt, decode_backend="fused")
+    eng = _engine(kv_cache_dtype=qdt)
+    assert eng.decode_attn_impl == "xla-walk"
     eng.start()
     try:
         toks = _run(eng, [5, 3, 8, 1], mt=6)
@@ -199,7 +110,7 @@ def test_teacher_forced_quality_smoke(qdt):
     gaussian K/V are the worst case for 4-bit; real checkpoints
     quantize far better — the bar is structural sanity)."""
     cfg = llama.TINY
-    params = _params(False)
+    params = _params()
     ps = 16
     kv_shape = (cfg.n_layers, 2, 9 * ps, cfg.n_kv_heads, cfg.head_dim)
     pt = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
@@ -239,8 +150,7 @@ class TestQuantizedRoundTrips:
     quantized pages BIT-exactly, scales included."""
 
     def _quant_engine(self, qdt, **over):
-        return _engine(f32=False, kv_cache_dtype=qdt,
-                       decode_backend="fused", num_pages=24,
+        return _engine(kv_cache_dtype=qdt, num_pages=24,
                        kv_host_bytes=1 << 24,
                        warm_prefill_buckets=2, **over)
 
@@ -334,7 +244,7 @@ class TestQuantizedRoundTrips:
         from aigw_tpu.tpuserve.engine import MigrationError
 
         eng = self._quant_engine("int8")
-        nat = _engine(f32=False, num_pages=24)
+        nat = _engine(num_pages=24)
         eng.start()
         nat.start()
         eng.warmup()
@@ -358,8 +268,7 @@ def test_quantized_migration_roundtrip():
     from aigw_tpu.tpuserve.engine import continuation_request
 
     def mk():
-        return _engine(f32=False, kv_cache_dtype="int8",
-                       decode_backend="fused", num_pages=32,
+        return _engine(kv_cache_dtype="int8", num_pages=32,
                        warm_prefill_buckets=2)
 
     from aigw_tpu.tpuserve.engine import MigrationError

@@ -329,56 +329,22 @@ def test_prefill_bucket_divisibility_guard(pair):
 
 
 def test_decode_attn_resolution_exported(pair):
-    """The default rung is the page walk (ISSUE 31), on a mesh each
-    device over its local head shard; a kernel request on a mesh
-    resolves to the same per-device walk under the name it asked for
-    (ISSUE 13), exported with its reason. The narrowed row — heads not
-    divisible by tp — still gathers, with its own reason."""
+    """The rung is the page walk (ISSUE 31), on a mesh each device over
+    its local head shard, exported with its reason. The narrowed row —
+    heads not divisible by tp — still gathers, with its own reason."""
     single, mesh = pair
     assert mesh.decode_attn_impl == "xla-walk-spmd"
     assert "LOCAL head shard" in mesh.decode_attn_reason
     assert single.decode_attn_impl == "xla-walk"
-    eng = _mk_engine(True, pallas_attn=True, spec_tokens=0)
-    assert eng.decode_attn_impl == "fused-xla-spmd"
-    assert "LOCAL head shard" in eng.decode_attn_reason
-    assert eng.verify_attn_impl == ""  # verify keeps the chained path
-    assert eng.ici_bytes_per_token > 0
-    assert pair[0].ici_bytes_per_token == 0  # unsharded: no ICI
+    assert mesh.ici_bytes_per_token > 0
+    assert single.ici_bytes_per_token == 0  # unsharded: no ICI
     # the narrowed row: TINY's 2 KV heads don't divide tp=8
     from aigw_tpu.parallel import MeshSpec, make_mesh
     from aigw_tpu.tpuserve.attention import resolve_decode_backend
 
     impl, why = resolve_decode_backend(
-        EngineConfig(decode_backend="fused"), llama.TINY,
-        make_mesh(MeshSpec(dp=1, tp=8)))
-    assert impl == "xla-gather" and "narrowed" in why
-    impl, why = resolve_decode_backend(
         EngineConfig(), llama.TINY, make_mesh(MeshSpec(dp=1, tp=8)))
     assert impl == "xla-gather" and "narrowed" in why
-
-
-@pytest.mark.slow
-def test_mesh_fused_decode_byte_identical_to_single(pair):
-    """tp=8 byte-identity PRESERVED through the fused local-shard walk
-    (ISSUE 13): the mesh engine with decode_backend=fused streams the
-    same tokens as the single-device chained engine — the deleted
-    gather row changed the memory traffic, not the math."""
-    eng = _mk_engine(True, decode_backend="fused", spec_tokens=0)
-    assert eng.decode_attn_impl == "fused-xla-spmd"
-    eng.start()
-    try:
-        out = _burst(eng, [
-            (_PROMPTS[24], _greedy(), None),
-            (_PROMPTS[40], _greedy(logit_bias=((42, 3.0),)), None),
-        ])
-        assert eng.healthy, eng.last_error
-    finally:
-        eng.stop()
-    ref = _burst(pair[0], [
-        (_PROMPTS[24], _greedy(), None),
-        (_PROMPTS[40], _greedy(logit_bias=((42, 3.0),)), None),
-    ])
-    assert out == ref
 
 
 def test_gateway_migrator_respects_capability_flag():

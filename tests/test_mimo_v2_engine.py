@@ -25,7 +25,6 @@ import pytest
 from aigw_tpu.models import mimo_v2
 from aigw_tpu.models.cache import StateCache
 from aigw_tpu.models.registry import family_fns, get_model_spec
-from aigw_tpu.tpuserve.attention import resolve_decode_backend
 from aigw_tpu.tpuserve.engine import Engine, EngineConfig, GenRequest
 from aigw_tpu.tpuserve.kvcache import PageAllocator
 from aigw_tpu.tpuserve.sampling import SamplingParams
@@ -229,16 +228,6 @@ def test_a_quantized_pool_refuses_at_start_up():
         _engine(kv_cache_dtype="int8")
 
 
-@pytest.mark.parametrize("requested", [
-    dict(decode_backend="fused"), dict(pallas_attn=True)])
-def test_decode_kernels_fall_back_to_the_walk(requested):
-    cfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=16,
-                       **requested)
-    impl, why = resolve_decode_backend(cfg, SHARE, None,
-                                       family_fns("mimo_v2"))
-    assert impl == "xla-walk" and "no kernel rung" in why
-
-
 def test_ragged_backend_request_falls_back_to_bucketed():
     eng = _engine(attention_backend="pallas-ragged")
     assert eng.attn.name == "xla-bucketed"
@@ -250,7 +239,6 @@ def test_registered_preset_and_config_surface():
     assert spec.family == "mimo_v2" and spec.config is mimo_v2.TINY
     fns = family_fns("mimo_v2")
     assert fns.moe_stats and fns.prefill_suffix is not None
-    assert not fns.decode_kernels
     assert (fns.verify_step, fns.prefill_sp, fns.prefill_sp_suffix,
             fns.prefill_ragged) == (None,) * 4
     assert mimo_v2.TINY.layer_kinds == (

@@ -3,8 +3,8 @@ stack on the modern program families.
 
 The tentpole's verify bar: with both fallback-matrix family rows
 deleted, the expert-parallel family must ride the ragged prefill stream
-and the fused decode rung at full parity — byte-identical streams in
-the deterministic f32 rig against the bucketed+chained control across
+and the decode rung at full parity — byte-identical streams in
+the deterministic f32 rig against the bucketed control across
 the complete feature mix (speculating + penalized + constrained +
 prefix-resume slots sharing one decode window), zero hot XLA compiles
 after warmup, zero pipeline-draining state rebuilds. Plus the ISSUE 13
@@ -137,25 +137,23 @@ def _full_mix(eng: Engine) -> list[list[int]]:
 
 
 def test_moe_ragged_fused_resolve_first_class():
-    """Both deleted matrix rows, asserted from the resolver outputs:
-    the family lands on pallas-ragged prefill and the fused decode rung
-    with no family-shaped reason, and the routing-stats channel is on."""
-    eng = _engine(attention_backend="pallas-ragged",
-                  decode_backend="fused")
+    """Asserted from the resolver outputs: the family lands on
+    pallas-ragged prefill and the page walk with no family-shaped
+    reason, and the routing-stats channel is on."""
+    eng = _engine(attention_backend="pallas-ragged")
     assert eng.attn.name == "pallas-ragged"
-    assert eng.decode_attn_impl == "fused-xla"  # CPU reference rung
+    assert eng.decode_attn_impl == "xla-walk"
     assert "family" not in eng.decode_attn_reason
     assert eng._moe and eng.fns.moe_stats
     assert eng._moe_experts == CFG.n_experts
 
 
 def test_moe_ragged_byte_identical_quick():
-    """Tier-1 identity probe on the family: ragged+fused vs
-    bucketed+chained, greedy + penalized, no warmup — the full feature
-    mix + compile tripwire lives in the slow twin below."""
+    """Tier-1 identity probe on the family: ragged vs bucketed
+    prefill, greedy + penalized, no warmup — the full feature mix +
+    compile tripwire lives in the slow twin below."""
     control = _engine(attention_backend="xla-bucketed")
-    child = _engine(attention_backend="pallas-ragged",
-                    decode_backend="fused")
+    child = _engine(attention_backend="pallas-ragged")
     for e in (control, child):
         e.start()
     try:
@@ -187,19 +185,17 @@ def test_moe_ragged_byte_identical_quick():
 
 @pytest.mark.slow
 def test_moe_full_mix_byte_identical_zero_hot_compiles():
-    """Acceptance (ISSUE 18 tentpole): tiny-moe on ragged prefill +
-    fused decode streams byte-identically with the bucketed+chained
-    control across speculating + penalized + constrained +
+    """Acceptance (ISSUE 18 tentpole): tiny-moe on ragged prefill
+    streams byte-identically with the bucketed control across speculating + penalized + constrained +
     prefix-resume slots in one window, with zero hot compiles after
     warmup and state_rebuilds == 0."""
     control = _engine(attention_backend="xla-bucketed",
                       spec_tokens=3, spec_adaptive=False,
                       warm_prefill_buckets=2, warm_decode_buckets=3)
     child = _engine(attention_backend="pallas-ragged",
-                    decode_backend="fused",
                     spec_tokens=3, spec_adaptive=False,
                     warm_prefill_buckets=2, warm_decode_buckets=3)
-    assert child.decode_attn_impl == "fused-xla"
+    assert child.decode_attn_impl == "xla-walk"
     assert control.decode_attn_impl == "xla-walk"
     for e in (control, child):
         e.warmup()
@@ -220,7 +216,7 @@ def test_moe_full_mix_byte_identical_zero_hot_compiles():
         got = _full_mix(child)
         assert got == want
         assert child.compile_tracker.compiles_since(cp) == 0, (
-            "MoE ragged+fused compiled on the hot path")
+            "MoE ragged compiled on the hot path")
         assert child.stats.state_rebuilds == 0
     finally:
         control.stop()
@@ -233,8 +229,8 @@ def test_moe_quantized_pages_serve_and_account(qdt):
     """int8/int4 KV pages on the family (the deleted resolver gate):
     the quantized pool serves end to end and /state's capacity math is
     the same layout formula as dense families'."""
-    eng = _engine(f32=False, kv_cache_dtype=qdt, decode_backend="fused",
-                  num_pages=24)
+    eng = _engine(f32=False, kv_cache_dtype=qdt, num_pages=24)
+    assert eng.decode_attn_impl == "xla-walk"
     eng.start()
     try:
         toks = _run(eng, [4, 8, 15, 16, 23, 42], mt=4)
@@ -249,8 +245,7 @@ def test_moe_quantized_pages_serve_and_account(qdt):
 
 
 def _quant_engine(**over):
-    return _engine(f32=False, kv_cache_dtype="int8",
-                   decode_backend="fused", num_pages=24,
+    return _engine(f32=False, kv_cache_dtype="int8", num_pages=24,
                    kv_host_bytes=1 << 24, warm_prefill_buckets=2,
                    **over)
 
@@ -391,7 +386,7 @@ def test_moe_migration_resume_byte_identical_f32():
     mid-decode on one MoE replica and resumed on another yields the
     byte-identical stream a solo run produces — routing decisions and
     the recomputed partial-page tail both reproduce exactly."""
-    mk = lambda: _engine(decode_backend="fused", num_pages=24,  # noqa: E731
+    mk = lambda: _engine(num_pages=24,  # noqa: E731
                          warm_prefill_buckets=2)
     solo, a, b = mk(), mk(), mk()
     for e in (solo, a, b):

@@ -320,6 +320,157 @@ def test_hybrid_decode_step_leaves_dead_rows_state_and_pages():
     assert sorted(changed.tolist()) == want
 
 
+# -- the one rung, over several steps and as the engine resolves it -----------
+
+def _model_pool(rng, cfg, n_pages, dtype):
+    """A pool of ``cfg``'s layers and heads filled with random rows, in
+    the format ``dtype`` names."""
+    rows = jnp.asarray(rng.normal(size=(
+        cfg.n_layers, 2, n_pages * PAGE, cfg.n_kv_heads, cfg.head_dim)),
+        jnp.float32)
+    if dtype in kvq.QUANT_DTYPES:
+        q, scale = kvq.quantize_rows(rows, dtype)
+        return {"q": q, "scale": scale}
+    return rows.astype(dtype)
+
+
+def _rows_f32(kv):
+    if kvq.is_quantized(kv):
+        return np.asarray(kvq.dequantize_rows(kv["q"], kv["scale"]),
+                          np.float32)
+    return np.asarray(kv, np.float32)
+
+
+#: walk against gather, logits of one decode step from one pool: the two
+#: reads reduce in different orders over bfloat16 operands, a few ulps at
+#: these magnitudes (the bound the deleted kernel cases held their
+#: kernels to, ``parity.BF16_TOL``). An int4 row's grid step is a seventh
+#: of its largest value, and the step reads the row it has just written:
+#: where that rounding moved the new row across a step, the next layer
+#: sees it. A wrong page, a missed row or a dropped scale moves logits of
+#: this size by O(1).
+STEP_TOL = {"bfloat16": 5e-2, "int8": 5e-2, "int4": 2e-1}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8", "int4"])
+@pytest.mark.parametrize("geometry", ["tiny-random", "tiny-moe"])
+def test_decode_steps_carry_the_pool(geometry, dtype):
+    """Six consecutive ``decode_step`` calls at B=8 through the family's
+    own entry point, the walk carrying its pool from step to step, and
+    at every step ``attn_impl="gather"`` from the same pool beside it:
+    the logits of the live rows and the pool each hands on. The start
+    positions put appends just before, on and after a page boundary, on
+    a fresh sequence (position 0, a length of 1) and on an inactive
+    slot, and every step crosses a boundary somewhere. Stands for the
+    deleted ``TestFusedDecodeKernel`` (consecutive steps, fresh page
+    and inactive slot, tiny-moe geometry, quantized pools),
+    ``TestDecodeStepPallasAttn`` and ``test_single_token_length``, now
+    held of the rung that serves: a quantized pool through
+    ``decode_step`` over several steps was held nowhere.
+
+    The pools: layer 0 is written from the tokens alone and must be
+    equal bit for bit; a deeper layer's new row comes from attention
+    outputs that differ by ``STEP_TOL``'s rounding, so it may differ by
+    it — and, quantized, by one step of its row's grid besides."""
+    from aigw_tpu.models.registry import family_fns, get_model_spec
+
+    spec = get_model_spec(geometry)
+    cfg, fns = spec.config, family_fns(spec.family)
+    B, P, steps = 8, 4, 6
+    tol = STEP_TOL[dtype]
+    rng = np.random.default_rng(11)
+    params = fns.init_params(jax.random.PRNGKey(0), cfg)
+    pool = _model_pool(rng, cfg, B * P + 1, dtype)
+    pt = jnp.asarray(rng.permutation(B * P).reshape(B, P), jnp.int32)
+    positions = jnp.asarray([PAGE - 3, PAGE - 1, PAGE, 2 * PAGE - 2, 0,
+                             3 * PAGE - 4, 5, PAGE // 2 + 1], jnp.int32)
+    active = jnp.asarray([b != B - 2 for b in range(B)])
+    live = np.asarray(active)
+
+    def step(impl):
+        return jax.jit(lambda tok, pos, kv: fns.decode_step(
+            params, cfg, tok, pos, kv, pt, PAGE, active, attn_impl=impl))
+
+    walk, gather = step(""), step("gather")
+    kv = pool
+    for n in range(steps):
+        tok = jnp.asarray(rng.integers(1, cfg.vocab_size, size=B), jnp.int32)
+        lg, kv_g = gather(tok, positions, kv)
+        lw, kv = walk(tok, positions, kv)
+        np.testing.assert_allclose(
+            np.asarray(lw, np.float32)[live],
+            np.asarray(lg, np.float32)[live], atol=tol, rtol=tol,
+            err_msg=f"step {n}")
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_array_equal(
+                np.asarray(a)[0], np.asarray(b)[0]), kv, kv_g)
+        grid = (np.asarray(kv_g["scale"], np.float32)[..., None]
+                if kvq.is_quantized(kv) else 0.0)
+        assert (np.abs(_rows_f32(kv) - _rows_f32(kv_g))
+                <= grid * 1.001 + 5e-2).all(), f"step {n}"
+        positions = positions + active.astype(jnp.int32)
+    # the inactive slot's pages, and the page no table names: as they were
+    got, before = _rows_f32(kv), _rows_f32(pool)
+    for page in (*np.asarray(pt)[B - 2], B * P):
+        rows = slice(page * PAGE, (page + 1) * PAGE)
+        np.testing.assert_array_equal(got[:, :, rows], before[:, :, rows])
+    # each live row gained exactly ``steps`` tokens in every layer
+    changed = (got != before).any(axis=(0, 1, 3, 4)).sum()
+    assert changed == steps * int(live.sum())
+
+
+@pytest.mark.parametrize("model,tp,want", [
+    ("tiny-random", 1, "xla-walk"), ("tiny-moe", 1, "xla-walk"),
+    ("tiny-qwen3-next", 1, "xla-walk"), ("tiny-axk1", 1, "xla-walk"),
+    ("tiny-mimo-v2", 1, "xla-walk"),
+    ("tiny-random", 2, "xla-walk-spmd"), ("tiny-moe", 2, "xla-walk-spmd"),
+    ("tiny-random", 4, "xla-gather"), ("tiny-moe", 4, "xla-gather")],
+    ids=lambda v: str(v))
+def test_decode_rung_follows_what_the_engine_can_see(model, tp, want):
+    """``resolve_decode_backend`` takes no request: a family on one chip
+    walks, whatever its KV dtype, and a quantized pool's reason names
+    the dequantizing read; on a mesh the heads decide — 4 query / 2 KV
+    heads divide tp=2 (``-spmd``) and not tp=4 (the one ``xla-gather``
+    row, "narrowed"). Stands for the deleted
+    per-family ``..._fall_back_to_the_walk`` cases and the two
+    rung ids of ``test_engine_counts_what_its_decode_programs_read``:
+    what they held of five names is held of the three that are left."""
+    from aigw_tpu.models.registry import get_model_spec
+    from aigw_tpu.parallel.mesh import MeshSpec, make_mesh
+    from aigw_tpu.tpuserve.attention import resolve_decode_backend
+    from aigw_tpu.tpuserve.engine import EngineConfig
+
+    model_cfg = get_model_spec(model).config
+    mesh = make_mesh(MeshSpec(tp=tp)) if tp > 1 else None
+    for dtype in ("bfloat16", "int8", "int4"):
+        impl, why = resolve_decode_backend(
+            EngineConfig(kv_cache_dtype=dtype), model_cfg, mesh)
+        assert impl == want, why
+        if want == "xla-gather":
+            assert "narrowed" in why and f"tp={tp}" in why
+            continue
+        assert "page walk" in why
+        assert (f"{dtype} KV pages dequantize" in why) == (
+            dtype != "bfloat16")
+        assert ("LOCAL head shard" in why) == (tp > 1)
+
+
+@pytest.mark.parametrize("family", ["qwen3_next", "axk1", "mimo_v2"])
+def test_a_family_without_a_gather_refuses_it(family):
+    """What the deleted per-family ``..._fall_back_to_the_walk`` cases
+    really protected: a family never silently runs a rung it
+    lacks. ``attn_impl="gather"`` — what the engine hands a family on a
+    mesh whose tp does not divide its heads — raises, and the message
+    names the family and the rung."""
+    from aigw_tpu.models.registry import family_fns
+
+    with pytest.raises(NotImplementedError) as e:
+        family_fns(family).decode_step(
+            None, None, None, None, None, None, PAGE, None,
+            attn_impl="gather")
+    assert family in str(e.value) and "'gather'" in str(e.value)
+
+
 # -- the counters that say what the decode programs read ---------------------
 
 @pytest.mark.parametrize("key", [
@@ -392,16 +543,16 @@ def _stream(eng, prompt, n):
     return toks
 
 
-@pytest.mark.parametrize("rung", [
-    {}, {"decode_backend": "fused"}, {"pallas_attn": True}],
-    ids=["xla-walk", "fused-xla", "pallas"])
-def test_engine_counts_what_its_decode_programs_read(rung):
-    """On the walk the count is the loop's bound (one row of four live:
-    its pairs rounded up to a trip, which at this tiny pool's 4 KiB a
-    pair is the page table of the step's page bucket, and no trip once
-    nothing is live); on a kernel rung it is the [B, P] window the
-    program addresses at every step. The pages the live row held are
-    counted the same way on both."""
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8", "int4"],
+                         ids=["xla-walk", "int8", "int4"])
+def test_engine_counts_what_its_decode_programs_read(kv_dtype):
+    """The count is the walk's loop bound (one row of four live: its
+    pairs rounded up to a trip, which at this tiny pool's bytes a pair
+    is the page table of the step's page bucket, and no trip once
+    nothing is live). ``[int8]`` / ``[int4]``: the same two counters
+    under a quantized pool, whose smaller pairs make longer trips —
+    held nowhere before (they take the place of the two kernel-rung
+    ids, whose names are gone)."""
     from aigw_tpu.models.registry import get_model_spec
     from aigw_tpu.tpuserve.engine import Engine, EngineConfig
 
@@ -411,7 +562,8 @@ def test_engine_counts_what_its_decode_programs_read(rung):
     eng = Engine(params, spec.config, EngineConfig(
         max_batch_size=4, max_seq_len=256, page_size=16,
         min_prefill_bucket=16, decode_steps_per_tick=4, spec_tokens=0,
-        kv_cache_dtype="float32", **rung))
+        kv_cache_dtype=kv_dtype))
+    assert eng.decode_attn_impl == "xla-walk"
     eng.start()
     try:
         toks = _stream(eng, [3, 1, 4, 1, 5, 9, 2, 6], 40)
@@ -423,9 +575,5 @@ def test_engine_counts_what_its_decode_programs_read(rung):
     # positions 8..47 at 16 tokens a page: 1 page until the 16th token,
     # then 2, then 3; junk steps past the request's end hold nothing
     assert st.decode_kv_pages_live <= 3 * st.decode_steps
-    walks = eng.decode_attn_impl in ("xla-walk", "fused-xla")
-    if walks:
-        assert (st.decode_kv_pages_live < st.decode_kv_pages_read
-                <= 4 * 16 * st.decode_steps)
-    else:
-        assert st.decode_kv_pages_read > 4 * st.decode_steps
+    assert (st.decode_kv_pages_live < st.decode_kv_pages_read
+            <= 4 * 16 * st.decode_steps)

@@ -4,359 +4,13 @@ Runs in interpreter mode on the CPU test platform. The references, the
 cases and the tolerances live in ``aigw_tpu/ops/pallas/parity.py`` —
 the chip smoke's kernels child runs the same checks compiled on the
 TPU; ``tests/test_pallas_tpu_aot.py`` keeps every kernel lowering
-through Mosaic without a chip."""
+through Mosaic without a chip. The W8A16 matmul's cases are in
+``tests/test_qmatmul.py``."""
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from aigw_tpu.ops.pallas import parity
-from aigw_tpu.ops.pallas.paged_attention import paged_attention_decode_v2
-
-
-@pytest.mark.parametrize("lengths", [[7, 33], [1, 64], [40, 17]])
-@pytest.mark.slow
-def test_paged_attention_decode_matches_xla(lengths):
-    parity.check_decode_v2(H=4, Hkv=2, D=128, page=16, lengths=lengths,
-                           P=4, n_pages=16, seed=0, interpret=True)
-
-
-def test_single_token_length():
-    """length=1 edge: only the first slot of the first page attends."""
-    B, H, Hkv, D = 1, 2, 1, 128
-    page_size = 8
-    q = jnp.ones((B, H, D), jnp.bfloat16)
-    k_pool = jnp.zeros((4 * page_size, Hkv, D), jnp.bfloat16)
-    v_pool = jnp.zeros((4 * page_size, Hkv, D), jnp.bfloat16)
-    v_pool = v_pool.at[0].set(3.0)
-    pt = jnp.array([[0, 1, 2, 3]], jnp.int32)
-    out = paged_attention_decode_v2(
-        q, k_pool, v_pool, pt, jnp.array([1], jnp.int32),
-        page_size=page_size, interpret=True,
-    )
-    np.testing.assert_allclose(np.asarray(out, jnp.float32),
-                               np.full((B, H, D), 3.0), rtol=1e-2)
-
-
-class TestDecodeStepPallasAttn:
-    """llama.decode_step attn_impl='pallas' vs the XLA gather path."""
-
-    def _setup(self):
-        from aigw_tpu.models import llama
-
-        cfg = llama.TINY
-        params = llama.init_params(jax.random.PRNGKey(3), cfg)
-        ps = 16
-        kv_shape = (cfg.n_layers, 2, 8 * ps, cfg.n_kv_heads, cfg.head_dim)
-        kv = jnp.zeros(kv_shape, jnp.bfloat16)
-        pt = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
-        prompts = jnp.asarray(
-            [[3, 1, 4, 1, 5, 0, 0, 0], [2, 7, 1, 8, 2, 8, 1, 8]], jnp.int32)
-        lens = jnp.asarray([5, 8], jnp.int32)
-        _, kv = llama.prefill(params, cfg, prompts, lens, kv, pt, ps)
-        return llama, cfg, params, kv, pt, ps
-
-    def test_logits_match_gather_path(self):
-        llama, cfg, params, kv, pt, ps = self._setup()
-        tokens = jnp.asarray([9, 4], jnp.int32)
-        positions = jnp.asarray([5, 8], jnp.int32)
-        active = jnp.asarray([True, True])
-        ref, _ = llama.decode_step(params, cfg, tokens, positions, kv, pt,
-                                   ps, active)
-        got, _ = llama.decode_step(params, cfg, tokens, positions, kv, pt,
-                                   ps, active, attn_impl="pallas")
-        # bf16 noise floor: the interpret-mode kernel and the XLA gather
-        # path accumulate attention in different orders; with ~2-magnitude
-        # logits a worst-case element lands a few bf16 ulps (~0.008 each)
-        # past the old 0.02 atol on some jax/host combinations (observed:
-        # 1/1024 elements at 0.0249). 0.05 stays far below any real
-        # kernel defect while clearing the reduction-order jitter.
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-2, atol=5e-2)
-        assert int(jnp.argmax(got[0])) == int(jnp.argmax(ref[0]))
-        assert int(jnp.argmax(got[1])) == int(jnp.argmax(ref[1]))
-
-    def test_inactive_slot_masked(self):
-        llama, cfg, params, kv, pt, ps = self._setup()
-        tokens = jnp.asarray([9, 4], jnp.int32)
-        positions = jnp.asarray([5, 8], jnp.int32)
-        both, _ = llama.decode_step(
-            params, cfg, tokens, positions, kv, pt, ps,
-            jnp.asarray([True, False]), attn_impl="pallas")
-        ref, _ = llama.decode_step(
-            params, cfg, tokens, positions, kv, pt, ps,
-            jnp.asarray([True, True]), attn_impl="pallas")
-        # the active slot's logits are unaffected by the inactive one
-        np.testing.assert_allclose(np.asarray(both[0]), np.asarray(ref[0]),
-                                   rtol=1e-5)
-
-
-@pytest.mark.slow
-
-
-def test_engine_pallas_attn_matches_gather():
-    """End-to-end: the engine with pallas_attn=True generates the same
-    greedy stream as the default gather engine."""
-    import threading
-
-    from aigw_tpu.models import llama
-    from aigw_tpu.tpuserve.engine import Engine, EngineConfig, GenRequest
-    from aigw_tpu.tpuserve.sampling import SamplingParams
-
-    def gen(pallas: bool):
-        cfg = EngineConfig(max_batch_size=2, max_seq_len=128, page_size=16,
-                           min_prefill_bucket=16, decode_steps_per_tick=4,
-                           pallas_attn=pallas)
-        params = llama.init_params(jax.random.PRNGKey(0), llama.TINY)
-        eng = Engine(params, llama.TINY, cfg, eos_token_ids=(257,))
-        eng.start()
-        try:
-            done = threading.Event()
-            toks: list[int] = []
-
-            def emit(tok, fin):
-                if tok >= 0:
-                    toks.append(tok)
-                if fin is not None:
-                    done.set()
-
-            eng.submit(GenRequest(prompt=[5, 3, 8, 1], max_tokens=8,
-                                  sampling=SamplingParams(temperature=0.0),
-                                  emit=emit))
-            assert done.wait(timeout=120)
-            return toks
-        finally:
-            eng.stop()
-
-    assert gen(True) == gen(False)
-
-
-class TestVerifyKernel:
-    """Multi-query speculative-verify kernel vs the gather path."""
-
-    @pytest.mark.slow
-
-    def test_matches_gather_verify_step(self):
-        from aigw_tpu.models import llama
-
-        cfg = llama.TINY
-        params = llama.init_params(jax.random.PRNGKey(5), cfg)
-        ps = 16
-        kv_shape = (cfg.n_layers, 2, 8 * ps, cfg.n_kv_heads, cfg.head_dim)
-        pt = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
-        prompts = jnp.asarray(
-            [[3, 1, 4, 1, 5, 0, 0, 0], [2, 7, 1, 8, 2, 8, 1, 8]], jnp.int32)
-        lens = jnp.asarray([5, 8], jnp.int32)
-        kv0 = jnp.zeros(kv_shape, jnp.bfloat16)
-        _, kv0 = llama.prefill(params, cfg, prompts, lens, kv0, pt, ps)
-
-        inputs = jnp.asarray([[9, 2, 6, 5], [4, 4, 1, 2]], jnp.int32)
-        positions = jnp.asarray([5, 8], jnp.int32)
-        active = jnp.asarray([True, True])
-        limits = jnp.asarray([64, 64], jnp.int32)
-        ref, _ = llama.verify_step(params, cfg, inputs, positions, kv0,
-                                   pt, ps, active, limits)
-        got, _ = llama.verify_step(params, cfg, inputs, positions, kv0,
-                                   pt, ps, active, limits,
-                                   attn_impl="pallas")
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=5e-2, atol=5e-2)
-        # argmax agreement at every verified position
-        assert (np.argmax(np.asarray(got), -1)
-                == np.argmax(np.asarray(ref), -1)).all()
-
-    @pytest.mark.slow
-
-    def test_engine_spec_pallas_matches_spec_gather(self):
-        """Speculation + ragged kernel produces the same stream as
-        speculation + gather — bit-equivalence through the engine."""
-        import threading
-
-        from aigw_tpu.models import llama
-        from aigw_tpu.tpuserve.engine import Engine, EngineConfig, GenRequest
-        from aigw_tpu.tpuserve.sampling import SamplingParams
-
-        def gen(pallas: bool):
-            # fixed draft width: the quantity under test is kernel
-            # acceptance parity, not the adaptive ladder (which would
-            # collapse this low-acceptance random-weight stream)
-            cfg = EngineConfig(max_batch_size=2, max_seq_len=128,
-                               page_size=16, min_prefill_bucket=16,
-                               decode_steps_per_tick=4, spec_tokens=3,
-                               spec_adaptive=False, pallas_attn=pallas)
-            params = llama.init_params(jax.random.PRNGKey(0), llama.TINY)
-            eng = Engine(params, llama.TINY, cfg, eos_token_ids=(257,))
-            eng.start()
-            try:
-                done = threading.Event()
-                toks: list[int] = []
-
-                def emit(tok, fin):
-                    if tok >= 0:
-                        toks.append(tok)
-                    if fin is not None:
-                        done.set()
-
-                # bias pins the greedy stream to one token: the n-gram
-                # source proposes full drafts once (7,7) repeats, so
-                # BOTH attention impls must accept — a random-weight
-                # free-running stream accepts nothing and the parity
-                # assertion would be vacuous (pre-PR-4 this test
-                # depended on the stream happening to self-repeat)
-                eng.submit(GenRequest(
-                    prompt=[5, 6, 7, 5, 6], max_tokens=10,
-                    sampling=SamplingParams(
-                        temperature=0.0, logit_bias=((7, 100.0),)),
-                    emit=emit))
-                assert done.wait(timeout=180)
-                return toks, eng.stats.spec_accepted
-            finally:
-                eng.stop()
-
-        (a, acc_a), (b, acc_b) = gen(True), gen(False)
-        assert a == b
-        # the kernel must ACCEPT like the gather path, not silently
-        # reject every draft (output streams would still match)
-        assert acc_a == acc_b and acc_a > 0
-
-
-class TestProductionShapes:
-    """Interpret-mode A/B at llama-3-8B attention geometry (H=32,
-    Hkv=8, D=128, 128-token pages): the decode AND verify kernels must
-    agree with the XLA gather path at the shapes production would run.
-    The chip smoke runs the same checks compiled at the served
-    geometry."""
-
-    def test_decode_v2_production_shape(self):
-        parity.check_decode_v2(H=32, Hkv=8, interpret=True)
-
-    def test_verify_production_shape(self):
-        # pending token + 4 drafts — the top bench rung
-        parity.check_verify(H=32, Hkv=8, interpret=True)
-
-
-# -- fused decode kernel (ISSUE 13) --------------------------------------
-
-class TestFusedDecodeKernel:
-    """Interpret-mode parity for the FUSED decode step (RoPE + KV
-    append + paged attention in one kernel, optionally over int8/int4
-    pages with per-page scale blocks) vs the scatter-then-walk XLA
-    reference that serves off-TPU — at llama-3-8B attention geometry
-    (H=32, Hkv=8, D=128, 128-token pages) with page-misaligned append
-    offsets, page-aligned fresh-page appends, inactive slots, and both
-    quantized dtypes."""
-
-    def _case(self, **kw):
-        return parity.fused_case(interpret=True, **kw)
-
-    def test_production_shape_native(self):
-        parity.check_fused(H=32, Hkv=8, interpret=True)
-
-    @pytest.mark.parametrize("qdt", ["int8", "int4"])
-    def test_production_shape_quantized(self, qdt):
-        parity.check_fused(H=32, Hkv=8, qdt=qdt, interpret=True)
-
-    def test_consecutive_steps_carry_the_pool(self):
-        """Several fused steps feeding on the pool the previous step
-        wrote (tiny geometry; the compiled production-shape run is the
-        chip smoke's)."""
-        parity.check_fused_steps(H=4, Hkv=2, D=16, ps=16, steps=3,
-                                 theta=10000.0, interpret=True)
-
-    def test_tiny_moe_geometry(self):
-        """tiny-moe attention geometry (ISSUE 18): H=4, Hkv=2 (GROUP
-        divides heads), D=16, 16-token pages — the shapes the MoE
-        family's fused decode serves at now that the family exception
-        row is gone. Mid-page and page-straddling appends."""
-        outs, want, aux = self._case(
-            B=3, H=4, Hkv=2, D=16, ps=16, n_pages=16, P=4,
-            positions=[17, 0, 48], active=[True, True, True])
-        parity.assert_active_close(outs, want, [True, True, True])
-        pt, slot, positions, active, k_pool, knr, vn = aux
-        # appended K row is the roped new K, bit-for-bit the XLA recipe
-        np.testing.assert_array_equal(
-            np.asarray(outs[1][slot[0]]), np.asarray(knr[0]))
-
-    def test_tiny_moe_geometry_quantized(self):
-        """Same MoE geometry over int8 pages — the resolver gate the
-        tentpole deleted means these shapes now serve quantized too."""
-        outs, want, aux = self._case(
-            B=2, H=4, Hkv=2, D=16, ps=16, n_pages=12, P=4,
-            positions=[33, 16], active=[True, True], qdt="int8")
-        parity.assert_active_close(outs, want, [True, True])
-
-    def test_fresh_page_pos0_and_inactive(self):
-        """Page-aligned appends start a fresh page; pos=0 attends only
-        itself; inactive slots leave every table-referenced page
-        untouched (their write lands in the dump page)."""
-        B, H, Hkv, D, ps, n_pages, P = 3, 4, 2, 128, 16, 16, 4
-        outs, want, aux = self._case(
-            B=B, H=H, Hkv=Hkv, D=D, ps=ps, n_pages=n_pages, P=P,
-            positions=[16, 0, 33], active=[True, True, False])
-        parity.assert_active_close(outs, want, [True, True, False])
-        pt, slot, positions, active, k_pool, knr, vn = aux
-        # inactive slot 2: its pages (and every non-append page) are
-        # bit-identical to the input pool; only the dump page may churn
-        touched = {int(pt[0, 1]), int(pt[1, 0]), n_pages - 1}
-        mask = np.ones(n_pages * ps, bool)
-        for pg in touched:
-            mask[pg * ps:(pg + 1) * ps] = False
-        np.testing.assert_array_equal(np.asarray(outs[1])[mask],
-                                      np.asarray(k_pool)[mask])
-        # pos=0: the fresh page's row 0 is the appended K row
-        np.testing.assert_array_equal(
-            np.asarray(outs[1][int(pt[1, 0]) * ps]),
-            np.asarray(knr[1]))
-
-
-@pytest.mark.slow
-def test_engine_fused_pallas_interpret_matches_chained():
-    """End-to-end: the engine forced onto the fused Pallas kernel
-    (interpret mode via AIGW_DECODE_FUSED_IMPL) generates the same
-    greedy stream as the chained gather engine."""
-    import os
-    import threading
-
-    from aigw_tpu.models import llama
-    from aigw_tpu.tpuserve.engine import Engine, EngineConfig, GenRequest
-    from aigw_tpu.tpuserve.sampling import SamplingParams
-
-    def gen(impl_env: str):
-        cfg = EngineConfig(max_batch_size=2, max_seq_len=128,
-                           page_size=16, min_prefill_bucket=16,
-                           decode_steps_per_tick=4,
-                           decode_backend="fused" if impl_env else "auto")
-        params = llama.init_params(jax.random.PRNGKey(0), llama.TINY)
-        if impl_env:
-            os.environ["AIGW_DECODE_FUSED_IMPL"] = impl_env
-        try:
-            eng = Engine(params, llama.TINY, cfg, eos_token_ids=(257,))
-        finally:
-            os.environ.pop("AIGW_DECODE_FUSED_IMPL", None)
-        if impl_env:
-            assert eng.decode_attn_impl == "fused-pallas"
-        eng.start()
-        try:
-            done = threading.Event()
-            toks: list[int] = []
-
-            def emit(tok, fin):
-                if tok >= 0:
-                    toks.append(tok)
-                if fin is not None:
-                    done.set()
-
-            eng.submit(GenRequest(prompt=[5, 3, 8, 1], max_tokens=6,
-                                  sampling=SamplingParams(temperature=0.0),
-                                  emit=emit))
-            assert done.wait(timeout=300)
-            assert eng.healthy, eng.last_error
-            return toks
-        finally:
-            eng.stop()
-
-    assert gen("pallas") == gen("")
 
 
 # -- ragged prefill kernel (ISSUE 6) -------------------------------------

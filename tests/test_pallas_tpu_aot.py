@@ -5,7 +5,7 @@ libtpu can describe a TPU topology on a chipless box, so each
 ahead of time by the real Mosaic compiler (``interpret=False``) at the
 two served attention geometries. Interpret-mode parity tests cannot see
 a Mosaic refusal (block shapes, in-kernel reshapes, unaligned slices):
-``fused_paged_decode`` passed them for eight PRs and had never lowered.
+a kernel once passed them for eight PRs and had never lowered.
 Agreement with the XLA twins on real hardware is ``chip_smoke.py``'s
 kernels phase; this file only keeps the lowering from regressing.
 """
@@ -17,12 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 from aigw_tpu.ops.pallas import qmatmul
-from aigw_tpu.ops.pallas.decode_fused import fused_paged_decode
-from aigw_tpu.ops.pallas.paged_attention import (
-    paged_attention_decode_v2,
-    paged_attention_verify,
-    ragged_prefill_attention,
-)
+from aigw_tpu.ops.pallas.paged_attention import ragged_prefill_attention
 
 #: (n_heads, n_kv_heads, dim, ffn_dim, vocab_size)
 GEOMETRIES = {
@@ -30,7 +25,7 @@ GEOMETRIES = {
     "llama-3-8b": (32, 8, 4096, 14336, 128256),
 }
 D, PAGE, B, P = 128, 128, 8, 16
-N_SLOTS = (B * P + 1) * PAGE  # the engine's pool: pages + the dump page
+N_SLOTS = (B * P + 1) * PAGE  # the engine's pool: pages + its extra page
 
 
 @pytest.fixture(scope="module")
@@ -63,25 +58,9 @@ def test_attention_kernels_compile(v5e, geometry):
     pool = ((N_SLOTS, Hkv, D), bf16)
     pt = ((B, P), i32)
     _compile(
-        functools.partial(paged_attention_decode_v2, page_size=PAGE),
-        v5e, ((B, H, D), bf16), pool, pool, pt, ((B,), i32))
-    _compile(
-        functools.partial(paged_attention_verify, page_size=PAGE),
-        v5e, ((B, 5, H, D), bf16), pool, pool, pt, ((B,), i32))
-    _compile(
         functools.partial(ragged_prefill_attention, page_size=PAGE),
         v5e, ((256, H, D), bf16), pool, pool, pt, ((B + 1,), i32),
         ((B,), i32))
-    fused = functools.partial(fused_paged_decode, rope_theta=1e6,
-                              page_size=PAGE)
-    new = ((B, Hkv, D), bf16)
-    tail = (pt, ((B,), i32), ((B,), jnp.bool_))
-    _compile(fused, v5e, ((B, H, D), bf16), new, new, pool, pool, *tail)
-    for qdt in (jnp.int8, jnp.int4):
-        qpool = ((N_SLOTS, Hkv, D), qdt)
-        scale = ((N_SLOTS, Hkv), jnp.float32)
-        _compile(fused, v5e, ((B, H, D), bf16), new, new, qpool, qpool,
-                 *tail, k_scale=scale, v_scale=scale)
 
 
 @pytest.mark.parametrize("geometry", sorted(GEOMETRIES))

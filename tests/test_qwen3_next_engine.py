@@ -22,7 +22,6 @@ import pytest
 
 from aigw_tpu.models import qwen3_next as qn
 from aigw_tpu.models.registry import family_fns, get_model_spec
-from aigw_tpu.tpuserve.attention import resolve_decode_backend
 from aigw_tpu.tpuserve.engine import Engine, EngineConfig, GenRequest
 from aigw_tpu.tpuserve.kvcache import PageAllocator
 from aigw_tpu.tpuserve.sampling import SamplingParams
@@ -170,21 +169,6 @@ def test_lora_refuses_at_start_up(kwargs):
         Engine(make_params(SHARE), SHARE, EngineConfig(
             max_batch_size=2, max_seq_len=64, page_size=16),
             fns=family_fns("qwen3_next"), **kwargs)
-
-
-@pytest.mark.parametrize("requested", [
-    dict(decode_backend="fused"), dict(pallas_attn=True),
-    dict(decode_backend="fused", kv_cache_dtype="int8")])
-def test_decode_kernels_fall_back_to_the_walk(requested):
-    cfg = EngineConfig(max_batch_size=2, max_seq_len=64, page_size=16,
-                       **requested)
-    impl, why = resolve_decode_backend(cfg, SHARE, None,
-                                       family_fns("qwen3_next"))
-    assert impl == "xla-walk" and "no kernel rung" in why
-    # the family's ModelFns says so, not the shape of its config
-    assert resolve_decode_backend(
-        cfg, SHARE, None, family_fns("llama")) == resolve_decode_backend(
-        cfg, SHARE, None)
 
 
 def test_ragged_backend_request_falls_back_to_bucketed():

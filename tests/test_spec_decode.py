@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from aigw_tpu.models import llama
+from aigw_tpu.models import kvq, llama
 from aigw_tpu.tpuserve.engine import Engine, EngineConfig, GenRequest
 from aigw_tpu.tpuserve.sampling import SamplingParams
 from aigw_tpu.tpuserve.speculation import accept_counts, ngram_drafts
@@ -223,14 +223,30 @@ class TestSpecEdges:
 
 
 class TestVerifyStep:
-    @pytest.mark.parametrize("rung,tol", [("gather", 2e-2), ("", 3e-2)],
-                             ids=["gather", "walk"])
-    def test_matches_sequential_decode(self, rung, tol):
+    @pytest.mark.parametrize("rung,kv_dtype,tol", [
+        ("gather", "bfloat16", 2e-2), ("", "bfloat16", 3e-2),
+        ("", "int8", 5e-2), ("", "int4", 2e-1)],
+        ids=["gather", "walk", "walk-int8", "walk-int4"])
+    def test_matches_sequential_decode(self, rung, kv_dtype, tol):
         """verify_step's logits at every position equal running
         decode_step one token at a time over the same inputs: on the
         window gather, which is verify_step's own attention, and — a
         bfloat16 rounding further, since the walk accumulates the
-        values in float32 across pages — on the page walk."""
+        values in float32 across pages — on the page walk.
+
+        ``[walk-int8]`` / ``[walk-int4]``: verify over a QUANTIZED pool
+        ("gather-dequant": ``kvq.window_kv`` dequantizes the window it
+        reads), which no tier-1 case held — the deleted
+        ``test_verify_production_shape`` stood beside it on a native
+        pool. The reference is the walk, whose trips dequantize in code
+        of their own (ops/paged_walk.py), so a scale dropped on either
+        side shows. Tolerances: int8's grid step is a 127th of a row's
+        largest value, below the bfloat16 rounding the two reads
+        already differ by (``parity.BF16_TOL``); int4's is a seventh,
+        and a row the two sides quantized from values a rounding apart
+        may land a step apart (the bound of
+        ``test_paged_walk.STEP_TOL``). A dropped scale moves these
+        logits by O(1)."""
         cfg = llama.TINY
         params = llama.init_params(jax.random.PRNGKey(1), cfg)
         ps = 16
@@ -243,7 +259,7 @@ class TestVerifyStep:
         inputs = [9, 2, 6, 5]  # pending + 3 "drafts"
 
         # sequential reference
-        kv = jnp.zeros(kv_shape, jnp.bfloat16)
+        kv = kvq.make_pool(kv_shape, kv_dtype)
         _, kv = llama.prefill(params, cfg, prompt, seq_lens, kv,
                               page_table, ps)
         seq_logits = []
@@ -255,7 +271,7 @@ class TestVerifyStep:
             seq_logits.append(np.asarray(lg[0]))
 
         # one verify step
-        kv = jnp.zeros(kv_shape, jnp.bfloat16)
+        kv = kvq.make_pool(kv_shape, kv_dtype)
         _, kv = llama.prefill(params, cfg, prompt, seq_lens, kv,
                               page_table, ps)
         ver, _ = llama.verify_step(

@@ -18,6 +18,7 @@ import threading
 import aiohttp
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from aigw_tpu.obs import xla_events
@@ -301,6 +302,20 @@ def ready_serve():
                 EngineConfig(max_batch_size=2, max_seq_len=256,
                              page_size=16, min_prefill_bucket=16))
             _runner, holder["port"] = await listen(server, "127.0.0.1", 0)
+            # warmup loaded the row update at the idle page bucket (1)
+            # alone. Whether a request is admitted by a row update or a
+            # full state build is a race between the client's next ask
+            # and the engine going idle, so on a loaded machine the
+            # row update at the prompt's bucket could first load under
+            # the THIRD request (ROADMAP D7). Load it at every bucket,
+            # as warmup does at its own, before any request is timed.
+            eng = server.engine
+            P = 1
+            while P <= eng.cfg.max_pages_per_seq:
+                eng._row_update_fn_built()(
+                    eng._build_device_state(bucket=P), np.int32(0),
+                    eng._row_host_values(0, P))
+                P *= 2
             holder["loop"] = asyncio.get_running_loop()
             started.set()
             await asyncio.Event().wait()
