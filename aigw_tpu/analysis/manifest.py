@@ -41,8 +41,6 @@ METRICS_ONLY: dict[str, str] = {
                       "carries the live decode_window instead",
     "window_grows": "adaptive-window transition counter; /state "
                     "carries the live decode_window instead",
-    "prefix_tokens_reused": "volume counter, /metrics only; the "
-                            "picker scores prefix_cache_hit_rate",
     "prefix_full_hits": "fast-path counter, /metrics only",
     "prefix_cow_copies": "CoW counter, /metrics only",
     "adapter_resident": "/state exports the adapters_resident NAME "

@@ -33,13 +33,23 @@ were before this description existed. A family with state gets a
 :class:`StateCache` — the pool and the state leaves in one pytree that
 rides the same donation chain and the decode scan's carry.
 
-What moves pages only (prefix-cache hits, the host KV tier, parking and
-migration of a live sequence, fleet fetch, speculative verify) cannot
-serve a family with per-slot state: a page without the state that goes
-with it is half a sequence — a recurrent state, or a slot's window
-keys. Nor, yet, a family whose pages are latent rows: the movers'
-programs and checks know K and V planes alone. The engine switches them
-off by asking ``spec.pinned`` — by what the family is, not by a flag.
+What moves pages only (the host KV tier, parking and migration of a live
+sequence, fleet fetch, speculative verify) cannot serve a family with
+per-slot state: a page without the state that goes with it is half a
+sequence — a recurrent state, or a slot's window keys. Nor, yet, a
+family whose pages are latent rows: the movers' programs and checks know
+K and V planes alone. The engine switches them off by asking
+``spec.pinned`` — by what the family is, not by a flag.
+
+The prefix cache is the one mover that a family with recurrent state can
+have: where the state at a chunk boundary of a prompt is the whole of
+what the state layers know of the prefix (``spec.snapshots``), the
+engine copies a slot's state leaves into a row of a **snapshot pool** —
+the same leaves as the slot state, ``snapshot_rows`` rows — at chosen
+boundaries of a prefill, keyed with the page chain; a later prompt's hit
+is valid only at a chain node that holds a snapshot, which is copied
+back into the slot before ``prefill_suffix`` resumes there. Everything
+else that ``pinned`` names stays off.
 """
 
 from __future__ import annotations
@@ -76,6 +86,12 @@ class CacheSpec:
     #: ``slot_state`` leaf ``window`` tokens long, whatever the context
     #: (0: every attending layer attends over its whole context)
     window: int = 0
+    #: the ``slot_state`` at a chunk boundary of a prompt is all that
+    #: the state layers know of the prefix, so a copy of it taken there
+    #: lets a later prompt resume at that boundary: the prefix cache
+    #: serves the family (a ring of window keys is not such a state
+    #: yet: ROADMAP.md M4)
+    snapshots: bool = False
 
     @property
     def stateful(self) -> bool:
@@ -91,6 +107,10 @@ class CacheSpec:
                     f"window layers keep a slot's last {self.window} "
                     "tokens and own no pages (ROADMAP.md M2, M4: a ring "
                     "snapshot at a page boundary)")
+        if self.snapshots:
+            return ("the family keeps per-slot recurrent state beside its "
+                    "pages, and only the prefix cache carries a snapshot of "
+                    "it with the pages (ROADMAP.md M4: the other movers)")
         if self.stateful:
             return ("the family keeps per-slot recurrent state beside its "
                     "pages (ROADMAP.md M4: state snapshots)")
@@ -121,6 +141,30 @@ class CacheSpec:
                    * _leaf_dtype(dt, kv_cache_dtype).itemsize
                    for _, layers, shape, dt in self.slot_state)
 
+    def snapshot_rows(self, n_slots: int) -> int:
+        """Rows of the snapshot pool — THE rule that sizes it, from the
+        family's spec and the engine's slot count alone: three a slot.
+        A sequence in a slot has at most three boundaries worth keeping
+        at a time (the end of a prefix it shares with other sequences,
+        the last whole chunk of its own prompt, and the boundary its
+        next turn will pass while this one is still referenced); fewer
+        and a turn's own snapshot evicts the shared one, more is memory
+        the page pool would use better."""
+        return 3 * n_slots if self.snapshots else 0
+
+    def make_snapshots(self, n_slots: int, kv_cache_dtype: str) -> dict:
+        """The zero-initialised snapshot pool: the ``slot_state``
+        leaves with ``snapshot_rows`` rows where a slot pool has
+        ``n_slots``."""
+        return self._state_leaves(self.snapshot_rows(n_slots),
+                                  kv_cache_dtype)
+
+    def _state_leaves(self, n_rows: int, kv_cache_dtype: str) -> dict:
+        return {
+            name: jnp.zeros((layers, n_rows, *shape),
+                            _leaf_dtype(dt, kv_cache_dtype))
+            for name, layers, shape, dt in self.slot_state}
+
     def make(self, n_rows: int, n_slots: int, kv_cache_dtype: str,
              mesh=None, data_spec=None):
         """Zero-initialised device cache: the bare pool, or a
@@ -129,10 +173,7 @@ class CacheSpec:
                              data_spec)
         if not self.stateful:
             return pool
-        return StateCache(pool, {
-            name: jnp.zeros((layers, n_slots, *shape),
-                            _leaf_dtype(dt, kv_cache_dtype))
-            for name, layers, shape, dt in self.slot_state})
+        return StateCache(pool, self._state_leaves(n_slots, kv_cache_dtype))
 
 
 def _leaf_dtype(name: str, kv_cache_dtype: str):

@@ -17,7 +17,8 @@ from aigw_tpu.models import llama
 @dataclass(frozen=True)
 class ModelSpec:
     name: str
-    #: "llama" | "mixtral" | "qwen3_next" | "axk1" | "mimo_v2"
+    #: "llama" | "mixtral" | "qwen3_next" | "axk1" | "mimo_v2" |
+    #: "olmo_hybrid"
     family: str
     config: Any
     weights: str = "random"  # "random" | "orbax:<dir>" | "hf:<dir>"
@@ -57,6 +58,12 @@ class ModelFns:
     # placed counts + capacity drops per layer). The engine turns it on
     # for MoE families (configs carrying ``n_experts``)
     moe_stats: bool = False
+    # ``state_reads(cache, active) -> [2] int32``: what a decode step's
+    # live-row loops read of the per-slot state pool a layer (slots read,
+    # live rows), for a DENSE family with such state — an expert family
+    # carries the two on its routing-stats tape. The engine adds them to
+    # the page counters its decode window already fetches. None: nothing
+    state_reads: Any = None
     # ``serving_params(params, cfg)``: the tree the family's programs
     # read, out of ``init_params``' (a checkpoint's) tree — weights laid
     # out once, at load, for the products that read them. None: the
@@ -113,6 +120,16 @@ def family_fns(family: str) -> ModelFns:
                         mimo_v2.decode_step, mimo_v2.hidden_states,
                         prefill_suffix=mimo_v2.prefill_suffix,
                         moe_stats=True)
+    if family == "olmo_hybrid":
+        from aigw_tpu.models import olmo_hybrid
+
+        # dense: no routing stats. No verify_step (a rejected draft
+        # would need the DeltaNet state rolled back), no
+        # sequence-parallel and no ragged prefill
+        return ModelFns(olmo_hybrid.init_params, olmo_hybrid.prefill,
+                        olmo_hybrid.decode_step, olmo_hybrid.hidden_states,
+                        prefill_suffix=olmo_hybrid.prefill_suffix,
+                        state_reads=olmo_hybrid.state_reads)
     raise KeyError(f"unknown model family {family!r}")
 
 
@@ -184,6 +201,16 @@ def _register_mimo_v2() -> None:
 
 
 _register_mimo_v2()
+
+
+def _register_olmo_hybrid() -> None:
+    from aigw_tpu.models import olmo_hybrid
+
+    register_model(ModelSpec("tiny-olmo-hybrid", "olmo_hybrid",
+                             olmo_hybrid.TINY, chat_template="chatml"))
+
+
+_register_olmo_hybrid()
 register_model(ModelSpec("llama-3-8b", "llama", llama.LLAMA3_8B,
                          weights="orbax:checkpoints/llama-3-8b"))
 register_model(ModelSpec("qwen2-7b", "llama", llama.QWEN2_7B,

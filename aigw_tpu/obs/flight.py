@@ -29,6 +29,7 @@ engine's counters cut to the capture (``capture_*``).
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -453,6 +454,14 @@ class LoopLedger:
             a = _trace_annotation(name, **facts)
             a.__enter__()
             a.__exit__(None, None, None)
+
+    def span(self, name: str, **facts: Any):
+        """A span of its own on the profiler's clock, INSIDE the running
+        phase's (whose self time it stays part of): a context manager;
+        nothing but a null context off a capture."""
+        if self._ann is None:
+            return contextlib.nullcontext()
+        return _trace_annotation(name, **facts)
 
     def _counters(self, now: int) -> tuple:
         return (now, list(self.ns),

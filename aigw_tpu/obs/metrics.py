@@ -161,6 +161,14 @@ ENGINE_GAUGES: tuple[tuple[str, str], ...] = (
     ("state_rebuilds", "tpuserve_state_rebuilds_total"),
     ("prefix_cache_hits", "tpuserve_prefix_cache_hits_total"),
     ("prefix_tokens_reused", "tpuserve_prefix_tokens_reused_total"),
+    # a family whose prefix cache resumes from a snapshot of its
+    # per-slot state (Engine.chunk_boundary; 0 elsewhere)
+    ("state_snapshots_saved", "tpuserve_state_snapshots_saved_total"),
+    ("state_snapshots_restored", "tpuserve_state_snapshots_restored_total"),
+    ("state_snapshots_evicted", "tpuserve_state_snapshots_evicted_total"),
+    ("state_snapshot_bytes_total", "tpuserve_state_snapshot_bytes"),
+    ("prefix_tokens_unrestorable",
+     "tpuserve_prefix_tokens_unrestorable_total"),
     # prefix-cache reuse surface (ISSUE 3): hit/miss/eviction counters,
     # the full-hit fast path (CoW'd final page + single-token resume),
     # and the residency/pinning gauges behind HBM capacity planning
@@ -541,7 +549,8 @@ CAPTURE_COUNTERS: tuple[str, ...] = (
     "moe_local_assignments", "moe_total_assignments",
     "moe_held_hits_decode", "decode_kv_pages_live",
     "prefill_keys_attended", "decode_state_rows_live",
-    "swa_keys_attended",
+    "swa_keys_attended", "state_snapshots_saved",
+    "state_snapshots_restored",
 )
 
 #: the loop ledger's flat surface: key of ``LoopLedger.flat()`` (spread

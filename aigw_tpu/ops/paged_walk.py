@@ -18,8 +18,9 @@ format picks the plan, ``models/kvq.walk_plan``):
   (rounded up to whole trips) padded past its end, walked ``N`` pairs
   a trip in one loop. ``N`` is static and follows the program's shapes
   alone (``trip_pairs``: ``_TRIP_BYTES`` over a pair's bytes, 16 on
-  qwen2); ``trips = ceil(n_pairs / N)`` is the traced loop bound, so a
-  page bucket is still one program. A trip folds each pair's (max, sum,
+  qwen2, and no fewer than ``_MIN_TRIP_PAIRS``); ``trips =
+  ceil(n_pairs / N)`` is the traced loop bound, so a page bucket is
+  still one program. A trip folds each pair's (max, sum,
   values) into float32 per-row statistics carried across trips, through
   the ``[N, B]`` one-hot of the pairs' rows: pairs of one row are
   adjacent, and a row may span trips. No sort and no block rule.
@@ -93,6 +94,8 @@ from jax.experimental.layout import Layout, with_layout_constraint
 #: K and V bytes a trip should move: enough that the loop's own
 #: bookkeeping (a few microseconds a trip) stays under the read itself
 _TRIP_BYTES = 4 << 20
+#: fewest pairs a trip of the flat walk reads (``trip_pairs``)
+_MIN_TRIP_PAIRS = 8
 
 
 class PairPlan(NamedTuple):
@@ -185,8 +188,14 @@ def walk_plan(lengths: jax.Array, n_cols: int, page_size: int,
 def trip_pairs(pair: int) -> int:
     """(row, page) pairs a trip of the flat walk reads, from the
     ``pair_bytes`` of the pool a device holds: about ``_TRIP_BYTES``
-    (and never more than the page table has, ``pair_plan``)."""
-    return max(1, _TRIP_BYTES // max(pair, 1))
+    (and never more than the page table has, ``pair_plan``), and no
+    fewer than ``_MIN_TRIP_PAIRS`` however wide a pair is. A trip is
+    some thirty device operations of its own whatever it reads (the
+    plan's slices, the fold, the loop that gathers its pages), so a
+    pool of 32 key heads under a group of one — 2 MiB a pair — walked
+    two pairs a trip was two thirds of a decode step's operations for a
+    sixth of its time (PERF.md, PR 52)."""
+    return max(_MIN_TRIP_PAIRS, _TRIP_BYTES // max(pair, 1))
 
 
 def pair_plan(lengths: jax.Array, page_table: jax.Array, page_size: int,

@@ -337,6 +337,8 @@ class XlaBucketedBackend(AttentionBackend):
                 if req.trace is not None:
                     req.trace.event("prefill_chunk", tokens=chunk,
                                     consumed=prefix_len + consumed)
+                # (a family with state snapshots may save one here)
+                eng.chunk_boundary(seq_id, prefix_len + consumed, n)
                 # interleave: active streams keep decoding between
                 # chunks (their windows overlap this chunk's compute)
                 eng._decode_tick()

@@ -15,6 +15,20 @@ The TPU-native scheduler design (not a vLLM port):
   async, so the thread overlaps host bookkeeping with device compute.
   Tokens flow back to asyncio consumers via loop.call_soon_threadsafe.
 
+- **What a family keeps beside its pages** (models/cache.py): a family
+  with per-slot state, latent pages or a window ring has everything that
+  moves pages alone switched off by what it is (``features_off``: host
+  KV tier, migration, parking, fleet fetch, speculation, LoRA — and the
+  prefix cache). A family whose recurrent state can be SNAPSHOTTED with
+  the page chain keeps the prefix cache: a prefill saves the slot's
+  state into a fixed pool (three rows a slot, ``CacheSpec.snapshot_rows``
+  — the one rule that sizes it) at every ``SNAPSHOT_EVERY_CHUNKS``-th
+  chunk boundary of its prompt and at its last, a hit resumes at the
+  deepest cached chain node that holds one, and
+  ``state_snapshots_saved`` / ``_restored`` / ``_evicted``,
+  ``state_snapshot_bytes_total`` and ``prefix_tokens_unrestorable`` say
+  what it did.
+
 Telemetry (KV occupancy, queue depth, active slots) feeds the endpoint
 picker — the reference's EPP signal (SURVEY.md §3.4).
 """
@@ -39,7 +53,7 @@ import numpy as np
 
 from aigw_tpu.analysis.registry import engine_thread_only
 from aigw_tpu.models import kvq, llama
-from aigw_tpu.models.cache import spec_of
+from aigw_tpu.models.cache import StateCache, spec_of
 from aigw_tpu.ops import paged_walk
 from aigw_tpu.obs.flight import (
     ADMIT,
@@ -65,6 +79,7 @@ from aigw_tpu.tpuserve.kvcache import (
     PageAllocator,
     PrefixCache,
     RefcountedAllocator,
+    StateSnapshots,
     page_chain_hashes,
 )
 from aigw_tpu.tpuserve.sampling import (
@@ -75,6 +90,22 @@ from aigw_tpu.tpuserve.sampling import (
 )
 
 logger = logging.getLogger(__name__)
+
+
+#: a prefill saves a snapshot of the slot's state at every this-many-th
+#: chunk boundary of its prompt, and at the last (``Engine.chunk_boundary``)
+SNAPSHOT_EVERY_CHUNKS = 4
+
+
+@dataclass
+class _SnapAdmission:
+    """What an admission in flight knows of its snapshots."""
+
+    chain_keys: list
+    #: keys this admission's prefill saved a snapshot under
+    saved: list = field(default_factory=list)
+    #: the key of the snapshot it resumed from, held until it is done
+    held: bytes | None = None
 
 
 class EngineOverloadedError(Exception):
@@ -703,6 +734,18 @@ class EngineStats:
     swa_keys_in_context: int = 0
     prefix_cache_hits: int = 0
     prefix_tokens_reused: int = 0
+    # a family whose prefix cache resumes from a SNAPSHOT of its
+    # per-slot state (models/cache.py ``CacheSpec.snapshots``; all 0
+    # elsewhere): snapshots copied into the pool at a chunk boundary of
+    # a prefill / copied back into a slot by a hit / dropped least
+    # recently used to make room for a newer one; the pool's bytes (a
+    # constant); and prompt tokens whose pages were cached but had to
+    # be prefilled again because no snapshot stood at or behind them
+    state_snapshots_saved: int = 0
+    state_snapshots_restored: int = 0
+    state_snapshots_evicted: int = 0
+    state_snapshot_bytes_total: int = 0
+    prefix_tokens_unrestorable: int = 0
     # prefix-cache surface (ISSUE 3): misses counted over page-eligible
     # prompts (≥ one full page of potential reuse), so hit_rate is
     # hits / (hits + misses) over prompts the cache could have served
@@ -983,12 +1026,17 @@ class Engine:
         # the state recurrent or a slot's sliding-window keys — nor,
         # yet, a family whose page holds one latent row a token and no
         # K and V planes, so it is off by what the family is
-        # (``CacheSpec.pinned``): prefix-cache hits (and with them the
-        # host KV tier, parking, migration and fleet fetch, which all
-        # need the content-addressed allocator), speculation (a
-        # rejected draft would need the state rolled back; no verify
-        # step reads a latent row) and LoRA. Chunked prefill stays: a
-        # chunk resumes from the slot's state, or from the rows behind it.
+        # (``CacheSpec.pinned``): the host KV tier, parking, migration
+        # and fleet fetch, speculation (a rejected draft would need the
+        # state rolled back; no verify step reads a latent row), LoRA
+        # and — but for a family whose state can be SNAPSHOTTED with
+        # the page chain (``CacheSpec.snapshots``) — prefix-cache hits.
+        # Such a family keeps the content-addressed allocator and the
+        # prefix cache, a hit resuming from a chain node that holds a
+        # snapshot (``_probe_prefix``, ``chunk_boundary``); everything
+        # else that needs that allocator asks ``_pinned`` as well.
+        # Chunked prefill stays: a chunk resumes from the slot's
+        # state, or from the rows behind it.
         self.cache_spec = spec_of(model_cfg)
         self._stateful = self.cache_spec.stateful
         why = self.cache_spec.pinned
@@ -999,6 +1047,8 @@ class Engine:
                 ("prefix_cache", "kv_host_tier", "migration",
                  "batch_parking", "kv_fleet_fetch", "speculation", "lora"),
                 why)
+            if self.cache_spec.snapshots:
+                del self.features_off["prefix_cache"]
             if lora_params or adapter_names or adapter_store is not None:
                 raise ValueError(f"LoRA serving is off: {why}")
             if mesh is not None:
@@ -1013,10 +1063,14 @@ class Engine:
             if cfg.kv_host_bytes > 0 or cfg.spec_tokens > 0:
                 logger.warning(
                     "kv_host_bytes / spec_tokens ignored: %s", why)
-        if (cfg.enable_prefix_cache and not self._pinned
+        if (cfg.enable_prefix_cache and "prefix_cache" not in self.features_off
                 and self.fns.prefill_suffix is not None):
             self.allocator = RefcountedAllocator(cfg.num_pages, cfg.page_size)
-            self.prefix_cache = PrefixCache(self.allocator, cfg.page_size)
+            self.prefix_cache = PrefixCache(
+                self.allocator, cfg.page_size,
+                StateSnapshots(self.cache_spec.snapshot_rows(
+                    cfg.max_batch_size))
+                if self.cache_spec.snapshots else None)
         else:
             self.allocator = PageAllocator(cfg.num_pages, cfg.page_size)
             self.prefix_cache = None
@@ -1028,7 +1082,8 @@ class Engine:
         # allocator — without content addressing there is nothing to
         # key the tier by.
         self.host_tier = None
-        if cfg.kv_host_bytes > 0 and self.prefix_cache is not None:
+        if (cfg.kv_host_bytes > 0 and self.prefix_cache is not None
+                and not self._pinned):
             from aigw_tpu.tpuserve.kvhost import HostKVTier
 
             self.host_tier = HostKVTier(cfg.kv_host_bytes)
@@ -1155,6 +1210,16 @@ class Engine:
         # copy-on-write page clone (full-prefix hits): one compiled
         # program regardless of src/dst ids (dynamic slice indices)
         self._copy_page_fn = None
+        # a family whose prefix cache resumes from a snapshot of its
+        # per-slot state: the bookkeeping (tpuserve/kvcache.py), the
+        # device pool and the two copy programs (``_init_snapshots``),
+        # and what each admission in flight knows of its own, by slot
+        self._snap = (self.prefix_cache.snapshots
+                      if self.prefix_cache is not None else None)
+        self._snap_pool = None
+        self._snap_admissions: dict[int, _SnapAdmission] = {}
+        if self._snap is not None:
+            self._init_snapshots()
         # migration page movers (ISSUE 8): device→host page gather and
         # host→device page scatter, each ONE compiled program for any
         # page id (dynamic indices) — pre-compiled by warmup() so an
@@ -1375,6 +1440,8 @@ class Engine:
                 _prefill_sp_suffix_step, donate_argnums=(5,))
 
         walks = not attn_impl  # the default rung: the page walk
+        state_reads = self.fns.state_reads
+        n_counts = 2 if state_reads is None else 4
 
         def _kv_pages(kv, st, act, pages, walks=walks):
             """One decode step's KV read, counted on the device:
@@ -1383,15 +1450,21 @@ class Engine:
             first IS the loops' bound — the plan returned here goes to
             the model's decode_step as ``walk`` — on the others
             (window gather, the verify step: no plan) it is the whole
-            [B, P] window they address."""
+            [B, P] window they address. A dense family with per-slot
+            state adds two more (``ModelFns.state_reads``: slots whose
+            state its live-row loops read a layer, live rows): it has
+            no routing-stats tape for them to ride, and the window's
+            one fetch of this vector brings them along."""
             lengths = jnp.where(act, st["positions"] + 1, 0)
             B, P = st["page_table"].shape
             plan = kvq.walk_plan(kv.kv if stateful else kv, lengths,
                                  st["page_table"], ps, mesh) if walks else None
             read = plan.pages_read if walks else B * P
-            return plan, pages + jnp.stack([
-                jnp.asarray(read, jnp.int32),
-                paged_walk.pages_live(lengths, ps)])
+            counts = [jnp.asarray(read, jnp.int32),
+                      paged_walk.pages_live(lengths, ps)]
+            if state_reads is not None:
+                counts += list(state_reads(kv, act))
+            return plan, pages + jnp.stack(counts)
 
         def _decode_scan(k: int, lean: bool = False):
             """Factory: k fused decode+sample steps; sampled tokens feed
@@ -1462,8 +1535,8 @@ class Engine:
                                    jnp.int32) if is_moe else None)
                 (kv, state, macc, pages), sampled = jax.lax.scan(
                     lambda c, _: body(params, lora, c),
-                    (kv, state, macc0, jnp.zeros((2,), jnp.int32)), None,
-                    length=k
+                    (kv, state, macc0, jnp.zeros((n_counts,), jnp.int32)),
+                    None, length=k
                 )
                 return sampled, _pin_state(state), kv, macc, pages
 
@@ -1587,8 +1660,8 @@ class Engine:
                                    jnp.int32) if is_moe else None)
                 (kv, state, macc, pages), out = jax.lax.scan(
                     lambda c, _: body(params, lora, c),
-                    (kv, state, macc0, jnp.zeros((2,), jnp.int32)), None,
-                    length=k_steps)
+                    (kv, state, macc0, jnp.zeros((n_counts,), jnp.int32)),
+                    None, length=k_steps)
                 return out, _pin_state(state), kv, macc, pages
 
             return scan_k
@@ -2094,8 +2167,9 @@ class Engine:
     def _refresh_kv_digest(self) -> None:
         """Engine-thread digest rebuild (throttled by _refresh_stats):
         the only thread that mutates _by_key and the host tier's key
-        set, so iteration here is race-free."""
-        if self.prefix_cache is None:
+        set, so iteration here is race-free. (A pinned family
+        publishes none: no sibling could use its pages alone.)"""
+        if self.prefix_cache is None or self._pinned:
             return
         keys = list(self.prefix_cache._by_key.keys())
         if self.host_tier is not None:
@@ -2130,7 +2204,7 @@ class Engine:
 
     @engine_thread_only
     def _do_fetch(self, keys: list) -> list:
-        if self.prefix_cache is None:
+        if self.prefix_cache is None or self._pinned:
             return []
         # the wire rule for quantized pools: pages travel at NATIVE
         # dtype + their scale blocks, bit-exactly (re-rounding through
@@ -2321,6 +2395,132 @@ class Engine:
         self._queue.put(req)
         self._wake.set()
 
+    # -- state snapshots: the prefix cache of a family with state -----------
+    def _init_snapshots(self) -> None:
+        """The snapshot pool — the family's ``slot_state`` leaves with
+        ``CacheSpec.snapshot_rows`` rows, a fixed size — and the two
+        copy programs, each ONE compiled program for any (slot, row)
+        pair (dynamic indices), compiled in warm-up. They are named as
+        prefill programs are: what they cost is part of what a prefill
+        costs, in a trace's module groups too."""
+        cfg = self.cfg
+        self._snap_pool = self.cache_spec.make_snapshots(
+            cfg.max_batch_size, cfg.kv_cache_dtype)
+        self.stats.state_snapshot_bytes_total = (
+            self._snap.n_rows
+            * self.cache_spec.state_bytes_per_slot(cfg.kv_cache_dtype))
+
+        def _move(dst, src, to, frm):
+            row = jax.lax.dynamic_slice_in_dim(src, frm, 1, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(dst, row, to, axis=1)
+
+        def _prefill_state_snapshot(pool, slots, slot, row):
+            return {k: _move(pool[k], slots[k], row, slot) for k in pool}
+
+        def _prefill_state_restore(cache, pool, row, slot):
+            return StateCache(cache.kv, {
+                k: _move(cache.slots[k], pool[k], slot, row) for k in pool})
+
+        self._snap_save_fn = self.compile_tracker.register(
+            "state_snapshot",
+            jax.jit(_prefill_state_snapshot, donate_argnums=(0,)))
+        self._snap_restore_fn = self.compile_tracker.register(
+            "state_restore",
+            jax.jit(_prefill_state_restore, donate_argnums=(0,)))
+
+    def _save_snapshot_dev(self, slot: int, row: int) -> None:
+        """Copy ``slot``'s state into pool row ``row``: dispatched
+        behind the chunk that left the state there, and the host does
+        not wait for it (the next chunk's donation of the cache orders
+        itself behind this read)."""
+        with self.stats.loop.span("engine/state_snapshot", row=row):
+            self._snap_pool = self._snap_save_fn(
+                self._snap_pool, self.kv_cache.slots, np.int32(slot),
+                np.int32(row))
+
+    def _restore_snapshot_dev(self, row: int, slot: int) -> None:
+        with self.stats.loop.span("engine/state_restore", row=row):
+            self.kv_cache = self._snap_restore_fn(
+                self.kv_cache, self._snap_pool, np.int32(row),
+                np.int32(slot))
+
+    def _probe_prefix(self, chain_keys: list, n: int
+                      ) -> tuple[list[int], int]:
+        """(pages of the longest cached prefix a prompt of ``n`` tokens
+        can resume behind, prompt tokens cached beyond it that must
+        prefill again). The second is 0 for a family whose pages are
+        all there is to a prefix; a family with snapshots resumes only
+        at a chain node that holds one, and never behind its whole
+        prompt: the last token's logits need the state BEFORE it, which
+        a snapshot at the prompt's end is not."""
+        ps = self.cfg.page_size
+        pages = self.prefix_cache.probe(chain_keys)
+        hits = min(len(pages), n // ps)
+        if self._snap is None:
+            return pages[:hits], 0
+        depth = self._snap.longest(chain_keys, min(hits, (n - 1) // ps))
+        return pages[:depth], (hits - depth) * ps
+
+    def _begin_snapshots(self, slot: int, chain_keys: list,
+                         depth: int) -> None:
+        """An admission into ``slot`` starts its prefill behind
+        ``depth`` cached pages: copy the snapshot at that chain node
+        into the slot's state rows — held, so that this prompt's own
+        saves cannot evict it, until ``_end_snapshots`` — and note the
+        chain for ``chunk_boundary``."""
+        adm = _SnapAdmission(chain_keys)
+        self._snap_admissions[slot] = adm
+        if depth:
+            adm.held = chain_keys[depth - 1]
+            self._snap.hold(adm.held)
+            self._restore_snapshot_dev(
+                self._snap.restore_row(adm.held), slot)
+            self.stats.state_snapshots_restored = self._snap.restored
+
+    def chunk_boundary(self, seq_id: int, done: int, n: int) -> None:
+        """The chunk loop has dispatched the first ``done`` tokens of
+        sequence ``seq_id``'s ``n``-token prompt. THE rule that picks
+        the boundaries a snapshot is taken at, for a family that has
+        them: every ``SNAPSHOT_EVERY_CHUNKS``-th chunk boundary of the
+        prompt (where prompts that share a long head — a system prompt
+        — part ways is unknown, so the head is covered at a fixed
+        stride) and its last whole-chunk boundary (where the next turn
+        of the same session resumes). Boundaries are multiples of the
+        chunk from the prompt's start, so a hit's suffix runs the chunk
+        partition a cold prefill runs; a chunk that is no multiple of
+        the page has no page-aligned boundary and nothing is saved."""
+        if self._snap is None:
+            return
+        slot = self._slot_of_seq[seq_id]
+        adm = self._snap_admissions.get(slot)
+        chunk, ps = self.cfg.prefill_chunk_tokens, self.cfg.page_size
+        if adm is None or chunk % ps or done % chunk or done >= n:
+            return
+        if (n - done > chunk
+                and (done // chunk) % SNAPSHOT_EVERY_CHUNKS):
+            return
+        key = adm.chain_keys[done // ps - 1]
+        row = self._snap.claim(key)
+        if row is not None:
+            adm.saved.append(key)
+            self._save_snapshot_dev(slot, row)
+            self.stats.state_snapshots_saved = self._snap.saved
+            self.stats.state_snapshots_evicted = self._snap.evicted
+
+    def _end_snapshots(self, slot: int) -> None:
+        """The admission into ``slot`` is over, installed or not: let
+        go of the snapshot it resumed from, and drop those it saved
+        whose pages never got registered (a prefill cut short: a
+        snapshot lives no longer than its chain's pages)."""
+        adm = self._snap_admissions.pop(slot, None)
+        if adm is None:
+            return
+        if adm.held is not None:
+            self._snap.release(adm.held)
+        for key in adm.saved:
+            if key not in self.prefix_cache._by_key:
+                self._snap.drop(key)
+
     def warmup(self) -> None:
         """Compile every decode-window program in the adaptive ladder —
         plain (lean + full) AND every nonzero draft rung of the
@@ -2395,6 +2595,11 @@ class Engine:
             rows = kvq.page_to_host(self._export_page_dev(0))
             for r in self._import_rungs():
                 self._import_pages_dev([0] * r, [rows] * r)
+        if self._snap is not None:
+            # both snapshot copies run on the admission path: row 0 of
+            # the (empty) pool to slot 0 and back, nothing serving yet
+            self._restore_snapshot_dev(0, 0)
+            self._save_snapshot_dev(0, 0)
         # NOTE: warm passes discard program results wholesale, so the
         # MoE routing accumulators stay at zero here — the exported
         # stats count real traffic only (folds happen at the traffic
@@ -2565,6 +2770,9 @@ class Engine:
             raise MigrationError(
                 "migration requires the prefix cache "
                 "(refcounted page allocator)")
+        if self._pinned:
+            raise MigrationError(
+                f"migration is off: {self.features_off['migration']}")
         if req.emit_lp is not None:
             raise MigrationError(
                 "logprobs sessions are not migratable")
@@ -2823,6 +3031,7 @@ class Engine:
             return True
         req = s.req
         if (not isinstance(self.allocator, RefcountedAllocator)
+                or self._pinned
                 or req.emit_lp is not None
                 or req.constraint is not None
                 or s.generated < 1):
@@ -2880,9 +3089,10 @@ class Engine:
         ``start`` offsets the chain depth the pages land at (a fleet
         fetch extends an already-resident prefix); ``source`` picks the
         counters (migration vs cross-replica fetch)."""
-        if self.prefix_cache is None:
+        if self.prefix_cache is None or self._pinned:
             raise MigrationError(
-                "migration import requires the prefix cache")
+                "migration import requires the prefix cache of a family "
+                "whose pages are all there is to a prefix")
         ps = self.cfg.page_size
         k = len(pages_data)
         if k == 0:
@@ -3446,8 +3656,8 @@ class Engine:
                 chain = req.prefix_hashes
             else:
                 chain = self.prefix_cache.chain_keys(req.prompt)
-            hits = len(self.prefix_cache.probe(chain))
-            if min(hits, n // ps) > 0:
+            hits = len(self._probe_prefix(chain, n)[0])
+            if hits > 0:
                 return False, chain
             if (self.host_tier is not None and hits < n // ps
                     and self.host_tier.contains(chain[hits])):
@@ -3613,6 +3823,8 @@ class Engine:
             for sid in [k for k, v in self._slot_of_seq.items()
                         if v == slot_idx]:
                 del self._slot_of_seq[sid]
+            if self._snap is not None:
+                self._end_snapshots(slot_idx)
 
     @engine_thread_only
     def _admit_one_reserved(self, req: GenRequest, slot_idx: int,
@@ -3635,6 +3847,7 @@ class Engine:
         cached_pages: list[int] = []
         chain_keys: list = []
         full_hit = False
+        unrestorable = 0
         if self.prefix_cache is not None and n > 1:
             chain_keys = (chain if chain is not None
                           else self.prefix_cache.chain_keys(req.prompt))
@@ -3644,10 +3857,12 @@ class Engine:
                 # BEFORE the probe — the adoption below then sees the
                 # revived pages as ordinary cached prefix
                 self._revive_chain(chain_keys)
-            hit_pages = self.prefix_cache.probe(chain_keys)
-            hits = min(len(hit_pages), n // ps)
+            # (a family with snapshots: the pages a snapshot lets it
+            # resume behind — never the whole prompt — and the tokens
+            # cached beyond them that prefill again)
+            cached_pages, unrestorable = self._probe_prefix(chain_keys, n)
+            hits = len(cached_pages)
             full_hit = hits > 0 and hits * ps == n
-            cached_pages = hit_pages[:hits]
         prefix_len = len(cached_pages) * ps
         if full_hit:
             # re-run only the last prompt token: its forward pass
@@ -3782,6 +3997,10 @@ class Engine:
             jnp.asarray([adapter_row], jnp.int32),
         )
         t0 = time.monotonic()
+        if self._snap is not None:
+            # the slot's state rows become the cached prefix's; the
+            # chunk loop's boundaries save this prompt's own
+            self._begin_snapshots(slot_idx, chain_keys, len(cached_pages))
         # pow2 page bucket covering the sequence — the gather window
         # of suffix/chunked steps, not the full max_seq_len window
         need = self.allocator.pages_for(total)
@@ -3867,6 +4086,7 @@ class Engine:
         elif chain_keys:
             # page-eligible prompt, nothing reusable cached
             self.stats.prefix_cache_misses += 1
+        self.stats.prefix_tokens_unrestorable += unrestorable
         # start token 0's host copy under the prefill's compute
         self._start_host_copy(next_tok)
         # (every branch above left the ledger in prefill_block: host
@@ -4504,9 +4724,14 @@ class Engine:
         # the window's routing-stats leaf settles with the window — a
         # dispatch-time read would sync against the running program
         self._fold_moe(w.moe, decode=True)
-        read, live = np.asarray(w.kv_pages, np.int64)
+        # (_kv_pages: pages read and live, then a dense stateful
+        # family's state rows read and live)
+        read, live, *state = np.asarray(w.kv_pages, np.int64)
         self.stats.decode_kv_pages_read += int(read)
         self.stats.decode_kv_pages_live += int(live)
+        if state:
+            self.stats.decode_state_rows_read += int(state[0])
+            self.stats.decode_state_rows_live += int(state[1])
         for seq_id in w.frees:
             self.allocator.free(seq_id)
 

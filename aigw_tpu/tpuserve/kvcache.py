@@ -292,6 +292,96 @@ class RefcountedAllocator(PageAllocator):
                    is not None)
 
 
+class StateSnapshots:
+    """Which nodes of the page chain hold a SNAPSHOT of the per-slot
+    state that goes with their pages, and in which row of the fixed
+    device pool (models/cache.py ``CacheSpec.snapshot_rows``). Host
+    bookkeeping only: the engine owns the pool and the two copy
+    programs (tpuserve/engine.py).
+
+    For a family with recurrent state a page hit without the state is
+    no hit, so the prefix cache of such a family asks here how deep a
+    cached chain can be RESUMED: at the deepest node that holds a
+    snapshot (:meth:`longest`). A snapshot lives no longer than its
+    chain's page (``PrefixCache._evicted`` drops it), the pool evicts
+    least recently used first, and a snapshot held by an admission in
+    flight (:meth:`hold`, from its restore until its prefill is done)
+    is never the victim."""
+
+    def __init__(self, n_rows: int) -> None:
+        self.n_rows = n_rows
+        #: chain key → pool row; insertion-ordered, oldest use first
+        self._row_of: dict[bytes, int] = {}
+        self._free = list(range(n_rows - 1, -1, -1))
+        self._held: dict[bytes, int] = {}
+        self.saved = 0
+        self.restored = 0
+        #: snapshots that made room for a newer one (monotonic; one
+        #: dropped with its page is the page's eviction, not counted)
+        self.evicted = 0
+
+    def __len__(self) -> int:
+        return len(self._row_of)
+
+    def __contains__(self, key: bytes) -> bool:
+        return key in self._row_of
+
+    def longest(self, keys: list[bytes], depth: int) -> int:
+        """The deepest ``d <= depth`` such that ``keys[d - 1]`` holds a
+        snapshot: how many pages of a cached chain a hit can resume
+        behind (0: none)."""
+        for d in range(min(depth, len(keys)), 0, -1):
+            if keys[d - 1] in self._row_of:
+                return d
+        return 0
+
+    def restore_row(self, key: bytes) -> int:
+        """The row to copy back into a slot; counts a use."""
+        row = self._row_of.pop(key)
+        self._row_of[key] = row  # most recently used
+        self.restored += 1
+        return row
+
+    def claim(self, key: bytes) -> int | None:
+        """The row a NEW snapshot under ``key`` is to be copied into —
+        a free one, else the least recently used that no admission
+        holds — or None: the key has one already (same chain, same
+        state: it only counts as used), or every row is held."""
+        row = self._row_of.pop(key, None)
+        if row is not None:
+            self._row_of[key] = row
+            return None
+        if self._free:
+            row = self._free.pop()
+        else:
+            victim = next((k for k in self._row_of
+                           if k not in self._held), None)
+            if victim is None:
+                return None
+            row = self._row_of.pop(victim)
+            self.evicted += 1
+        self._row_of[key] = row
+        self.saved += 1
+        return row
+
+    def drop(self, key: bytes) -> None:
+        """``key``'s page is gone (evicted, or its prefill was cut):
+        the snapshot goes with it."""
+        row = self._row_of.pop(key, None)
+        if row is not None:
+            self._free.append(row)
+
+    def hold(self, key: bytes) -> None:
+        self._held[key] = self._held.get(key, 0) + 1
+
+    def release(self, key: bytes) -> None:
+        n = self._held.get(key, 0) - 1
+        if n > 0:
+            self._held[key] = n
+        else:
+            self._held.pop(key, None)
+
+
 class PrefixCache:
     """Content-addressed map of full prompt pages → pool page ids.
 
@@ -300,9 +390,13 @@ class PrefixCache:
     prefix-caching construction, built independently for this engine).
     """
 
-    def __init__(self, allocator: "RefcountedAllocator", page_size: int):
+    def __init__(self, allocator: "RefcountedAllocator", page_size: int,
+                 snapshots: StateSnapshots | None = None):
         self.allocator = allocator
         self.page_size = page_size
+        #: a family with recurrent state: the chain nodes that hold a
+        #: snapshot of it (None: pages are all there is to a prefix)
+        self.snapshots = snapshots
         self._by_key: dict[bytes, int] = {}
         self._key_by_page: dict[int, bytes] = {}
         # chain key → the tokens that FOLLOWED that prefix last time it
@@ -400,3 +494,5 @@ class PrefixCache:
                     logger.exception("KV spill failed for page %d", page)
             self._key_by_page.pop(page, None)
             self.evictions += 1
+            if self.snapshots is not None:
+                self.snapshots.drop(key)

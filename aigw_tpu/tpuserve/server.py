@@ -2403,6 +2403,17 @@ class TPUServeServer:
                 "prefix_cache_hits": s.prefix_cache_hits,
                 "prefix_cache_misses": s.prefix_cache_misses,
                 "prefix_cache_evictions": s.prefix_cache_evictions,
+                "prefix_tokens_reused": s.prefix_tokens_reused,
+                # a family whose prefix cache resumes from a snapshot
+                # of its per-slot state (0 elsewhere): snapshots saved
+                # / restored / evicted least recently used, the pool's
+                # bytes, and tokens cached by pages that prefilled
+                # again for want of a snapshot
+                "state_snapshots_saved": s.state_snapshots_saved,
+                "state_snapshots_restored": s.state_snapshots_restored,
+                "state_snapshots_evicted": s.state_snapshots_evicted,
+                "state_snapshot_bytes_total": s.state_snapshot_bytes_total,
+                "prefix_tokens_unrestorable": s.prefix_tokens_unrestorable,
                 # speculative decoding surface: acceptance telemetry
                 # for dashboards
                 "spec_accepted": s.spec_accepted,
@@ -2556,8 +2567,8 @@ class TPUServeServer:
         Strictly best-effort: any failure falls back to cold prefill."""
         peers_hdr = request.headers.get(KV_PEERS_HEADER, "")
         eng = self.engine
-        if (not peers_hdr or not hashes
-                or eng.prefix_cache is None):
+        if (not peers_hdr or not hashes or eng.prefix_cache is None
+                or "kv_fleet_fetch" in eng.features_off):
             return
         ps = eng.cfg.page_size
         # the wire rule (PR 8): only pages whose every row is written KV

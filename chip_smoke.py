@@ -49,7 +49,13 @@ chip by its environment, behind one gateway: 64 requests, every replica
 serves some).
 
 ``--platform cpu --model tiny-random`` is the orchestrator's dry run on
-a CPU (tests/test_chip_smoke.py); it cannot pass for a TPU.
+a CPU (tests/test_chip_smoke.py); it cannot pass for a TPU. Any
+registered model serves the ``build,serve`` phases the same way — a
+family's tiny preset among them (``--model tiny-qwen3-next``,
+``tiny-axk1``, ``tiny-mimo-v2``, ``tiny-olmo-hybrid`` with
+``--no-quantize``: those families refuse ``--quantize``); the repeated
+burst then also checks that a family whose prefix cache resumes from a
+state snapshot answers it with zero compiles.
 """
 
 from __future__ import annotations

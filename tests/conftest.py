@@ -47,7 +47,8 @@ import pytest  # noqa: E402
 #: HERE, outside the benchmark's paths, by name and by nothing else. A
 #: ``benchmark`` PR folds both into the module's ``OPEN`` and deletes
 #: them (PERF.md section 7, "Left by PR 45", "Left by PR 47").
-APPENDED_OUTSIDE = {"mimo-v2.5.short-long": 47}
+APPENDED_OUTSIDE = {"mimo-v2.5.short-long": 47,
+                    "olmo-hybrid-7b.sessions": 52}
 
 
 @pytest.fixture(autouse=True)
@@ -97,3 +98,45 @@ def _manifest_as_of_the_modules_pr(request, monkeypatch):
     if getattr(mod, "__name__", "") == "test_cellbench_axk1":
         monkeypatch.setattr(mod, "json", _ManifestAsOf(
             "a.x-k1-1chip", "a.x-k1.long-both"))
+
+
+def _session_contexts(mix: dict) -> dict:
+    """The prompt lengths a ``sessions`` mix SENDS, as the
+    ``prompt_tokens`` bounds of a mix that shares nothing would state
+    them: a turn resends the system prompt and every turn before it,
+    so the shortest prompt is a session's first turn and the longest
+    its last (each message but the newest with a template's 32 tokens
+    at the most, 14 at the least around the system prompt)."""
+    sh, user, out = (mix["sharing"], mix["prompt_tokens"],
+                     mix["output_tokens"])
+    sys_len = sh["system_tokens"].get("value") or sh["system_tokens"]["max"]
+    turns = int(sh["turns"]["max"])
+    return dict(user, min=sys_len + 14 + user["min"],
+                max=sys_len + turns * user["max"]
+                + (turns - 1) * out["max"] + (2 * turns - 1) * 32)
+
+
+@pytest.fixture(autouse=True)
+def _a_sessions_mix_sends_whole_histories(request, monkeypatch):
+    """tests/cellbench/test_cellbench_manifest.py (the benchmark's own
+    file) checks a cell's lead-in tour against the program shapes its
+    mix can reach, and reads the mix's ``prompt_tokens`` as the length
+    of a prompt. In a ``sessions`` mix that key is the length of ONE
+    user message (cellbench/traffic.py ``_sessions``): the prompt is
+    the whole history. The module's ``load`` hands such a mix over with
+    the lengths it sends, and the module's assertions stay what they
+    are. A ``benchmark`` PR teaches ``_shapes``' caller the sharing
+    kind and deletes this (PERF.md section 7, "Left by PR 52")."""
+    mod = getattr(request, "module", None)
+    if getattr(mod, "__name__", "") != "test_cellbench_manifest":
+        return
+    load = mod.load
+
+    def load_as_sent(*parts):
+        doc = load(*parts)
+        if isinstance(doc, dict) and doc.get("sharing", {}).get(
+                "kind") == "sessions":
+            doc = dict(doc, prompt_tokens=_session_contexts(doc))
+        return doc
+
+    monkeypatch.setattr(mod, "load", load_as_sent)

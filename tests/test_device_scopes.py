@@ -156,7 +156,8 @@ def test_scopes_live_only_in_the_programs_and_the_ledger():
     assert found == {
         "named_scope(": {"models/axk1.py", "models/llama.py",
                          "models/mimo_v2.py", "models/mixtral.py",
-                         "models/qwen3_next.py", "ops/paged_walk.py",
+                         "models/olmo_hybrid.py", "models/qwen3_next.py",
+                         "ops/paged_walk.py",
                          "tpuserve/sampling.py"},
         "TraceAnnotation(": {"obs/flight.py"},
     }

@@ -134,10 +134,12 @@ def test_walk_at_and_past_a_page_edge(at):
     [1, PAGE * 8, 0, 1, PAGE * 3 + 1, PAGE * 8],
     [0, 0, PAGE * 5, PAGE * 8, 0, PAGE * 8],
 ], ids=["full", "ragged", "dead-first"])
-def test_walk_folds_a_row_that_spans_two_trips(lengths):
-    """The plan is handed a pair 1 MiB wide, so a trip is 4 pairs:
-    rows of 8 pages span two or three trips and share a trip with
-    their neighbours, and a row of one token is one pair among them."""
+def test_walk_folds_a_row_that_spans_two_trips(lengths, monkeypatch):
+    """The plan is handed a pair 1 MiB wide (and no floor on the pairs
+    a trip), so a trip is 4 pairs: rows of 8 pages span two or three
+    trips and share a trip with their neighbours, and a row of one
+    token is one pair among them."""
+    monkeypatch.setattr(paged_walk, "_MIN_TRIP_PAIRS", 1)
     B, P, Hkv, D = 6, 8, 2, 128
     rng = np.random.default_rng(11)
     kv, pt = _pool(rng, B, P, Hkv, D, "float32")
@@ -159,7 +161,8 @@ def test_walk_folds_a_row_that_spans_two_trips(lengths):
     (512 << 10, 8),    # mixtral-8x7b-1chip
     (128 << 10, 32),   # qwen2's pool as int8
     (1 << 10, 4096),   # a tiny pool: every pair in one trip
-    (8 << 20, 1),      # a pair wider than a trip
+    (2 << 20, 8),      # olmo-hybrid-7b-1chip: 32 stored key heads, the floor
+    (8 << 20, 8),      # a pair wider than a trip: the floor still
 ])
 def test_pairs_a_trip_follow_the_pools_shapes(pair, want):
     assert paged_walk.trip_pairs(pair) == want
